@@ -42,6 +42,9 @@ def main(argv=None) -> None:
     add_platform_arg(parser)
     args = parser.parse_args(argv)
     apply_platform(args)
+    from raft_ncup_tpu.utils.runtime import enable_compilation_cache
+
+    enable_compilation_cache()
 
     # In the reference demo, --model is the checkpoint path (demo.py:52-53)
     # and the architecture is plain raft. Keep that: if --model points at a

@@ -54,6 +54,9 @@ def main(argv=None) -> None:
     from raft_ncup_tpu.models.raft import RAFT
 
     args, model_cfg, data_cfg = parse_eval(argv)
+    from raft_ncup_tpu.utils.runtime import enable_compilation_cache
+
+    enable_compilation_cache()
     model = RAFT(model_cfg)
     variables = load_variables(model, model_cfg, args.restore_ckpt)
 

@@ -44,8 +44,8 @@ compile time (never on the hot path — a warmed call pays one dict
 read), and hands downstream consumers plain host dicts.
 
 **MFU** = achieved FLOP/s over the chip's peak. :func:`peak_flops` is
-the per-backend peak table: TPU generations from the spec sheet
-(``utils/flops.TPU_PEAK_FLOPS``), CPU from a nominal per-core figure
+the per-backend peak table: TPU chips by ``device_kind`` from the spec
+sheet (``utils/flops.TPU_PEAK_FLOPS``), CPU from a nominal per-core figure
 (overridable via ``RAFT_NCUP_CPU_PEAK_FLOPS``) so CPU rows report a
 real — if humbling — utilization instead of ``null``. ``None`` means
 the BACKEND is unknown, never "we didn't measure": the moment a chip
@@ -55,7 +55,6 @@ answers, the same code path reports real MFU with zero new code.
 from __future__ import annotations
 
 import os
-import re
 import threading
 from typing import Dict, Optional
 
@@ -82,13 +81,13 @@ _MEMORY_STAT_FIELDS = (
 
 
 def peak_flops(
-    backend: Optional[str],
-    device_kind: Optional[str] = None,
-    tpu_gen: Optional[str] = None,
+    backend: Optional[str], device_kind: Optional[str] = None
 ) -> Optional[float]:
-    """Peak dense FLOP/s per chip for a backend, ``None`` only when the
-    backend (or TPU generation) is unknown. ``tpu_gen`` wins over
-    parsing ``device_kind`` (e.g. ``"TPU v5e"``)."""
+    """Peak dense FLOP/s per chip. On ``tpu`` the lookup is keyed by the
+    real ``device_kind`` string (``utils/flops.TPU_PEAK_FLOPS``) and an
+    unknown chip raises: a silently-null ``mfu`` on the chip the project
+    targets is exactly the failure this guards. ``None`` only for a
+    backend that has no table at all."""
     if not backend:
         return None
     backend = backend.lower()
@@ -101,11 +100,12 @@ def peak_flops(
                 pass
         return (os.cpu_count() or 1) * CPU_PEAK_FLOPS_PER_CORE
     if backend == "tpu":
-        gen = (tpu_gen or "").lower()
-        if not gen and device_kind:
-            m = re.search(r"v\d+[a-z]*", device_kind.lower())
-            gen = m.group(0) if m else ""
-        return TPU_PEAK_FLOPS.get(gen)
+        if device_kind not in TPU_PEAK_FLOPS:
+            raise KeyError(
+                f"no peak FLOP/s entry for TPU device_kind {device_kind!r}; "
+                "add it to utils/flops.TPU_PEAK_FLOPS with its source"
+            )
+        return TPU_PEAK_FLOPS[device_kind]
     return None
 
 

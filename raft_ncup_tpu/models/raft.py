@@ -314,12 +314,7 @@ class RAFT:
                     )
 
         elif cfg.corr_impl == "pallas":
-            try:
-                from raft_ncup_tpu.ops.corr_pallas import corr_lookup_pallas
-            except ImportError as e:
-                raise NotImplementedError(
-                    "corr_impl='pallas' requires raft_ncup_tpu.ops.corr_pallas"
-                ) from e
+            from raft_ncup_tpu.ops.corr_pallas import corr_lookup_pallas
 
             # Dispatch is per pyramid level inside the op, THREE tiers:
             # levels whose padded slab fits the VMEM budget take the
@@ -329,12 +324,12 @@ class RAFT:
             # kernel tier), and only the remainder takes the XLA
             # on-the-fly path. Shapes are static at trace time, so this
             # is a compile-time choice.
-            # Mosaic lowers only on TPU-class backends; on non-TPU
-            # platforms the kernel runs in interpret mode (slow but
-            # correct) so corr_impl='pallas' works everywhere.
-            from raft_ncup_tpu.utils.runtime import is_tpu_class_backend
+            # Mosaic compiles for the TPU only; elsewhere the kernel
+            # runs in interpret mode (slow but correct) so
+            # corr_impl='pallas' works everywhere.
+            from raft_ncup_tpu.utils.runtime import is_tpu_backend
 
-            interpret = not is_tpu_class_backend()
+            interpret = not is_tpu_backend()
 
             def corr_fn(coords):
                 return corr_lookup_pallas(

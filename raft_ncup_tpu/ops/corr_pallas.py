@@ -11,8 +11,11 @@ the volume never exists anywhere — the §2a(a) design from SURVEY.md:
   (K+1) x (K+1) integer-aligned patch of correlations.
 - That patch is `sum_c f1[q, c] * f2[iy : iy+K+1, ix : ix+K+1, c]` — a
   dynamic-start slice of the VMEM-resident fmap2 level followed by a
-  lane reduction on the VPU. No gather, no roll, and HBM traffic is
-  fmap2 once per query block instead of a volume pass.
+  lane reduction on the VPU. No gather, and HBM traffic is fmap2 once
+  per query block instead of a volume pass. (Mosaic takes a dynamic
+  start on the sublane-tiled column dimension only when it is provably
+  tile-aligned, so the slice is the aligned window holding the patch,
+  rotated by the residue — :func:`_load_patch`.)
 
 Kernel shape (round-3 redesign; the round-2 version looped one query at a
 time with scalar work per step — VERDICT.md weak #3): queries are
@@ -33,10 +36,14 @@ side; window starts are clamped into the padded array, and any fully-OOB
 window lands entirely inside the zero margin.
 
 VMEM budget: the RESIDENT kernel keeps the whole padded level on-chip
-next to the pipeline's block buffers. The budget is derived from the
-per-core VMEM capacity (~16 MiB on current TPUs —
-/opt/skills/guides/pallas_guide.md "Memory Hierarchy"; override with
-RAFT_NCUP_VMEM_BYTES) minus the blocked operands' double buffers.
+next to the pipeline's block buffers. The budget is Mosaic's scoped
+VMEM limit (16 MiB by default, stated to the compiler as
+``vmem_limit_bytes``; override with RAFT_NCUP_VMEM_BYTES) and every
+buffer is counted as Mosaic allocates it — last two dims padded to the
+(8, 128) tile, pipelined blocks double-buffered (:func:`_block_bytes`).
+The chip's compiler refused the first, unpadded count (20.2 MB asked
+of 16 MiB); tests/test_tpu_aot_compile.py keeps gate and compiler in
+agreement at the flagship's widths.
 
 Banded tier (round-15 redesign — the correlation memory wall,
 ROADMAP item 4): levels whose padded slab exceeds the resident budget
@@ -72,12 +79,12 @@ f32, levels 0-1 (~42 MB / ~15.3 MB padded, both over the 0.9x resident
 budget) now take the BANDED kernel and levels 2-3 the resident one; at
 4K (2176x3840) every level qualifies for a kernel tier at f32 and bf16
 (exact counts pinned by tests/test_pallas_lowering.py). The XLA
-fallback remains only for jax builds without pallas-tpu and for band
-overrides that reject.
+fallback remains only for band overrides that reject; on the TPU a
+call whose every level falls back raises.
 
 Tuning knobs (the first real surface for ROADMAP item 1's autotuner;
 recorded in the cost-ledger meta via ``ops.corr.corr_tuning_meta``):
-``RAFT_NCUP_CORR_QUERY_BLOCK`` (queries per block, default 512) and
+``RAFT_NCUP_CORR_QUERY_BLOCK`` (queries per block, default 128) and
 ``RAFT_NCUP_CORR_BAND_ROWS`` (band origin rows; default: largest that
 fits the budget, multiple-of-8 preferred).
 
@@ -96,18 +103,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu provides the SMEM/VMEM memory-space constants on TPU builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = pltpu.SMEM
-except ImportError:  # pragma: no cover - CPU-only jax builds
-    pltpu = None
-    _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from raft_ncup_tpu.utils.knobs import knob_positive_int
 from raft_ncup_tpu.utils.runtime import VMEM_BYTES as _VMEM_BYTES
 
-_QUERY_BLOCK = 512
+_QUERY_BLOCK = 128
 _GROUP = 8  # queries per vectorized inner step (sublane tile)
 
 QUERY_BLOCK_ENV = "RAFT_NCUP_CORR_QUERY_BLOCK"
@@ -116,7 +117,7 @@ BAND_ROWS_ENV = "RAFT_NCUP_CORR_BAND_ROWS"
 
 def effective_query_block() -> int:
     """The query-block size both kernel tiers trace with: the
-    ``RAFT_NCUP_CORR_QUERY_BLOCK`` override when set, else 512. A
+    ``RAFT_NCUP_CORR_QUERY_BLOCK`` override when set, else 128. A
     tuning knob (ROADMAP item 1): smaller blocks shrink the
     double-buffered block term of the VMEM budget, buying band rows."""
     return knob_positive_int(QUERY_BLOCK_ENV) or _QUERY_BLOCK
@@ -188,6 +189,25 @@ def _padded_hw(h: int, w: int, radius: int) -> tuple[int, int, int]:
     return h + 2 * pad, w + 2 * pad, pad
 
 
+def _block_bytes(
+    channels: int, radius: int, query_block: int, itemsize: int
+) -> int:
+    """Bytes of VMEM both kernel tiers need beside their slab, counted
+    as Mosaic allocates them: the last two dims of every buffer padded
+    to the (8, 128) tile, pipelined blocks double-buffered. The f1 block
+    is at ``itemsize``; the frac block (Q, 2), the out block (Q, K, K)
+    and the patch scratch (G, K+1, K+1, C) are float32 — the out block's
+    (9, 9) tail pads to (16, 128), which is most of the total and why
+    the default query block is small."""
+    K, K1 = 2 * radius + 1, 2 * radius + 2
+    t8 = lambda n: -(-n // 8) * 8  # noqa: E731
+    f1 = 2 * query_block * channels * itemsize
+    frac = 2 * query_block * 128 * 4
+    out = 2 * query_block * t8(K) * 128 * 4
+    scratch = _GROUP * K1 * t8(K1) * channels * 4
+    return f1 + frac + out + scratch
+
+
 def _level_vmem_bytes(
     h: int,
     w: int,
@@ -206,11 +226,10 @@ def _level_vmem_bytes(
     if query_block is None:
         query_block = effective_query_block()
     hp, wp, _ = _padded_hw(h, w, radius)
-    K1 = 2 * radius + 2
-    slab = hp * wp * channels
-    blocks = 2 * query_block * (channels + 2 + (K1 - 1) ** 2)  # f1+frac+out, x2 pipeline
-    scratch = _GROUP * K1 * K1 * channels
-    return itemsize * (slab + blocks + scratch)
+    slab = hp * _alloc_width(wp, radius, itemsize) * channels
+    return itemsize * slab + _block_bytes(
+        channels, radius, query_block, itemsize
+    )
 
 
 def fits_vmem(
@@ -271,11 +290,11 @@ def _banded_vmem_bytes(
     if query_block is None:
         query_block = effective_query_block()
     _, wp, _ = _padded_hw(h, w, radius)
-    K1 = 2 * radius + 2
-    slab = (band_rows + _band_halo(radius)) * wp * channels
-    blocks = 2 * query_block * (channels + 2 + (K1 - 1) ** 2)
-    scratch = _GROUP * K1 * K1 * channels
-    return itemsize * (slab + blocks + scratch)
+    wpa = _alloc_width(wp, radius, itemsize)
+    slab = (band_rows + _band_halo(radius)) * wpa * channels
+    return itemsize * slab + _block_bytes(
+        channels, radius, query_block, itemsize
+    )
 
 
 def band_plan(
@@ -312,7 +331,9 @@ def band_plan(
         )
         if fixed > budget:
             return None  # blocks+scratch+halo alone blow the budget
-        per_row = itemsize * (w + 2 * (2 * radius + 3)) * channels
+        per_row = itemsize * channels * _alloc_width(
+            w + 2 * (2 * radius + 3), radius, itemsize
+        )
         band_rows = (budget - fixed) // per_row
         if band_rows < 1:
             return None
@@ -320,6 +341,53 @@ def band_plan(
         if band_rows >= 8:
             band_rows -= band_rows % 8
     return band_rows, _band_geometry(hp, radius, band_rows)[1]
+
+
+def _compiler_params():
+    # The budget the dispatch plans against, stated to the compiler
+    # instead of left to its default.
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_BYTES)
+
+
+def _sublane_tile(itemsize: int) -> int:
+    # Rows of one (sublane, lane) tile: 8 for 32-bit, 16 for bf16.
+    return 8 * (4 // itemsize)
+
+
+def _patch_cols(radius: int, itemsize: int) -> int:
+    """Columns of the tile-aligned window that is sure to hold a query's
+    K+1 patch columns whatever the residue of its start: K+1 plus a tile
+    less one, rounded up to whole tiles."""
+    a = _sublane_tile(itemsize)
+    return -(-(2 * radius + 2 + a - 1) // a) * a
+
+
+def _alloc_width(wp: int, radius: int, itemsize: int) -> int:
+    """Columns a padded level (or band slab) is allocated with: its
+    ``wp`` plus zero columns on the right, so that the aligned window of
+    the right-most clamped origin (``wp - (K+1)``) is in-bounds, rounded
+    up to whole sublane tiles."""
+    a = _sublane_tile(itemsize)
+    return -(-(wp + _patch_cols(radius, itemsize) - (2 * radius + 2)) // a) * a
+
+
+def _load_patch(ref, iy, ix, radius: int):
+    """The (K+1, K+1, C) float32 patch of ``ref`` (rows, cols, C) at the
+    dynamic origin (iy, ix). Rows are a leading, untiled dimension and
+    take any dynamic start. Columns ride the sublane tile, where Mosaic
+    accepts a dynamic start only if it is provably tile-aligned (the
+    chip's compiler refused the direct ``pl.ds(ix, K+1)`` load: "cannot
+    statically prove that index in dimension 2 is a multiple of 8"). So
+    load the aligned window that contains the patch and rotate it left
+    by the residue."""
+    K1 = 2 * radius + 2
+    itemsize = jnp.dtype(ref.dtype).itemsize
+    a = _sublane_tile(itemsize)
+    cols = _patch_cols(radius, itemsize)
+    ix0 = pl.multiple_of((ix // a) * a, a)
+    win = ref[pl.ds(iy, K1), pl.ds(ix0, cols), :].astype(jnp.float32)
+    win = pltpu.roll(win, (cols - (ix - ix0)) % cols, axis=1)
+    return win[:, :K1, :]
 
 
 def _lookup_kernel(
@@ -351,8 +419,8 @@ def _lookup_kernel(
         for g in range(G):
             ix = ibase_ref[base + g, 0]
             iy = ibase_ref[base + g, 1]
-            scratch_ref[g] = f2_ref[pl.ds(iy, K + 1), pl.ds(ix, K + 1), :]
-        patch = scratch_ref[...].astype(jnp.float32)  # (G, K+1, K+1, C)
+            scratch_ref[g] = _load_patch(f2_ref, iy, ix, radius)
+        patch = scratch_ref[...]  # (G, K+1, K+1, C) float32
         f1g = f1_ref[pl.ds(base, G), :].astype(jnp.float32)  # (G, C)
         corr = jnp.sum(patch * f1g[:, None, None, :], axis=-1)  # (G,K+1,K+1)
         fr = frac_ref[pl.ds(base, G), :]  # (G, 2)
@@ -386,7 +454,10 @@ def _lookup_one_level(
     fdt = f1.dtype
     K = 2 * radius + 1
     Hp, Wp, pad = _padded_hw(Hl, Wl, radius)
-    f2p = jnp.pad(f2l, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+    Wpa = _alloc_width(Wp, radius, jnp.dtype(fdt).itemsize)
+    f2p = jnp.pad(
+        f2l, ((0, 0), (pad, pad), (pad, pad + Wpa - Wp), (0, 0))
+    )
 
     # Window origin + sub-pixel offset per query, computed on the XLA side
     # so the kernel's SMEM operand is plain int32 indices.
@@ -405,33 +476,31 @@ def _lookup_one_level(
         ibase = jnp.pad(ibase, ((0, 0), (0, n_pad), (0, 0)))
     n_blocks = (N + n_pad) // qblk
 
-    if pltpu is None:  # pragma: no cover - jax builds without pallas-tpu
-        raise NotImplementedError(
-            "corr_lookup_pallas requires jax.experimental.pallas.tpu"
-        )
     # Integer window origins live in SMEM (the home for indices driving
     # dynamic slices); interpret mode keeps the default space since the
     # CPU interpreter has no SMEM emulation for blocked operands.
     ibase_spec = pl.BlockSpec(
         (None, qblk, 2),
         lambda b, i: (b, i, 0),
-        **({} if interpret else {"memory_space": _SMEM}),
+        **({} if interpret else {"memory_space": pltpu.SMEM}),
     )
     K1 = K + 1
 
     out = pl.pallas_call(
         functools.partial(_lookup_kernel, radius=radius),
         grid=(B, n_blocks),
-        scratch_shapes=[pltpu.VMEM((_GROUP, K1, K1, C), fdt)],
+        scratch_shapes=[pltpu.VMEM((_GROUP, K1, K1, C), jnp.float32)],
         in_specs=[
             ibase_spec,
             pl.BlockSpec((None, qblk, C), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, qblk, 2), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Hp, Wp, C), lambda b, i: (b, 0, 0, 0)),
+            pl.BlockSpec((None, Hp, Wpa, C), lambda b, i: (b, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, qblk, K, K), lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N + n_pad, K, K), jnp.float32),
         interpret=interpret,
+        compiler_params=_compiler_params(),
+        name="corr_lookup_resident",
     )(
         ibase,
         f1.astype(fdt),
@@ -511,10 +580,8 @@ def _banded_lookup_kernel(
             for g in range(G):
                 ix = ibase_ref[gbase + g, 0]
                 iy = ibase_ref[gbase + g, 1]
-                scratch_ref[g] = slab_ref[
-                    pl.ds(iy, K + 1), pl.ds(ix, K + 1), :
-                ]
-            patch = scratch_ref[...].astype(jnp.float32)
+                scratch_ref[g] = _load_patch(slab_ref, iy, ix, radius)
+            patch = scratch_ref[...]
             f1g = f1_ref[pl.ds(gbase, G), :].astype(jnp.float32)
             corr = jnp.sum(patch * f1g[:, None, None, :], axis=-1)
             fr = frac_ref[pl.ds(gbase, G), :]
@@ -562,8 +629,9 @@ def _banded_lookup_one_level(
     # first origin row) is in-bounds; the extra rows are zeros, i.e.
     # exactly the margin the clamped-origin semantics already rely on.
     extra = n_bands * band_rows + halo - Hp
+    Wpa = _alloc_width(Wp, radius, jnp.dtype(fdt).itemsize)
     f2p = jnp.pad(
-        f2l, ((0, 0), (pad, pad + extra), (pad, pad), (0, 0))
+        f2l, ((0, 0), (pad, pad + extra), (pad, pad + Wpa - Wp), (0, 0))
     ).astype(fdt)
 
     cl = coords.astype(jnp.float32) / (2.0**level)
@@ -638,14 +706,10 @@ def _banded_lookup_one_level(
     fresh = jnp.where(starts < Nq, fresh, 0)  # dummies never DMA
     tbl = jnp.stack([bnd, blk, starts, ends, fresh], axis=-1)
 
-    if pltpu is None:  # pragma: no cover - guarded by _forward dispatch
-        raise NotImplementedError(
-            "corr_lookup_pallas requires jax.experimental.pallas.tpu"
-        )
     ibase_spec = pl.BlockSpec(
         (None, qblk, 2),
         lambda b, j, t: (b, t[b, j, 1], 0),
-        **({} if interpret else {"memory_space": _SMEM}),
+        **({} if interpret else {"memory_space": pltpu.SMEM}),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -658,14 +722,14 @@ def _banded_lookup_one_level(
             pl.BlockSpec(
                 (None, qblk, 2), lambda b, j, t: (b, t[b, j, 1], 0)
             ),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # level stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # level stays in HBM
         ],
         out_specs=pl.BlockSpec(
             (None, qblk, K, K), lambda b, j, t: (b, t[b, j, 1], 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((band_rows + halo, Wp, C), fdt),
-            pltpu.VMEM((_GROUP, K1, K1, C), fdt),
+            pltpu.VMEM((band_rows + halo, Wpa, C), fdt),
+            pltpu.VMEM((_GROUP, K1, K1, C), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
     )
@@ -679,6 +743,8 @@ def _banded_lookup_one_level(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Nq, K, K), jnp.float32),
         interpret=interpret,
+        compiler_params=_compiler_params(),
+        name="corr_lookup_banded",
     )(
         tbl,
         ibase_s,
@@ -724,29 +790,16 @@ def _forward(
     outs: dict[int, jax.Array] = {}
     fallback = []
     _count("levels_total", num_levels)
-    if pltpu is None:
-        # jax builds without pallas-tpu: the kernel can't declare its VMEM
-        # scratch there even in interpret mode, so every level routes to
-        # the equivalent XLA path. Warn so benchmark rows labeled 'pallas'
-        # aren't silently measuring the fallback.
-        import warnings
-
-        warnings.warn(
-            "pallas-tpu unavailable; corr_impl='pallas' is running the "
-            "XLA onthefly fallback",
-            stacklevel=2,
-        )
     for lvl, f2l in enumerate(f2_levels):
         Hl, Wl = f2l.shape[1], f2l.shape[2]
-        if pltpu is not None and fits_vmem(Hl, Wl, C, radius, dtype=dtype):
+        if fits_vmem(Hl, Wl, C, radius, dtype=dtype):
             _count("kernel")
             outs[lvl] = _lookup_one_level(
                 f1, f2l, cflat, radius, lvl, interpret=interpret,
                 query_block=qblk,
             )
-        elif pltpu is not None and (
-            plan := band_plan(Hl, Wl, C, radius, dtype=dtype,
-                              query_block=qblk)
+        elif plan := band_plan(
+            Hl, Wl, C, radius, dtype=dtype, query_block=qblk
         ):
             _count("banded")
             outs[lvl] = _banded_lookup_one_level(
@@ -757,19 +810,23 @@ def _forward(
             _count("fallback")
             fallback.append(lvl)
     if fallback:
-        if pltpu is not None and len(fallback) == num_levels:
-            # Same mislabeled-measurement hazard as the pltpu-is-None
-            # branch above: every level rejected by BOTH kernel tiers
-            # (resident fits_vmem AND band_plan) means
-            # corr_impl='pallas' is measuring pure XLA onthefly.
+        if len(fallback) == num_levels:
+            # Every level rejected by BOTH kernel tiers (resident
+            # fits_vmem AND band_plan): corr_impl='pallas' would be pure
+            # XLA onthefly under the kernel's name. On the chip that is
+            # an error; elsewhere (interpret-mode tests) a warning.
+            from raft_ncup_tpu.utils.runtime import is_tpu_backend
+
+            msg = (
+                f"all {num_levels} corr pyramid levels exceed the VMEM "
+                "budget; corr_impl='pallas' would run the XLA onthefly "
+                "fallback for every level"
+            )
+            if is_tpu_backend():
+                raise RuntimeError(msg + " — select corr_impl='onthefly'")
             import warnings
 
-            warnings.warn(
-                f"all {num_levels} corr pyramid levels exceed the VMEM "
-                "budget; corr_impl='pallas' is running the XLA onthefly "
-                "fallback for every level",
-                stacklevel=2,
-            )
+            warnings.warn(msg, stacklevel=2)
         fb = corr_lookup_onthefly(
             fmap1, fmap2, coords, radius, num_levels, levels=tuple(fallback),
             dtype=dtype,

@@ -88,9 +88,10 @@ def nconv2d(
       impl: 'xla' (two convs + divide) or 'pallas' (fused single-pass
         kernel, raft_ncup_tpu.ops.nconv_pallas) — default comes from env
         RAFT_NCUP_NCONV_IMPL ('xla' until hardware timing proves the
-        kernel). 'pallas' silently falls back to 'xla' for unsupported
-        configurations (stride/groups/even kernels) or slabs past the
-        VMEM budget, per shape at trace time.
+        kernel). Off the TPU 'pallas' runs the XLA composition with a
+        warning (and counts a fallback); on the TPU a call outside the
+        kernel's surface (stride/groups/even kernels, rows too wide for
+        the VMEM budget) raises.
     Returns:
       (out, conf_out), both (B, H', W', Cout); SAME padding for odd kernels
       (reference pads kernel//2, core/nconv_modules.py:143-144).
@@ -100,30 +101,34 @@ def nconv2d(
     impl = impl or knob_str("RAFT_NCUP_NCONV_IMPL")
     if impl == "pallas":
         from raft_ncup_tpu.ops import nconv_pallas as npk
+        from raft_ncup_tpu.utils.runtime import is_tpu_backend
 
-        from raft_ncup_tpu.utils.runtime import is_tpu_class_backend
-
-        fused_ok = (
-            # Mosaic lowers only on TPU-class backends; cpu/gpu fall back.
-            is_tpu_class_backend()
-            and npk.supported(weight.shape, stride, groups)
-            and npk.fits_vmem(
-                data.shape[1], data.shape[2], data.shape[3],
-                weight.shape[-1], weight.shape[0],
-            )
+        on_tpu = is_tpu_backend()  # Mosaic compiles for the TPU only
+        in_surface = npk.supported(
+            weight.shape, stride, groups
+        ) and npk.fits_vmem(
+            data.shape[1], data.shape[2], data.shape[3],
+            weight.shape[-1], weight.shape[0],
         )
-        if fused_ok:
+        if on_tpu and in_surface:
             _dispatch_counts["fused"] += 1
             out, conf_out = npk.nconv2d_fused(data, conf, weight, bias, eps)
             return out, (conf_out if propagate_conf else None)
         _dispatch_counts["fallback"] += 1
+        msg = (
+            "nconv impl='pallas' cannot run the fused kernel for shape "
+            f"data={tuple(data.shape)} weight={tuple(weight.shape)} "
+            f"stride={stride} groups={groups} (on tpu: {on_tpu}, in the "
+            f"kernel's surface: {in_surface})"
+        )
+        if on_tpu:
+            # On the chip a call that reaches no kernel is an error, not
+            # XLA under the kernel's name.
+            raise RuntimeError(msg + " — use impl='xla' for this call")
         import warnings
 
         warnings.warn(
-            "nconv impl='pallas' fell back to XLA for shape "
-            f"data={tuple(data.shape)} weight={tuple(weight.shape)} "
-            f"stride={stride} groups={groups} (backend tpu-class: "
-            f"{is_tpu_class_backend()}) — measurements labeled "
+            msg + " — running the XLA composition; measurements labeled "
             "nconv=pallas did NOT run the fused kernel here",
             stacklevel=2,
         )

@@ -17,12 +17,19 @@ from __future__ import annotations
 
 from raft_ncup_tpu.config import ModelConfig
 
-# Peak dense-matmul FLOPs/s per chip (bf16), public spec-sheet numbers.
+# Peak dense-matmul FLOP/s per chip (bf16), keyed by the ``device_kind``
+# string jax reports (``jax.devices()[0].device_kind``). Source: Google
+# Cloud TPU documentation, the per-generation "System architecture" pages
+# ("TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s). A chip that is
+# not in this table is an error on platform ``tpu``, never a default.
 TPU_PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
 }
 
 
@@ -129,11 +136,3 @@ def train_step_flops(
 ) -> float:
     """Forward + backward ~= 3x forward (standard paper accounting)."""
     return 3.0 * forward_flops(cfg, batch, height, width, iters)
-
-
-def peak_flops(tpu_gen: str | None) -> float | None:
-    """Per-chip peak bf16 FLOPs/s for a TPU generation string (e.g. 'v5e'),
-    None when unknown."""
-    if not tpu_gen:
-        return None
-    return TPU_PEAK_FLOPS.get(tpu_gen.lower())

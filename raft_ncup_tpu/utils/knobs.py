@@ -65,7 +65,7 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("RAFT_NCUP_NCONV_IMPL", "str", "xla",
          "Normalized-convolution implementation: 'xla' or 'pallas' "
          "(falls back per shape when the kernel cannot lower)."),
-    Knob("RAFT_NCUP_CORR_QUERY_BLOCK", "posint", "512",
+    Knob("RAFT_NCUP_CORR_QUERY_BLOCK", "posint", "128",
          "Pallas correlation query-block size; smaller blocks buy band "
          "rows inside the VMEM budget (ROADMAP item 1 sweep surface)."),
     Knob("RAFT_NCUP_CORR_BAND_ROWS", "posint", None,
@@ -89,9 +89,6 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("RAFT_NCUP_CHAOS", "raw", None,
          "Deterministic fault-injection spec (resilience/chaos.py); "
          "the --chaos flag's env fallback."),
-    Knob("RAFT_NCUP_COMPILATION_CACHE", "flag", "0",
-         "Opt into the persistent XLA compilation cache in train.py "
-         "(accelerator hosts only; see train.py for the CPU caveat)."),
     Knob("RAFT_NCUP_TELEMETRY", "enabled", "1",
          "Process-default telemetry hub enable; '0' creates the "
          "default hub disabled."),

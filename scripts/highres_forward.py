@@ -101,7 +101,9 @@ def main(argv=None) -> int:
         mesh_fingerprint,
     )
     from raft_ncup_tpu.parallel.step import make_eval_step
+    from raft_ncup_tpu.utils.runtime import enable_compilation_cache
 
+    enable_compilation_cache()
     h, w = args.size
     if (h // 8) % args.spatial:
         raise SystemExit(

@@ -638,6 +638,9 @@ def main(argv=None) -> int:
     from raft_ncup_tpu.cli import apply_platform
 
     apply_platform(args)
+    from raft_ncup_tpu.utils.runtime import enable_compilation_cache
+
+    enable_compilation_cache()
 
     from evaluate import load_variables
     from raft_ncup_tpu.cli import model_config_from_args, serve_config_from_args

@@ -40,13 +40,9 @@ def main() -> None:
         + " --xla_force_host_platform_device_count=2"
     ).strip()
 
-    from raft_ncup_tpu.utils.runtime import (
-        enable_compilation_cache,
-        force_platform,
-    )
+    from raft_ncup_tpu.utils.runtime import force_platform
 
     force_platform("cpu")
-    enable_compilation_cache()  # repeat suite runs hit warm executables
 
     import jax
     import numpy as np

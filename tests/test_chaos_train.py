@@ -238,10 +238,10 @@ def test_child_process_external_sigterm_kill_resume(tmp_path):
     to an uninterrupted child run."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    # NOT opting into RAFT_NCUP_COMPILATION_CACHE here: this host's XLA
-    # CPU cache entries have produced glibc heap corruption on reload
-    # (observed as SIGABRT in the resumed child). Cold compiles are
-    # slower but deterministic.
+    # No persistent compile cache on the CPU backend (utils/runtime.py):
+    # this host's XLA CPU cache entries have produced glibc heap
+    # corruption on reload (observed as SIGABRT in the resumed child).
+    # Cold compiles are slower but deterministic.
 
     def spawn(name, extra):
         return subprocess.Popen(

@@ -125,9 +125,6 @@ class TestPallasLookup:
         must equal the pure XLA path."""
         from raft_ncup_tpu.ops import corr_pallas as cpk
 
-        if cpk.pltpu is None:
-            pytest.skip("pallas-tpu unavailable; dispatch loop can't "
-                        "take the kernel branch")
         fmap1, fmap2 = setup()
         coords = coords_grid(B, H, W) + 0.25
         ref = corr_lookup(
@@ -164,13 +161,25 @@ class TestPallasLookup:
         measures XLA — a warning must say so."""
         from raft_ncup_tpu.ops import corr_pallas as cpk
 
-        if cpk.pltpu is None:
-            pytest.skip("pallas-tpu unavailable; pltpu-None branch warns")
         fmap1, fmap2 = setup()
         coords = coords_grid(B, H, W)
         monkeypatch.setattr(cpk, "fits_vmem", lambda *a, **k: False)
         monkeypatch.setattr(cpk, "band_plan", lambda *a, **k: None)
         with pytest.warns(UserWarning, match="onthefly fallback for every"):
+            cpk.corr_lookup_pallas(fmap1, fmap2, coords, RADIUS, LEVELS, True)
+
+    def test_all_levels_fallback_raises_on_tpu(self, monkeypatch):
+        """On the chip the same zero-kernel dispatch is an error: the
+        'pallas' label must not measure XLA there."""
+        from raft_ncup_tpu.ops import corr_pallas as cpk
+        from raft_ncup_tpu.utils import runtime
+
+        fmap1, fmap2 = setup()
+        coords = coords_grid(B, H, W)
+        monkeypatch.setattr(runtime, "is_tpu_backend", lambda: True)
+        monkeypatch.setattr(cpk, "fits_vmem", lambda *a, **k: False)
+        monkeypatch.setattr(cpk, "band_plan", lambda *a, **k: None)
+        with pytest.raises(RuntimeError, match="corr_impl='onthefly'"):
             cpk.corr_lookup_pallas(fmap1, fmap2, coords, RADIUS, LEVELS, True)
 
     def test_banded_tier_dispatch_matches_onthefly(self, monkeypatch):
@@ -180,8 +189,6 @@ class TestPallasLookup:
         from raft_ncup_tpu.ops import corr_pallas as cpk
         from raft_ncup_tpu.ops.corr import corr_lookup_onthefly
 
-        if cpk.pltpu is None:
-            pytest.skip("pallas-tpu unavailable")
         fmap1, fmap2 = setup()
         g = np.random.default_rng(7)
         coords = coords_grid(B, H, W) + jnp.asarray(
@@ -386,8 +393,6 @@ class TestBandedLookup:
         reference — the op stays trainable at banded shapes."""
         from raft_ncup_tpu.ops import corr_pallas as cpk
 
-        if cpk.pltpu is None:
-            pytest.skip("pallas-tpu unavailable")
         fmap1, fmap2 = setup()
         coords = coords_grid(B, H, W) + 0.3
         monkeypatch.setattr(cpk, "fits_vmem", lambda *a, **k: False)

@@ -619,9 +619,10 @@ class TestCostLedger:
         from raft_ncup_tpu.inference import costs
 
         assert costs.peak_flops("cpu") > 0
-        assert costs.peak_flops("tpu", tpu_gen="v5e") == 197e12
+        assert costs.peak_flops("tpu", device_kind="TPU v5 lite") == 197e12
         assert costs.peak_flops("tpu", device_kind="TPU v4") == 275e12
-        assert costs.peak_flops("tpu", device_kind="weird") is None
+        with pytest.raises(KeyError, match="weird"):
+            costs.peak_flops("tpu", device_kind="weird")
         assert costs.peak_flops("quantum") is None
         assert costs.peak_flops(None) is None
         assert costs.mfu(1e9, 3.0, 48e9) == pytest.approx(0.0625)

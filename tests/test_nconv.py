@@ -192,8 +192,27 @@ class TestFusedNConvPallas:
         assert npk.supported((5, 5, 1, 2), stride=1, groups=1)
         assert not npk.supported((5, 5, 1, 2), stride=2, groups=1)
         assert not npk.supported((4, 4, 1, 2), stride=1, groups=1)
+        # Row-tiled: the footprint follows the row WIDTH, not the image
+        # height, so 1080p and 4K planes are admitted and only a row too
+        # wide for one strip is rejected (the chip's compiler agrees:
+        # tests/test_tpu_aot_compile.py).
         assert npk.fits_vmem(368, 768, 1, 2, 5)
-        assert not npk.fits_vmem(1088, 1920, 1, 2, 5)
+        assert npk.fits_vmem(1088, 1920, 1, 2, 5)
+        assert npk.fits_vmem(2176, 3840, 4, 2, 3)
+        assert not npk.fits_vmem(64, 8192, 2, 2, 5)
+        assert not npk.supported((11, 11, 1, 1), stride=1, groups=1)
+
+    def test_pallas_raises_on_tpu_outside_the_surface(self, monkeypatch):
+        """On the chip a 'pallas' call that reaches no kernel is an
+        error, never XLA under the kernel's name."""
+        from raft_ncup_tpu.ops import nconv
+        from raft_ncup_tpu.utils import runtime
+
+        monkeypatch.setattr(runtime, "is_tpu_backend", lambda: True)
+        x = jnp.ones((1, 8, 8, 1), jnp.float32)
+        w = jnp.ones((3, 3, 1, 1), jnp.float32)
+        with pytest.raises(RuntimeError, match="use impl='xla'"):
+            nconv.nconv2d(x, x, w, stride=2, impl="pallas")
 
     def test_channel_count_gate(self):
         """VERDICT r3 #3: the kernel body unrolls cout*k*k*cin Python
@@ -218,9 +237,10 @@ class TestFusedNConvPallas:
             jnp.asarray(g.normal(size=(5, 5, 1, 2)), jnp.float32)
         )
         nconv.reset_dispatch_counts()
-        # CPU backend is not TPU-class, so 'pallas' must fall back, warn,
-        # and still produce the XLA result.
-        with pytest.warns(UserWarning, match="fell back to XLA"):
+        # Off the TPU 'pallas' must run the XLA composition, warn, count
+        # a fallback and still produce the XLA result (on the TPU the
+        # same call raises instead — test_pallas_raises_on_tpu).
+        with pytest.warns(UserWarning, match="cannot run the fused kernel"):
             out, conf_out = nconv.nconv2d(data, conf, weight, impl="pallas")
         counts = nconv.dispatch_counts()
         assert counts == {"fused": 0, "fallback": 1}

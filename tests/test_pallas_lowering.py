@@ -5,8 +5,9 @@ the math; these tests prove the kernels survive the Pallas -> Mosaic
 MLIR conversion for a real TPU lowering target (`lowering_platforms=
 ("tpu",)` runs that conversion on any host) — the layer where dynamic
 `pl.ds` slices, SMEM operands, and scratch shapes typically fail
-(VERDICT r3 weak #4). The remaining hardware-gated step is only the
-Mosaic -> TPU binary compile + execution, covered by tests_tpu/.
+(VERDICT r3 weak #4). One layer further down — the Mosaic -> TPU
+binary compile, where every refusal of the first bring-up happened —
+is tests/test_tpu_aot_compile.py; execution is chip_smoke.py.
 
 Shapes mirror the real workloads: the Sintel fine-tune crop's 1/8-res
 feature maps for the corr lookup, full-res 1-2 channel NCUP convs for
@@ -22,11 +23,6 @@ from raft_ncup_tpu.ops import corr_pallas as cpk
 from raft_ncup_tpu.ops.geometry import coords_grid
 from raft_ncup_tpu.ops.nconv import positivity
 from raft_ncup_tpu.ops.nconv_pallas import nconv2d_fused
-
-pytestmark = pytest.mark.skipif(
-    cpk.pltpu is None, reason="pallas-tpu unavailable in this jax build"
-)
-
 
 @pytest.fixture(autouse=True)
 def _pin_vmem_budget(monkeypatch):
@@ -169,7 +165,7 @@ class TestFullModelLowering:
         # The model and nconv2d gate Mosaic on the *current* backend;
         # pretend it is TPU-class so the lowered graph takes the real
         # kernel paths (interpret=False) rather than the interpreter.
-        monkeypatch.setattr(runtime, "is_tpu_class_backend", lambda: True)
+        monkeypatch.setattr(runtime, "is_tpu_backend", lambda: True)
         monkeypatch.setenv("RAFT_NCUP_NCONV_IMPL", "pallas")
 
         model = get_model(

@@ -159,14 +159,17 @@ class TestPolicySemantics:
 
 
 class TestFitsVmemItemsize:
-    def test_bytes_scale_exactly_with_itemsize(self):
+    def test_bytes_shrink_with_itemsize(self):
         from raft_ncup_tpu.ops.corr_pallas import _level_vmem_bytes
 
+        # The slab and the f1 blocks halve at bf16; the frac/out blocks
+        # and the patch scratch are float32 whatever the policy, and the
+        # bf16 sublane tile is 16 rows, so the total lands between half
+        # and all of the f32 figure — counted as Mosaic allocates it.
         for h, w, c in ((46, 96, 256), (135, 240, 256), (17, 33, 128)):
-            assert (
-                2 * _level_vmem_bytes(h, w, c, 4, itemsize=2)
-                == _level_vmem_bytes(h, w, c, 4, itemsize=4)
-            )
+            b16 = _level_vmem_bytes(h, w, c, 4, itemsize=2)
+            f32 = _level_vmem_bytes(h, w, c, 4, itemsize=4)
+            assert f32 / 2 < b16 < f32
 
     def test_bf16_doubles_the_onchip_threshold(self):
         """The dispatch-threshold contract: scanning level heights, the
@@ -193,20 +196,19 @@ class TestFitsVmemItemsize:
         assert not fits_vmem(band_h, 2 * band_h, c, r)
         assert fits_vmem(band_h, 2 * band_h, c, r, dtype=jnp.bfloat16)
 
-    def test_banded_budget_scales_exactly_with_itemsize(self):
+    def test_banded_budget_shrinks_with_itemsize(self):
         """The band-budget extension of the itemsize contract: the
         BANDED tier's VMEM bytes (_banded_vmem_bytes — single-buffered
-        band slab + query blocks + scratch) halve exactly at bf16, for
-        any band geometry."""
+        band slab + query blocks + scratch) shrink at bf16 by the slab
+        and f1 terms, for any band geometry."""
         from raft_ncup_tpu.ops.corr_pallas import _banded_vmem_bytes
 
         for h, w, c, br in (
             (136, 240, 256, 8), (272, 480, 256, 8), (68, 120, 128, 32),
         ):
-            assert (
-                2 * _banded_vmem_bytes(h, w, c, 4, br, itemsize=2)
-                == _banded_vmem_bytes(h, w, c, 4, br, itemsize=4)
-            )
+            b16 = _banded_vmem_bytes(h, w, c, 4, br, itemsize=2)
+            f32 = _banded_vmem_bytes(h, w, c, 4, br, itemsize=4)
+            assert f32 / 2 < b16 < f32
 
     def test_bf16_buys_wider_bands(self):
         """Threshold ratio at the banded tier: bf16 halves the per-row
@@ -228,8 +230,6 @@ class TestFitsVmemItemsize:
         (trace-time dispatch counts; interpret mode, no TPU needed)."""
         from raft_ncup_tpu.ops import corr_pallas as cp
 
-        if cp.pltpu is None:
-            pytest.skip("pallas-tpu unavailable in this jax build")
         rng = np.random.default_rng(5)
         B, H, W, C = 1, 8, 8, 16
         f1 = jnp.asarray(rng.normal(size=(B, H, W, C)), jnp.float32)

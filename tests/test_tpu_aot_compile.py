@@ -1,0 +1,180 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+*described* v5e (on-chip-measurement guide, section 2). Interpret-mode
+tests guard the kernels' math and tests/test_pallas_lowering.py stops at
+Pallas -> Mosaic MLIR; every refusal the first chip bring-up met (a
+dynamic sublane start Mosaic could not prove aligned, a scoped-VMEM
+overflow the dispatch gate had admitted) happens one layer further down,
+in the compile these tests run. Nothing executes; a pass is not a chip
+run.
+
+All of it lives in this ONE file: the worker that runs it loads libtpu
+and keeps its lock until it exits, so a second file on another xdist
+worker could describe no topology. The topology is described inside a
+fixture, never at import, and every compile happens in the test's own
+process. Each case was probed by hand first and compiles (or is gated
+off) within seconds; the whole file is ~80 s.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from raft_ncup_tpu.ops import corr_pallas as cpk
+from raft_ncup_tpu.ops import nconv_pallas as npk
+
+C, RADIUS, LEVELS = 256, 4, 4  # flagship fnet width and lookup geometry
+
+# Every distinct nconv2d site (H, W, k, Cin, Cout) the flagship NCUP
+# stack issues for a 368x768 frame (enumerated by tracing the forward).
+NCUP_SITES_368x768 = [
+    (368, 768, 5, 1, 2),
+    (368, 768, 5, 2, 2),
+    (184, 384, 5, 2, 2),
+    (368, 768, 3, 4, 2),
+    (368, 768, 1, 2, 1),
+]
+# More shapes the gate admits: the eval frame's sites and a 1080p plane.
+NCONV_ADMITTED = [
+    (440, 1024, 5, 2, 2),
+    (220, 512, 5, 2, 2),
+    (1088, 1920, 5, 2, 2),
+]
+# A row too wide for one strip: the gate must say no (the compiler does
+# too, after a minute — which is why the gate's answer is what is tested).
+NCONV_REJECTED = (64, 8192, 5, 2, 2)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it off around these.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def _compile_corr(sds, h, w, dtype):
+    """corr_lookup_pallas over the whole 4-level pyramid of an (h, w)
+    1/8-resolution feature map; returns (dispatch tally, compiled text)."""
+    cpk.reset_dispatch_counts()
+    fn = jax.jit(
+        lambda a, b, c: cpk.corr_lookup_pallas(
+            a, b, c, RADIUS, LEVELS, False, dtype
+        )
+    )
+    feat = sds((1, h, w, C))
+    text = fn.lower(feat, feat, sds((1, h, w, 2))).compile().as_text()
+    return cpk.dispatch_counts(), text
+
+
+def _compile_nconv(sds, h, w, k, cin, cout):
+    fn = jax.jit(
+        lambda d, c, wt, b: npk.nconv2d_fused(d, c, wt, b, 1e-20, False)
+    )
+    plane = sds((2, h, w, cin))
+    return fn.lower(
+        plane, plane, sds((k, k, cin, cout)), sds((cout,))
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_corr_resident_kernel_compiles_at_the_sintel_crop(sds, dtype):
+    """46x96x256 is the 368x768 crop at 1/8 resolution: every level fits
+    the resident tier, and all four compile to Mosaic calls."""
+    tiers, text = _compile_corr(sds, 46, 96, dtype)
+    assert tiers == {
+        "kernel": 4, "banded": 0, "fallback": 0, "levels_total": 4,
+    }
+    assert text.count("tpu_custom_call") == 4
+
+
+def test_corr_banded_kernel_compiles_at_1080p(sds):
+    """136x240x256 (1088x1920 / 8): levels 0-1 exceed residency and take
+    the banded kernel, 2-3 stay resident; nothing falls back to XLA."""
+    tiers, text = _compile_corr(sds, 136, 240, jnp.float32)
+    assert tiers == {
+        "kernel": 2, "banded": 2, "fallback": 0, "levels_total": 4,
+    }
+    assert text.count("tpu_custom_call") == 4
+
+
+@pytest.mark.parametrize("h,w", [(55, 128), (23, 48)],
+                         ids=["eval440x1024", "quarter"])
+def test_corr_gate_admitted_levels_compile(sds, h, w):
+    """Gate truthfulness for the correlation tiers: whatever mix of
+    resident and banded levels fits_vmem / band_plan choose at a shape,
+    every level they admit compiles (55x128 is chip_smoke's eval frame)."""
+    tiers, text = _compile_corr(sds, h, w, jnp.float32)
+    assert tiers["fallback"] == 0
+    assert text.count("tpu_custom_call") == tiers["kernel"] + tiers["banded"]
+
+
+@pytest.mark.parametrize("site", NCUP_SITES_368x768,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_nconv_fused_compiles_at_every_ncup_site(sds, site):
+    h, w, k, cin, cout = site
+    assert npk.supported((k, k, cin, cout), 1, 1)
+    assert npk.fits_vmem(h, w, cin, cout, k)
+    assert "tpu_custom_call" in _compile_nconv(sds, *site)
+
+
+def test_nconv_gate_rejects_a_row_too_wide_for_one_strip():
+    h, w, k, cin, cout = NCONV_REJECTED
+    assert npk.supported((k, k, cin, cout), 1, 1)
+    assert not npk.fits_vmem(h, w, cin, cout, k)
+
+
+@pytest.mark.parametrize("site", NCONV_ADMITTED,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_nconv_gate_admitted_shapes_compile(sds, site):
+    """Gate truthfulness for the fused NConv: admitted => compiles."""
+    h, w, k, cin, cout = site
+    assert npk.fits_vmem(h, w, cin, cout, k)
+    assert "tpu_custom_call" in _compile_nconv(sds, *site)
+
+
+def test_flagship_eval_forward_compiles_for_v5e(sds):
+    """The program chip_smoke's eval phase runs: raft_nc_dbl test mode,
+    1x440x1024 (a padded Sintel frame), 32 iterations, default XLA
+    paths, float32 — and it fits one chip's 16 GB with room."""
+    from raft_ncup_tpu.config import flagship_config
+    from raft_ncup_tpu.models.raft import RAFT
+
+    model = RAFT(flagship_config(dataset="sintel"))
+    variables = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), (1, 64, 96, 3))
+        ),
+    )
+    img = sds((1, 440, 1024, 3))
+    compiled = jax.jit(
+        lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
+    ).lower(variables, img, img).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30

@@ -67,19 +67,13 @@ def main(argv=None) -> int:
 
     args, model_cfg, train_cfg, data_cfg = parse_train(argv)
     initialize_distributed()  # no-op off-pod; wires processes on a pod
-    from raft_ncup_tpu.utils.knobs import knob_flag
+    # Persistent XLA cache (utils/runtime.py has the one rule): on an
+    # accelerator a kill/resume cycle hits warm executables, so resume
+    # costs restore latency, not a multi-minute recompile. After the
+    # distributed init: the rule asks jax for its backend.
+    from raft_ncup_tpu.utils.runtime import enable_compilation_cache
 
-    if knob_flag("RAFT_NCUP_COMPILATION_CACHE"):
-        # Persistent XLA cache: kill/resume cycles hit warm executables
-        # (resume overhead = restore latency, not a recompile). Opt-in
-        # by env and OFF by default: on the CPU CI host, reloading cache
-        # entries for the fwd+bwd train program has produced glibc heap
-        # corruption in this jax build (both in-process re-enables and
-        # child reloads) — use on accelerator hosts, where the cache is
-        # the difference between seconds and minutes of resume.
-        from raft_ncup_tpu.utils.runtime import enable_compilation_cache
-
-        enable_compilation_cache()
+    enable_compilation_cache()
     np.random.seed(train_cfg.seed)  # reference: train.py:345-346
     chaos = ChaosSpec.parse(args.chaos)
 
@@ -205,6 +199,18 @@ def main(argv=None) -> int:
 
     step_fn = make_train_step(model, train_cfg, mesh=mesh)
     schedule = build_schedule(train_cfg)
+    if not multihost:
+        # Commit the state to where the step leaves its output. A fresh
+        # (uncommitted) state and the step's own committed output are two
+        # jit signatures, and the train program was compiled once for
+        # each: ~4 extra minutes at every start on the chip (first chip
+        # run, PR 21: 172 compiles, 2 x ~245 s in one trainer).
+        from raft_ncup_tpu.parallel.mesh import replicated
+
+        state = jax.device_put(
+            state,
+            replicated(mesh) if mesh is not None else jax.devices()[0],
+        )
     # Batch shardings feed the device prefetcher on every mesh run (not
     # just multihost): single-process device_put straight into the step's
     # input layout means jit dispatch never re-lays-out the batch.
