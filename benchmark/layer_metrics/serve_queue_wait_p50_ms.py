@@ -1,0 +1,6 @@
+"""``serve_queue_wait_p50_ms``: median of the program's ``serve_queue_wait`` span over the
+window (bucketed histogram of the span tracer, ``FlowServer.report()``)."""
+
+
+def read(run: dict):
+    return run["report"].get("stages", {}).get("serve_queue_wait", {}).get("p50_ms")
