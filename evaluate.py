@@ -46,11 +46,6 @@ def load_variables(model, model_cfg, restore_ckpt: str | None):
 
 def main(argv=None) -> None:
     from raft_ncup_tpu.cli import parse_eval
-    from raft_ncup_tpu.evaluation import (
-        VALIDATORS,
-        create_kitti_submission,
-        create_sintel_submission,
-    )
     from raft_ncup_tpu.models.raft import RAFT
 
     args, model_cfg, data_cfg = parse_eval(argv)
@@ -79,6 +74,19 @@ def main(argv=None) -> None:
         from raft_ncup_tpu.parallel.mesh import make_mesh
 
         mesh = make_mesh(data=1, spatial=args.spatial_parallel)
+
+    from raft_ncup_tpu.utils.profiling import trace
+
+    with trace(args.trace_dir):
+        _evaluate(args, model, variables, data_cfg, mesh)
+
+
+def _evaluate(args, model, variables, data_cfg, mesh) -> None:
+    from raft_ncup_tpu.evaluation import (
+        VALIDATORS,
+        create_kitti_submission,
+        create_sintel_submission,
+    )
 
     iters_kw = {"iters": args.iters} if args.iters is not None else {}
     val_kw = dict(iters_kw)

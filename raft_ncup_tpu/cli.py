@@ -250,6 +250,11 @@ def add_serve_args(parser: argparse.ArgumentParser) -> None:
                         "executable set compiles under "
                         "(docs/PRECISION.md); part of every compiled-"
                         "program key. Default: inherit the model's policy")
+    parser.add_argument("--trace_dir", default=None, metavar="DIR",
+                        help="capture a jax.profiler trace of the replay "
+                        "(after warmup, through the drain) into DIR: the "
+                        "program's spans lie on the device's clock; reduce "
+                        "with scripts/device_trace_report.py DIR")
 
 
 def serve_config_from_args(args: argparse.Namespace) -> ServeConfig:
@@ -553,6 +558,11 @@ def build_eval_parser() -> argparse.ArgumentParser:
                         help="validation batch-size override (default "
                         "keeps each validator's preset); frames group "
                         "per padded shape, short groups on shape change")
+    parser.add_argument("--trace_dir", default=None, metavar="DIR",
+                        help="capture a jax.profiler trace of the whole "
+                        "validation or submission into DIR: the program's "
+                        "spans lie on the device's clock; reduce with "
+                        "scripts/device_trace_report.py DIR")
     add_model_args(parser)
     add_data_args(parser)
     add_platform_arg(parser)

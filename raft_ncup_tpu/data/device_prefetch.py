@@ -27,6 +27,17 @@ Contracts:
   worker even while it is blocked on a full queue, joins it, and closes
   the wrapped iterator. Safe to call more than once.
 
+Spans (docs/OBSERVABILITY.md), shared by the train and the eval loop, on
+the process hub unless one is passed, each with the batch's index
+``batch`` and the caller's ``span_attrs`` (the eval pass's ``pass_id``):
+``input_stage`` (worker thread: the wait for the wrapped iterator's next
+host batch — decode wait, pad, stack), ``input_h2d`` (worker thread: the
+transfer, waited for until the batch is resident) and ``input_wait``
+(consumer thread: how long ``next()`` waited for a staged batch — the
+only one of the three on the critical path).
+The wait that ends in exhaustion records nothing, so each count is the
+count of batches.
+
 Transfer policy lives in :func:`raft_ncup_tpu.parallel.multihost.
 device_put_batch`: ``jax.device_put`` against the batch sharding on the
 single-process path, ``jax.make_array_from_process_local_data`` on a pod.
@@ -38,6 +49,11 @@ import queue
 import sys
 import threading
 from typing import Any, Iterable, Iterator, Mapping, Optional
+
+import jax
+
+from raft_ncup_tpu.observability import get_telemetry
+from raft_ncup_tpu.utils.profiling import annotate_spans
 
 # Queue sentinel: the wrapped iterator was exhausted (finite iterators —
 # FlowLoader.batches() is infinite, but tests and epoch-bounded consumers
@@ -64,6 +80,9 @@ class DevicePrefetcher:
     drop_keys:
         Batch keys removed before transfer (non-array metadata such as
         ``extra_info``).
+    telemetry / span_attrs:
+        The hub the ``input_*`` spans go to (``None``: the process hub)
+        and host-scalar attributes every one of them carries.
     """
 
     def __init__(
@@ -74,9 +93,15 @@ class DevicePrefetcher:
         mesh=None,
         shardings: Optional[dict] = None,
         drop_keys: tuple[str, ...] = ("extra_info",),
+        telemetry=None,
+        span_attrs: Optional[Mapping[str, Any]] = None,
     ):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
+        self._tel = telemetry if telemetry is not None else get_telemetry()
+        annotate_spans(self._tel)
+        self._span_attrs = dict(span_attrs or {})
+        self._taken = 0  # consumer side: batches handed out
         self._it = iter(batches)
         self._mesh = mesh
         self._shardings = shardings
@@ -109,14 +134,27 @@ class DevicePrefetcher:
 
     def _worker(self) -> None:
         try:
+            index = 0
             while not self._stop.is_set():
-                try:
-                    batch = next(self._it)
-                except StopIteration:
-                    self._put(_END)
+                attrs = {"batch": index, **self._span_attrs}
+                with self._tel.span("input_stage", **attrs) as span:
+                    try:
+                        batch = next(self._it)
+                    except StopIteration:
+                        span.discard()
+                        self._put(_END)
+                        return
+                with self._tel.span("input_h2d", **attrs):
+                    # device_put only enqueues the copy (2 ms for 115 MB on
+                    # a v5e, PERF.md PR 24). Waiting for it here, off the
+                    # critical path, makes the span the copy's own time and
+                    # hands the consumer a batch that is really resident.
+                    device_batch = jax.block_until_ready(
+                        self._transfer(batch)
+                    )
+                if not self._put(device_batch):
                     return
-                if not self._put(self._transfer(batch)):
-                    return
+                index += 1
         except BaseException as e:  # noqa: BLE001 — surfaced to consumer
             self._put(e)
         finally:
@@ -142,6 +180,18 @@ class DevicePrefetcher:
         return self
 
     def __next__(self) -> dict:
+        with self._tel.span(
+            "input_wait", batch=self._taken, **self._span_attrs
+        ) as span:
+            try:
+                item = self._next_item()
+            except BaseException:
+                span.discard()  # exhausted, closed or failed: not a batch
+                raise
+        self._taken += 1
+        return item
+
+    def _next_item(self) -> dict:
         while True:
             if self._stop.is_set():
                 raise StopIteration

@@ -46,6 +46,7 @@ from raft_ncup_tpu.inference.pipeline import (
 )
 from raft_ncup_tpu.io import write_flo, write_flow_kitti
 from raft_ncup_tpu.models.raft import RAFT
+from raft_ncup_tpu.observability import get_telemetry, new_span_id
 from raft_ncup_tpu.ops import InputPadder
 from raft_ncup_tpu.ops.warmstart import forward_interpolate_batch
 from raft_ncup_tpu.parallel.multihost import (
@@ -165,6 +166,7 @@ def _run_metric_pass(
     band_fn=None,
     num_workers: int = 4,
     depth: int = 2,
+    telemetry=None,
 ) -> np.ndarray:
     """One validation pass: stream ``dataset`` through the
     double-buffered :class:`EvalPipeline`, folding every batch into an
@@ -179,7 +181,18 @@ def _run_metric_pass(
     ``band_fn`` (epe_band only) computes the host-side boundary mask
     during staging. Returns the host accumulator (float32 sums, ready
     for ``allreduce_sum_across_hosts`` + ``metrics.finalize``).
+
+    Spans, on ``telemetry`` (None: the process hub), all with this pass's
+    ``pass_id`` and the batch's index: the pipeline's ``input_wait`` /
+    ``input_stage`` / ``input_h2d`` (data/device_prefetch.py), then per
+    batch ``eval_dispatch`` (the jit dispatch) and ``eval_throttle_wait``
+    (the bounded wait for an earlier batch), and once ``eval_pull`` (the
+    pass's one pull, which waits for the device to finish). The counter
+    ``eval_pairs_total`` grows by the batch's pairs where
+    ``eval_dispatch`` closes.
     """
+    tel = telemetry if telemetry is not None else get_telemetry()
+    pass_id = new_span_id()
     divisor = _pad_divisor(mesh)
 
     def stage(group: list) -> tuple:
@@ -235,14 +248,22 @@ def _run_metric_pass(
         num_workers=num_workers,
         mesh=mesh,
         shardings=shardings,
+        telemetry=tel,
+        span_attrs={"pass_id": pass_id},
     ) as pipe:
-        for batch, meta in pipe:
-            acc = fwd.metrics(
-                batch, iters=iters, acc=acc, kind=kind, pad=meta["pad"]
-            )
-            throttle.push(acc)
+        for index, (batch, meta) in enumerate(pipe):
+            attrs = {"pass_id": pass_id, "batch": index}
+            with tel.span("eval_dispatch", **attrs):
+                acc = fwd.metrics(
+                    batch, iters=iters, acc=acc, kind=kind, pad=meta["pad"]
+                )
+            tel.inc("eval_pairs_total", batch["image1"].shape[0])
+            with tel.span("eval_throttle_wait", **attrs):
+                throttle.push(acc)
     # The window's single sanctioned pull: a few float32 sums, not fields.
-    return np.asarray(jax.device_get(acc), np.float64)
+    with tel.span("eval_pull", pass_id=pass_id):
+        host_acc = jax.device_get(acc)
+    return np.asarray(host_acc, np.float64)
 
 
 # The device-side warm-start splat: jit caches one tiny executable per

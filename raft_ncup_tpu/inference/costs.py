@@ -20,6 +20,12 @@ Per warmed executable the ledger holds:
 - ``memory_stats`` from ``Compiled.memory_analysis()``
   (argument/output/temp/generated-code bytes — the
   ``compiled_memory_stats`` surface),
+- ``op_scopes``: which ``jax.named_scope`` each instruction of the
+  compiled module lies in (``utils/profiling.hlo_op_scopes`` over
+  ``Compiled.as_text()``), under the module's name — a device trace
+  names instructions and carries no ``op_name``, so the per-scope
+  reduction of a capture joins on this bank
+  (:meth:`CostLedger.op_scopes`; kept out of ``snapshot()``),
 
 keyed by ``"<backend>|<executable key>"`` where the executable key is
 the SAME tuple that keys the compiled-program LRU (mesh fingerprint,
@@ -129,7 +135,7 @@ def probe_compiled(compiled) -> dict:
     that field, never an exception (the probe must not be able to take
     a warmup down)."""
     out: dict = {"flops": None, "bytes_accessed": None,
-                 "memory_stats": {}}
+                 "memory_stats": {}, "module": None, "op_scopes": {}}
     try:
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):
@@ -149,6 +155,14 @@ def probe_compiled(compiled) -> dict:
             for f in _MEMORY_STAT_FIELDS
             if getattr(ma, f, None) is not None
         }
+    except Exception:  # pragma: no cover - backend-specific
+        pass
+    try:
+        from raft_ncup_tpu.utils.profiling import hlo_op_scopes
+
+        text = compiled.as_text()
+        out["module"] = text.split(",", 1)[0].split()[-1]  # "HloModule <name>, ..."
+        out["op_scopes"] = hlo_op_scopes(text)
     except Exception:  # pragma: no cover - backend-specific
         pass
     return out
@@ -232,13 +246,35 @@ class CostLedger:
                 return e
         return None
 
+    def op_scopes(self) -> dict:
+        """``{hlo module name: {instruction name: scope}}`` over every
+        banked executable — the join table of a device trace's per-scope
+        reduction (``utils/profiling.read_device_trace``). Programs that
+        share a module name (every shape of ``jit(fn)``) number their
+        instructions independently: an instruction they place in
+        different scopes is left out, so it reads as ``unscoped`` rather
+        than as the wrong scope."""
+        with self._lock:
+            entries = list(self._entries.values())
+        merged: dict = {}
+        clash: dict = {}
+        for e in entries:
+            into = merged.setdefault(e.get("module"), {})
+            for name, scope in (e.get("op_scopes") or {}).items():
+                if into.setdefault(name, scope) != scope:
+                    clash.setdefault(e.get("module"), set()).add(name)
+        for module, names in clash.items():
+            for name in names:
+                del merged[module][name]
+        return {m: v for m, v in merged.items() if m and v}
+
     def snapshot(self) -> dict:
         """JSON-able dump: every entry (tuples stringified) plus
         accounting — what serve.py reports and the autotuner will read."""
         with self._lock:
             entries = {
                 k: {
-                    **e,
+                    **{f: v for f, v in e.items() if f != "op_scopes"},
                     "meta": {
                         mk: (list(mv) if isinstance(mv, tuple) else mv)
                         for mk, mv in (e.get("meta") or {}).items()

@@ -55,9 +55,10 @@ import jax.numpy as jnp
 from raft_ncup_tpu.data.device_prefetch import DevicePrefetcher
 from raft_ncup_tpu.inference import metrics as metrics_mod
 from raft_ncup_tpu.inference.costs import get_cost_ledger
-from raft_ncup_tpu.observability import get_telemetry
+from raft_ncup_tpu.observability import NOOP_SPAN, get_telemetry
 from raft_ncup_tpu.observability.telemetry import LEGACY_KEY_ALIASES
 from raft_ncup_tpu.precision import resolve_policy
+from raft_ncup_tpu.utils.profiling import annotate_spans
 
 _EXEC_CANON = LEGACY_KEY_ALIASES["inference"]
 
@@ -188,6 +189,10 @@ class EvalPipeline:
     dispatch thread at every call, which is exactly the per-batch stall
     this pipeline exists to remove.
 
+    ``telemetry``/``span_attrs`` forward to the DevicePrefetcher too: its
+    ``input_stage`` span times this pipeline's decode wait + ``stage_fn``,
+    ``input_h2d`` the transfer, ``input_wait`` the consumer's ``next()``.
+
     Exceptions from decode or staging re-raise from ``next()``;
     ``close()`` (or the context manager) tears down both threads and the
     decode pool even mid-epoch.
@@ -204,6 +209,8 @@ class EvalPipeline:
         lookahead: Optional[int] = None,
         mesh=None,
         shardings: Optional[dict] = None,
+        telemetry=None,
+        span_attrs: Optional[dict] = None,
     ):
         self._sp = SamplePrefetcher(
             dataset,
@@ -227,7 +234,7 @@ class EvalPipeline:
 
         self._pf = DevicePrefetcher(
             staged(), depth=depth, mesh=mesh, shardings=shardings,
-            drop_keys=(),
+            drop_keys=(), telemetry=telemetry, span_attrs=span_attrs,
         )
 
     def __iter__(self) -> Iterator[tuple]:
@@ -298,6 +305,14 @@ class AsyncDrain:
     ``submit()`` or from ``close()``; ``close()`` flushes the queue and
     joins. The queue bound (``depth``) also bounds device memory pinned
     by in-flight pulls.
+
+    The worker's three stages are the submitter's to name: ``span`` (a
+    ``stage -> context manager``, e.g. ``lambda stage:
+    hub.span("serve_" + stage, batch_id=7)``) is entered around
+    ``device_wait`` (``jax.block_until_ready`` on the tree: the program
+    still running, which the pull would wait for anyway), ``pull`` (the
+    ``device_get``: the device-to-host copy alone) and ``deliver`` (the
+    callback) — disjoint, in that order, on the worker thread.
     """
 
     def __init__(self, depth: int = 2):
@@ -315,9 +330,14 @@ class AsyncDrain:
                 return
             if self._exc is not None:
                 continue  # keep consuming so the producer never deadlocks
-            tree, callback = item
+            tree, callback, span = item
             try:
-                callback(jax.device_get(tree))
+                with span("device_wait"):
+                    jax.block_until_ready(tree)
+                with span("pull"):
+                    host = jax.device_get(tree)
+                with span("deliver"):
+                    callback(host)
             except BaseException as e:  # noqa: BLE001 — surfaced to producer
                 self._exc = e
 
@@ -326,9 +346,12 @@ class AsyncDrain:
             exc, self._exc = self._exc, None
             raise exc
 
-    def submit(self, tree, callback: Callable) -> None:
+    def submit(
+        self, tree, callback: Callable,
+        span: Callable[[str], object] = lambda stage: NOOP_SPAN,
+    ) -> None:
         self._raise_pending()
-        self._q.put((tree, callback))
+        self._q.put((tree, callback, span))
 
     def close(self) -> None:
         """Flush remaining work, stop the worker, re-raise its error."""
@@ -411,6 +434,10 @@ class ShapeCachedForward:
         # one ring event per warm batch would flood the span ring with
         # the steady state the ring exists to contextualize.
         self._tel = telemetry if telemetry is not None else get_telemetry()
+        # This cache owns the hub's jax side: its spans (and those of the
+        # server or engine that handed the hub in) go on the profiler's
+        # timeline too (utils/profiling.annotate_spans).
+        annotate_spans(self._tel)
         # The executable cost ledger (inference/costs.py; docs/PERF.md):
         # every program this cache compiles is AOT-lowered so its XLA
         # cost analysis, compile wall time, and memory stats land in the

@@ -19,10 +19,21 @@ validated host scalars/strings — handing a device array to a span is a
 
 Finishing a span also feeds ``{name}_ms`` in the metrics registry, so
 per-stage p50/p99 fall out of the same fixed-bucket histograms the rest
-of telemetry uses; a point event feeds ``{name}_total``. xprof-side
-stage labels are NOT this module's job — the ``jax.profiler`` named
-annotations live with the jitted code they label (``models/raft.py``,
-``parallel/step.py``, ``utils/profiling.py``).
+of telemetry uses; a point event feeds ``{name}_total``.
+
+**The profiler's timeline.** A tracer with an ``annotate`` factory
+(``(name, attrs) -> context manager``) enters one annotation of the
+span's name for every ``span(...)`` context, on the thread that runs
+it, so in a profiler capture the program's spans lie on ``/host:CPU``
+on the clock of the device's ``XLA Ops``. The factory is injected,
+never imported (JGL010): ``utils/profiling.annotate_spans(hub)``
+installs ``jax.profiler.TraceAnnotation``, and the jax-side owners of a
+hub (``ShapeCachedForward``, ``DevicePrefetcher``) call it. Externally
+timed intervals (``observe_ms``) and point events stay ring-only: their
+ends are on different threads. The *device-side* labels are
+``jax.named_scope`` in the jitted code (``models/raft.py``,
+``parallel/step.py``); ``utils/profiling.scope_seconds`` reduces a
+capture by them.
 
 **Cross-process traces** (docs/OBSERVABILITY.md "Trace propagation"):
 a request whose life spans the fleet's router → replica hop carries a
@@ -151,13 +162,14 @@ class Span:
     """One in-progress or finished stage. Created by
     :meth:`SpanTracer.span`; ``duration_ms`` is valid after exit."""
 
-    __slots__ = ("name", "attrs", "start_s", "end_s")
+    __slots__ = ("name", "attrs", "start_s", "end_s", "discarded")
 
     def __init__(self, name: str, attrs: dict, start_s: float):
         self.name = name
         self.attrs = attrs
         self.start_s = start_s
         self.end_s: Optional[float] = None
+        self.discarded = False
 
     @property
     def duration_ms(self) -> Optional[float]:
@@ -170,6 +182,12 @@ class Span:
         only known after assembly)."""
         for k, v in attrs.items():
             self.attrs[k] = _host_attr(self.name, k, v)
+
+    def discard(self) -> None:
+        """The body found nothing to time (a wait that ended in an
+        exhausted iterator): leave no record and no observation, so a
+        stage's count stays the count of its batches."""
+        self.discarded = True
 
     def record(self) -> dict:
         # ``t_s`` is the span's start on the tracer's monotonic clock:
@@ -187,18 +205,28 @@ class Span:
 
 
 class _SpanContext:
-    """Context manager yielded by :meth:`SpanTracer.span`."""
+    """Context manager yielded by :meth:`SpanTracer.span`. With an
+    ``annotate`` factory on the tracer, the same ``with`` also holds a
+    profiler annotation of the span's name (entered after the span's
+    start is read, left before its end is)."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", span: Span):
         self._tracer = tracer
         self.span = span
+        self._annotation = None
 
     def __enter__(self) -> Span:
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            self._annotation = annotate(self.span.name, self.span.attrs)
+            self._annotation.__enter__()
         return self.span
 
     def __exit__(self, *exc) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         self._tracer._finish(self.span)
 
 
@@ -209,6 +237,9 @@ class _NoopSpan:
     __slots__ = ()
 
     def set(self, **attrs) -> None:
+        pass
+
+    def discard(self) -> None:
         pass
 
     def __enter__(self) -> "_NoopSpan":
@@ -231,9 +262,13 @@ class SpanTracer:
         registry: Optional[MetricsRegistry] = None,
         capacity: int = DEFAULT_SPAN_CAPACITY,
         clock: Callable[[], float] = time.monotonic,
+        annotate: Optional[Callable[[str, dict], object]] = None,
     ):
         self.registry = registry
         self.clock = clock
+        # (name, attrs) -> context manager entered alongside every span()
+        # context (the bridge to the profiler's timeline; module docstring).
+        self.annotate = annotate
         self._records: deque = deque(maxlen=max(1, int(capacity)))
         self._dropped = 0
         self._lock = threading.Lock()
@@ -250,6 +285,8 @@ class SpanTracer:
         return _SpanContext(self, Span(name, checked, self.clock()))
 
     def _finish(self, span: Span) -> None:
+        if span.discarded:
+            return
         span.end_s = self.clock()
         self._append(span.record())
         if self.registry is not None:

@@ -29,10 +29,12 @@ Data path (one dispatcher thread, clients on their own threads):
    size, then ``ShapeCachedForward.forward_device`` — one compiled
    program per (padded shape, batch size, iters), LRU-bounded, with
    ``DispatchThrottle`` capping in-flight programs per backend.
-5. **complete** (drain worker): ``AsyncDrain`` performs the sanctioned
-   ``jax.device_get`` off the dispatch thread, the callback unpads each
-   row back to its native shape (host slicing) and completes the
-   request's handle with latency accounting.
+5. **complete** (drain worker): ``AsyncDrain`` waits for the batch's
+   program, performs the sanctioned ``jax.device_get`` off the dispatch
+   thread, and the callback unpads each row back to its native shape
+   (host slicing) and completes the request's handle with latency
+   accounting — three spans per batch (``serve_device_wait``,
+   ``serve_pull``, ``serve_deliver``; docs/OBSERVABILITY.md).
 
 **Drain contract** (``drain()``, reused by serve.py's SIGTERM path via
 ``resilience/preemption.PreemptionHandler``): stop admitting (new
@@ -404,12 +406,15 @@ class FlowServer:
             img2 = np.stack(rows2)
         self.stats.note_batch(pad_rows)
         t_dispatch = self._clock()
-        # The dispatch span times jit dispatch + the throttle's bounded
-        # wait, NOT device completion (the drain span covers dispatch ->
-        # delivery); it carries the full correlation set — request ids,
-        # batch id, mesh + policy fingerprints.
-        from raft_ncup_tpu.utils.profiling import stage_annotation
-
+        # The dispatch span times the host-to-device copy and the jit
+        # dispatch alone; the throttle's bounded wait (one batch time on
+        # a saturated accelerator: inflight 2) has its own span, and the
+        # drain worker's three (device wait, pull, deliver) follow under
+        # the same batch id. With the wait in the drainer's queue they
+        # tile the externally timed ``serve_drain`` interval (dispatch ->
+        # the top of deliver). ``serve_dispatch`` carries the full
+        # correlation set — request ids, batch id, mesh + policy
+        # fingerprints.
         trace_ids = [r.trace_id for r in live if r.trace_id is not None]
         ee_tol = self._earlyexit_tol
         with self._tel.span(
@@ -421,7 +426,7 @@ class FlowServer:
             policy=self._fwd.policy.name,
             **({"trace_ids": trace_ids} if trace_ids else {}),
             **({"earlyexit_tol": ee_tol} if ee_tol is not None else {}),
-        ), stage_annotation("serve.dispatch"):
+        ):
             if ee_tol is not None:
                 # Detection on: the executed-iters counter rides the
                 # SAME drain tree as the flow — the per-batch summary
@@ -434,6 +439,7 @@ class FlowServer:
             else:
                 _, flow_up = self._fwd.forward_device(img1, img2, iters)
                 drain_tree = flow_up
+        with self._tel.span("serve_throttle_wait", batch_id=token):
             self._throttle.push(flow_up)
         with self._inflight_lock:
             self._inflight[token] = live
@@ -498,7 +504,12 @@ class FlowServer:
             # backlog exactly when sheds happen.
             self._note_service((done - t_dispatch) / len(live))
 
-        self._drainer.submit(drain_tree, deliver)
+        self._drainer.submit(
+            drain_tree, deliver,
+            span=lambda stage: self._tel.span(
+                "serve_" + stage, batch_id=token
+            ),
+        )
 
     def _fail_inflight(self, exc: BaseException) -> None:
         """Complete every batch stranded by a drain-worker failure with

@@ -663,8 +663,6 @@ class StreamEngine:
                 "stream_slot_occupancy", self.registry.occupancy
             )
 
-        from raft_ncup_tpu.utils.profiling import stage_annotation
-
         t_dispatch = self._clock()
         step = self._step(n_rows)
         trace_ids = [r.trace_id for r in batch if r.trace_id is not None]
@@ -676,7 +674,7 @@ class StreamEngine:
             mesh=self._fwd.mesh_fp,
             policy=self._policy.name,
             **({"trace_ids": trace_ids} if trace_ids else {}),
-        ), stage_annotation("stream.dispatch"):
+        ):
             with self._table_lock:
                 self._table, flow_up, bad = step(
                     self._fwd.variables,

@@ -227,6 +227,7 @@ def run_stream(args, model, variables) -> int:
         StreamTraffic,
         replay_streams,
     )
+    from raft_ncup_tpu.utils.profiling import trace
 
     chaos = ChaosSpec.parse(args.chaos)
     if chaos.active:
@@ -262,7 +263,8 @@ def run_stream(args, model, variables) -> int:
         style=args.style,
     )
     t0 = time.monotonic()
-    with _telemetry_export(args), PreemptionHandler() as preempt:
+    with trace(args.trace_dir), _telemetry_export(args), \
+            PreemptionHandler() as preempt:
         handles, interrupted = replay_streams(
             engine, traffic, preempt=preempt,
             sigterm_after=chaos.sigterm_after,
@@ -653,6 +655,7 @@ def main(argv=None) -> int:
         nearest_rank_ms,
         replay,
     )
+    from raft_ncup_tpu.utils.profiling import trace
 
     model_cfg = model_config_from_args(args)
     model = RAFT(model_cfg)
@@ -698,7 +701,8 @@ def main(argv=None) -> int:
         style=args.style,
     )
     t0 = time.monotonic()
-    with _telemetry_export(args), PreemptionHandler() as preempt:
+    with trace(args.trace_dir), _telemetry_export(args), \
+            PreemptionHandler() as preempt:
         handles, interrupted = replay(
             server, traffic, preempt=preempt,
             sigterm_after=chaos.sigterm_after,

@@ -246,3 +246,53 @@ def test_logger_push_device_arrays_end_to_end(tmp_path):
         log.push(s, {"loss": jnp.float32(s + 1)})
     log.close()
     assert "loss 2.0000" in (tmp_path / "log.txt").read_text()
+
+
+class TestInputSpans:
+    """The prefetcher's three spans, shared by the train and eval loops."""
+
+    def test_each_span_once_per_batch_and_none_for_exhaustion(self):
+        from raft_ncup_tpu.observability import Telemetry
+
+        tel = Telemetry()
+        with DevicePrefetcher(
+            iter(_host_batches(3)), depth=2, telemetry=tel,
+            span_attrs={"pass_id": "p0"},
+        ) as pf:
+            assert len(list(pf)) == 3
+            with pytest.raises(StopIteration):
+                next(pf)  # a later next() is not a batch either
+        for name in ("input_stage", "input_h2d", "input_wait"):
+            recs = tel.tracer.records(name)
+            assert [r["attrs"]["batch"] for r in recs] == [0, 1, 2], name
+            assert {r["attrs"]["pass_id"] for r in recs} == {"p0"}
+
+    def test_stage_span_times_the_wrapped_iterators_wait(self):
+        from raft_ncup_tpu.observability import Telemetry
+
+        def slow():
+            for b in _host_batches(2):
+                time.sleep(0.05)
+                yield b
+
+        tel = Telemetry()
+        with DevicePrefetcher(slow(), depth=1, telemetry=tel) as pf:
+            assert len(list(pf)) == 2
+        stage = tel.tracer.records("input_stage")
+        assert all(r["duration_ms"] >= 45.0 for r in stage)
+        # the consumer waited for the first batch at least that long too
+        assert tel.tracer.records("input_wait")[0]["duration_ms"] >= 45.0
+
+    def test_failed_wait_is_not_counted_as_a_batch(self):
+        from raft_ncup_tpu.observability import Telemetry
+
+        def broken():
+            yield _host_batches(1)[0]
+            raise ValueError("decode failed")
+
+        tel = Telemetry()
+        pf = DevicePrefetcher(broken(), depth=1, telemetry=tel)
+        next(pf)
+        with pytest.raises(ValueError, match="decode failed"):
+            next(pf)
+        assert len(tel.tracer.records("input_wait")) == 1

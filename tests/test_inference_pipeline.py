@@ -647,3 +647,65 @@ class TestCostLedger:
         assert snap["enabled"] is True
         (entry,) = snap["entries"].values()
         assert entry["meta"]["shape"] == [1, 8, 10, 3]
+
+
+# ------------------------------------------------ the eval pass's spans
+
+
+class TestEvalPassSpans:
+    """``_run_metric_pass`` records where the pass waits: the input
+    pipeline's three ``input_*`` spans and ``eval_dispatch`` /
+    ``eval_throttle_wait`` once per batch, ``eval_pull`` once per pass,
+    and ``eval_pairs_total`` counted where ``eval_dispatch`` closes."""
+
+    PER_BATCH = ("input_wait", "input_stage", "input_h2d", "eval_dispatch",
+                 "eval_throttle_wait")
+
+    @pytest.mark.parametrize("n,batch_size", [(5, 2), (4, 4), (3, 1)])
+    def test_one_span_per_batch_and_pairs_counted(self, n, batch_size):
+        from raft_ncup_tpu.evaluation import _run_metric_pass
+        from raft_ncup_tpu.observability import Telemetry
+
+        tel = Telemetry()
+        fwd = ShapeCachedForward(_DummyModel(), {}, telemetry=tel)
+        ds = _ListDataset(_mk_samples(n))
+        for _ in range(2):  # two passes: the ids tell them apart
+            _run_metric_pass(
+                fwd, ds, kind="epe", iters=1, batch_size=batch_size,
+                num_workers=2, telemetry=tel,
+            )
+        batches = -(-n // batch_size)
+        assert tel.counter_value("eval_pairs_total") == 2 * n
+        passes = {r["attrs"]["pass_id"] for r in tel.tracer.records("eval_pull")}
+        assert len(passes) == 2
+        for name in self.PER_BATCH:
+            recs = tel.tracer.records(name)
+            assert len(recs) == 2 * batches, name
+            for pass_id in passes:
+                mine = [r for r in recs if r["attrs"]["pass_id"] == pass_id]
+                assert sorted(r["attrs"]["batch"] for r in mine) == list(range(batches))
+            assert all(r["duration_ms"] >= 0 and r["t_s"] > 0 for r in recs)
+        stages = tel.tracer.stage_summary()
+        assert stages["input_wait"]["count"] == stages["eval_dispatch"]["count"]
+
+    def test_process_hub_is_the_default_and_a_disabled_hub_records_nothing(self):
+        from raft_ncup_tpu.evaluation import _run_metric_pass
+        from raft_ncup_tpu.observability import Telemetry, set_telemetry
+
+        ds = _ListDataset(_mk_samples(4))
+        mine = Telemetry()
+        prev = set_telemetry(mine)
+        try:
+            fwd = ShapeCachedForward(_DummyModel(), {})
+            want = _run_metric_pass(fwd, ds, kind="epe", iters=1, batch_size=2)
+        finally:
+            set_telemetry(prev)
+        assert mine.counter_value("eval_pairs_total") == 4
+        assert len(mine.tracer.records("input_h2d")) == 2
+        off = Telemetry(enabled=False)
+        got = _run_metric_pass(
+            ShapeCachedForward(_DummyModel(), {}, telemetry=off), ds,
+            kind="epe", iters=1, batch_size=2, telemetry=off,
+        )
+        assert off.registry.names() == [] and off.tracer.records() == []
+        np.testing.assert_array_equal(got, want)

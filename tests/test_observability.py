@@ -269,6 +269,148 @@ class TestSpanTracer:
         assert tel.tracer.records() == []
 
 
+class _FakeAnnotator:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs the enter and
+    exit of every annotation it hands out."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, attrs):
+        log = self.log
+
+        class _Annotation:
+            def __enter__(self):
+                log.append(("enter", name, dict(attrs)))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Annotation()
+
+
+class TestProfilerBridge:
+    """A span context also holds a profiler annotation of its name
+    (utils/profiling.annotate_spans installs the real one); the factory
+    is injected, so observability/ itself never imports jax."""
+
+    def test_annotator_sees_enter_and_exit_in_span_order(self):
+        fake = _FakeAnnotator()
+        tel = Telemetry()
+        tel.tracer.annotate = fake
+        with tel.span("outer", batch_id=3):
+            with tel.span("inner", batch_id=3, request_ids=[1, 2]):
+                pass
+        assert [e[:2] for e in fake.log] == [
+            ("enter", "outer"), ("enter", "inner"),
+            ("exit", "inner"), ("exit", "outer"),
+        ]
+        assert fake.log[0][2] == {"batch_id": 3}
+        # the ring and the histograms are fed exactly as without a bridge
+        assert [r["name"] for r in tel.tracer.records()] == ["inner", "outer"]
+        assert tel.registry.histogram("outer_ms").count == 1
+
+    @pytest.mark.parametrize("produce", [
+        pytest.param(lambda tel: tel.observe_ms("serve_drain", 5.0, batch_id=1),
+                     id="observe_ms"),
+        pytest.param(lambda tel: tel.event("stream_slot_evicted", slot=2),
+                     id="event"),
+        pytest.param(lambda tel: tel.hist_observe("serve_e2e_ms", 5.0),
+                     id="hist_observe"),
+    ])
+    def test_ring_only_producers_enter_no_annotation(self, produce):
+        fake = _FakeAnnotator()
+        tel = Telemetry()
+        tel.tracer.annotate = fake
+        produce(tel)
+        assert fake.log == [] and tel.registry.names()
+
+    def test_disabled_hub_enters_no_annotation(self):
+        fake = _FakeAnnotator()
+        tel = Telemetry(enabled=False)
+        tel.tracer.annotate = fake
+        with tel.span("serve_dispatch", batch_id=1) as sp:
+            sp.set(rows=2)
+            sp.discard()
+        assert fake.log == [] and tel.tracer.records() == []
+
+    def test_annotation_exits_when_the_body_raises(self):
+        fake = _FakeAnnotator()
+        tel = Telemetry()
+        tel.tracer.annotate = fake
+        with pytest.raises(KeyError):
+            with tel.span("serve_dispatch"):
+                raise KeyError("boom")
+        assert [e[:2] for e in fake.log] == [
+            ("enter", "serve_dispatch"), ("exit", "serve_dispatch"),
+        ]
+        assert tel.registry.histogram("serve_dispatch_ms").count == 1
+
+    def test_discarded_span_leaves_no_record_but_closes_its_annotation(self):
+        fake = _FakeAnnotator()
+        tel = Telemetry()
+        tel.tracer.annotate = fake
+        with tel.span("input_wait", batch=4) as sp:
+            sp.discard()
+        assert tel.tracer.records() == [] and tel.registry.names() == []
+        assert [e[:2] for e in fake.log] == [
+            ("enter", "input_wait"), ("exit", "input_wait"),
+        ]
+
+    @pytest.mark.parametrize("owner", ["forward", "server", "prefetcher"])
+    def test_jax_side_owners_install_the_real_annotation(self, owner):
+        """A hub built as ``Telemetry()`` gets the bridge from whichever
+        jax-side object it is handed to."""
+        from raft_ncup_tpu.data import DevicePrefetcher
+        from raft_ncup_tpu.inference.pipeline import ShapeCachedForward
+        from raft_ncup_tpu.utils import profiling
+
+        tel = Telemetry()
+        assert tel.tracer.annotate is None
+        if owner == "forward":
+            ShapeCachedForward(_DummyModel(), {}, telemetry=tel)
+        elif owner == "server":
+            FlowServer(_DummyModel(), {}, _cfg(), telemetry=tel).drain()
+        else:
+            DevicePrefetcher(iter(()), telemetry=tel).close()
+        assert tel.tracer.annotate is profiling._annotation
+        ann = tel.tracer.annotate("serve_dispatch", {"batch_id": 1, "ids": [1]})
+        assert isinstance(ann, jax.profiler.TraceAnnotation)
+
+    def test_spans_land_on_the_profilers_host_plane(self, tmp_path):
+        """In a capture the hub's spans are host events carrying the
+        mark and their scalar attributes; the runtime's own events and
+        unmarked annotations are not taken for program spans."""
+        from raft_ncup_tpu.utils import profiling
+
+        tel = Telemetry()
+        profiling.annotate_spans(tel)
+        with profiling.trace(str(tmp_path)):
+            with tel.span("input_wait", batch=0, pass_id="abcd"):
+                with jax.profiler.TraceAnnotation("bench.window"):
+                    jnp.ones((32, 32)).sum().block_until_ready()
+            tel.observe_ms("serve_drain", 1.0)
+        _, spans = profiling.read_device_trace(
+            profiling.find_xplane(str(tmp_path))
+        )
+        assert [s[0] for s in spans] == ["input_wait"]
+        assert spans[0][2] > spans[0][1]
+        assert os.path.isfile(tmp_path / profiling.OP_SCOPES_FILE)
+        report = profiling.device_trace_report(str(tmp_path))
+        assert report["program_spans"] == {"input_wait": 1}
+        assert report["devices"] == {}  # a CPU capture has no device plane
+
+    def test_observability_package_still_imports_no_jax(self):
+        from raft_ncup_tpu.analysis.lint import run_lint
+
+        pkg = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "raft_ncup_tpu", "observability",
+        )
+        result = run_lint([pkg], select=["JGL010"])
+        assert not result.parse_errors and result.findings == []
+
+
 # ------------------------------------------- stats mirroring / aliases
 
 

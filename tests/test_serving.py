@@ -656,3 +656,101 @@ class TestUhdAdmission:
             assert err is not None and "exceeds maximum" in err
         finally:
             server.drain()
+
+
+# ------------------------------------------- stage spans of one batch
+
+
+_BATCH_STAGES = (
+    "serve_dispatch", "serve_throttle_wait", "serve_device_wait",
+    "serve_pull", "serve_deliver",
+)
+
+
+class _TickClock:
+    """Every read is one second after the last, from any thread."""
+
+    def __init__(self):
+        self._t, self._lock = 0.0, threading.Lock()
+
+    def __call__(self) -> float:
+        with self._lock:
+            self._t += 1.0
+            return self._t
+
+
+def _stage_records(tel, batch_id):
+    out = {}
+    for name in _BATCH_STAGES + ("serve_drain",):
+        (rec,) = [
+            r for r in tel.tracer.records(name)
+            if r["attrs"]["batch_id"] == batch_id
+        ]
+        out[name] = (rec["t_s"], rec["t_s"] + rec["duration_ms"] / 1e3)
+    return out
+
+
+class TestBatchStageSpans:
+    """``serve_dispatch`` is the copy and the jit dispatch alone; the
+    throttle's wait and the drain worker's device wait, pull and deliver
+    are spans of their own under the batch's id. With the wait in the
+    drainer's queue they tile the externally timed ``serve_drain``."""
+
+    def test_stages_tile_serve_drain_under_an_injected_clock(self):
+        from raft_ncup_tpu.observability import Telemetry
+
+        clock = _TickClock()
+        tel = Telemetry(clock=clock)
+        srv = FlowServer(
+            _DummyModel(), {}, _cfg(), clock=clock, telemetry=tel
+        )
+        try:
+            srv.pause()
+            h1 = srv.submit(_img(1), _img(2))
+            h2 = srv.submit(_img(3), _img(4))
+            srv.resume()
+            assert h1.result(60).ok and h2.result(60).ok
+            _wait_idle(srv)
+        finally:
+            srv.drain()
+        st = _stage_records(tel, batch_id=0)
+        order = [st[name] for name in _BATCH_STAGES]
+        # disjoint, in this order, each one tick long
+        for (s0, e0), (s1, e1) in zip(order, order[1:]):
+            assert s0 < e0 < s1 < e1
+        # serve_drain runs from the read before serve_dispatch to the
+        # read at the top of deliver; observe_ms reads the clock once
+        # more to place it, hence the tick taken off its end.
+        drain_start, drain_end = st["serve_drain"][0] - 1.0, st["serve_drain"][1] - 1.0
+        assert drain_start == st["serve_dispatch"][0] - 1.0
+        assert st["serve_pull"][1] < drain_end
+        assert st["serve_deliver"][0] < drain_end < st["serve_deliver"][1]
+        covered = sum(e - s for s, e in order[:4])
+        # what the four spans leave of serve_drain is clock reads alone:
+        # 2 per span boundary pair, plus deliver's start and `done`.
+        assert (drain_end - drain_start) - covered == pytest.approx(6.0)
+
+    def test_every_batch_has_each_stage_once_inside_its_drain(self):
+        from raft_ncup_tpu.observability import Telemetry
+
+        tel = Telemetry()
+        srv = FlowServer(_DummyModel(), {}, _cfg(), telemetry=tel)
+        try:
+            handles = [srv.submit(_img(i), _img(i + 1)) for i in range(5)]
+            assert all(h.result(60).ok for h in handles)
+            _wait_idle(srv)
+        finally:
+            stats = srv.drain()
+        batches = {r["attrs"]["batch_id"] for r in tel.tracer.records("serve_drain")}
+        assert len(batches) == stats.batches >= 3
+        for b in batches:
+            st = _stage_records(tel, b)  # exactly one record a stage
+            order = [st[name] for name in _BATCH_STAGES]
+            slack = 2e-3  # records round to microseconds and 1e-3 ms
+            for (s0, e0), (s1, e1) in zip(order, order[1:]):
+                assert s0 <= e0 <= s1 + slack and s1 <= e1
+            d0, d1 = st["serve_drain"]
+            assert d0 - slack <= order[0][0] and order[3][1] <= d1 + slack
+        report = srv.report()["stages"]
+        assert {n for n in _BATCH_STAGES} <= set(report)
+        assert report["serve_pull"]["count"] == stats.batches
