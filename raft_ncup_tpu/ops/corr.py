@@ -8,6 +8,17 @@ Two interchangeable implementations behind one signature:
   pooling. Fast at training resolutions; the volume at 1/8 res of a 400x720
   crop is ~100 MB/pair in fp32.
 
+  The lookup reads the volume densely and selects with arithmetic: the
+  (2r+1)^2 bilinear taps of a query share one centre, so they are
+  ``Ay @ V[q] @ Ax^T`` with per-axis weight matrices of two non-zeros a
+  row. A scalar gather of the same taps fetched 0.05% of the volume and
+  took 40-80x as long as this on a TPU v5e (root PERF.md section 6, PR 25:
+  at 8 x 55x128 queries the four levels cost 394 ms as gathers, 9.4 ms as
+  multiply + reduce, 5.0 ms with level 0's x axis on the MXU). The x-first
+  matmul form won only where the level's width fills whole lanes (55x128:
+  5.3 against 9.9 ms; 27x64, 46x96, 68x120, 136x240: 1.1-2.6x slower), so
+  ``_window_contract`` chooses by that.
+
 - ``corr_lookup_onthefly`` never materializes the volume. Because the
   lookup bilinearly samples the volume over its *second* pair of spatial
   dims for a fixed query pixel, and correlation is linear in fmap2,
@@ -109,8 +120,8 @@ def build_corr_pyramid(
         the precision policy's bf16 presets halve it here
         (``PrecisionPolicy.corr_jnp``). The dot products ACCUMULATE in
         f32 regardless (``preferred_element_type``); only storage
-        narrows. Lookup arithmetic re-widens via ``grid_sample``'s
-        promotion, so coordinates never demote.
+        narrows. ``corr_lookup`` widens the level again before its
+        arithmetic, so coordinates never demote.
     """
     B, H, W, C = fmap1.shape
     dtype = dtype or jnp.float32
@@ -129,10 +140,65 @@ def build_corr_pyramid(
     return CorrPyramid(levels=tuple(levels), query_hw=(H, W))
 
 
+def _axis_weights(centre: jax.Array, size: int, radius: int) -> jax.Array:
+    """Bilinear weights of the K = 2r+1 window taps along one axis:
+    window centres ``(...)`` -> ``(..., K, size)``.
+
+    Tap ``k`` sits at ``t = centre + k - r``; its row holds ``1 - d`` at
+    position ``floor(t)`` and ``d = t - floor(t)`` at ``floor(t) + 1`` —
+    the very numbers ``grid_sample`` multiplies its corner taps by — and
+    zeros elsewhere. A corner outside ``[0, size)`` matches no position,
+    which is ``padding_mode='zeros'`` without a mask.
+    """
+    taps = jnp.arange(-radius, radius + 1, dtype=centre.dtype)
+    t = centre[..., None] + taps  # (..., K)
+    t0 = jnp.floor(t)
+    d = (t - t0)[..., None]
+    t0 = t0[..., None]
+    pos = jnp.arange(size, dtype=centre.dtype)
+    return jnp.where(pos == t0, 1.0 - d, 0.0) + jnp.where(
+        pos == t0 + 1.0, d, 0.0
+    )
+
+
+# The TPU's lane width: a level whose row fills whole lanes contracts its
+# x axis on the MXU (``_window_contract``).
+_LANES = 128
+
+
+def _window_contract(vol: jax.Array, ax: jax.Array, ay: jax.Array) -> jax.Array:
+    """``out[..., i, j] = sum_y sum_x ay[..., j, y] vol[..., y, x] ax[..., i, x]``
+    for ``vol`` (..., Hl, Wl), ``ax`` (..., K, Wl), ``ay`` (..., K, Hl).
+
+    Two orders of the same sums, chosen from the level's width (timings:
+    module docstring). Both are float32 arithmetic under any ambient
+    ``jax_default_matmul_precision``: the dot pins HIGHEST itself, the
+    multiply + reduce forms never were matmuls.
+    """
+    if vol.shape[-1] % _LANES == 0:
+        # x first, one small matmul per query: (Hl, Wl) @ (Wl, K).
+        cols = jnp.einsum(
+            "...yx,...ix->...yi", vol, ax,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=vol.dtype,
+        )  # (..., Hl, K_x)
+        ay_t = jnp.swapaxes(ay, -1, -2)  # (..., Hl, K_y)
+        return jnp.sum(cols[..., :, :, None] * ay_t[..., :, None, :], axis=-3)
+    # y first: y is the major axis of the layout XLA gives the volume, so
+    # this is an accumulation of whole rows; then x over the K rows left.
+    rows = jnp.sum(ay[..., :, :, None] * vol[..., None, :, :], axis=-2)
+    return jnp.sum(ax[..., :, None, :] * rows[..., None, :, :], axis=-1)
+
+
 def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Array:
     """Sample (2r+1)^2 windows around ``coords / 2^l`` at every level.
 
-    Reference: core/corr.py:23-44.
+    Reference: core/corr.py:23-44. Gather-free: all K*K taps of a query
+    sit at integer offsets from one centre, so its bilinear samples
+    factorise per axis into a contraction of the query's whole level with
+    two small weight matrices (:func:`_axis_weights`,
+    :func:`_window_contract`). Every element of the level may contribute;
+    the selection is arithmetic.
 
     Args:
       pyramid: from :func:`build_corr_pyramid`.
@@ -140,22 +206,23 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Arr
     Returns:
       (B, H, W, L * (2r+1)^2) at the promoted (volume, coords) dtype —
       float32 whenever coords are f32 (the policy's coord contract),
-      level-major then window-tap order.
+      level-major then window-tap order, the first window axis
+      offsetting x (:func:`_delta_window`).
     """
     B, H, W, _ = coords.shape
     K = 2 * radius + 1
-    delta = _delta_window(radius)  # (K, K, 2)
 
     out = []
     for lvl, corr in enumerate(pyramid.levels):
         _, _, Hl, Wl = corr.shape
-        centroid = coords.reshape(B, H * W, 1, 1, 2) / (2**lvl)
-        coords_lvl = centroid + delta[None, None]  # (B, HW, K, K, 2)
-        # Fold queries into the batch dim for the gather.
-        vol = corr.reshape(B * H * W, Hl, Wl, 1)
-        c = coords_lvl.reshape(B * H * W, K, K, 2)
-        sampled = grid_sample(vol, c)  # (B*HW, K, K, 1)
-        out.append(sampled.reshape(B, H, W, K * K))
+        # A narrow-storage volume (bf16 under the precision policy) is
+        # widened here; the coordinates are never narrowed.
+        wdt = jnp.promote_types(corr.dtype, coords.dtype)
+        centre = coords.reshape(B, H * W, 2).astype(wdt) / (2**lvl)
+        ax = _axis_weights(centre[..., 0], Wl, radius)  # (B, HW, K, Wl)
+        ay = _axis_weights(centre[..., 1], Hl, radius)  # (B, HW, K, Hl)
+        win = _window_contract(corr.astype(wdt), ax, ay)  # (B, HW, K_x, K_y)
+        out.append(win.reshape(B, H, W, K * K))
     return jnp.concatenate(out, axis=-1)
 
 

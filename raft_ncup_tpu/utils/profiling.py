@@ -133,7 +133,7 @@ def measure_throughput_detailed(
 # Ops`` event's stats are its device offset and duration and nothing else:
 # the ``jax.named_scope`` path lives only in the compiled module's text
 # (``metadata={op_name="jit(fn)/raft.refinement/while/body/closed_call/
-# raft.corr_lookup/gather"}``; scopes inside the ``while`` body survive),
+# raft.corr_lookup/reduce_sum"}``; scopes inside the ``while`` body survive),
 # so the join is on the instruction's name. Host threads are lines of
 # ``/host:CPU``; a ``TraceAnnotation``'s keyword arguments are its stats.
 

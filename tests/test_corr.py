@@ -1,6 +1,7 @@
 """Correlation volume + lookup vs the reference math (torch oracle) and
 cross-implementation equivalence (volume vs on-the-fly)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,6 +14,8 @@ from raft_ncup_tpu.ops import (
     corr_lookup,
     corr_lookup_onthefly,
 )
+from raft_ncup_tpu.ops.corr import CorrPyramid
+from raft_ncup_tpu.ops.geometry import grid_sample
 
 
 def torch_corr_block(fmap1, fmap2, num_levels=4, radius=4):
@@ -108,3 +111,193 @@ def test_corr_pyramid_shapes():
     ]
     out = corr_lookup(pyr, coords_grid(B, H, W), radius=4)
     assert out.shape == (B, H, W, 4 * 81)
+
+
+# ---------------------------------------------------------------------------
+# The gather-free lookup against the formulation it replaced. The oracle
+# below IS the old ``corr_lookup``: the (2r+1)^2 taps of every query
+# sampled one by one through ``grid_sample``'s four scalar gathers.
+
+
+def gather_lookup_oracle(pyramid, coords, radius):
+    B, H, W, _ = coords.shape
+    K = 2 * radius + 1
+    d = jnp.arange(-radius, radius + 1, dtype=jnp.float32)
+    di, dj = jnp.meshgrid(d, d, indexing="ij")
+    delta = jnp.stack([di, dj], axis=-1)  # first window axis offsets x
+    out = []
+    for lvl, corr in enumerate(pyramid.levels):
+        _, _, Hl, Wl = corr.shape
+        centroid = coords.reshape(B, H * W, 1, 1, 2) / (2**lvl)
+        taps = (centroid + delta[None, None]).reshape(B * H * W, K, K, 2)
+        sampled = grid_sample(corr.reshape(B * H * W, Hl, Wl, 1), taps)
+        out.append(sampled.reshape(B, H, W, K * K))
+    return jnp.concatenate(out, axis=-1)
+
+
+def _random_pyramid(seed, B, H, W, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    f1 = jnp.asarray(rng.standard_normal((B, H, W, 8)).astype(np.float32))
+    f2 = jnp.asarray(rng.standard_normal((B, H, W, 8)).astype(np.float32))
+    return build_corr_pyramid(f1, f2, num_levels=4, dtype=dtype)
+
+
+def _jittered_coords(seed, B, H, W, spread):
+    rng = np.random.default_rng(seed)
+    return coords_grid(B, H, W) + jnp.asarray(
+        rng.uniform(-spread, spread, size=(B, H, W, 2)).astype(np.float32)
+    )
+
+
+def _assert_matches_oracle(ours, pyr, coords, radius):
+    np.testing.assert_allclose(
+        np.asarray(ours),
+        np.asarray(gather_lookup_oracle(pyr, coords, radius)),
+        atol=2e-6, rtol=1e-6,
+    )
+
+
+# 11x16 is the eval cell's 55x128 scaled down: pooling drops an odd row
+# at levels 0 -> 1 (11 -> 5) and 1 -> 2 (5 -> 2), as 55 -> 27 -> 13 does.
+# A 128-wide level 0 (whole lanes) takes the lookup's matmul form for x;
+# every narrower level takes multiply + reduce.
+SHAPES = pytest.mark.parametrize(
+    "hw", [(11, 16), (16, 24), (9, 128)], ids=["11x16", "16x24", "9x128"]
+)
+BOTH_FORMS = pytest.mark.parametrize(
+    "hw", [(11, 16), (9, 128)], ids=["11x16", "9x128"]
+)
+
+
+@pytest.mark.parametrize("radius", [3, 4])
+@SHAPES
+def test_lookup_matches_gather_oracle(hw, radius):
+    B, (H, W) = 2, hw
+    pyr = _random_pyramid(2, B, H, W)
+    coords = _jittered_coords(3, B, H, W, 6)
+    ours = corr_lookup(pyr, coords, radius)
+    assert ours.dtype == jnp.float32
+    assert ours.shape == (B, H, W, 4 * (2 * radius + 1) ** 2)
+    _assert_matches_oracle(ours, pyr, coords, radius)
+
+
+def _special_coords(case, H, W):
+    """(1, H, W, 2) query centres for one edge case of the window."""
+    base = coords_grid(1, H, W)
+    if case == "integers":  # dx = dy = 0 at level 0, halves further down
+        return base + jnp.asarray([2.0, -1.0])
+    frac = jnp.asarray([0.25, 0.625])
+    x, y = {
+        "left": (-0.5, H / 2), "right": (W - 0.5, H / 2),
+        "top": (W / 2, -0.5), "bottom": (W / 2, H - 0.5),
+        "corner": (W - 0.5, H - 0.5),
+        "outside": (-80.0, -80.0),  # no tap of any level lands inside
+        "far_outside": (1e6, -1e6),
+    }[case]
+    return jnp.zeros_like(base) + jnp.asarray([x, y]) + (
+        0.0 if case == "far_outside" else frac * base / max(H, W)
+    )
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["integers", "left", "right", "top", "bottom", "corner", "outside",
+     "far_outside"],
+)
+def test_lookup_window_edges_match_gather_oracle(case):
+    H, W, radius = 11, 16, 4
+    pyr = _random_pyramid(4, 1, H, W)
+    coords = _special_coords(case, H, W)
+    ours = np.asarray(corr_lookup(pyr, coords, radius))
+    _assert_matches_oracle(ours, pyr, coords, radius)
+    if case in ("outside", "far_outside"):
+        assert not ours.any()  # padding_mode='zeros': an all-zero window
+    else:
+        assert ours.any()
+
+
+@BOTH_FORMS
+def test_lookup_widens_a_bf16_volume_to_float32(hw):
+    """``PrecisionPolicy.corr_jnp`` stores the volume in bf16; the
+    interpolation still runs, and answers, in float32."""
+    B, (H, W), radius = 1, hw, 4
+    pyr = _random_pyramid(5, B, H, W, dtype=jnp.bfloat16)
+    assert all(lvl.dtype == jnp.bfloat16 for lvl in pyr.levels)
+    coords = _jittered_coords(6, B, H, W, 3)
+    ours = corr_lookup(pyr, coords, radius)
+    assert ours.dtype == jnp.float32
+    # Against the oracle on the SAME bf16 values: were the weights or the
+    # products rounded to bf16 the gap would be ~1e-2, not ~1e-6.
+    _assert_matches_oracle(ours, pyr, coords, radius)
+
+
+@pytest.mark.parametrize("radius", [3, 4])
+@BOTH_FORMS
+def test_lookup_grad_wrt_levels_matches_gather_oracle(hw, radius):
+    """train.py differentiates the lookup with respect to the volume only
+    (coords are stop_gradient-ed per iteration): the gather's scatter-add
+    and the contraction's transposes must deposit the same cotangent."""
+    B, (H, W) = 1, hw
+    pyr = _random_pyramid(7, B, H, W)
+    coords = _jittered_coords(8, B, H, W, 5)
+    cot = jnp.asarray(
+        np.random.default_rng(10)
+        .standard_normal((B, H, W, 4 * (2 * radius + 1) ** 2))
+        .astype(np.float32)
+    )
+
+    def loss(lookup):
+        return lambda levels: jnp.sum(
+            lookup(CorrPyramid(levels, pyr.query_hw), coords, radius) * cot
+        )
+
+    ours = jax.grad(loss(corr_lookup))(pyr.levels)
+    theirs = jax.grad(loss(gather_lookup_oracle))(pyr.levels)
+    for lvl, (g, t) in enumerate(zip(ours, theirs)):
+        assert g.shape == pyr.levels[lvl].shape
+        assert np.asarray(t).any()
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(t), atol=2e-6, rtol=1e-6
+        )
+
+
+def _lookup_eqns(ambient_precision, hw):
+    """Every equation of ``corr_lookup``'s jaxpr (sub-jaxprs included),
+    traced under an ambient ``jax_default_matmul_precision``."""
+    pyr = _random_pyramid(9, 1, *hw)
+    coords = coords_grid(1, *hw)
+    with jax.default_matmul_precision(ambient_precision):
+        closed = jax.make_jaxpr(lambda lv, c: corr_lookup(
+            CorrPyramid(lv, pyr.query_hw), c, 4))(pyr.levels, coords)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return list(walk(closed.jaxpr))
+
+
+@pytest.mark.parametrize("ambient", ["bfloat16", "float32"])
+@BOTH_FORMS
+def test_lookup_has_no_gather_and_pins_its_own_precision(hw, ambient):
+    """Structure, not speed: no gather is left in the volume lookup, and
+    its interpolation is float32 arithmetic whatever matmul precision the
+    caller's process runs at (jax's TPU default is one bf16 pass) — any
+    ``dot_general`` in it carries HIGHEST and a float32 result itself."""
+    eqns = _lookup_eqns(ambient, hw)
+    names = [e.primitive.name for e in eqns]
+    assert not set(names) & {
+        "gather", "dynamic_slice", "scatter", "scatter-add",
+        "conv_general_dilated",
+    }
+    # One matmul, for the x axis of the 128-wide level, and only there.
+    assert names.count("dot_general") == (1 if hw[1] % 128 == 0 else 0)
+    for e in eqns:
+        if e.primitive.name == "dot_general":
+            assert e.params["precision"] is not None
+            assert set(jax.tree.leaves(e.params["precision"])) == {
+                jax.lax.Precision.HIGHEST
+            }
+            assert e.params["preferred_element_type"] == jnp.float32

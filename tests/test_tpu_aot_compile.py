@@ -13,8 +13,10 @@ All of it lives in this ONE file: the worker that runs it loads libtpu
 and keeps its lock until it exits, so a second file on another xdist
 worker could describe no topology. The topology is described inside a
 fixture, never at import, and every compile happens in the test's own
-process. Each case was probed by hand first and compiles (or is gated
-off) within seconds; the whole file is ~80 s.
+process. Each kernel case was probed by hand first and compiles (or is
+gated off) within seconds (~80 s together); the two whole programs at
+the end (the eval cell's batch-8 forward, the Sintel train step) take
+one to two minutes each.
 """
 
 import jax
@@ -159,22 +161,85 @@ def test_nconv_gate_admitted_shapes_compile(sds, site):
     assert "tpu_custom_call" in _compile_nconv(sds, *site)
 
 
-def test_flagship_eval_forward_compiles_for_v5e(sds):
-    """The program chip_smoke's eval phase runs: raft_nc_dbl test mode,
-    1x440x1024 (a padded Sintel frame), 32 iterations, default XLA
-    paths, float32 — and it fits one chip's 16 GB with room."""
+def _abstract(sds, tree):
+    return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+
+def _flagship(sds):
+    """The flagship Sintel model (`corr_impl` "volume", the default) and
+    its variables as shapes on the described chip."""
     from raft_ncup_tpu.config import flagship_config
     from raft_ncup_tpu.models.raft import RAFT
 
     model = RAFT(flagship_config(dataset="sintel"))
-    variables = jax.tree.map(
-        lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(
-            lambda: model.init(jax.random.PRNGKey(0), (1, 64, 96, 3))
-        ),
-    )
+    assert model.cfg.corr_impl == "volume"
+    variables = _abstract(sds, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), (1, 64, 96, 3))
+    ))
+    return model, variables
+
+
+def test_flagship_eval_forward_compiles_for_v5e(sds):
+    """The program chip_smoke's eval phase runs: raft_nc_dbl test mode,
+    1x440x1024 (a padded Sintel frame), 32 iterations, default XLA
+    paths, float32 — and it fits one chip's 16 GB with room."""
+    model, variables = _flagship(sds)
     img = sds((1, 440, 1024, 3))
     compiled = jax.jit(
         lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
     ).lower(variables, img, img).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def _record_temp(record_property, compiled) -> float:
+    gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
+    record_property("temp_size_gib", round(gib, 3))
+    print(f"temp_size {gib:.3f} GiB")
+    return gib
+
+
+def test_eval_cell_volume_forward_compiles_gather_free(sds, record_property):
+    """The `eval_sintel_nc` cell's program: raft_nc_dbl, `corr_impl`
+    "volume", 8x440x1024, 32 iterations, float32 with every product at
+    `highest` (as `benchmark/configs/` states). The lookup inside the
+    loop is dense arithmetic — no gather is left anywhere in the forward
+    — and the program's temporaries leave most of the chip free (PR 25:
+    4.54 GiB; the gather form was 5.14 GiB)."""
+    model, variables = _flagship(sds)
+    img = sds((8, 440, 1024, 3))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(
+            lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
+        ).lower(variables, img, img).compile()
+    assert " gather(" not in compiled.as_text()
+    assert _record_temp(record_property, compiled) < 6.0
+
+
+def test_sintel_train_step_compiles_for_v5e(sds, record_property):
+    """`train.py --stage sintel` at the published crop, batch 2 (the
+    batch `chip_smoke.py` trains at), 12 iterations, float32: forward,
+    the lookup's backward into the volume (a pair of transposed
+    contractions since PR 25, the gather's scatter-add before), AdamW.
+    PR 25: 5.83 GiB of temporaries (6.23 GiB with the gather, PR 21)."""
+    from raft_ncup_tpu.config import TrainConfig, flagship_config
+    from raft_ncup_tpu.models.raft import RAFT
+    from raft_ncup_tpu.parallel.step import make_train_step
+    from raft_ncup_tpu.training.state import create_train_state
+
+    model_cfg = flagship_config(dataset="sintel", mixed_precision=False)
+    train_cfg = TrainConfig(
+        stage="sintel", batch_size=2, image_size=(368, 768), iters=12,
+        num_steps=10,
+    )
+    state = _abstract(sds, jax.eval_shape(lambda: create_train_state(
+        jax.random.PRNGKey(0), model_cfg, train_cfg,
+        image_shape=(1, 64, 96, 3),
+    )[1]))
+    batch = {
+        "image1": sds((2, 368, 768, 3)), "image2": sds((2, 368, 768, 3)),
+        "flow": sds((2, 368, 768, 2)), "valid": sds((2, 368, 768)),
+    }
+    rng = _abstract(sds, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    step = make_train_step(RAFT(model_cfg), train_cfg, mesh=None)
+    compiled = step.lower(state, batch, rng).compile()
+    assert _record_temp(record_property, compiled) < 8.0
