@@ -7,6 +7,7 @@ from raft_ncup_tpu.data.augment import (
 from raft_ncup_tpu.data.datasets import (
     HD1K,
     KITTI,
+    ArrayFlowDataset,
     FlowDataset,
     FlyingChairs,
     FlyingThings3D,
@@ -24,6 +25,7 @@ __all__ = [
     "SparseFlowAugmentor",
     "resize_sparse_flow_map",
     "FlowDataset",
+    "ArrayFlowDataset",
     "FlyingChairs",
     "FlyingThings3D",
     "MpiSintel",

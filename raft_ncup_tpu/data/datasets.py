@@ -43,6 +43,16 @@ class FlowDataset:
     def __len__(self) -> int:
         return len(self.image_list)
 
+    def _read(self, index: int):
+        """``(image1, image2, flow, valid or None)`` of one pair, decoded."""
+        if self.sparse:
+            flow, valid = read_flow_kitti(self.flow_list[index])
+        else:
+            flow, valid = read_gen(self.flow_list[index]), None
+        img1 = read_gen(self.image_list[index][0])
+        img2 = read_gen(self.image_list[index][1])
+        return img1, img2, flow, valid
+
     def sample(self, index: int, rng: Optional[np.random.Generator] = None):
         """Load (and optionally augment) one training pair."""
         if self.is_test:
@@ -54,14 +64,7 @@ class FlowDataset:
                 "extra_info": self.extra_info[index],
             }
 
-        index %= len(self.image_list)
-        if self.sparse:
-            flow, valid = read_flow_kitti(self.flow_list[index])
-        else:
-            flow, valid = read_gen(self.flow_list[index]), None
-
-        img1 = read_gen(self.image_list[index][0])
-        img2 = read_gen(self.image_list[index][1])
+        img1, img2, flow, valid = self._read(index % len(self))
         flow = np.asarray(flow, np.float32)
 
         if self.augmentor is not None:
@@ -86,6 +89,23 @@ class FlowDataset:
             "flow": np.ascontiguousarray(flow, np.float32),
             "valid": np.ascontiguousarray(valid, np.float32),
         }
+
+
+class ArrayFlowDataset(FlowDataset):
+    """Dense pairs held in memory (dicts of ``image1`` / ``image2`` uint8
+    (H, W, 3) and ``flow`` float32 (H, W, 2)) behind the same ``sample``
+    as the file datasets: augmentor, validity, dtypes."""
+
+    def __init__(self, pairs: list, aug_params: Optional[dict] = None):
+        super().__init__(aug_params)
+        self.pairs = list(pairs)
+
+    def __len__(self) -> int:
+        return len(self.pairs)
+
+    def _read(self, index: int):
+        pair = self.pairs[index]
+        return pair["image1"], pair["image2"], pair["flow"], None
 
 
 class MpiSintel(FlowDataset):

@@ -57,6 +57,12 @@ from raft_ncup_tpu.ops.geometry import (
 )
 
 
+def _save_conv_outputs(prim, *_, **__) -> bool:
+    """``jax.checkpoint`` policy of the encoders in the training step:
+    a convolution's output is saved, everything else is recomputed."""
+    return prim is jax.lax.conv_general_dilated_p
+
+
 class RAFT:
     """Model bundle + functional forward.
 
@@ -166,7 +172,7 @@ class RAFT:
         """The submodule-application closure shared by every forward
         entry point; mutates ``bstats`` in place when ``bn_train``."""
 
-        def run(name, module, *args, **kwargs):
+        def run(name, module, *args, remat_policy=None, **kwargs):
             # Only the upsampler may be parameter-free (bilinear head): its
             # empty group gets dropped by flatten/unflatten round-trips
             # (checkpoint merge). For every other submodule absence is a
@@ -177,23 +183,45 @@ class RAFT:
                 v = {"params": params[name]}
             if name in bstats:
                 v["batch_stats"] = bstats[name]
-            if bn_train and name in bstats:
-                out, mut = module.apply(
-                    v, *args, mutable=["batch_stats"], rngs=rngs, **kwargs
+            mutable = ["batch_stats"] if bn_train and name in bstats else False
+
+            def apply(v, *args):
+                return module.apply(
+                    v, *args, mutable=mutable, rngs=rngs, **kwargs
                 )
+
+            if remat_policy is not None:
+                # Nothing of this submodule outlives its forward: the
+                # backward runs it again (outer checkpoint) and, while it
+                # differentiates that second run, keeps what the policy
+                # names and recomputes the rest (inner checkpoint).
+                apply = jax.checkpoint(
+                    jax.checkpoint(apply, policy=remat_policy)
+                )
+            out = apply(v, *args)
+            if mutable:
+                out, mut = out
                 bstats[name] = mut["batch_stats"]
-                return out
-            return module.apply(v, *args, rngs=rngs, **kwargs)
+            return out
 
         return run
 
     def _encode(
         self, run, image1, image2, *, train=False, bn_train=False,
-        flow_init=None, net_init=None, net_warm=None,
+        flow_init=None, net_init=None, net_warm=None, remat=False,
     ):
         """Everything before the first refinement iteration: normalize,
         siamese fnet, context cnet, warm-start select, initial query
-        coordinates. Returns ``(fmap1, fmap2, net, inp, coords1)``."""
+        coordinates. Returns ``(fmap1, fmap2, net, inp, coords1)``.
+
+        ``remat`` (the training step): each encoder is rematerialised.
+        Its forward leaves nothing behind; its backward, which comes after
+        the whole refinement loop's, runs it again and keeps of that
+        second run the convolutions' outputs alone, recomputing what lies
+        between them (bias, norm, relu, residual add). Kept whole, the
+        encoders' activations at 1/2 and 1/4 resolution outlived the
+        entire loop, forward and backward: 1.6 of the 1.9 GiB a sample
+        that the Sintel step held at its peak (PERF.md section 6, PR 26)."""
         cfg = self.cfg
         policy = self.policy
         if image1.shape[1] % 8 or image1.shape[2] % 8:
@@ -208,9 +236,9 @@ class RAFT:
 
         # Siamese feature extraction: both frames through fnet in one batch
         # (reference: core/extractor.py:168-174). jax.named_scope labels
-        # carry into the HLO metadata, so an xprof capture of this
-        # program is stage-labeled (docs/OBSERVABILITY.md) — staged for
-        # the ROADMAP item-1 hardware window.
+        # carry into the HLO metadata, by which a capture's reduction
+        # gives device seconds per stage (docs/OBSERVABILITY.md).
+        policy_kw = {"remat_policy": _save_conv_outputs} if remat else {}
         with jax.named_scope("raft.fnet"):
             fmaps = run(
                 "fnet",
@@ -218,6 +246,7 @@ class RAFT:
                 jnp.concatenate([img1, img2], axis=0),
                 train=train,
                 bn_train=bn_train,
+                **policy_kw,
             )
         fmap1, fmap2 = jnp.split(fmaps, 2, axis=0)
         # Correlation features/volume ride the policy's corr dtype — the
@@ -229,7 +258,8 @@ class RAFT:
 
         with jax.named_scope("raft.cnet"):
             cnet_out = run(
-                "cnet", self.cnet, img1, train=train, bn_train=bn_train
+                "cnet", self.cnet, img1, train=train, bn_train=bn_train,
+                **policy_kw,
             )
         net = jnp.tanh(cnet_out[..., :hdim])
         inp = jax.nn.relu(cnet_out[..., hdim:])
@@ -438,9 +468,10 @@ class RAFT:
             if test_mode:
                 out = None
             else:
-                out = self._upsample(
-                    run, coords1 - coords0, net, up_mask, bn_train
-                )
+                with jax.named_scope("raft.upsample"):
+                    out = self._upsample(
+                        run, coords1 - coords0, net, up_mask, bn_train
+                    )
             new_stats = dict(stats)
             if "upsampler" in stats:
                 new_stats["upsampler"] = bstats["upsampler"]
@@ -556,6 +587,7 @@ class RAFT:
         fmap1, fmap2, net, inp, coords1 = self._encode(
             run, image1, image2, train=train, bn_train=bn_train,
             flow_init=flow_init, net_init=net_init, net_warm=net_warm,
+            remat=train and remat,
         )
         corr_fn = self._build_corr_fn(fmap1, fmap2, mesh, spatial_axis)
 

@@ -137,6 +137,8 @@ def nconv2d(
     dn = jax.lax.conv_dimension_numbers(data.shape, weight.shape, ("NHWC", "HWIO", "NHWC"))
 
     def conv(x: jax.Array) -> jax.Array:
+        if stride == 1 and groups == 1 and kh % 2 == 1 and kw % 2 == 1:
+            return _conv_same(x, weight)
         return jax.lax.conv_general_dilated(
             x,
             weight,
@@ -159,6 +161,53 @@ def nconv2d(
     else:
         conf_out = None
     return out, conf_out
+
+
+def _conv_same_xla(x: jax.Array, weight: jax.Array) -> jax.Array:
+    kh, kw = weight.shape[0], weight.shape[1]
+    return jax.lax.conv_general_dilated(
+        x, weight, window_strides=(1, 1),
+        padding=((kh // 2, kh // 2), (kw // 2, kw // 2)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+
+
+@jax.custom_vjp
+def _conv_same(x: jax.Array, weight: jax.Array) -> jax.Array:
+    """Stride-1 SAME convolution, NHWC x HWIO, odd kernel: the forward
+    (and the input's cotangent) is ``conv_general_dilated`` as before; the
+    kernel's cotangent has its own rule below."""
+    return _conv_same_xla(x, weight)
+
+
+def _conv_same_fwd(x, weight):
+    return _conv_same(x, weight), (x, weight)
+
+
+def _conv_same_bwd(res, g):
+    """Input cotangent: the convolution's own transpose. Kernel cotangent:
+    one contraction per tap, ``dw[ky, kx] = sum_bhw xpad[b, h+ky, w+kx, :]
+    (x) g[b, h, w, :]``. XLA's rule is a convolution of the input with the
+    output cotangent as its window; for NCUP's planes (a full-resolution
+    frame, 1-4 channels) that window is the whole frame, and the TPU
+    compiler's code for it at float32 `highest` took over 19 GB of host
+    memory to compile for ONE 368x768 sample (PERF.md section 6, PR 26)."""
+    x, weight = res
+    kh, kw = weight.shape[0], weight.shape[1]
+    h, w = x.shape[1], x.shape[2]
+    _, vjp_x = jax.vjp(lambda x_: _conv_same_xla(x_, weight), x)
+    xpad = jnp.pad(x, ((0, 0), (kh // 2, kh // 2), (kw // 2, kw // 2), (0, 0)))
+    dw = jnp.stack([
+        jnp.stack([
+            jnp.einsum("bhwc,bhwo->co", xpad[:, ky : ky + h, kx : kx + w], g)
+            for kx in range(kw)
+        ])
+        for ky in range(kh)
+    ])
+    return vjp_x(g)[0], dw.astype(weight.dtype)
+
+
+_conv_same.defvjp(_conv_same_fwd, _conv_same_bwd)
 
 
 def downsample_data_conf(

@@ -103,9 +103,10 @@ def make_train_step(
         return loss, (metrics, new_stats)
 
     def step(state: TrainState, batch: dict, rng: jax.Array):
-        # jax.named_scope: stage labels in the compiled step's HLO so an
-        # xprof capture splits fwd+bwd / optimizer / sentinel wall time
-        # (docs/OBSERVABILITY.md; staged for the hardware window).
+        # jax.named_scope: stage labels in the compiled step's HLO, by
+        # which a capture's reduction splits forward + backward /
+        # optimizer / sentinel device time (docs/OBSERVABILITY.md;
+        # utils/profiling.scope_of).
         with jax.named_scope("train.forward_backward"):
             (loss, (metrics, new_stats)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True

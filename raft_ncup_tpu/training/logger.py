@@ -98,7 +98,13 @@ class Logger:
             # same name): float(lr) on a schedule that returns a device
             # scalar would be an implicit pull (JGL001's runtime analogue
             # — guards.py flags it under --strict_guards).
-            sums, lr = jax.device_get((self._acc, lr))
+            # The pull waits for whatever step is still in flight: device
+            # time seen from the host, under ``train_metrics_pull``.
+            from raft_ncup_tpu.observability import get_telemetry
+
+            tel = get_telemetry()
+            with tel.span("train_metrics_pull", step=step):
+                sums, lr = jax.device_get((self._acc, lr))
             lr = None if lr is None else float(lr)
             means = {k: float(v) / self._acc_n for k, v in sums.items()}
             self._acc, self._acc_n = {}, 0
@@ -108,9 +114,6 @@ class Logger:
             # the SAME boundary pull as host floats into gauges — the
             # training loop's scalars join the one registry every other
             # subsystem reports to, at zero additional syncs.
-            from raft_ncup_tpu.observability import get_telemetry
-
-            tel = get_telemetry()
             for k, v in means.items():
                 tel.gauge_set(f"train_{k}", v)
             tel.gauge_set("train_steps_per_sec", sps)

@@ -49,16 +49,23 @@ def create_train_state(
     model_cfg: ModelConfig,
     train_cfg: TrainConfig,
     image_shape: Optional[tuple[int, ...]] = None,
+    variables: Optional[dict] = None,
 ) -> tuple[RAFT, TrainState]:
-    """Build the model, initialize variables, and assemble the optimizer
-    (with the freeze_raft mask when configured)."""
+    """Build the model, initialize variables (or start from ``variables``,
+    a tree in the checkpoint layout), and assemble the optimizer (with the
+    freeze_raft mask when configured)."""
     import jax.numpy as jnp
 
     model = RAFT(model_cfg)
-    if image_shape is None:
-        h, w = train_cfg.image_size
-        image_shape = (1, h, w, 3)
-    variables = model.init(rng, image_shape)
+    if variables is None:
+        if image_shape is None:
+            h, w = train_cfg.image_size
+            image_shape = (1, h, w, 3)
+        variables = model.init(rng, image_shape)
+    else:
+        # The step donates its state: train on copies, so the caller's
+        # tree survives the first step.
+        variables = jax.tree.map(jnp.array, variables)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
 

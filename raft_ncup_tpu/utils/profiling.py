@@ -47,7 +47,7 @@ SPAN_MARK = "program_span"
 OP_SCOPES_FILE = "op_scopes.json"
 UNSCOPED = "unscoped"
 
-_SCOPE = re.compile(r"\braft\.[a-z_]+")
+_SCOPE = re.compile(r"\b(?:raft|train)\.[a-z_]+")
 
 
 def _annotation(name: str, attrs: dict):
@@ -147,10 +147,22 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+) \(.*\{\s*$")
 
 
 def scope_of(op_name: str) -> Optional[str]:
-    """The innermost ``raft.*`` scope of an ``op_name`` path, None when the
-    path lies in none."""
+    """The innermost ``raft.*`` or ``train.*`` scope of an ``op_name`` path,
+    None when the path lies in none. In a differentiated program (the
+    training step) the phase is appended: the forward's operations keep
+    the plain name (``.../jvp(raft.fnet)/...``), the backward's are
+    ``<scope>.bwd`` (``.../transpose(jvp(raft.refinement))/while/body/
+    closed_call/checkpoint/raft.update_block/...``) and the forward that a
+    ``jax.checkpoint`` runs again inside the backward is ``<scope>.remat``
+    (``.../checkpoint/rematted_computation/raft.update_block/...``)."""
     found = _SCOPE.findall(op_name)
-    return found[-1] if found else None
+    if not found:
+        return None
+    if "rematted_computation" in op_name:
+        return found[-1] + ".remat"
+    if "transpose(" in op_name:
+        return found[-1] + ".bwd"
+    return found[-1]
 
 
 def hlo_op_scopes(hlo_text: str) -> dict:

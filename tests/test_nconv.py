@@ -127,6 +127,37 @@ def test_nconv_gradient_flows():
     assert np.isfinite(np.asarray(g)).all()
 
 
+@pytest.mark.parametrize("k,cin,cout", [(5, 1, 2), (5, 2, 2), (3, 4, 2), (1, 2, 1)])
+def test_nconv_gradients_are_the_convolutions_own(k, cin, cout):
+    """The kernel's cotangent is computed tap by tap (``_conv_same``'s own
+    rule, PR 26); data, confidence and kernel gradients of the whole
+    normalized convolution equal those of the plain two-convolution
+    composition, at every NCUP site's kernel and channel counts."""
+    keys = jax.random.split(jax.random.PRNGKey(k * 10 + cin), 4)
+    data = jax.random.normal(keys[0], (2, 12, 14, cin))
+    conf = jax.random.uniform(keys[1], (2, 12, 14, cin), minval=0.1)
+    w = jax.random.uniform(keys[2], (k, k, cin, cout), minval=0.1)
+    g = jax.random.normal(keys[3], (2, 12, 14, cout))
+
+    def plain(data, conf, w):
+        conv = lambda x: jax.lax.conv_general_dilated(  # noqa: E731
+            x, w, (1, 1), ((k // 2, k // 2),) * 2, dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        denom = conv(conf)
+        return conv(data * conf) / (denom + 1e-20), denom / w.sum(axis=(0, 1, 2))
+
+    def loss(fn):
+        def f(data, conf, w):
+            out, cout_ = fn(data, conf, w)
+            return (out * g).sum() + (cout_ * g).sum()
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(data, conf, w)
+
+    (va, ga), (vb, gb) = loss(lambda d, c, w: nconv2d(d, c, w, impl="xla")), loss(plain)
+    assert float(va) == pytest.approx(float(vb), rel=1e-6)
+    for a, b in zip(ga, gb):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=1e-5 * float(jnp.abs(b).max()))
+
+
 class TestFusedNConvPallas:
     """Interpret-mode equivalence of the fused Pallas NConv2d
     (raft_ncup_tpu.ops.nconv_pallas) against the XLA composition."""

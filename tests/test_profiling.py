@@ -24,6 +24,21 @@ LOOKUP, UPDATE, LOOP, FNET = (
     ("jit(fn)/raft.upsample/raft.metric_head/reduce_sum", "raft.metric_head"),
     ("jit(fn)/div", None),
     ("jit(fn)/aircraft.wing/mul", None),
+    # the training step (PR 26): train.* scopes, and the phase of a
+    # differentiated program's operations
+    ("jit(step)/train.optimizer_update/mul", "train.optimizer_update"),
+    ("jit(step)/train.forward_backward/reduce_sum", "train.forward_backward"),
+    ("jit(step)/train.forward_backward/jvp(raft.fnet)/checkpoint/Encoder/conv1/conv_general_dilated", FNET),
+    ("jit(step)/train.forward_backward/transpose(jvp(raft.fnet))/train.forward_backward/jvp(raft.fnet)"
+     "/checkpoint/checkpoint/Encoder/conv1/conv_general_dilated", FNET + ".bwd"),
+    ("jit(step)/train.forward_backward/transpose(jvp(raft.fnet))/train.forward_backward/jvp(raft.fnet)"
+     "/checkpoint/checkpoint/rematted_computation/Encoder/norm1/mul", FNET + ".remat"),
+    ("jit(step)/train.forward_backward/transpose(jvp(raft.refinement))/while/body/closed_call/checkpoint"
+     "/raft.update_block/BasicUpdateBlock/gru/convq1/conv_general_dilated", UPDATE + ".bwd"),
+    ("jit(step)/train.forward_backward/transpose(jvp(raft.refinement))/while/body/closed_call/checkpoint"
+     "/rematted_computation/raft.corr_lookup/eq", LOOKUP + ".remat"),
+    ("jit(step)/train.forward_backward/transpose(jvp(raft.refinement))/while/body/closed_call/add_any", LOOP + ".bwd"),
+    ("jit(step)/train.forward_backward/transpose(train.forward_backward)/mul", "train.forward_backward.bwd"),
 ])
 def test_scope_of_takes_the_innermost_raft_scope(op_name, want):
     assert P.scope_of(op_name) == want

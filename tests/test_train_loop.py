@@ -1,0 +1,153 @@
+"""The training loop the package owns (``training/loop.py``), its spans and
+counters, and the rematerialisation of the training forward (PR 26)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from raft_ncup_tpu.config import DataConfig, TrainConfig, flagship_config
+from raft_ncup_tpu.data import ArrayFlowDataset, SyntheticFlowDataset
+from raft_ncup_tpu.data.synthetic import make_pair
+from raft_ncup_tpu.models.raft import RAFT
+from raft_ncup_tpu.observability import Telemetry, set_telemetry, telemetry_report
+from raft_ncup_tpu.training.logger import Logger
+from raft_ncup_tpu.training.loop import open_train_run, train_steps
+from raft_ncup_tpu.training.loss import sequence_loss
+
+HW = (64, 96)
+
+
+def _batch(seed=0, b=2):
+    rng = np.random.default_rng(seed)
+    pairs = [make_pair(rng, HW, 6.0) for _ in range(b)]
+    out = {k: jnp.asarray(np.stack([p[k] for p in pairs])) for k in ("image1", "image2", "flow")}
+    out["valid"] = jnp.ones((b,) + HW, jnp.float32)
+    return out
+
+
+@pytest.fixture()
+def hub():
+    tel = Telemetry()
+    prev = set_telemetry(tel)
+    yield tel
+    set_telemetry(prev)
+
+
+def _run(tmp_path, **kw):
+    train_cfg = TrainConfig(
+        stage="sintel", batch_size=2, image_size=HW, iters=2, num_steps=10,
+        sum_freq=2, checkpoint_dir=str(tmp_path),
+    )
+    data_cfg = DataConfig(num_workers=1)
+    dataset = SyntheticFlowDataset(HW, length=8)
+    return open_train_run(
+        flagship_config(dataset="sintel"), train_cfg, data_cfg, dataset=dataset, **kw
+    )
+
+
+def test_loop_counts_steps_spans_and_stops_where_told(tmp_path, hub):
+    run = _run(tmp_path)
+    logger = Logger(str(tmp_path / "log"), sum_freq=2, use_tensorboard=False)
+    seen = []
+    try:
+        train_steps(run, lambda i: i >= 3, logger=logger,
+                    after_step=lambda i, m: seen.append((i, m["loss"])) or False)
+        assert run.step_i == 3 and int(run.state.step) == 3
+        assert [i for i, _ in seen] == [1, 2, 3]
+        assert all(np.isfinite(float(v)) for _, v in seen)
+        # after_step returning True ends the loop after that step
+        train_steps(run, lambda i: False, after_step=lambda i, m: i >= 5)
+        assert run.step_i == 5
+    finally:
+        run.close()
+        logger.close()
+    assert hub.counter_value("train_steps_total") == 5
+    assert hub.counter_value("train_pairs_total") == 10
+    stages = telemetry_report(hub)["stages"]
+    for name, count in (("train_dispatch", 5), ("train_throttle_wait", 5),
+                        ("train_metrics_pull", 1), ("input_wait", 5)):
+        assert stages[name]["count"] == count, name
+    assert not run.throttle._pending  # every dispatched step has finished
+
+
+def test_run_starts_from_given_weights_and_keeps_the_callers_tree(tmp_path, hub):
+    model = RAFT(flagship_config(dataset="sintel"))
+    variables = model.init(jax.random.PRNGKey(7), (1,) + HW + (3,))
+    before = jax.tree.map(np.asarray, variables)
+    run = _run(tmp_path, variables=variables)
+    try:
+        got = jax.tree.map(np.asarray, run.state.params)
+        assert all(np.array_equal(a, b) for a, b in
+                   zip(jax.tree.leaves(got), jax.tree.leaves(before["params"])))
+        train_steps(run, lambda i: i >= 1)
+    finally:
+        run.close()
+    # the step donates its state; the caller's arrays are still readable
+    after = jax.tree.map(np.asarray, variables)
+    assert all(np.array_equal(a, b) for a, b in
+               zip(jax.tree.leaves(after), jax.tree.leaves(before)))
+
+
+def test_array_dataset_goes_through_the_file_datasets_sample():
+    rng = np.random.default_rng(0)
+    pairs = [make_pair(rng, (92, 128), 6.0) for _ in range(3)]
+    ds = ArrayFlowDataset(pairs, dict(crop_size=HW, min_scale=-0.2, max_scale=0.6, do_flip=True))
+    s = ds.sample(4, np.random.default_rng(1))  # index wraps
+    assert len(ds) == 3 and s["image1"].shape == HW + (3,) and s["image1"].dtype == np.uint8
+    assert s["flow"].shape == HW + (2,) and s["valid"].shape == HW and s["valid"].dtype == np.float32
+    plain = ArrayFlowDataset(pairs).sample(1)
+    assert np.array_equal(plain["image2"], pairs[1]["image2"])
+
+
+# --------------------------------------------------------- rematerialisation
+
+
+def _loss_and_grads(model, variables, batch, remat):
+    def loss_fn(params):
+        preds = model.apply(
+            {**variables, "params": params}, batch["image1"].astype(jnp.float32),
+            batch["image2"].astype(jnp.float32), iters=3, train=True, freeze_bn=True,
+            remat=remat,
+        )
+        return sequence_loss(preds, batch["flow"], batch["valid"], 0.85)[0]
+
+    return jax.jit(jax.value_and_grad(loss_fn))(variables["params"])
+
+
+def test_rematerialised_step_is_the_old_arithmetic():
+    """Encoders and loop body rematerialised (what the step compiles)
+    against nothing rematerialised: same loss, same gradient on every
+    leaf, to float32 rounding."""
+    model = RAFT(flagship_config(dataset="sintel"))
+    variables = model.init(jax.random.PRNGKey(3), (1,) + HW + (3,))
+    batch = _batch()
+    loss_r, grads_r = _loss_and_grads(model, variables, batch, remat=True)
+    loss_p, grads_p = _loss_and_grads(model, variables, batch, remat=False)
+    assert float(loss_r) == pytest.approx(float(loss_p), rel=1e-6)
+    flat_r = jax.tree_util.tree_leaves_with_path(grads_r)
+    flat_p = jax.tree.leaves(grads_p)
+    scale = max(float(jnp.max(jnp.abs(g))) for g in flat_p)
+    for (path, a), b in zip(flat_r, flat_p):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6 * scale,
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+def test_only_the_training_forward_is_rematerialised():
+    """The inference programs are what they were: no checkpoint in a
+    ``test_mode`` forward; the training forward has the loop body's and
+    the two encoders' (each nested: outer, and inner with the policy)."""
+    model = RAFT(flagship_config(dataset="sintel"))
+    variables = model.init(jax.random.PRNGKey(3), (1,) + HW + (3,))
+    img = jnp.zeros((1,) + HW + (3,), jnp.float32)
+    infer = str(jax.make_jaxpr(
+        lambda v, a, b: model.apply(v, a, b, iters=2, test_mode=True))(variables, img, img))
+    assert "remat" not in infer and "checkpoint" not in infer
+    train = str(jax.make_jaxpr(
+        lambda v, a, b: model.apply(v, a, b, iters=2, train=True, freeze_bn=True))(variables, img, img))
+    assert train.count("remat2[") == 5
+    plain = str(jax.make_jaxpr(
+        lambda v, a, b: model.apply(v, a, b, iters=2, train=True, freeze_bn=True, remat=False))(variables, img, img))
+    assert "remat" not in plain

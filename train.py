@@ -39,15 +39,14 @@ import numpy as np
 
 def main(argv=None) -> int:
     from raft_ncup_tpu.cli import parse_train
-    from raft_ncup_tpu.data import DevicePrefetcher, FlowLoader, fetch_training_set
+    from raft_ncup_tpu.data import fetch_training_set
     from raft_ncup_tpu.evaluation import VALIDATORS
-    from raft_ncup_tpu.parallel.mesh import batch_sharding, make_mesh
+    from raft_ncup_tpu.parallel.mesh import make_mesh
     from raft_ncup_tpu.parallel.multihost import (
         initialize_distributed,
         is_main_process,
         is_multihost,
     )
-    from raft_ncup_tpu.parallel.step import make_train_step
     from raft_ncup_tpu.resilience import (
         EXIT_DIVERGED,
         EXIT_PREEMPTED,
@@ -62,8 +61,7 @@ def main(argv=None) -> int:
         load_pretrained_trunk,
     )
     from raft_ncup_tpu.training.logger import Logger
-    from raft_ncup_tpu.training.optim import build_schedule
-    from raft_ncup_tpu.training.state import create_train_state
+    from raft_ncup_tpu.training.loop import open_train_run, train_steps
 
     args, model_cfg, train_cfg, data_cfg = parse_train(argv)
     initialize_distributed()  # no-op off-pod; wires processes on a pod
@@ -132,91 +130,73 @@ def main(argv=None) -> int:
         f"{train_cfg.spatial_parallel} spatial)"
     )
 
-    model, state = create_train_state(
-        jax.random.PRNGKey(train_cfg.seed), model_cfg, train_cfg
-    )
-
-    if train_cfg.load_pretrained:
-        variables = {"params": state.params}
-        if state.batch_stats:
-            variables["batch_stats"] = state.batch_stats
-        merged = load_pretrained_trunk(train_cfg.load_pretrained, variables)
-        state = state.replace(
-            params=merged["params"],
-            batch_stats=merged.get("batch_stats", state.batch_stats),
-        )
-        logger.write_text(f"warm-started trunk from {train_cfg.load_pretrained}")
+    def restore(state):
+        if train_cfg.load_pretrained:
+            variables = {"params": state.params}
+            if state.batch_stats:
+                variables["batch_stats"] = state.batch_stats
+            merged = load_pretrained_trunk(train_cfg.load_pretrained, variables)
+            state = state.replace(
+                params=merged["params"],
+                batch_stats=merged.get("batch_stats", state.batch_stats),
+            )
+            logger.write_text(
+                f"warm-started trunk from {train_cfg.load_pretrained}"
+            )
+        if train_cfg.restore_ckpt:
+            same_dir = (
+                os.path.abspath(train_cfg.restore_ckpt)
+                == os.path.abspath(run_dir)
+            )
+            restore_mgr = (
+                ckpt
+                if same_dir
+                else CheckpointManager(train_cfg.restore_ckpt, metadata=meta)
+            )
+            try:
+                state = restore_mgr.restore(state)
+            finally:
+                if restore_mgr is not ckpt:
+                    restore_mgr.close()
+            logger.write_text(
+                f"restored step {int(state.step)} from {train_cfg.restore_ckpt}"
+            )
+        return state
 
     # Exact-resume metadata rides next to every orbax payload and is
     # verified before any restore: a wrong-arch/seed resume fails with a
     # clear message, not an orbax pytree error.
     meta = resume_metadata(model_cfg, train_cfg)
     ckpt = CheckpointManager(run_dir, max_to_keep=5, metadata=meta)
-    if train_cfg.restore_ckpt:
-        same_dir = (
-            os.path.abspath(train_cfg.restore_ckpt) == os.path.abspath(run_dir)
-        )
-        restore_mgr = (
-            ckpt
-            if same_dir
-            else CheckpointManager(train_cfg.restore_ckpt, metadata=meta)
-        )
-        try:
-            state = restore_mgr.restore(state)
-        finally:
-            if restore_mgr is not ckpt:
-                restore_mgr.close()
-        logger.write_text(
-            f"restored step {int(state.step)} from {train_cfg.restore_ckpt}"
-        )
 
     dataset = fetch_training_set(
         train_cfg.stage, train_cfg.image_size, data_cfg
     )
     if chaos.ioerror_reads:
         dataset = ChaosDataset(dataset, chaos.ioerror_reads)
-    # --batch_size is the GLOBAL batch (reference semantics); each host
-    # loads its slice.
-    n_proc = jax.process_count()
-    if train_cfg.batch_size % n_proc:
-        raise SystemExit(
-            f"--batch_size {train_cfg.batch_size} not divisible by "
-            f"{n_proc} hosts"
-        )
-    loader = FlowLoader(
-        dataset,
-        batch_size=train_cfg.batch_size // n_proc,
-        seed=train_cfg.seed,
-        num_workers=data_cfg.num_workers,
-        prefetch=data_cfg.prefetch,
-        io_retries=data_cfg.io_retries,
-        io_retry_backoff_s=data_cfg.io_retry_backoff_s,
+
+    def wrap_batches(batches, start_step):
+        if chaos.nan_steps:
+            return chaos_batches(
+                batches, chaos.nan_steps, start_step=start_step,
+                log=logger.write_text,
+            )
+        return batches
+
+    # State, step, loader, device prefetcher and the dispatch loop are the
+    # package's (training/loop.py), shared with the benchmark's driver.
+    run = open_train_run(
+        model_cfg, train_cfg, data_cfg, mesh=mesh, dataset=dataset,
+        restore=restore, wrap_batches=wrap_batches,
     )
+    model, loader, prefetcher = run.model, run.loader, run.prefetcher
     logger.write_text(
         f"training with {len(dataset)} pairs "
         f"({len(loader)} batches/epoch/host)"
     )
 
-    step_fn = make_train_step(model, train_cfg, mesh=mesh)
-    schedule = build_schedule(train_cfg)
-    if not multihost:
-        # Commit the state to where the step leaves its output. A fresh
-        # (uncommitted) state and the step's own committed output are two
-        # jit signatures, and the train program was compiled once for
-        # each: ~4 extra minutes at every start on the chip (first chip
-        # run, PR 21: 172 compiles, 2 x ~245 s in one trainer).
-        from raft_ncup_tpu.parallel.mesh import replicated
-
-        state = jax.device_put(
-            state,
-            replicated(mesh) if mesh is not None else jax.devices()[0],
-        )
-    # Batch shardings feed the device prefetcher on every mesh run (not
-    # just multihost): single-process device_put straight into the step's
-    # input layout means jit dispatch never re-lays-out the batch.
-    shardings = batch_sharding(mesh) if mesh is not None else None
-
     def run_validation(step: int) -> None:
+        state = run.state
         variables = {"params": state.params}
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
@@ -233,31 +213,7 @@ def main(argv=None) -> int:
             logger.write_dict(step, results)
 
     total = train_cfg.num_steps
-    # Resume the data stream where the restored run left off: the loader
-    # is deterministic per (seed, epoch, index), so the (epoch, batch)
-    # position is derived from the restored step and the intra-epoch
-    # batches already consumed are skipped without loading.
-    step_i = int(state.step)
-    start_step = step_i
-    per_epoch = max(len(loader), 1)
-    batches = loader.batches(
-        start_epoch=step_i // per_epoch, start_batch=step_i % per_epoch
-    )
-    if chaos.nan_steps:
-        batches = chaos_batches(
-            batches, chaos.nan_steps, start_step=step_i,
-            log=logger.write_text,
-        )
-    # Async input pipeline: a worker thread moves host batches onto device
-    # (into the step's batch sharding) depth>=2 steps ahead, so in steady
-    # state next() hands back an already-device-resident batch and the
-    # loop's only work between dispatches is the rng fold-in.
-    prefetcher = DevicePrefetcher(
-        batches,
-        depth=data_cfg.device_prefetch,  # <2 trades overlap for HBM headroom
-        mesh=mesh,
-        shardings=shardings,
-    )
+    start_step = run.start_step
     # --strict_guards: the invariants graftlint proves statically,
     # asserted live — implicit host pulls inside the step scope raise
     # GuardViolation immediately; steady-state recompiles fail the run at
@@ -271,8 +227,9 @@ def main(argv=None) -> int:
 
         step_guard = StepGuard()
         guard_scope = step_guard.scope
-    sentinel_on = train_cfg.anomaly_sentinel and state.sentinel is not None
-    profiling = False
+    sentinel_on = (
+        train_cfg.anomaly_sentinel and run.state.sentinel is not None
+    )
     profile_scope = contextlib.ExitStack()
     loop_scope = contextlib.ExitStack()
     if step_guard is not None:
@@ -297,85 +254,89 @@ def main(argv=None) -> int:
     train_health = tel.health("train", fresh=True)
     status = 0
     preempted = halted = False
-    train_health.ready(f"training from step {step_i}")
-    try:
-        while step_i < total:
-            if preempt.poll(step_i):
-                preempted = True
-                break
-            if args.profile_steps and step_i == start_step + 1:
-                # Skip the compile step, then trace a few hot steps.
-                from raft_ncup_tpu.utils.profiling import trace
+    train_health.ready(f"training from step {start_step}")
+    def stop(step_i: int) -> bool:
+        nonlocal preempted
+        if step_i >= total:
+            return True
+        preempted = preempt.poll(step_i)
+        return preempted
 
-                profile_scope.enter_context(
-                    trace(os.path.join(run_dir, "profile"))
-                )
-                profiling = True
-            with guard_scope():
-                device_batch = next(prefetcher)
-                rng = jax.random.fold_in(
-                    jax.random.PRNGKey(train_cfg.seed), step_i
-                )
-                state, metrics = step_fn(state, device_batch, rng)
-                step_i += 1  # host-side counter; int(state.step) would sync
-                logger.push(step_i - 1, metrics, lr=schedule(step_i - 1))
-            if chaos.sigterm_after == step_i:
-                # Chaos harness: a REAL signal through the real handler,
-                # pinned to a step boundary so tests replay exactly.
-                os.kill(os.getpid(), signal.SIGTERM)
-            if profiling and step_i >= start_step + 1 + args.profile_steps:
-                jax.block_until_ready(metrics["loss"])
-                profile_scope.close()
-                profiling = False
+    def before_step(step_i: int) -> None:
+        nonlocal profiling
+        if args.profile_steps and step_i == start_step + 1:
+            # Skip the compile step, then trace a few hot steps.
+            from raft_ncup_tpu.utils.profiling import trace
+
+            profile_scope.enter_context(
+                trace(os.path.join(run_dir, "profile"))
+            )
+            profiling = True
+
+    def after_step(step_i: int, metrics: dict) -> bool:
+        """Chaos, profile end, sentinel, checkpoint and validation at the
+        step boundary; True halts the loop (sentinel)."""
+        nonlocal profiling, halted
+        if chaos.sigterm_after == step_i:
+            # Chaos harness: a REAL signal through the real handler,
+            # pinned to a step boundary so tests replay exactly.
+            os.kill(os.getpid(), signal.SIGTERM)
+        if profiling and step_i >= start_step + 1 + args.profile_steps:
+            jax.block_until_ready(metrics["loss"])
+            profile_scope.close()
+            profiling = False
+            logger.write_text(f"profile trace written to {run_dir}/profile")
+        if sentinel_on and step_i % train_cfg.sum_freq == 0:
+            # The sentinel's ONLY host pull: window cadence, explicit
+            # sanctioned device_get — the steady-state loop stays
+            # sync-free (same contract as the Logger's boundary pull).
+            sen = jax.device_get(run.state.sentinel)
+            # Telemetry rides the SAME sanctioned pull: host ints
+            # into gauges, never a second sync (observability/).
+            tel.gauge_set("train_sentinel_skipped", int(sen["skipped"]))
+            tel.gauge_set(
+                "train_sentinel_consecutive", int(sen["consecutive"])
+            )
+            tel.gauge_set(
+                "train_sentinel_ema_grad_norm", float(sen["ema_grad_norm"])
+            )
+            if int(sen["skipped"]):
                 logger.write_text(
-                    f"profile trace written to {run_dir}/profile"
+                    f"sentinel @ {step_i}: skipped={int(sen['skipped'])} "
+                    f"consecutive={int(sen['consecutive'])} "
+                    f"ema_grad_norm={float(sen['ema_grad_norm']):.4f}"
                 )
-            if sentinel_on and step_i % train_cfg.sum_freq == 0:
-                # The sentinel's ONLY host pull: window cadence, explicit
-                # sanctioned device_get — the steady-state loop stays
-                # sync-free (same contract as the Logger's boundary pull).
-                sen = jax.device_get(state.sentinel)
-                # Telemetry rides the SAME sanctioned pull: host ints
-                # into gauges, never a second sync (observability/).
-                from raft_ncup_tpu.observability import get_telemetry
+            if int(sen["consecutive"]) >= train_cfg.sentinel_halt_after:
+                tel.event(
+                    "train_sentinel_halt", step=step_i,
+                    consecutive=int(sen["consecutive"]),
+                )
+                train_health.halted(
+                    f"sentinel: {int(sen['consecutive'])} "
+                    f"consecutive bad steps @ {step_i}"
+                )
+                # Fault trigger: bank the timeline (sentinel gauges,
+                # io-retry events, the halt event itself) before the
+                # rollback + exit-76 path discards the process.
+                tel.flight_dump(
+                    "sentinel_halt", step=step_i,
+                    consecutive=int(sen["consecutive"]),
+                    skipped=int(sen["skipped"]),
+                )
+                halted = True
+                return True
+        if step_i % train_cfg.val_freq == 0 or step_i == total:
+            ckpt.save(run.state)  # synchronous: committed on return
+            run_validation(step_i)
+        return False
 
-                tel = get_telemetry()
-                tel.gauge_set("train_sentinel_skipped", int(sen["skipped"]))
-                tel.gauge_set(
-                    "train_sentinel_consecutive", int(sen["consecutive"])
-                )
-                tel.gauge_set(
-                    "train_sentinel_ema_grad_norm",
-                    float(sen["ema_grad_norm"]),
-                )
-                if int(sen["skipped"]):
-                    logger.write_text(
-                        f"sentinel @ {step_i}: skipped={int(sen['skipped'])} "
-                        f"consecutive={int(sen['consecutive'])} "
-                        f"ema_grad_norm={float(sen['ema_grad_norm']):.4f}"
-                    )
-                if int(sen["consecutive"]) >= train_cfg.sentinel_halt_after:
-                    tel.event(
-                        "train_sentinel_halt", step=step_i,
-                        consecutive=int(sen["consecutive"]),
-                    )
-                    train_health.halted(
-                        f"sentinel: {int(sen['consecutive'])} "
-                        f"consecutive bad steps @ {step_i}"
-                    )
-                    # Fault trigger: bank the timeline (sentinel gauges,
-                    # io-retry events, the halt event itself) before the
-                    # rollback + exit-76 path discards the process.
-                    tel.flight_dump(
-                        "sentinel_halt", step=step_i,
-                        consecutive=int(sen["consecutive"]),
-                        skipped=int(sen["skipped"]),
-                    )
-                    halted = True
-                    break
-            if step_i % train_cfg.val_freq == 0 or step_i == total:
-                ckpt.save(state)  # synchronous: committed on return
-                run_validation(step_i)
+    profiling = False
+    try:
+        train_steps(
+            run, stop, logger=logger, guard_scope=guard_scope,
+            before_step=before_step, after_step=after_step,
+        )
+        state, step_i = run.state, run.step_i
         # ---- post-loop: clean completion / preemption / sentinel halt --
         if preempted:
             # The one atomic preemption checkpoint: every process agreed
