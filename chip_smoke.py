@@ -219,6 +219,7 @@ def phase_eval(seed: int, hw=EVAL_HW, iters=EVAL_ITERS):
     from evaluate import load_variables
     from raft_ncup_tpu.analysis.guards import max_recompiles
     from raft_ncup_tpu.inference.pipeline import ShapeCachedForward
+    from raft_ncup_tpu.ops import nconv
 
     padder, p1, p2 = _padded_pair(seed, hw)
     flows = {}
@@ -228,8 +229,17 @@ def phase_eval(seed: int, hw=EVAL_HW, iters=EVAL_ITERS):
         if variables is None:
             variables = load_variables(model, cfg, None)
         fwd = ShapeCachedForward(model, variables)
+        nconv.reset_dispatch_counts()
         _, up = fwd.forward_device(p1, p2, iters)
         if impl == "volume":
+            # The one trace of the forward: every NCUP layer a tap sum on
+            # the vector units, none an MXU convolution (the weights net's
+            # convolutions are flax layers and never reach nconv2d).
+            engines = nconv.dispatch_counts()
+            check(
+                engines["taps"] > 0 and engines["mxu"] == 0,
+                f"NCUP layers by engine {engines}: one is not a tap sum",
+            )
             with max_recompiles(0):
                 _, up = fwd.forward_device(p1, p2, iters)
         flows[impl] = _checked_flow(impl, padder, up, hw)
@@ -243,6 +253,7 @@ def phase_eval(seed: int, hw=EVAL_HW, iters=EVAL_ITERS):
         "iters": iters,
         "flow_abs_mean_px": float(np.abs(flows["volume"]).mean()),
         "epe_volume_vs_onthefly_px": epe,
+        "nconv_engines": engines,
     }
     return facts, variables, flows["volume"]
 

@@ -1,12 +1,17 @@
 """Pallas TPU kernel: fused normalized convolution (SURVEY.md §2a(b)).
 
-The XLA path (raft_ncup_tpu.ops.nconv.nconv2d) issues two convolutions —
+The XLA path (raft_ncup_tpu.ops.nconv.nconv2d) computes two convolutions —
 ``conv(conf * data)`` and ``conv(conf)`` — plus a divide and a scale
-(reference semantics: core/nconv_modules.py:164-199). On TPU these NCUP
-convolutions are pathological for the MXU: 1-2 channels at FULL image
-resolution (XLA pads channels toward 128 lanes, so the arithmetic is
-~1% useful), run 12 times per forward at e.g. 368x768. They are
-memory-bound shift-and-accumulate stencils, not matmuls.
+(reference semantics: core/nconv_modules.py:164-199). NCUP's convolutions
+are pathological for the MXU: 1-4 channels at FULL image resolution (the
+arithmetic fills ~1% of a tile), once per inference forward and twelve
+times per training step at 368x768. They are shift-and-accumulate
+stencils, not matmuls, and since PR 27 the XLA path computes them as such
+(float32 tap sums on the vector units, forward and both cotangents). Timed
+on a v5e (PERF.md section 6, PR 27): one NCUP forward through this kernel
+4.35 ms at 12 x 368x768 and 10.88 ms at 16 x 440x1024, through the XLA tap
+sums 3.28 and 8.31 (through MXU convolutions 66.7 and 128.5). The kernel
+stays an option (``RAFT_NCUP_NCONV_IMPL=pallas``), off the default path.
 
 This kernel computes the whole NConv2d in ONE pass, as an unrolled
 shift-multiply-accumulate over ROW TILES of the image plane:

@@ -108,6 +108,9 @@ def test_eval_phase_toy():
     assert facts["padded_shape"] == [1, 64, 96, 3]  # InputPadder at work
     assert 0 <= facts["epe_volume_vs_onthefly_px"] < cs.EPE_BUDGET_PX
     assert flow.shape == (1, 60, 90, 2) and "params" in variables
+    # all of NCUP's layers engaged the tap form (four layers, five call
+    # sites: the shared encoder is traced twice), none the MXU
+    assert facts["nconv_engines"] == {"fused": 0, "fallback": 0, "taps": 5, "mxu": 0}
 
 
 def test_server_phase_toy(tmp_path):

@@ -19,6 +19,8 @@ the end (the eval cell's batch-8 forward, the Sintel train step at the
 published batch 6) take two to five minutes each beside busy workers.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -191,6 +193,19 @@ def test_flagship_eval_forward_compiles_for_v5e(sds):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
+def _ncup_plane_convolutions(text: str, planes: int, h: int, w: int) -> list:
+    """Lines of the compiled text with a `convolution` that has an operand
+    of NCUP's plane shape: `planes` full-resolution frames with at most 4
+    channels. Since PR 27 those layers are tap sums on the vector units
+    (`ops/nconv.py::tap_form`); the weights-estimation net's convolutions
+    run at 1/4 resolution with 32-130 channels and are not matched."""
+    plane = re.compile(rf"f32\[{planes},{h},{w},[1-4]\]")
+    return [
+        line.strip()[:200] for line in text.splitlines()
+        if " convolution(" in line and plane.search(line)
+    ]
+
+
 def _record_temp(record_property, compiled) -> float:
     gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
     record_property("temp_size_gib", round(gib, 3))
@@ -204,14 +219,19 @@ def test_eval_cell_volume_forward_compiles_gather_free(sds, record_property):
     `highest` (as `benchmark/configs/` states). The lookup inside the
     loop is dense arithmetic — no gather is left anywhere in the forward
     — and the program's temporaries leave most of the chip free (PR 25:
-    4.54 GiB; the gather form was 5.14 GiB)."""
+    4.54 GiB; the gather form was 5.14 GiB). NCUP, once per forward over
+    16 planes of 440x1024, is tap sums on the vector units (PR 27): no
+    `convolution` has its plane shape (temporaries unchanged, 4.54 GiB)."""
     model, variables = _flagship(sds)
     img = sds((8, 440, 1024, 3))
     with jax.default_matmul_precision("highest"):
         compiled = jax.jit(
             lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
         ).lower(variables, img, img).compile()
-    assert " gather(" not in compiled.as_text()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert " convolution(" in text  # the encoders, the update block
+    assert _ncup_plane_convolutions(text, 16, 440, 1024) == []
     assert _record_temp(record_property, compiled) < 6.0
 
 
@@ -222,9 +242,12 @@ def test_sintel_train_step_compiles_for_v5e(sds, record_property, batch=6):
     contractions since PR 25), AdamW. Since PR 26 rematerialises the
     encoders and takes NCUP's kernel gradient tap by tap, the step asks
     5.4 GiB at `highest` (14.8 GiB before; at batch 2 2.1 GiB, 6.7 before).
-    One compile, at jax's default precision (minutes less than `highest`,
-    the same buffers), ~5 min beside the other workers: batch 2, which this
-    test compiled until PR 25, is left to `chip_smoke.py`."""
+    Since PR 27 NCUP's convolutions are float32 tap sums on the vector
+    units, forward and both cotangents: no `convolution` over its planes is
+    left in the step, whose temporaries at one pass read 5.42 GiB (5.05
+    before). One compile, at jax's default precision (minutes less than
+    `highest`, the same buffers), 2-6 min beside the other workers: batch 2,
+    which this test compiled until PR 25, is left to `chip_smoke.py`."""
     limit_gib = 8.0
     from raft_ncup_tpu.config import TrainConfig, flagship_config
     from raft_ncup_tpu.models.raft import RAFT
@@ -248,4 +271,5 @@ def test_sintel_train_step_compiles_for_v5e(sds, record_property, batch=6):
     rng = _abstract(sds, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     step = make_train_step(RAFT(model_cfg), train_cfg, mesh=None)
     compiled = step.lower(state, data, rng).compile()
+    assert _ncup_plane_convolutions(compiled.as_text(), 12, 368, 768) == []
     assert _record_temp(record_property, compiled) < limit_gib
