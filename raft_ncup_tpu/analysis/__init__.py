@@ -13,9 +13,8 @@ Two layers, one invariant set:
   accelerator backend.
 - **runtime guards** (``guards.py``): ``forbid_host_transfers`` /
   ``RecompileWatchdog`` / ``max_recompiles`` / ``strict_guards`` assert
-  the same invariants live, on the actual train/bench loop (pytest
-  fixtures in tests/conftest.py; ``--strict_guards`` in train.py;
-  counter rows in bench.py).
+  the same invariants live, on the actual train loop (pytest
+  fixtures in tests/conftest.py; ``--strict_guards`` in train.py).
 
 The linter proves the invariants statically; the guards catch what
 static analysis cannot see (dispatch-time transfers, shape-drift

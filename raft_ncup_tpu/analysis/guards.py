@@ -12,7 +12,7 @@ dtype drift. Three primitives:
   ``jax.Array``) and raises :class:`GuardViolation` (or counts, with
   ``raise_on_violation=False``). The *explicit* ``jax.device_get`` stays
   sanctioned — it is the contract for window-boundary pulls (the
-  Logger's one-get-per-``sum_freq``; the bench loop's one-get-per-window).
+  Logger's one-get-per-``sum_freq``; the eval loop's one-get-per-window).
   Layered on top, ``jax.transfer_guard_device_to_host("disallow")``
   catches native-path transfers on real accelerators; the Python-level
   interception exists because on the CPU backend device→host is zero-copy
@@ -72,8 +72,7 @@ class GuardViolation(RuntimeError):
 
 @dataclass(eq=False)  # a counter object: identity, not value, equality
 class GuardStats:
-    """Counters a guard scope fills in; the bench row and --strict_guards
-    report these."""
+    """Counters a guard scope fills in; --strict_guards reports these."""
 
     host_transfers: int = 0  # forbidden implicit pulls observed
     sanctioned_gets: int = 0  # explicit jax.device_get calls
@@ -238,8 +237,7 @@ def forbid_host_transfers(
     """Forbid implicit device→host pulls inside the scope.
 
     Yields the :class:`GuardStats` being filled. With
-    ``raise_on_violation=False`` violations only count (the bench row's
-    mode). ``native_guard`` additionally arms jax's own
+    ``raise_on_violation=False`` violations only count. ``native_guard`` additionally arms jax's own
     ``transfer_guard_device_to_host("disallow")`` — real coverage on
     accelerators, inert on zero-copy CPU.
     """

@@ -4,7 +4,7 @@ Per-module rules (JGL001-JGL010) see one file at a time; the invariants
 the fleet/observability control plane lives by are cross-file — an
 attribute is written under ``self._lock`` in one method and read without
 it in another, a wire header key is produced in ``serve.py`` and
-consumed in ``fleet/router.py``, an env knob is read in ``bench.py`` and
+consumed in ``fleet/router.py``, an env knob is read in ``serve.py`` and
 declared (or not) in ``utils/knobs.py``. :class:`ProjectIndex` walks
 every parsed module ONCE and collects the per-site facts those rules
 need:

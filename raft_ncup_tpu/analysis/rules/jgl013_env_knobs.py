@@ -1,6 +1,6 @@
 """JGL013 — one env-knob registry, no stragglers.
 
-Every ``RAFT_NCUP_*``/``BENCH_*`` environment knob is declared exactly
+Every ``RAFT_NCUP_*`` environment knob is declared exactly
 once in ``raft_ncup_tpu/utils/knobs.py`` (name, kind, default, doc) and
 read exclusively through its ``knob_*`` getters — the same
 one-declarative-object discipline the repo applies to fleet topology
@@ -18,7 +18,7 @@ and SLOs. Three checks, all whole-program:
   a dead knob, or a migration that silently dropped a reader. This
   half only runs when the linted set contains BOTH the registry
   (``knobs.py``) and every driver entry point (``train.py``,
-  ``serve.py``, ``bench.py`` — where most knob readers live): a
+  ``serve.py`` — where knob readers outside the package live): a
   package-only lint sees the registry but not the drivers and cannot
   call a knob dead, the same scope-completeness gate JGL012 applies
   to its drift halves.
@@ -27,8 +27,7 @@ Names are resolved through module-level string constants and import
 aliases (``os.environ.get(TELEMETRY_ENV)`` with ``TELEMETRY_ENV``
 imported from another module still resolves); dynamic names are out of
 static reach — the getters' runtime registry check covers them.
-Internal child-process handshake variables (``_BENCH_*``) do not match
-the prefixes and stay unmanaged on purpose.
+Variables under any other prefix are not knobs and stay unmanaged.
 """
 
 from __future__ import annotations
@@ -47,11 +46,11 @@ SUMMARY = (
     "registered knob never read (whole-program)"
 )
 
-KNOB_PREFIX = re.compile(r"^(RAFT_NCUP_|BENCH_)")
+KNOB_PREFIX = re.compile(r"^RAFT_NCUP_")
 
 # The entry points outside the package where knob readers live; the
 # unread-knob half only runs when all of them are in the linted set.
-DRIVER_BASENAMES = frozenset({"train.py", "serve.py", "bench.py"})
+DRIVER_BASENAMES = frozenset({"train.py", "serve.py"})
 
 
 def _basename(path: str) -> str:

@@ -182,8 +182,8 @@ def str2mesh(v: str) -> tuple[int, ...]:
 
 
 def add_mesh_arg(parser: argparse.ArgumentParser) -> None:
-    """The (data x spatial[, pipe]) SPMD mesh flag shared by evaluate.py,
-    serve.py, and bench.py (docs/SHARDING.md)."""
+    """The (data x spatial[, pipe]) SPMD mesh flag shared by evaluate.py
+    and serve.py (docs/SHARDING.md)."""
     parser.add_argument(
         "--mesh", type=str2mesh, default=None, metavar="DATA,SPATIAL[,PIPE]",
         help="run the inference/serving stack sharded on a "

@@ -47,8 +47,8 @@ array can add a device sync to every request it routes.
 Chaos: ``killreplica@N`` / ``stallreplica@N`` / ``drainreplica@N`` +
 the fleet-scale ``partitionhost@N`` / ``killsupervisor@N``
 (resilience/chaos.py) drive the blast-radius tests in
-tests/test_fleet.py. Bench: the guarded ``fleet_*`` and
-``elasticity_*`` rows in bench.py.
+tests/test_fleet.py. No cell of the benchmark runs the fleet
+(docs/FLEET.md "Not brought up on the chip yet").
 """
 
 from raft_ncup_tpu.fleet.autoscaler import FleetAutoscaler  # noqa: F401

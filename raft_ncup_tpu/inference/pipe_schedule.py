@@ -44,9 +44,8 @@ virtual "device groups" share one host, so the S× throughput claim is
 NOT measurable here — what IS pinnable is everything load-bearing:
 output parity with the monolithic scan, carry-handoff correctness at
 every seam, donation, guard-clean steady state, and the
-collective-permute fingerprint. The throughput claim stages for
-ROADMAP item 1's chip window via bench.py's guarded
-``pipeline_pairs_per_sec`` row.
+collective-permute fingerprint. The throughput claim is not measured:
+no cell of the benchmark runs the pipe axis (ROADMAP D6).
 
 **v1 scope**: the pipe axis composes with ``data``/``spatial`` sizes
 of 1 only. Running spatial sharding INSIDE a pipeline stage needs the

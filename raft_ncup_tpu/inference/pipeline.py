@@ -35,8 +35,7 @@ The eval loop's steady state mirrors the train loop's (docs/PERF.md):
 Run the whole loop under ``analysis/guards.py``
 (``forbid_host_transfers`` + ``RecompileWatchdog``) and it inherits the
 train loop's invariants: zero implicit host pulls, zero steady-state
-recompiles (tests/test_inference_pipeline.py pins both; bench.py's
-``val_*`` row records them).
+recompiles (tests/test_inference_pipeline.py pins both).
 """
 
 from __future__ import annotations

@@ -1,7 +1,6 @@
 """Telemetry export layer: the process-wide hub, a bounded JSONL event
 sink, periodic snapshots, a Prometheus text dump, and the one
-``telemetry_report()`` dict that ``serve.py --report`` and ``bench.py``
-both read.
+``telemetry_report()`` dict that ``serve.py --report`` reads.
 
 The :class:`Telemetry` hub bundles one :class:`MetricsRegistry` and one
 :class:`SpanTracer` behind no-op-when-disabled facade methods — every

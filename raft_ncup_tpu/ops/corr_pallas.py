@@ -142,7 +142,7 @@ def tuning_meta() -> dict:
 
 
 # Trace-time per-level dispatch tally, mirroring ops.nconv: callers that
-# label a measurement "corr=pallas" (bench.py) use this to tell which
+# label a measurement "corr=pallas" (chip_smoke.py) use this to tell which
 # tier carried each pyramid level — resident kernel, banded kernel, or
 # the XLA onthefly fallback (partial mixes are by design at large
 # shapes and still count as the kernel running). Guarded by a lock:
@@ -171,7 +171,7 @@ def dispatch_counts() -> dict:
     level per TRACE — a custom_vjp backward trace, a shape-driven
     retrace, or a concurrent thread each add their own tallies, so the
     counts are only interpretable between a reset and a single lowering
-    in a single thread, the discipline bench.py follows (mutation
+    in a single thread, the discipline its callers follow (mutation
     itself is lock-guarded, so concurrent traces can't lose counts)."""
     with _counts_lock:
         return dict(_dispatch_counts)

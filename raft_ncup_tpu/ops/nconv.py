@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 # Trace-time dispatch tally for the fused-kernel path: callers that label a
-# measurement "nconv=pallas" (bench.py) must be able to tell whether the
+# measurement "nconv=pallas" (chip_smoke.py) must be able to tell whether the
 # fused kernel actually ran or every call silently fell back to XLA
 # (ADVICE r3: a baseline pinned under '+nconv_pallas' that measured the
 # XLA path would poison every later comparison).
@@ -80,7 +80,7 @@ def dispatch_counts() -> dict:
     TRACE), not runtime executions — extra traces in the same process
     (custom_vjp backward, retraces, concurrent threads) inflate the
     tally, so values are only interpretable between a reset and a single
-    lowering in a single thread (bench.py's discipline)."""
+    lowering in a single thread (the callers' discipline)."""
     return dict(_dispatch_counts)
 
 

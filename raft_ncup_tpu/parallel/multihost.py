@@ -168,8 +168,8 @@ def barrier(name: str, timeout_s: float = 480.0) -> bool:
         # (e.g. the timeout keyword renamed) must degrade like the API
         # being absent, per this helper's contract.
         # stderr: child stdout is a parsed protocol stream in the tooling
-        # around this helper (tests/_distributed_child.py's LOSS= lines,
-        # bench.py's JSON-tail harvest) — diagnostics must not mix in.
+        # around this helper (tests/_distributed_child.py's LOSS= lines)
+        # — diagnostics must not mix in.
         print(
             f"multihost barrier unavailable ({e}); proceeding unaligned",
             file=sys.stderr,

@@ -32,7 +32,8 @@ inference stack already provides (``ops/padding.InputPadder(bucket=N)``
   (tests/test_serving.py) and the ``serve.py`` demo loop.
 
 Semantics, the executable-set arithmetic, and the chaos matrix:
-docs/SERVING.md. Bench: the guarded ``serve_*`` row in bench.py.
+docs/SERVING.md. Benchmark: the cell ``serve_sintel_raft``
+(benchmark/README.md).
 """
 
 from raft_ncup_tpu.serving.admission import AdmissionQueue  # noqa: F401

@@ -44,9 +44,8 @@ def nearest_rank_ms(latencies_s: Sequence[float], p: float) -> Optional[float]:
 
     The textbook estimator — value at index ``ceil(p*n) - 1`` of the
     sorted sample (p50 of 16 values is the 8th smallest, not the 9th a
-    floor-index would give) — shared by serve.py and bench.py so the
-    reported ``serve_p50_ms``/``serve_p99_ms`` mean the same thing
-    everywhere. ``None`` on an empty sample.
+    floor-index would give) — shared by serve.py and the benchmark so the
+    reported percentiles mean the same thing everywhere. ``None`` on an empty sample.
     """
     if not latencies_s:
         return None

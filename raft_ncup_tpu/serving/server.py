@@ -45,8 +45,7 @@ is ever silently lost; everything refused is told so explicitly.
 
 Invariants inherited from the rest of the stack: the steady-state
 serving loop performs zero implicit host transfers and zero recompiles
-(tests/test_serving.py pins both under ``analysis/guards.py``; bench.py
-records them as ``serve_recompiles`` / ``serve_host_transfers``). The
+(tests/test_serving.py pins both under ``analysis/guards.py``). The
 per-batch result pull is the *product* here, not a leak — it flows
 through the one sanctioned explicit ``jax.device_get`` in the
 ``AsyncDrain`` worker.

@@ -45,7 +45,7 @@ arithmetic blend), and the reset stream's next frame is bitwise a cold
 start. Eviction and slot reuse touch no device memory (the new owner's
 first frame is forced cold), so the steady-state executable set is
 exactly ``len(batch_sizes)`` programs: zero recompiles, zero implicit
-host transfers (``bench.py``'s ``stream_*`` row records both).
+host transfers (tests/test_streaming.py pins both).
 
 Drain contract: ``drain()`` stops stream and frame admission, flushes
 every admitted frame through compute, tears down, and returns the final

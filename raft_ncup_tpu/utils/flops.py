@@ -4,10 +4,11 @@ The reference records no FLOPs or throughput anywhere (BASELINE.md); this
 module provides an analytic per-forward FLOP count from the architecture
 constants (reference anchors: encoders core/extractor.py:118-192, corr
 matmul core/corr.py:13-21, update block core/update.py:79-141, NCUP
-core/upsampler.py:143-177 + core/nconv_modules.py:25-136) so the bench can
-report MFU = achieved FLOPs/s over the chip's peak. When a compiled
+core/upsampler.py:143-177 + core/nconv_modules.py:25-136) so a report can
+state MFU = achieved FLOPs/s over the chip's peak. When a compiled
 executable is at hand, prefer XLA's own ``cost_analysis()['flops']`` —
-``bench.py`` uses that and falls back to this estimate.
+the cost ledger (``inference/costs.py``) banks that for every warmed
+executable.
 
 Counting convention: one conv = 2*k*k*Cin*Cout*Hout*Wout FLOPs (MAC = 2).
 Elementwise/normalization work is ignored (sub-1% for these models).

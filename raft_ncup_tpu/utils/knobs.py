@@ -1,5 +1,5 @@
-"""The single env-knob registry: every ``RAFT_NCUP_*``/``BENCH_*``
-environment variable the repo reads is declared here ONCE — name, type,
+"""The single env-knob registry: every ``RAFT_NCUP_*`` environment
+variable the repo reads is declared here ONCE — name, type,
 default, one doc line — and read ONLY through the ``knob_*`` getters
 below. Lint rule JGL013 (analysis/rules/jgl013_env_knobs.py) enforces
 both halves statically: a bare ``os.environ`` read of a matching name
@@ -101,108 +101,6 @@ KNOBS: Tuple[Knob, ...] = (
     Knob("RAFT_NCUP_CPU_PEAK_FLOPS", "raw", None,
          "Override the nominal per-host CPU peak FLOP/s used for CPU "
          "MFU; unset = cores x 4.8e10."),
-    # ------------------------------------------------------ bench: run
-    Knob("BENCH_BUDGET_S", "float", "840",
-         "Total bench wall-clock budget in seconds; remaining rows are "
-         "skipped once it is exhausted."),
-    Knob("BENCH_MESH", "raw", None,
-         "Mesh spec 'data,model' for the sharded bench rows; the "
-         "--mesh flag's env fallback."),
-    Knob("BENCH_TRACE_DIR", "raw", None,
-         "Directory for bench JAX traces; unset disables tracing."),
-    Knob("BENCH_CORR_IMPL", "str", "volume",
-         "Correlation implementation the main bench rows run "
-         "('volume', 'onthefly', 'pallas')."),
-    Knob("BENCH_ALLOW_FULL_ON_CPU", "flag", "0",
-         "Run the full-resolution bench shape on a CPU host (normally "
-         "refused: it would blow the budget)."),
-    Knob("BENCH_STRICT_GUARDS", "flag", "0",
-         "Escalate bench guard-rail violations (recompiles, host "
-         "transfers) from warnings to hard failures."),
-    # ----------------------------------------------------- bench: skip
-    Knob("BENCH_SKIP_TRAIN", "flag", "0", "Skip the train bench row."),
-    Knob("BENCH_SKIP_VAL", "flag", "0", "Skip the val bench row."),
-    Knob("BENCH_SKIP_SERVE", "flag", "0", "Skip the serve bench row."),
-    Knob("BENCH_SKIP_STREAM", "flag", "0",
-         "Skip the streaming bench row."),
-    Knob("BENCH_SKIP_FLEET", "flag", "0", "Skip the fleet bench row."),
-    Knob("BENCH_SKIP_ELASTICITY", "flag", "0",
-         "Skip the elasticity bench row."),
-    Knob("BENCH_SKIP_BF16", "flag", "0", "Skip the bf16 bench row."),
-    Knob("BENCH_SKIP_HIGHRES", "flag", "0",
-         "Skip the high-resolution bench row."),
-    Knob("BENCH_SKIP_UHD", "flag", "0", "Skip the 4K/UHD bench row."),
-    Knob("BENCH_SKIP_PIPELINE", "flag", "0",
-         "Skip the iteration-pipelined bench row."),
-    Knob("BENCH_SKIP_EARLYEXIT", "flag", "0",
-         "Skip the early-exit bench row."),
-    Knob("BENCH_SKIP_TELEMETRY_COMPARE", "flag", "0",
-         "Skip the telemetry-overhead comparison window in the serve "
-         "and fleet rows."),
-    # --------------------------------------------------- bench: sizing
-    Knob("BENCH_TRAIN_LOOP_STEPS", "int", "6",
-         "Steps the train bench row runs."),
-    Knob("BENCH_VAL_LOOP_BATCHES", "int", "8",
-         "Batches per val bench rep."),
-    Knob("BENCH_VAL_LOOP_REPS", "int", "5", "Val bench reps."),
-    Knob("BENCH_SERVE_REQUESTS", "int", "16",
-         "Requests the serve bench row issues."),
-    Knob("BENCH_STREAM_STREAMS", "int", "4",
-         "Concurrent streams in the streaming bench row."),
-    Knob("BENCH_STREAM_FRAMES", "int", "6",
-         "Frames per stream in the streaming bench row."),
-    Knob("BENCH_FLEET_REPLICAS", "int", "2",
-         "Replica count the fleet bench row spawns."),
-    Knob("BENCH_FLEET_REQUESTS", "int", "12",
-         "Requests the fleet bench row routes."),
-    Knob("BENCH_ELASTICITY_LOW", "int", "4",
-         "Low-tide request count for the elasticity bench row."),
-    Knob("BENCH_ELASTICITY_HIGH", "int", "48",
-         "High-tide request count for the elasticity bench row."),
-    Knob("BENCH_ELASTICITY_GRACE_S", "float", "120",
-         "Scale-settle grace period for the elasticity bench row."),
-    Knob("BENCH_HIGHRES_SIZE", "str", "1088,1920",
-         "High-resolution bench row frame size 'H,W'."),
-    Knob("BENCH_HIGHRES_ITERS", "int", "32 on accelerator, 2 on CPU",
-         "RAFT iterations for the high-resolution bench row."),
-    Knob("BENCH_HIGHRES_REPS", "int", "3 on accelerator, 2 on CPU",
-         "High-resolution bench reps."),
-    Knob("BENCH_HIGHRES_COMPARE", "enabled", "1",
-         "Also time the unsharded reference window when a mesh is "
-         "active ('0' skips the comparison)."),
-    Knob("BENCH_UHD_SIZE", "str", "2176,3840",
-         "UHD bench row frame size 'H,W'."),
-    Knob("BENCH_UHD_ITERS", "int", "32 on accelerator, 1 on CPU",
-         "RAFT iterations for the UHD bench row."),
-    Knob("BENCH_UHD_REPS", "int", "3 on accelerator, 2 on CPU",
-         "UHD bench reps."),
-    Knob("BENCH_UHD_CORR", "str", "pallas on accelerator, onthefly on CPU",
-         "Correlation implementation for the UHD bench row."),
-    Knob("BENCH_PIPELINE_SEGMENTS", "posint", None,
-         "Pipeline segment count; unset = largest of 4, 2 that fits "
-         "the device count, else 1."),
-    Knob("BENCH_PIPELINE_SIZE", "str", "256,448",
-         "Pipeline bench row frame size 'H,W'."),
-    Knob("BENCH_PIPELINE_ITERS", "int", "32 on accelerator, 4 on CPU",
-         "RAFT iterations for the pipeline bench row (quantized down "
-         "to a segment boundary)."),
-    Knob("BENCH_PIPELINE_BATCHES", "int", "2 x segments",
-         "Micro-batches streamed through the pipeline bench row."),
-    Knob("BENCH_PIPELINE_COMPARE", "enabled", "1",
-         "Also time the monolithic (single-segment) reference window "
-         "('0' skips the comparison)."),
-    Knob("BENCH_EARLYEXIT_TOL", "float", "0.016",
-         "Convergence tolerance the early-exit bench row measures with "
-         "(low-res px; default tuned for the untrained bench weights)."),
-    Knob("BENCH_EARLYEXIT_ITERS", "int", "4",
-         "Iteration budget for the early-exit bench row (both windows). "
-         "Default sized for the untrained bench weights, whose flow "
-         "deltas plateau instead of decaying: converged lanes exit "
-         "around iteration 2, and the quality price grows with every "
-         "budgeted-but-skipped iteration, so a small budget keeps the "
-         "measured EPE delta inside EARLYEXIT_EPE_BUDGET."),
-    Knob("BENCH_EARLYEXIT_REQUESTS", "int", "12",
-         "Mixed-resolution requests the early-exit bench row streams."),
 )
 
 

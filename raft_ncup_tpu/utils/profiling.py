@@ -5,7 +5,7 @@ Here, one span system on the profiler's clock:
 
 - :func:`trace` captures a ``jax.profiler`` trace into a directory
   (``train.py --profile_steps``, ``evaluate.py`` / ``serve.py
-  --trace_dir``, ``bench.py --trace_dir``) and leaves beside it
+  --trace_dir``) and leaves beside it
   ``op_scopes.json``: which ``jax.named_scope`` each compiled
   instruction belongs to, from the cost ledger's compile-time bank
   (``inference/costs.py``), because a TPU trace's ``XLA Ops`` events
@@ -23,8 +23,6 @@ Here, one span system on the profiler's clock:
   tested without a trace, and :func:`read_device_trace` is the only
   part that touches ``jax.profiler.ProfileData``.
   ``scripts/device_trace_report.py`` prints it.
-- a wall-clock throughput meter for the north-star metric
-  (frame-pairs/sec/chip).
 """
 
 from __future__ import annotations
@@ -36,8 +34,7 @@ import heapq
 import json
 import os
 import re
-import time
-from typing import Callable, Iterable, Iterator, Optional
+from typing import Iterable, Iterator, Optional
 
 import jax
 
@@ -84,43 +81,6 @@ def trace(log_dir: Optional[str]) -> Iterator[None]:
 
         with open(os.path.join(log_dir, OP_SCOPES_FILE), "w") as f:
             json.dump(get_cost_ledger().op_scopes(), f)
-
-
-def measure_throughput(
-    fn: Callable[[], object],
-    warmup: int = 2,
-    reps: int = 5,
-    sync: Optional[Callable[[object], None]] = None,
-) -> float:
-    """Time ``fn`` (one unit of work) and return calls/sec."""
-    return measure_throughput_detailed(fn, warmup, reps, sync)[0]
-
-
-def measure_throughput_detailed(
-    fn: Callable[[], object],
-    warmup: int = 2,
-    reps: int = 5,
-    sync: Optional[Callable[[object], None]] = None,
-) -> tuple[float, list[float]]:
-    """Time ``fn`` per-rep and return ``(calls/sec, [rep_seconds...])``.
-
-    ``sync`` receives the output and must force completion (e.g. pull one
-    scalar to host); defaults to ``jax.block_until_ready``. Each rep is
-    synced individually so the record can carry dispersion — single-shot
-    CPU numbers on a shared host wobble ±5-10% (VERDICT r4 weak #1) and a
-    mean alone cannot distinguish noise from regression. The per-rep sync
-    costs one host round-trip per rep, negligible against the >100 ms
-    step times this harness measures.
-    """
-    sync = sync or (lambda out: jax.block_until_ready(out))
-    for _ in range(warmup):
-        sync(fn())
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        sync(fn())
-        times.append(time.perf_counter() - t0)
-    return reps / sum(times), times
 
 
 # ------------------------------------------------ reducing a device trace

@@ -24,8 +24,8 @@ DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".cache", "xla")
 
 
 def host_fingerprint() -> str:
-    """Stable-ish host id (cpu model + core count, sha1/8). Keys the CPU
-    perf baselines in bench.py only: cross-host CPU numbers differ >2x."""
+    """Stable-ish host id (cpu model + core count, sha1/8). Cross-host
+    CPU numbers differ >2x: a CPU figure is only comparable under one id."""
     import hashlib
 
     try:

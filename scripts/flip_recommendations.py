@@ -1,6 +1,9 @@
 #!/usr/bin/env python
 """Data-driven kernel-default recommendations from a bench record.
 
+Its input is no longer produced: bench.py went in PR 28, and ROADMAP D4
+removes this script with its tests.
+
 Reads one bench.py JSON record (file argument or stdin) and prints which
 implementation defaults the measurements support flipping:
 

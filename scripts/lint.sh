@@ -1,6 +1,6 @@
 #!/bin/bash
-# graftlint over everything that ships: the package, the drivers, the
-# bench and the scripts. Strict allowlist mode — an entry that no longer
+# graftlint over everything that ships: the package, the drivers and
+# the scripts. Strict allowlist mode — an entry that no longer
 # suppresses anything must be deleted (or its finding has come back).
 # Rule catalog + allowlist format: docs/ANALYSIS.md.
 # raft_ncup_tpu/observability/ and raft_ncup_tpu/fleet/ are named
@@ -13,5 +13,5 @@ cd "$(dirname "$0")/.."
 exec python -m raft_ncup_tpu.analysis \
     --strict-allowlist \
     raft_ncup_tpu/ raft_ncup_tpu/observability/ raft_ncup_tpu/fleet/ \
-    train.py evaluate.py demo.py serve.py bench.py scripts/ \
+    train.py evaluate.py demo.py serve.py scripts/ \
     "$@"

@@ -171,8 +171,7 @@ def build_parser() -> argparse.ArgumentParser:
                         help="embed the full telemetry report "
                         "(observability.telemetry_report(): registry "
                         "snapshot, per-stage p50/p99, event accounting) "
-                        "in the printed JSON — the same dict bench.py "
-                        "reads")
+                        "in the printed JSON")
     parser.add_argument("--telemetry_jsonl", default=None, metavar="PATH",
                         help="write periodic telemetry snapshots to this "
                         "bounded JSONL sink while serving "

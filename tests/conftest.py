@@ -52,8 +52,69 @@ def max_recompiles():
     return mr
 
 
+# The order in which files are handed to the workers, decided here and
+# nowhere else. The driver runs `-n 6 --dist loadfile`: a file is one unit
+# of work, a worker takes the next unit of the queue when it has at most
+# two tests left, and xdist's default queue is "most tests first", which
+# hands the cheap many-test files out first and the file that compiles
+# whole programs for the chip minutes into the run, to end alone (1378 s
+# of wall for a file of 1088 s, PR 28). So: the files that cost over 100 s
+# in the driver's junit file of that run go first, longest first, and the
+# rest follow in collection order (but see CPU_BUDGETED_FILES_LAST below).
+# tests/test_tpu_aot_compile.py must stay
+# first: it is the critical path whatever follows it, and with more than
+# two tests it gets no second unit queued behind it at the start.
+# tests/test_collection_order.py fails when a listed file is gone.
+LONGEST_FILES_FIRST = (
+    "tests/test_tpu_aot_compile.py",
+    "tests/benchmark/test_train_cell.py",
+    "tests/benchmark/test_benchmark.py",
+    "tests/test_chip_smoke.py",
+    "tests/test_train_loop.py",
+    "tests/test_corr_pallas.py",
+    "tests/test_drivers.py",
+    "tests/test_nconv.py",
+    "tests/test_corr.py",
+    "tests/test_chaos_train.py",
+    "tests/test_pac.py",
+    "tests/test_multihost.py",
+    "tests/test_checkpoint.py",
+    "tests/test_earlyexit.py",
+)
+
+
+# ...and last, the file with a test that holds a CPU-time budget
+# (tests/test_lint.py::test_whole_program_pass_stays_fast: 5 CPU-seconds
+# for 2.3 alone). Beside six busy workers and the TPU compiler's threads
+# that reading doubles; the end of the run, when most workers have nothing
+# left, is as quiet as its old place at the start used to be.
+CPU_BUDGETED_FILES_LAST = ("tests/test_lint.py",)
+
+_RANK = {path: i for i, path in enumerate(LONGEST_FILES_FIRST)}
+_RANK.update(
+    (path, len(LONGEST_FILES_FIRST) + 1 + i)
+    for i, path in enumerate(CPU_BUDGETED_FILES_LAST)
+)
+
+
+def file_rank(nodeid: str) -> int:
+    """A test's place in the hand-out order, by its file: the index in
+    LONGEST_FILES_FIRST, one rank after them all for an unlisted file,
+    and after those the files of CPU_BUDGETED_FILES_LAST."""
+    return _RANK.get(nodeid.split("::", 1)[0], len(LONGEST_FILES_FIRST))
+
+
+def pytest_collection_modifyitems(config, items):
+    # Stable: tests keep their order within a file, unlisted files theirs.
+    items.sort(key=lambda item: file_rank(item.nodeid))
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "reference: tests that import the read-only reference repo"
     )
     config.addinivalue_line("markers", "slow: long-running tests")
+    # xdist's count-based reorder of the queue would undo the order above;
+    # without xdist (`-p no:xdist`) the option does not exist.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False

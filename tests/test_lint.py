@@ -1488,7 +1488,7 @@ def test_jgl013_flags_stragglers_unregistered_and_dead_knobs(tmp_path):
             # The unread-knob half is gated on the full driver scope.
             "train.py": "",
             "serve.py": "",
-            "bench.py": """
+            "evaluate.py": """
             import os
             from raft_ncup_tpu.utils.knobs import knob_str
 
@@ -1518,7 +1518,7 @@ def test_jgl013_flags_stragglers_unregistered_and_dead_knobs(tmp_path):
 
 def test_jgl013_registered_reads_and_non_knob_names_clean(tmp_path):
     """Getter reads of registered names are the sanctioned shape;
-    non-prefixed env vars (PATH, _BENCH_* internals) are not knobs."""
+    non-prefixed env vars (PATH, JAX_PLATFORMS) are not knobs."""
     findings = lint_files(
         tmp_path,
         {
@@ -1534,7 +1534,7 @@ def test_jgl013_registered_reads_and_non_knob_names_clean(tmp_path):
             def f():
                 good = knob_str("RAFT_NCUP_ALPHA")
                 benign = os.environ.get("PATH")
-                internal = os.environ.get("_BENCH_FORCE_PLATFORM")
+                internal = os.environ.get("JAX_PLATFORMS")
                 return good, benign, internal
             """,
         },
@@ -1741,27 +1741,45 @@ def test_catalog_markdown_covers_registry():
 # ------------------------------------------------------------ self-check
 
 
+_TIMED_LINT = """
+import sys, time
+from raft_ncup_tpu.analysis.lint import DEFAULT_ALLOWLIST, run_lint
+t0 = time.process_time()
+run_lint(sys.argv[1:], allowlist_path=DEFAULT_ALLOWLIST)
+print(time.process_time() - t0)
+"""
+
+
 def test_whole_program_pass_stays_fast():
     """The project pass (one extra AST walk + three cross-module rules)
     must not turn lint.sh into a coffee break: the full tree-wide run,
     all rules, stays under 5 CPU-seconds. Budgeted on process time, not
-    wall — the pass is single-threaded in-process work, and wall time on
-    a loaded single-core CI host measures the host's OTHER tenants, not
-    a lint regression."""
-    import time as _time
-
-    from raft_ncup_tpu.analysis.lint import DEFAULT_ALLOWLIST
-
+    wall — the pass is single-threaded work, and wall time on a loaded
+    CI host measures the host's OTHER tenants, not a lint regression.
+    Timed in a process of its own, as lint.sh pays it, and best of three:
+    the pass is 2.3 CPU-seconds alone, up to 3.9 in a fresh process
+    beside six busy ones, and read 5.9 inside an xdist worker ten minutes
+    into the suite (PR 28: whatever that worker's earlier tests left
+    behind is charged to it too). A regression shows in every try where
+    a noisy neighbour does not."""
     paths = [
         os.path.join(REPO, p)
         for p in (
             "raft_ncup_tpu", "train.py", "evaluate.py", "demo.py",
-            "serve.py", "bench.py", "scripts",
+            "serve.py", "scripts",
         )
     ]
-    t0 = _time.process_time()
-    run_lint(paths, allowlist_path=DEFAULT_ALLOWLIST)
-    assert _time.process_time() - t0 < 5.0
+    best = float("inf")
+    for _ in range(3):
+        proc = subprocess.run(
+            [sys.executable, "-c", _TIMED_LINT, *paths],
+            cwd=REPO, capture_output=True, text=True, timeout=120,
+        )
+        assert proc.returncode == 0, proc.stderr
+        best = min(best, float(proc.stdout.split()[-1]))
+        if best < 5.0:
+            break
+    assert best < 5.0
 
 
 def test_shipped_tree_lints_clean_via_module_cli():
@@ -1781,7 +1799,7 @@ def test_shipped_tree_lints_clean_via_module_cli():
 
 
 def test_drivers_and_scripts_lint_clean():
-    """lint.sh's wider scope (drivers, bench, scripts) stays clean too —
+    """lint.sh's wider scope (drivers, scripts) stays clean too —
     in-process, so the tier-1 gate catches driver regressions without a
     subprocess."""
     from raft_ncup_tpu.analysis.lint import DEFAULT_ALLOWLIST
@@ -1790,7 +1808,7 @@ def test_drivers_and_scripts_lint_clean():
         os.path.join(REPO, p)
         for p in (
             "raft_ncup_tpu", "train.py", "evaluate.py", "demo.py",
-            "serve.py", "bench.py", "scripts",
+            "serve.py", "scripts",
         )
     ]
     result = run_lint(paths, allowlist_path=DEFAULT_ALLOWLIST)

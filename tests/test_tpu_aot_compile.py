@@ -13,13 +13,28 @@ All of it lives in this ONE file: the worker that runs it loads libtpu
 and keeps its lock until it exits, so a second file on another xdist
 worker could describe no topology. The topology is described inside a
 fixture, never at import, and every compile happens in the test's own
-process. Each kernel case was probed by hand first and compiles (or is
-gated off) within seconds (~80 s together); the two whole programs at
-the end (the eval cell's batch-8 forward, the Sintel train step at the
-published batch 6) take two to five minutes each beside busy workers.
+process.
+
+What the file costs, and why its place in the run matters. It is ten
+minutes of the suite and, being one file, one worker's work: the suite's
+wall cannot go under its length. tests/conftest.py therefore hands it
+out first (`LONGEST_FILES_FIRST`). The two whole programs (the Sintel
+train step at the published batch 6, the eval cell's batch-8 forward)
+are compiled ONCE each, in module-scoped fixtures, and every property of
+a program is a test of its own name on that one compile. The train step
+is 4 s of tracing, 1 s of lowering and some 600 CPU-seconds in the TPU
+compiler (its memory report is written ~20 s in, PR 26: what follows is
+code generation), spread over whatever cores the host has free: 114 to
+190 s of wall alone on 8 cores, about 300 s as the first test of the
+suite, 607 s when it started minutes in and ended beside five busy
+workers (PR 28). The eval forward is 94 s alone and 240-275 s in the
+suite, the 14 kernel cases 40 s alone and 90 s there. The whole programs
+come first in the file, the kernel cases, seconds each, last: they are
+what is left when the worker asks for its next file.
 """
 
 import re
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +121,144 @@ def _compile_nconv(sds, h, w, k, cin, cout):
     ).compile().as_text()
 
 
+def _abstract(sds, tree):
+    return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+
+class Program(NamedTuple):
+    """What one compile for the described chip hands to the tests of its
+    properties (the executable itself is let go)."""
+
+    text: str  # the optimised module
+    temp_gib: float  # memory_analysis().temp_size_in_bytes
+
+
+def _program(compiled) -> Program:
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    return Program(compiled.as_text(), temp / 2**30)
+
+
+@pytest.fixture(scope="module")
+def train_program(sds) -> Program:
+    """`train.py --stage sintel` as published: batch 6, crop 368x768, 12
+    iterations, float32, uint8 images as the loader ships them: forward,
+    the lookup's backward into the volume (a pair of transposed
+    contractions since PR 25), AdamW. One compile, at jax's default
+    precision (minutes less than `highest`, the same buffers); batch 2,
+    which this file compiled until PR 25, is left to `chip_smoke.py`."""
+    from raft_ncup_tpu.config import TrainConfig, flagship_config
+    from raft_ncup_tpu.models.raft import RAFT
+    from raft_ncup_tpu.parallel.step import make_train_step
+    from raft_ncup_tpu.training.state import create_train_state
+
+    batch = 6
+    model_cfg = flagship_config(dataset="sintel", mixed_precision=False)
+    train_cfg = TrainConfig(
+        stage="sintel", batch_size=batch, image_size=(368, 768), iters=12,
+        num_steps=10,
+    )
+    state = _abstract(sds, jax.eval_shape(lambda: create_train_state(
+        jax.random.PRNGKey(0), model_cfg, train_cfg,
+        image_shape=(1, 64, 96, 3),
+    )[1]))
+    images = (batch, 368, 768, 3)
+    data = {
+        "image1": sds(images, jnp.uint8), "image2": sds(images, jnp.uint8),
+        "flow": sds((batch, 368, 768, 2)), "valid": sds((batch, 368, 768)),
+    }
+    rng = _abstract(sds, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    step = make_train_step(RAFT(model_cfg), train_cfg, mesh=None)
+    return _program(step.lower(state, data, rng).compile())
+
+
+@pytest.fixture(scope="module")
+def eval_program(sds) -> Program:
+    """The `eval_sintel_nc` cell's program: raft_nc_dbl, `corr_impl`
+    "volume" (the default), 8x440x1024 (padded Sintel frames), 32
+    iterations, float32 with every product at `highest` (as
+    `benchmark/configs/` states). The same model at batch 1 and default
+    precision, which this file also compiled until PR 28 to see it under
+    2 GiB, is the weaker compile, and `chip_smoke.py`'s eval phase runs
+    it on the chip itself."""
+    from raft_ncup_tpu.config import flagship_config
+    from raft_ncup_tpu.models.raft import RAFT
+
+    model = RAFT(flagship_config(dataset="sintel"))
+    assert model.cfg.corr_impl == "volume"
+    variables = _abstract(sds, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), (1, 64, 96, 3))
+    ))
+    img = sds((8, 440, 1024, 3))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(
+            lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
+        ).lower(variables, img, img).compile()
+    return _program(compiled)
+
+
+def _ncup_plane_convolutions(text: str, planes: int, h: int, w: int) -> list:
+    """Lines of the compiled text with a `convolution` that has an operand
+    of NCUP's plane shape: `planes` full-resolution frames with at most 4
+    channels. Since PR 27 those layers are tap sums on the vector units
+    (`ops/nconv.py::tap_form`); the weights-estimation net's convolutions
+    run at 1/4 resolution with 32-130 channels and are not matched."""
+    plane = re.compile(rf"f32\[{planes},{h},{w},[1-4]\]")
+    return [
+        line.strip()[:200] for line in text.splitlines()
+        if " convolution(" in line and plane.search(line)
+    ]
+
+
+def _record_temp(record_property, program: Program) -> float:
+    record_property("temp_size_gib", round(program.temp_gib, 3))
+    print(f"temp_size {program.temp_gib:.3f} GiB")
+    return program.temp_gib
+
+
+def test_sintel_train_step_has_no_convolution_over_an_ncup_plane(
+    train_program,
+):
+    """Since PR 27 NCUP's convolutions are float32 tap sums on the vector
+    units, forward and both cotangents: no `convolution` over its 12
+    planes of 368x768 is left in the step."""
+    assert _ncup_plane_convolutions(train_program.text, 12, 368, 768) == []
+
+
+def test_sintel_train_step_temporaries_stay_under_8_gib(
+    train_program, record_property
+):
+    """Since PR 26 rematerialises the encoders and takes NCUP's kernel
+    gradient tap by tap, the step asks 5.4 GiB at `highest` (14.8 GiB
+    before; at batch 2 2.1 GiB, 6.7 before); at one pass, as compiled
+    here, 4.84 GiB in every run of PR 28 (5.42 in the driver's run of PR
+    27's tree: the compiler's answer is not the same on every host)."""
+    assert _record_temp(record_property, train_program) < 8.0
+
+
+def test_eval_cell_forward_has_no_gather(eval_program):
+    """The lookup inside the loop is dense arithmetic (PR 25): no gather
+    is left anywhere in the forward."""
+    assert " gather(" not in eval_program.text
+
+
+def test_eval_cell_forward_convolves_but_never_over_an_ncup_plane(
+    eval_program,
+):
+    """The encoders and the update block are convolutions; NCUP, once per
+    forward over 16 planes of 440x1024, is tap sums on the vector units
+    (PR 27): no `convolution` has its plane shape."""
+    assert " convolution(" in eval_program.text
+    assert _ncup_plane_convolutions(eval_program.text, 16, 440, 1024) == []
+
+
+def test_eval_cell_forward_temporaries_stay_under_6_gib(
+    eval_program, record_property
+):
+    """The program's temporaries leave most of the chip free (PR 25: 4.54
+    GiB; the gather form was 5.14 GiB; unchanged by PR 27)."""
+    assert _record_temp(record_property, eval_program) < 6.0
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_corr_resident_kernel_compiles_at_the_sintel_crop(sds, dtype):
@@ -161,115 +314,3 @@ def test_nconv_gate_admitted_shapes_compile(sds, site):
     h, w, k, cin, cout = site
     assert npk.fits_vmem(h, w, cin, cout, k)
     assert "tpu_custom_call" in _compile_nconv(sds, *site)
-
-
-def _abstract(sds, tree):
-    return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-
-
-def _flagship(sds):
-    """The flagship Sintel model (`corr_impl` "volume", the default) and
-    its variables as shapes on the described chip."""
-    from raft_ncup_tpu.config import flagship_config
-    from raft_ncup_tpu.models.raft import RAFT
-
-    model = RAFT(flagship_config(dataset="sintel"))
-    assert model.cfg.corr_impl == "volume"
-    variables = _abstract(sds, jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), (1, 64, 96, 3))
-    ))
-    return model, variables
-
-
-def test_flagship_eval_forward_compiles_for_v5e(sds):
-    """The program chip_smoke's eval phase runs: raft_nc_dbl test mode,
-    1x440x1024 (a padded Sintel frame), 32 iterations, default XLA
-    paths, float32 — and it fits one chip's 16 GB with room."""
-    model, variables = _flagship(sds)
-    img = sds((1, 440, 1024, 3))
-    compiled = jax.jit(
-        lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
-    ).lower(variables, img, img).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
-
-
-def _ncup_plane_convolutions(text: str, planes: int, h: int, w: int) -> list:
-    """Lines of the compiled text with a `convolution` that has an operand
-    of NCUP's plane shape: `planes` full-resolution frames with at most 4
-    channels. Since PR 27 those layers are tap sums on the vector units
-    (`ops/nconv.py::tap_form`); the weights-estimation net's convolutions
-    run at 1/4 resolution with 32-130 channels and are not matched."""
-    plane = re.compile(rf"f32\[{planes},{h},{w},[1-4]\]")
-    return [
-        line.strip()[:200] for line in text.splitlines()
-        if " convolution(" in line and plane.search(line)
-    ]
-
-
-def _record_temp(record_property, compiled) -> float:
-    gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
-    record_property("temp_size_gib", round(gib, 3))
-    print(f"temp_size {gib:.3f} GiB")
-    return gib
-
-
-def test_eval_cell_volume_forward_compiles_gather_free(sds, record_property):
-    """The `eval_sintel_nc` cell's program: raft_nc_dbl, `corr_impl`
-    "volume", 8x440x1024, 32 iterations, float32 with every product at
-    `highest` (as `benchmark/configs/` states). The lookup inside the
-    loop is dense arithmetic — no gather is left anywhere in the forward
-    — and the program's temporaries leave most of the chip free (PR 25:
-    4.54 GiB; the gather form was 5.14 GiB). NCUP, once per forward over
-    16 planes of 440x1024, is tap sums on the vector units (PR 27): no
-    `convolution` has its plane shape (temporaries unchanged, 4.54 GiB)."""
-    model, variables = _flagship(sds)
-    img = sds((8, 440, 1024, 3))
-    with jax.default_matmul_precision("highest"):
-        compiled = jax.jit(
-            lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
-        ).lower(variables, img, img).compile()
-    text = compiled.as_text()
-    assert " gather(" not in text
-    assert " convolution(" in text  # the encoders, the update block
-    assert _ncup_plane_convolutions(text, 16, 440, 1024) == []
-    assert _record_temp(record_property, compiled) < 6.0
-
-
-def test_sintel_train_step_compiles_for_v5e(sds, record_property, batch=6):
-    """`train.py --stage sintel` as published: batch 6, crop 368x768, 12
-    iterations, float32, uint8 images as the loader ships them: forward,
-    the lookup's backward into the volume (a pair of transposed
-    contractions since PR 25), AdamW. Since PR 26 rematerialises the
-    encoders and takes NCUP's kernel gradient tap by tap, the step asks
-    5.4 GiB at `highest` (14.8 GiB before; at batch 2 2.1 GiB, 6.7 before).
-    Since PR 27 NCUP's convolutions are float32 tap sums on the vector
-    units, forward and both cotangents: no `convolution` over its planes is
-    left in the step, whose temporaries at one pass read 5.42 GiB (5.05
-    before). One compile, at jax's default precision (minutes less than
-    `highest`, the same buffers), 2-6 min beside the other workers: batch 2,
-    which this test compiled until PR 25, is left to `chip_smoke.py`."""
-    limit_gib = 8.0
-    from raft_ncup_tpu.config import TrainConfig, flagship_config
-    from raft_ncup_tpu.models.raft import RAFT
-    from raft_ncup_tpu.parallel.step import make_train_step
-    from raft_ncup_tpu.training.state import create_train_state
-
-    model_cfg = flagship_config(dataset="sintel", mixed_precision=False)
-    train_cfg = TrainConfig(
-        stage="sintel", batch_size=batch, image_size=(368, 768), iters=12,
-        num_steps=10,
-    )
-    state = _abstract(sds, jax.eval_shape(lambda: create_train_state(
-        jax.random.PRNGKey(0), model_cfg, train_cfg,
-        image_shape=(1, 64, 96, 3),
-    )[1]))
-    images = (batch, 368, 768, 3)
-    data = {
-        "image1": sds(images, jnp.uint8), "image2": sds(images, jnp.uint8),
-        "flow": sds((batch, 368, 768, 2)), "valid": sds((batch, 368, 768)),
-    }
-    rng = _abstract(sds, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
-    step = make_train_step(RAFT(model_cfg), train_cfg, mesh=None)
-    compiled = step.lower(state, data, rng).compile()
-    assert _ncup_plane_convolutions(compiled.as_text(), 12, 368, 768) == []
-    assert _record_temp(record_property, compiled) < limit_gib
