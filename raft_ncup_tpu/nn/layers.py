@@ -13,6 +13,13 @@ core/extractor.py:150-157) is available as ``init_mode='kaiming_out'``.
 Mixed precision: params live in float32; when ``dtype`` is bfloat16 the
 convolution computes in bfloat16 (the TPU analogue of the reference's CUDA
 autocast regions), while norms always compute in float32.
+
+How ``Conv2d`` is computed is chosen from the kernel's shape, stride,
+dilation and groups (:func:`conv_form`). A convolution with a thin side (the
+motion encoder's 7x7 over the 2 flow channels, the flow head's 3x3 onto
+them) fills a sixty-fourth of an MXU tile per kernel tap as
+``conv_general_dilated``; with its taps folded into the thin dimension it
+is ONE matrix product (PERF.md section 6, PR 29).
 """
 
 from __future__ import annotations
@@ -41,6 +48,125 @@ def _pair(v) -> tuple[int, int]:
     if isinstance(v, (tuple, list)):
         return (int(v[0]), int(v[1]))
     return (int(v), int(v))
+
+
+# The ``Conv2d`` call sites that took each form: a trace-time tally (as
+# ``ops/nconv.dispatch_counts`` is), so that a measurement can tell which
+# sites were folded and that every other one is the convolution it was.
+# Sites are module paths below the module that was applied (fnet's and
+# cnet's ``conv1`` are one name); sets, so a retrace changes nothing.
+_conv_forms: dict[str, set] = {"folded_in": set(), "folded_out": set(), "conv": set()}
+
+# Widest thin side that is folded (:func:`conv_form`). Measured on a v5e,
+# float32 `highest`, each site alone: device time of a call in ms, forward
+# / forward + both cotangents, ``conv_general_dilated`` against the folded
+# product (PERF.md section 6, PR 29; chiprun_out/pr29/fold_bench2.jsonl,
+# fold_wide.jsonl):
+#
+#                      8 x 55 x 128                  6 x 46 x 96
+#   site             conv          folded          conv          folded
+#   7x7   2 -> 128   0.94 / 5.12   0.21 / 0.34     0.11 / 1.56   0.16 / 0.44
+#   7x7   2 ->  64   0.92 / 4.76   0.18 / 0.30     0.11 / 1.54   0.15 / 0.39
+#   3x3 256 ->   2   1.04 / 1.88   0.09 / 0.31     0.35 / 0.85   0.04 / 0.17
+#   3x3 128 ->   2   0.52 / 0.95   0.04 / 0.15     0.17 / 0.50   0.02 / 0.08
+#   7x7   8 -> 128   1.65 / 8.58   0.31 / 1.04
+#   3x3   8 -> 128   0.40 / 1.56   0.08 / 0.16
+#   3x3 256 ->   8   1.08 / 2.16   0.08 / 0.34
+#   7x7 128 ->   8   2.82 / 7.13   0.26 / 0.71
+#   7x7  16 -> 128   1.65 / 7.64   0.74 / 2.04
+#   7x7  32 -> 128   1.66 / 7.34   1.42 / 4.53
+#   7x7  64 -> 128   1.66 / 7.49   2.80 / 10.08   (folding has lost)
+#   3x3 256 ->  16   1.08 / 2.30   0.14 / 0.54
+#   3x3 256 ->  32   1.08 / 2.30   0.44 / 1.02
+#   3x3 256 ->  64   1.09 / 2.48   0.72 / 1.90
+#
+# Folding stops winning between 32 and 64 channels of a 7x7 input (705 MB
+# of shifted copies at 64) and is still ahead at 64 outputs of a 3x3. The
+# one entry where the convolution is ahead under 32 is the 7x7's forward
+# alone on the small plane (0.11 against 0.16), which only the training
+# step runs, with the cotangents that make it 3.5x the other way. The
+# rule stops at 8, the widest side measured at both kernel sizes in both
+# forms, 3x or more ahead everywhere; the models' thin sites are 2 wide,
+# and the weights net's 64 -> 32 stays the convolution it was.
+FOLD_MAX_THIN = 8
+
+
+def reset_conv_forms() -> None:
+    for sites in _conv_forms.values():
+        sites.clear()
+
+
+def conv_forms() -> dict:
+    """{'folded_in' | 'folded_out' | 'conv': sorted module paths} of every
+    ``Conv2d`` call site traced since the last reset."""
+    return {form: sorted(sites) for form, sites in _conv_forms.items()}
+
+
+def conv_form(kernel_shape, stride=(1, 1), dilation=(1, 1), groups: int = 1) -> str:
+    """How a convolution with this HWIO kernel is computed, decided from
+    what the call can see: 'folded_in' (thin input: the taps join the
+    contraction), 'folded_out' (thin output: the taps join the outputs) or
+    'conv' (``conv_general_dilated``: everything wide, strided, dilated,
+    grouped, even or 1x1)."""
+    kh, kw, cin, cout = kernel_shape
+    if (
+        tuple(stride) != (1, 1) or tuple(dilation) != (1, 1) or groups != 1
+        or kh % 2 == 0 or kw % 2 == 0 or kh * kw == 1
+    ):
+        return "conv"
+    if cin <= FOLD_MAX_THIN:
+        return "folded_in"
+    if cout <= FOLD_MAX_THIN:
+        return "folded_out"
+    return "conv"
+
+
+def _window_hw(x: jax.Array, kernel: jax.Array, pad) -> tuple[int, int]:
+    (ph, _), (pw, _) = pad
+    return (
+        x.shape[1] + 2 * ph - kernel.shape[0] + 1,
+        x.shape[2] + 2 * pw - kernel.shape[1] + 1,
+    )
+
+
+def _conv_folded_in(x: jax.Array, kernel: jax.Array, pad) -> jax.Array:
+    """Stride-1 convolution of a thin input: the kh * kw shifted copies of
+    the zero-padded input side by side on the channel axis, contracted once
+    with the kernel as a ``[kh * kw * Cin, Cout]`` matrix.
+
+    The window's two shifts are taken apart, kw column shifts of the padded
+    plane and then kh row shifts of that stack: kh + kw slices, not kh * kw.
+    Every tap cut out of the plane on its own (49 slices into one
+    concatenate) ran the 7x7 site in 1.59 ms at 6 x 46 x 96, against 0.19
+    this way (a call on the host's clock; PERF.md section 6, PR 29)."""
+    kh, kw, _, cout = kernel.shape
+    ho, wo = _window_hw(x, kernel, pad)
+    xpad = jnp.pad(x, ((0, 0), pad[0], pad[1], (0, 0)))
+    cols = jnp.concatenate([xpad[:, :, kx : kx + wo] for kx in range(kw)], axis=-1)
+    patches = jnp.concatenate([cols[:, ky : ky + ho] for ky in range(kh)], axis=-1)
+    return jax.lax.dot_general(
+        patches, kernel.reshape(-1, cout), (((3,), (0,)), ((), ()))
+    )
+
+
+def _conv_folded_out(x: jax.Array, kernel: jax.Array, pad) -> jax.Array:
+    """Stride-1 convolution onto a thin output, the transpose of
+    :func:`_conv_folded_in`: one product with the kernel as a ``[kh * kw *
+    Cout, Cin]`` matrix, then the kh * kw thin result planes shifted and
+    summed, rows first and columns on the row sums (kh + kw slices).
+
+    The product puts the taps in front, ``[kh, kw, Cout, B, H, W]``: whole
+    (H, W) planes to shift, as the tap sums of ``ops/nconv.py`` are."""
+    kh, kw, cin, cout = kernel.shape
+    ho, wo = _window_hw(x, kernel, pad)
+    kmat = kernel.transpose(0, 1, 3, 2).reshape(kh * kw * cout, cin)
+    planes = jax.lax.dot_general(kmat, x, (((1,), (3,)), ((), ())))
+    planes = planes.reshape(kh, kw, cout, *x.shape[:3])
+    planes = jnp.pad(planes, ((0, 0),) * 4 + (pad[0], (0, 0)))
+    rows = sum(planes[ky, ..., ky : ky + ho, :] for ky in range(kh))
+    rows = jnp.pad(rows, ((0, 0),) * 4 + (pad[1],))
+    out = sum(rows[kx, ..., kx : kx + wo] for kx in range(kw))
+    return out.transpose(1, 2, 3, 0)
 
 
 def _uniform_init(bound: float):
@@ -97,18 +223,26 @@ class Conv2d(nn.Module):
         pad = ((ph, ph), (pw, pw))
 
         cdt = self.dtype or x.dtype
-        dn = jax.lax.conv_dimension_numbers(
-            x.shape, kernel.shape, ("NHWC", "HWIO", "NHWC")
-        )
-        y = jax.lax.conv_general_dilated(
-            x.astype(cdt),
-            kernel.astype(cdt),
-            window_strides=(sh, sw),
-            padding=pad,
-            rhs_dilation=(dh, dw),
-            dimension_numbers=dn,
-            feature_group_count=self.groups,
-        )
+        x, kernel = x.astype(cdt), kernel.astype(cdt)
+        form = conv_form(kernel.shape, (sh, sw), (dh, dw), self.groups)
+        _conv_forms[form].add("/".join(self.path))
+        if form == "folded_in":
+            y = _conv_folded_in(x, kernel, pad)
+        elif form == "folded_out":
+            y = _conv_folded_out(x, kernel, pad)
+        else:
+            dn = jax.lax.conv_dimension_numbers(
+                x.shape, kernel.shape, ("NHWC", "HWIO", "NHWC")
+            )
+            y = jax.lax.conv_general_dilated(
+                x,
+                kernel,
+                window_strides=(sh, sw),
+                padding=pad,
+                rhs_dilation=(dh, dw),
+                dimension_numbers=dn,
+                feature_group_count=self.groups,
+            )
         if self.use_bias:
             bias = self.param(
                 "bias",

@@ -209,6 +209,32 @@ def _ncup_plane_convolutions(text: str, planes: int, h: int, w: int) -> list:
     ]
 
 
+def _thin_side_convolutions(text: str, h: int, w: int) -> list:
+    """Lines of the compiled text with a `convolution` (a `dot_general` is
+    one too, on the TPU) whose result or an operand is an (h, w) plane with
+    a 2-wide feature side: the update block's `convf1` (7x7, 2 -> 128) and
+    `flow_head.conv2` (3x3, 256 -> 2) as they were until PR 29, a 128-wide
+    MXU tile filled to a sixty-fourth per kernel tap. Folded
+    (`nn/layers.py::conv_form`), the same planes carry 98 and 18 features.
+    A line names its operands; their shapes are on the lines that define
+    them."""
+    shape_of = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", text))
+
+    def thin(shape: str) -> bool:
+        dims = shape[shape.index("[") + 1 : -1].split(",")
+        return len(dims) == 4 and str(h) in dims and str(w) in dims and "2" in dims
+
+    found = []
+    for line in text.splitlines():
+        if " convolution(" not in line:
+            continue
+        result = re.search(r" = (\w+\[[\d,]*\])", line).group(1)
+        operands = re.findall(r"%[\w.\-]+", line.split(" convolution(")[1].split(")")[0])
+        if any(thin(s) for s in [result, *(shape_of[o] for o in operands)]):
+            found.append(line.strip()[:200])
+    return found
+
+
 def _record_temp(record_property, program: Program) -> float:
     record_property("temp_size_gib", round(program.temp_gib, 3))
     print(f"temp_size {program.temp_gib:.3f} GiB")
@@ -222,6 +248,16 @@ def test_sintel_train_step_has_no_convolution_over_an_ncup_plane(
     units, forward and both cotangents: no `convolution` over its 12
     planes of 368x768 is left in the step."""
     assert _ncup_plane_convolutions(train_program.text, 12, 368, 768) == []
+
+
+def test_sintel_train_step_has_no_convolution_with_a_2_wide_side(
+    train_program,
+):
+    """Since PR 29 the update block's two 2-channel convolutions are one
+    product each with their taps folded into the thin side, forward,
+    rematerialised and both cotangents: no `convolution` of the step has a
+    2-wide feature side on the 46x96 plane (368x768 / 8)."""
+    assert _thin_side_convolutions(train_program.text, 46, 96) == []
 
 
 def test_sintel_train_step_temporaries_stay_under_8_gib(
@@ -249,6 +285,15 @@ def test_eval_cell_forward_convolves_but_never_over_an_ncup_plane(
     (PR 27): no `convolution` has its plane shape."""
     assert " convolution(" in eval_program.text
     assert _ncup_plane_convolutions(eval_program.text, 16, 440, 1024) == []
+
+
+def test_eval_cell_forward_has_no_convolution_with_a_2_wide_side(
+    eval_program,
+):
+    """`convf1` and `flow_head.conv2`, 2.62 of the 20.9 ms an iteration of
+    a batch of 8 cost (PR 28's ledger lines), are folded (PR 29): no
+    `convolution` has a 2-wide feature side on the 55x128 plane."""
+    assert _thin_side_convolutions(eval_program.text, 55, 128) == []
 
 
 def test_eval_cell_forward_temporaries_stay_under_6_gib(
