@@ -10,7 +10,7 @@ here catches a failure to let the run continue, and nothing forces a
 platform: without a TPU the device phase fails and no result is printed.
 
     python chip_smoke.py            # one chip: device, eval, server,
-                                    # trainer, kernels
+                                    # warmstart, trainer, kernels
     python chip_smoke.py --chips 4  # ONLY the (data=2, spatial=2) mesh
                                     # train step and its one-device twin
 
@@ -341,6 +341,86 @@ def phase_server(
     return facts
 
 
+def phase_warmstart(
+    seed: int, variables, lr_hw=(55, 128), hw=EVAL_HW, iters=EVAL_ITERS,
+) -> dict:
+    """Phase 3b: the warm start's two halves on the device. (1) The
+    in-graph splat (``forward_interpolate_batch``: an all-pairs distance
+    argmin and a gather by its result) at the deployment's 1/8 grid,
+    against the host k-d tree version, on a dense flow and on one with
+    landings that leave the image: equal cell for cell, but for near
+    ties (the host sums its landing points in float64). (2) Through a
+    ``StreamEngine``, the same pair answered warm (second pair of a
+    stream) and cold (first pair of another), in one batch: the warm
+    answer differs, so the slot table's state reached the forward."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from raft_ncup_tpu.config import StreamConfig
+    from raft_ncup_tpu.observability import Telemetry
+    from raft_ncup_tpu.ops.warmstart import (
+        forward_interpolate,
+        forward_interpolate_batch,
+    )
+    from raft_ncup_tpu.streaming import StreamEngine
+
+    rng = np.random.default_rng(seed)
+    h8, w8 = lr_hw
+    flows = np.stack([
+        rng.normal(0.0, 1.5, (h8, w8, 2)),  # dense: nearly all land inside
+        rng.normal(0.0, 1.5, (h8, w8, 2)) * np.linspace(0.2, 40.0, w8)[None, :, None],
+        rng.normal(0.0, 80.0, (h8, w8, 2)),  # few survivors fill the grid
+    ]).astype(np.float32)
+    on_device = np.asarray(jax.device_get(
+        jax.jit(forward_interpolate_batch)(jnp.asarray(flows))
+    ))
+    on_host = np.stack([forward_interpolate(f) for f in flows])
+    differ = [int((a != b).any(-1).sum()) for a, b in zip(on_device, on_host)]
+    check(
+        max(differ) <= 1e-3 * h8 * w8,
+        f"splat on the device differs from the host's in {differ} of "
+        f"{h8 * w8} cells a row: argmin or gather is wrong here",
+    )
+
+    model, _ = _flagship("volume")
+    frames = [
+        rng.uniform(0.0, 255.0, (hw[0], hw[1], 3)).astype(np.uint8)
+        for _ in range(3)
+    ]
+    with StreamEngine(
+        model, variables,
+        StreamConfig(capacity=2, frame_hw=hw, iters=iters, batch_sizes=(2,)),
+        telemetry=Telemetry(),
+    ) as engine:
+        first = engine.submit("a", frames[0], frames[1]).result(1800)
+        warm_h = engine.submit("a", frames[1], frames[2])
+        cold_h = engine.submit("b", frames[1], frames[2])
+        warm, cold = warm_h.result(1800), cold_h.result(1800)
+        counters = engine.report()["counters"]
+    for name, resp in (("first", first), ("warm", warm), ("cold", cold)):
+        check(
+            resp.ok and bool(np.isfinite(resp.flow).all()),
+            f"stream answer {name!r}: {resp.status} {resp.detail}",
+        )
+    gap = _mean_epe(warm.flow, cold.flow)
+    check(
+        gap > 1e-3,
+        f"the warm answer equals the cold one of the same pair ({gap} px): "
+        "the slot table's flow did not reach the forward",
+    )
+    check(
+        counters["stream_frames_cold_start_total"] == 2,
+        f"cold starts {counters['stream_frames_cold_start_total']}, want 2",
+    )
+    return {
+        "splat_grid": [h8, w8],
+        "splat_cells_differing_from_host": differ,
+        "warm_vs_cold_mean_px": gap,
+        "stream_cold_starts": counters["stream_frames_cold_start_total"],
+    }
+
+
 def pick_train_batch(hbm_gib: float) -> tuple[int, str]:
     """Largest reference-compatible batch whose AOT ``temp_size`` leaves
     TRAIN_HEADROOM_GIB of the chip's memory free."""
@@ -629,6 +709,8 @@ def main(argv=None) -> int:
             facts.update(eval_facts)
         with phase("server", meter, dev0) as facts:
             facts.update(phase_server(args.seed, run_dir))
+        with phase("warmstart", meter, dev0) as facts:
+            facts.update(phase_warmstart(args.seed, variables))
         with phase("trainer", meter, dev0) as facts:
             facts.update(phase_trainer(run_dir, batch))
             facts["batch_why"] = batch_why

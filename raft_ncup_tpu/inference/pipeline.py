@@ -531,6 +531,10 @@ class ShapeCachedForward:
             elif len(key) >= 4 and key[1] == "pipe_encode":
                 meta = {"kind": "pipe_encode", "shape": key[2],
                         "policy": key[3]}
+            elif len(key) >= 4 and key[1] == "stream":
+                # StreamEngine's slot-table step (streaming/engine.py).
+                meta = {"kind": "stream_step", "rows": key[2],
+                        "policy": key[3]}
             if meta is not None:
                 # Optional trailing ("earlyexit", tol) marker — same
                 # contract as the forward key above.

@@ -33,7 +33,9 @@ threads):
    diverged low-res flow) → scatter the new state back, with anomalous
    rows reset to cold. State never leaves the device between frames.
 5. **deliver** (drain worker): the batch's ``(flow_up, bad_flags)``
-   ride ONE sanctioned ``jax.device_get`` in the ``AsyncDrain`` worker;
+   ride ONE sanctioned ``jax.device_get`` in the ``AsyncDrain`` worker
+   (three spans per batch: ``stream_device_wait``, ``stream_pull``,
+   ``stream_deliver``; docs/OBSERVABILITY.md);
    anomalous rows answer ``rejected`` (their stream just went cold),
    healthy rows answer ``ok`` with the unpadded native flow.
 
@@ -459,11 +461,18 @@ class StreamEngine:
 
     def _dispatch_loop(self) -> None:
         while True:
-            batch = self._queue.pop_batch(
-                self.cfg.max_batch,
-                timeout=_POLL_S,
-                distinct_fn=lambda r: r.stream_id,
-            )
+            # The wait for frames and the distinct-stream scan: one span
+            # per assembled batch (an idle poll leaves none).
+            with self._tel.span("stream_batch_assembly") as sp:
+                batch = self._queue.pop_batch(
+                    self.cfg.max_batch,
+                    timeout=_POLL_S,
+                    distinct_fn=lambda r: r.stream_id,
+                )
+                if batch:
+                    sp.set(batch_size=len(batch))
+                else:
+                    sp.discard()
             if not batch:
                 if self._queue.closed and not len(self._queue):
                     return
@@ -665,6 +674,10 @@ class StreamEngine:
 
         t_dispatch = self._clock()
         step = self._step(n_rows)
+        # As in FlowServer: the dispatch span times the host-to-device
+        # copy and the jit dispatch alone; the throttle's bounded wait
+        # has its own span, and the drain worker's three (device wait,
+        # pull, deliver) follow under the same batch id.
         trace_ids = [r.trace_id for r in batch if r.trace_id is not None]
         with self._tel.span(
             "stream_dispatch",
@@ -684,6 +697,7 @@ class StreamEngine:
                     jnp.asarray(np.asarray(slot_idx, np.int32)),
                     jnp.asarray(np.asarray(cold, np.float32)),
                 )
+        with self._tel.span("stream_throttle_wait", batch_id=token):
             self._throttle.push(flow_up)
         with self._inflight_lock:
             self._inflight[token] = batch
@@ -762,7 +776,12 @@ class StreamEngine:
             )
 
         # The batch's ONE sanctioned pull: full flow + B anomaly flags.
-        self._drainer.submit((flow_up, bad), deliver)
+        self._drainer.submit(
+            (flow_up, bad), deliver,
+            span=lambda stage: self._tel.span(
+                "stream_" + stage, batch_id=token
+            ),
+        )
 
     def _finish_frame(self, req: FrameRequest, reset: bool = False) -> None:
         """Per-frame terminal bookkeeping: pending counts, deferred
@@ -909,6 +928,17 @@ class StreamEngine:
                 self._fail_inflight(e)
         return self.stats
 
+    def executable_memory(self) -> list:
+        """XLA's ``memory_analysis()`` of every step program this engine's
+        cache compiled, as the cost ledger banked it at compile time."""
+        ledger, out = self._fwd.costs, []
+        for key in ledger.keys():
+            entry = ledger.entry(key) or {}
+            is_step = (entry.get("meta") or {}).get("kind") == "stream_step"
+            if is_step and entry.get("memory_stats"):
+                out.append({"key": key, **entry["memory_stats"]})
+        return out
+
     def report(self) -> dict:
         """One JSON-able summary: stats + slot-table occupancy +
         executable accounting."""
@@ -927,12 +957,19 @@ class StreamEngine:
         }
         return {
             "stats": self.stats.summary(),
+            # The same tallies under their canonical counter names, as
+            # numbers (what a check compares, docs/OBSERVABILITY.md).
+            "counters": {
+                canon: getattr(self.stats, key)
+                for key, canon in LEGACY_KEY_ALIASES["stream"].items()
+            },
             "capacity": self.cfg.capacity,
             "occupancy": occupancy,
             "peak_occupancy": peak,
             "mean_occupancy": round(self._occupancy_sum / batches, 2),
             "evicted": evicted,
             "executables": dict(self._fwd.stats),
+            "executable_memory": self.executable_memory(),
             "precision": self._policy.name,  # RESOLVED (None inherits)
             "mesh": self._fwd.mesh_fp,
             "stages": stages,

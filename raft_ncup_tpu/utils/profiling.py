@@ -44,7 +44,7 @@ SPAN_MARK = "program_span"
 OP_SCOPES_FILE = "op_scopes.json"
 UNSCOPED = "unscoped"
 
-_SCOPE = re.compile(r"\b(?:raft|train)\.[a-z_]+")
+_SCOPE = re.compile(r"\b(?:raft|train|stream)\.[a-z_]+")
 
 
 def _annotation(name: str, attrs: dict):
@@ -107,8 +107,8 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+) \(.*\{\s*$")
 
 
 def scope_of(op_name: str) -> Optional[str]:
-    """The innermost ``raft.*`` or ``train.*`` scope of an ``op_name`` path,
-    None when the path lies in none. In a differentiated program (the
+    """The innermost ``raft.*``, ``train.*`` or ``stream.*`` scope of an
+    ``op_name`` path, None when the path lies in none. In a differentiated program (the
     training step) the phase is appended: the forward's operations keep
     the plain name (``.../jvp(raft.fnet)/...``), the backward's are
     ``<scope>.bwd`` (``.../transpose(jvp(raft.refinement))/while/body/
@@ -127,7 +127,7 @@ def scope_of(op_name: str) -> Optional[str]:
 
 def hlo_op_scopes(hlo_text: str) -> dict:
     """``{instruction name: scope}`` of one compiled module's text. An
-    instruction is in the innermost ``raft.*`` scope of its own ``op_name``;
+    instruction is in the innermost scope (:func:`scope_of`) of its own ``op_name``;
     a fusion (or ``while``) whose own ``op_name`` names none takes the scope
     most of the instructions it calls are in. Instructions in no scope are
     left out."""

@@ -126,6 +126,26 @@ def test_server_phase_toy(tmp_path):
     assert not os.path.exists(os.path.join(REPO, "flight_recorder"))
 
 
+def test_warmstart_phase_toy():
+    from evaluate import load_variables
+
+    variables = load_variables(*cs._flagship("volume"), None)
+    facts = cs.phase_warmstart(0, variables, lr_hw=(12, 20), hw=(60, 90), iters=2)
+    assert facts["splat_cells_differing_from_host"] == [0, 0, 0]
+    assert facts["warm_vs_cold_mean_px"] > 1e-3
+    assert facts["stream_cold_starts"] == 2
+
+
+def test_warmstart_phase_fails_when_the_splat_is_wrong(monkeypatch):
+    from raft_ncup_tpu.ops import warmstart
+
+    monkeypatch.setattr(
+        warmstart, "forward_interpolate_batch", lambda flow, chunk=1024: flow
+    )
+    with pytest.raises(cs.SmokeFailure, match="argmin or gather"):
+        cs.phase_warmstart(0, None, lr_hw=(12, 20), hw=(60, 90), iters=2)
+
+
 def test_server_phase_reads_the_report_not_the_return_code(
     tmp_path, monkeypatch
 ):

@@ -375,8 +375,13 @@ class TestCostLedger:
             "kind": "pipe_encode", "shape": (1, 32, 32, 3),
             "policy": "f32",
         }
+        # StreamEngine's step carries its identity too (its report's
+        # ``executable_memory`` filters on it).
+        assert ShapeCachedForward._ledger_meta(
+            ("custom", "stream", 8, "f32")
+        ) == {"kind": "stream_step", "rows": 8, "policy": "f32"}
         # Other custom keys keep the opaque kind.
-        assert ShapeCachedForward._ledger_meta(("custom", "stream", 2)) == {
+        assert ShapeCachedForward._ledger_meta(("custom", "other", 2)) == {
             "kind": "custom"
         }
 

@@ -39,6 +39,11 @@ LOOKUP, UPDATE, LOOP, FNET = (
      "/rematted_computation/raft.corr_lookup/eq", LOOKUP + ".remat"),
     ("jit(step)/train.forward_backward/transpose(jvp(raft.refinement))/while/body/closed_call/add_any", LOOP + ".bwd"),
     ("jit(step)/train.forward_backward/transpose(train.forward_backward)/mul", "train.forward_backward.bwd"),
+    # StreamEngine's slot-table step (PR 30): stream.* around the forward's raft.*
+    ("jit(step)/stream.warmstart_splat/argmin", "stream.warmstart_splat"),
+    ("jit(step)/stream.slot_gather/gather", "stream.slot_gather"),
+    ("jit(step)/stream.anomaly_scatter/scatter", "stream.anomaly_scatter"),
+    ("jit(step)/upstream.thing/mul", None),
 ])
 def test_scope_of_takes_the_innermost_raft_scope(op_name, want):
     assert P.scope_of(op_name) == want
