@@ -95,7 +95,7 @@ class RAFT:
                 hdim + cdim, "none", cfg.dropout, small=True, dtype=dtype
             )
             self.update_block = SmallUpdateBlock(
-                cfg.corr_planes, hdim, dtype=dtype
+                cfg.corr_planes, hdim, cdim, dtype=dtype
             )
         else:
             self.fnet = Encoder(256, "instance", cfg.dropout, small=False, dtype=dtype)
@@ -105,6 +105,7 @@ class RAFT:
             self.update_block = BasicUpdateBlock(
                 cfg.corr_planes,
                 hdim,
+                cdim,
                 # raft_nc_dbl deletes the convex mask head (reference:
                 # core/raft_nc_dbl.py:68).
                 use_mask_head=(cfg.variant == "raft"),
@@ -395,13 +396,21 @@ class RAFT:
             flow_lr, up_mask.astype(policy.upsampler_jnp), 8
         )
 
+    def _gru_context(self, run, inp):
+        """What the update block makes of the context features, once per
+        pair: ``inp`` is the same in every iteration, so the refinement
+        loop closes over this and never reads ``inp`` itself."""
+        with jax.named_scope("raft.gru_context"):
+            return run("update_block", self.update_block, inp, method="context")
+
     def _make_step(
-        self, run, corr_fn, coords0, inp, bstats, *, test_mode,
+        self, run, corr_fn, coords0, gru_ctx, bstats, *, test_mode,
         carry_mask, bn_train, early_exit_tol=None,
     ):
         """One refinement iteration on the ``(net, coords1, stats)``
         carry — the single step body every scan (monolithic or segment)
         runs, so segmented execution can never drift from ``apply``.
+        ``gru_ctx``: :meth:`_gru_context`'s, constants of the loop.
 
         ``early_exit_tol`` (test mode only; docs/PERF.md "Early exit"):
         per-sample convergence detection on the GRU's own flow delta.
@@ -438,9 +447,10 @@ class RAFT:
                     "update_block",
                     self.update_block,
                     net,
-                    inp,
+                    gru_ctx,
                     corr,
                     flow.astype(net.dtype),
+                    method="step",
                 )
             # The coordinate carry is the refinement's f32 backbone: the
             # (possibly bf16) delta joins it at the policy's pinned
@@ -596,7 +606,7 @@ class RAFT:
 
         carry_mask = self._has_mask and test_mode
         step = self._make_step(
-            run, corr_fn, coords0, inp, bstats,
+            run, corr_fn, coords0, self._gru_context(run, inp), bstats,
             test_mode=test_mode, carry_mask=carry_mask, bn_train=bn_train,
             early_exit_tol=early_exit_tol,
         )
@@ -777,7 +787,7 @@ class RAFT:
                 )
             stats["converged"] = carry["converged"]
         step = self._make_step(
-            run, corr_fn, coords0, carry["inp"], {},
+            run, corr_fn, coords0, self._gru_context(run, carry["inp"]), {},
             test_mode=True, carry_mask=carry_mask, bn_train=False,
             early_exit_tol=early_exit_tol,
         )

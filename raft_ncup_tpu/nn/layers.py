@@ -24,6 +24,7 @@ is ONE matrix product (PERF.md section 6, PR 29).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional, Sequence
 
@@ -129,7 +130,7 @@ def _window_hw(x: jax.Array, kernel: jax.Array, pad) -> tuple[int, int]:
     )
 
 
-def _conv_folded_in(x: jax.Array, kernel: jax.Array, pad) -> jax.Array:
+def _conv_folded_in(x: jax.Array, kernel: jax.Array, pad, out_dtype=None) -> jax.Array:
     """Stride-1 convolution of a thin input: the kh * kw shifted copies of
     the zero-padded input side by side on the channel axis, contracted once
     with the kernel as a ``[kh * kw * Cin, Cout]`` matrix.
@@ -145,11 +146,12 @@ def _conv_folded_in(x: jax.Array, kernel: jax.Array, pad) -> jax.Array:
     cols = jnp.concatenate([xpad[:, :, kx : kx + wo] for kx in range(kw)], axis=-1)
     patches = jnp.concatenate([cols[:, ky : ky + ho] for ky in range(kh)], axis=-1)
     return jax.lax.dot_general(
-        patches, kernel.reshape(-1, cout), (((3,), (0,)), ((), ()))
+        patches, kernel.reshape(-1, cout), (((3,), (0,)), ((), ())),
+        preferred_element_type=out_dtype,
     )
 
 
-def _conv_folded_out(x: jax.Array, kernel: jax.Array, pad) -> jax.Array:
+def _conv_folded_out(x: jax.Array, kernel: jax.Array, pad, out_dtype=None) -> jax.Array:
     """Stride-1 convolution onto a thin output, the transpose of
     :func:`_conv_folded_in`: one product with the kernel as a ``[kh * kw *
     Cout, Cin]`` matrix, then the kh * kw thin result planes shifted and
@@ -160,13 +162,42 @@ def _conv_folded_out(x: jax.Array, kernel: jax.Array, pad) -> jax.Array:
     kh, kw, cin, cout = kernel.shape
     ho, wo = _window_hw(x, kernel, pad)
     kmat = kernel.transpose(0, 1, 3, 2).reshape(kh * kw * cout, cin)
-    planes = jax.lax.dot_general(kmat, x, (((1,), (3,)), ((), ())))
+    planes = jax.lax.dot_general(
+        kmat, x, (((1,), (3,)), ((), ())), preferred_element_type=out_dtype
+    )
     planes = planes.reshape(kh, kw, cout, *x.shape[:3])
     planes = jnp.pad(planes, ((0, 0),) * 4 + (pad[0], (0, 0)))
     rows = sum(planes[ky, ..., ky : ky + ho, :] for ky in range(kh))
     rows = jnp.pad(rows, ((0, 0),) * 4 + (pad[1],))
     out = sum(rows[kx, ..., kx : kx + wo] for kx in range(kw))
     return out.transpose(1, 2, 3, 0)
+
+
+def conv2d(
+    x: jax.Array, kernel: jax.Array, pad, *, site: str, stride=(1, 1),
+    dilation=(1, 1), groups: int = 1, out_dtype=None,
+) -> jax.Array:
+    """NHWC x HWIO convolution in the form :func:`conv_form` gives its
+    kernel, tallied under ``site``. ``out_dtype``: the dtype the products'
+    accumulator is handed out in (None: the operands')."""
+    form = conv_form(kernel.shape, stride, dilation, groups)
+    _conv_forms[form].add(site)
+    if form == "folded_in":
+        return _conv_folded_in(x, kernel, pad, out_dtype)
+    if form == "folded_out":
+        return _conv_folded_out(x, kernel, pad, out_dtype)
+    return jax.lax.conv_general_dilated(
+        x,
+        kernel,
+        window_strides=stride,
+        padding=pad,
+        rhs_dilation=dilation,
+        dimension_numbers=jax.lax.conv_dimension_numbers(
+            x.shape, kernel.shape, ("NHWC", "HWIO", "NHWC")
+        ),
+        feature_group_count=groups,
+        preferred_element_type=out_dtype,
+    )
 
 
 def _uniform_init(bound: float):
@@ -224,25 +255,10 @@ class Conv2d(nn.Module):
 
         cdt = self.dtype or x.dtype
         x, kernel = x.astype(cdt), kernel.astype(cdt)
-        form = conv_form(kernel.shape, (sh, sw), (dh, dw), self.groups)
-        _conv_forms[form].add("/".join(self.path))
-        if form == "folded_in":
-            y = _conv_folded_in(x, kernel, pad)
-        elif form == "folded_out":
-            y = _conv_folded_out(x, kernel, pad)
-        else:
-            dn = jax.lax.conv_dimension_numbers(
-                x.shape, kernel.shape, ("NHWC", "HWIO", "NHWC")
-            )
-            y = jax.lax.conv_general_dilated(
-                x,
-                kernel,
-                window_strides=(sh, sw),
-                padding=pad,
-                rhs_dilation=(dh, dw),
-                dimension_numbers=dn,
-                feature_group_count=self.groups,
-            )
+        y = conv2d(
+            x, kernel, pad, site="/".join(self.path), stride=(sh, sw),
+            dilation=(dh, dw), groups=self.groups,
+        )
         if self.use_bias:
             bias = self.param(
                 "bias",
@@ -252,6 +268,94 @@ class Conv2d(nn.Module):
             )
             y = y + bias.astype(cdt)
         return y
+
+
+def _wide_out(conv):
+    """``conv(x, kernel)`` of narrow operands with its accumulator handed out
+    in ``PARAM_DTYPE``. jax transposes a convolution with a
+    ``preferred_element_type`` into one of the wide cotangent with the
+    narrow operand, which ``conv_general_dilated`` refuses; the cotangent
+    is rounded to the operands' dtype first, as it would be had the
+    forward rounded."""
+
+    @jax.custom_vjp
+    def wide(x, kernel):
+        return conv(x, kernel, out_dtype=PARAM_DTYPE)
+
+    def bwd(operands, g):
+        return jax.vjp(conv, *operands)[1](g.astype(operands[0].dtype))
+
+    wide.defvjp(lambda x, kernel: (wide(x, kernel), (x, kernel)), bwd)
+    return wide
+
+
+class SplitConv2d(nn.Module):
+    """A stride-1 ``Conv2d`` over ``in_features`` channels of which the rows
+    ``fixed = (lo, hi)`` read an input that is the same in every call: the
+    GRU's context features over the refinement loop.
+
+    A convolution is linear in its input channels, so it is two sums.
+    :meth:`context`, once before the loop, takes the kernel apart and
+    convolves the fixed input with its rows; ``__call__``, in the loop,
+    convolves the other channels with theirs and adds that term. The
+    parameters are ``Conv2d``'s over the whole width, name for name, shape
+    for shape and draw for draw (``kernel`` ``(kh, kw, in_features,
+    features)`` and ``bias``, fan-in of the whole width), so a checkpoint
+    cannot tell the two apart. Each product takes its form from
+    :func:`conv_form`, tallied as ``<path>/context`` and ``<path>/step``.
+    """
+
+    features: int
+    kernel_size: Any
+    in_features: int
+    fixed: tuple[int, int]
+    dtype: Any = None  # compute dtype; None = input dtype
+
+    def setup(self):
+        kh, kw = _pair(self.kernel_size)
+        fan_in = self.in_features * kh * kw
+        self.kernel = self.param(
+            "kernel", _uniform_init(math.sqrt(1.0 / fan_in)),
+            (kh, kw, self.in_features, self.features), PARAM_DTYPE,
+        )
+        self.bias = self.param(
+            "bias", _uniform_init(1.0 / math.sqrt(fan_in)), (self.features,),
+            PARAM_DTYPE,
+        )
+
+    def _conv(self, x, kernel, part: str) -> jax.Array:
+        """The product of one part, handed out in the parameters' dtype:
+        the two parts are added there, and rounded to a narrower compute
+        dtype once, after."""
+        kh, kw = kernel.shape[:2]
+        conv = functools.partial(
+            conv2d, pad=((kh // 2, kh // 2), (kw // 2, kw // 2)),
+            site="/".join(self.path + (part,)),
+        )
+        if x.dtype == PARAM_DTYPE:
+            return conv(x, kernel)
+        return _wide_out(conv)(x, kernel)
+
+    def context(self, fixed_input: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """``(the kernel's other rows, conv(fixed_input, its rows))``: what
+        ``__call__`` takes as ``ctx``."""
+        lo, hi = self.fixed
+        cdt = self.dtype or fixed_input.dtype
+        kernel = self.kernel.astype(cdt)
+        term = self._conv(fixed_input.astype(cdt), kernel[:, :, lo:hi], "context")
+        rest = jnp.concatenate([kernel[:, :, :lo], kernel[:, :, hi:]], axis=2)
+        return rest, term
+
+    def __call__(self, x: jax.Array, ctx) -> jax.Array:
+        """``x``: the input's channels without the fixed rows. The bias is
+        added here, not into the term: every elementwise operation on the
+        result then sits in the loop body, ONE fused expression whether the
+        loop is a scan, a ``while_loop`` or a single iteration the compiler
+        inlines (``tests/test_earlyexit.py`` holds a frozen lane bitwise to
+        the plain forward of another executable)."""
+        rest, term = ctx
+        cdt = self.dtype or x.dtype
+        return (self._conv(x.astype(cdt), rest, "step") + term + self.bias).astype(cdt)
 
 
 class ConvTranspose2d(nn.Module):

@@ -8,6 +8,12 @@ core/update.py:138-140).
 
 These run inside ``lax.scan`` over refinement iterations, so everything is
 shape-static. The GRU state is the scan carry.
+
+The GRU reads ``[h, inp, motion]``, and the context features ``inp`` are
+the same tensor in every iteration. What the gate convolutions make of them
+is therefore computed once per pair, before the loop (``context``), and the
+loop's convolutions run over ``[h, motion]`` alone (``step``): the same sum
+in another order (PERF.md section 6, PR 31).
 """
 
 from __future__ import annotations
@@ -18,7 +24,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raft_ncup_tpu.nn.layers import Conv2d
+from raft_ncup_tpu.nn.layers import Conv2d, SplitConv2d
 
 
 class FlowHead(nn.Module):
@@ -34,49 +40,63 @@ class FlowHead(nn.Module):
         return Conv2d(2, 3, dtype=self.dtype, name="conv2")(x)
 
 
-class ConvGRU(nn.Module):
-    """Plain 3x3 conv GRU (reference: core/update.py:16-31)."""
+class _ContextGRU(nn.Module):
+    """A conv GRU over ``[h, inp, motion]`` whose context features ``inp``
+    do not change over the refinement loop: ``context(inp)``, once per pair,
+    gives every gate's part of them, and ``__call__(h, ctx, motion)`` is the
+    update the loop runs, over the channels of ``h`` and ``motion`` alone.
+    ``input_dim`` is the reference's: ``inp`` and ``motion`` together.
+    ``PASSES``: gate-name suffix -> kernel size, one GRU update each."""
+
+    PASSES = {}
 
     hidden_dim: int = 128
+    input_dim: int = 192 + 128
+    context_dim: int = 128
     dtype: Any = None
 
-    @nn.compact
-    def __call__(self, h: jax.Array, x: jax.Array) -> jax.Array:
-        hx = jnp.concatenate([h, x], axis=-1)
-        z = nn.sigmoid(Conv2d(self.hidden_dim, 3, dtype=self.dtype, name="convz")(hx))
-        r = nn.sigmoid(Conv2d(self.hidden_dim, 3, dtype=self.dtype, name="convr")(hx))
-        q = nn.tanh(
-            Conv2d(self.hidden_dim, 3, dtype=self.dtype, name="convq")(
-                jnp.concatenate([r * h, x], axis=-1)
-            )
-        )
-        return (1 - z) * h + z * q
+    def setup(self):
+        for suffix, kernel_size in self.PASSES.items():
+            for g in "zrq":
+                setattr(self, f"conv{g}{suffix}", SplitConv2d(
+                    self.hidden_dim, kernel_size, self.hidden_dim + self.input_dim,
+                    (self.hidden_dim, self.hidden_dim + self.context_dim),
+                    dtype=self.dtype,
+                ))
 
+    def _gates(self, suffix: str) -> tuple:
+        return tuple(getattr(self, f"conv{g}{suffix}") for g in "zrq")
 
-class SepConvGRU(nn.Module):
-    """Separable GRU: a horizontal (1x5) pass then a vertical (5x1) pass
-    (reference: core/update.py:33-60)."""
+    def context(self, inp: jax.Array) -> dict:
+        return {
+            gate.name: gate.context(inp)
+            for suffix in self.PASSES for gate in self._gates(suffix)
+        }
 
-    hidden_dim: int = 128
-    dtype: Any = None
-
-    @nn.compact
-    def __call__(self, h: jax.Array, x: jax.Array) -> jax.Array:
-        for suffix, ksize in (("1", (1, 5)), ("2", (5, 1))):
-            hx = jnp.concatenate([h, x], axis=-1)
-            z = nn.sigmoid(
-                Conv2d(self.hidden_dim, ksize, dtype=self.dtype, name=f"convz{suffix}")(hx)
-            )
-            r = nn.sigmoid(
-                Conv2d(self.hidden_dim, ksize, dtype=self.dtype, name=f"convr{suffix}")(hx)
-            )
+    def __call__(self, h: jax.Array, ctx: dict, motion: jax.Array) -> jax.Array:
+        for suffix in self.PASSES:
+            convz, convr, convq = self._gates(suffix)
+            hm = jnp.concatenate([h, motion], axis=-1)
+            z = nn.sigmoid(convz(hm, ctx[convz.name]))
+            r = nn.sigmoid(convr(hm, ctx[convr.name]))
             q = nn.tanh(
-                Conv2d(self.hidden_dim, ksize, dtype=self.dtype, name=f"convq{suffix}")(
-                    jnp.concatenate([r * h, x], axis=-1)
-                )
+                convq(jnp.concatenate([r * h, motion], axis=-1), ctx[convq.name])
             )
             h = (1 - z) * h + z * q
         return h
+
+
+class ConvGRU(_ContextGRU):
+    """Plain 3x3 conv GRU (reference: core/update.py:16-31)."""
+
+    PASSES = {"": 3}
+
+
+class SepConvGRU(_ContextGRU):
+    """Separable GRU: a horizontal (1x5) pass then a vertical (5x1) pass
+    (reference: core/update.py:33-60)."""
+
+    PASSES = {"1": (1, 5), "2": (5, 1)}
 
 
 class SmallMotionEncoder(nn.Module):
@@ -118,28 +138,47 @@ class BasicMotionEncoder(nn.Module):
         return jnp.concatenate([out, flow], axis=-1)
 
 
-class SmallUpdateBlock(nn.Module):
+class _UpdateBlock(nn.Module):
+    """What the two update blocks share: ``context(inp)`` once per pair,
+    ``step(net, ctx, corr, flow)`` in every refinement iteration, and
+    ``__call__(net, inp, corr, flow)``, the two in a row (one iteration
+    on its own; ``init``)."""
+
+    def context(self, inp: jax.Array) -> dict:
+        """The GRU gates' share of the context features (and the kernel
+        rows of the rest): everything of the block that reads ``inp``."""
+        return self.gru.context(inp)
+
+    def __call__(
+        self, net: jax.Array, inp: jax.Array, corr: jax.Array, flow: jax.Array
+    ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
+        return self.step(net, self.context(inp), corr, flow)
+
+
+class SmallUpdateBlock(_UpdateBlock):
     """reference: core/update.py:99-112. No mask head: the small path
     upsamples bilinearly."""
 
     corr_planes: int
     hidden_dim: int = 96
+    context_dim: int = 64
     dtype: Any = None
 
-    @nn.compact
-    def __call__(
-        self, net: jax.Array, inp: jax.Array, corr: jax.Array, flow: jax.Array
-    ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
-        motion = SmallMotionEncoder(self.corr_planes, dtype=self.dtype, name="encoder")(
-            flow, corr
+    def setup(self):
+        self.encoder = SmallMotionEncoder(self.corr_planes, dtype=self.dtype)
+        self.gru = ConvGRU(
+            self.hidden_dim, 82 + self.context_dim, self.context_dim, dtype=self.dtype
         )
-        x = jnp.concatenate([inp, motion], axis=-1)
-        net = ConvGRU(self.hidden_dim, dtype=self.dtype, name="gru")(net, x)
-        delta = FlowHead(128, dtype=self.dtype, name="flow_head")(net)
-        return net, None, delta
+        self.flow_head = FlowHead(128, dtype=self.dtype)
+
+    def step(
+        self, net: jax.Array, ctx: dict, corr: jax.Array, flow: jax.Array
+    ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
+        net = self.gru(net, ctx, self.encoder(flow, corr))
+        return net, None, self.flow_head(net)
 
 
-class BasicUpdateBlock(nn.Module):
+class BasicUpdateBlock(_UpdateBlock):
     """reference: core/update.py:114-141.
 
     ``use_mask_head=False`` reproduces raft_nc_dbl's deletion of the convex
@@ -149,24 +188,28 @@ class BasicUpdateBlock(nn.Module):
 
     corr_planes: int
     hidden_dim: int = 128
+    context_dim: int = 128
     use_mask_head: bool = True
     dtype: Any = None
 
-    @nn.compact
-    def __call__(
-        self, net: jax.Array, inp: jax.Array, corr: jax.Array, flow: jax.Array
-    ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
-        motion = BasicMotionEncoder(self.corr_planes, dtype=self.dtype, name="encoder")(
-            flow, corr
+    def setup(self):
+        self.encoder = BasicMotionEncoder(self.corr_planes, dtype=self.dtype)
+        self.gru = SepConvGRU(
+            self.hidden_dim, 128 + self.context_dim, self.context_dim, dtype=self.dtype
         )
-        x = jnp.concatenate([inp, motion], axis=-1)
-        net = SepConvGRU(self.hidden_dim, dtype=self.dtype, name="gru")(net, x)
-        delta = FlowHead(256, dtype=self.dtype, name="flow_head")(net)
+        self.flow_head = FlowHead(256, dtype=self.dtype)
+        if self.use_mask_head:
+            self.mask_conv1 = Conv2d(256, 3, dtype=self.dtype)
+            self.mask_conv2 = Conv2d(64 * 9, 1, dtype=self.dtype)
+
+    def step(
+        self, net: jax.Array, ctx: dict, corr: jax.Array, flow: jax.Array
+    ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
+        net = self.gru(net, ctx, self.encoder(flow, corr))
+        delta = self.flow_head(net)
 
         mask = None
         if self.use_mask_head:
-            m = nn.relu(Conv2d(256, 3, dtype=self.dtype, name="mask_conv1")(net))
-            m = Conv2d(64 * 9, 1, dtype=self.dtype, name="mask_conv2")(m)
             # 0.25 scale to balance gradients (reference: core/update.py:140).
-            mask = 0.25 * m
+            mask = 0.25 * self.mask_conv2(nn.relu(self.mask_conv1(net)))
         return net, mask, delta

@@ -72,11 +72,18 @@ def _update_block_flops(h8: int, w8: int, corr_planes: int) -> float:
     f += _conv(3, 128, 64, h8, w8)
     f += _conv(3, 192 + 64, 126, h8, w8)
     # SepConvGRU: two sequential GRUs (1x5 then 5x1), three k=5 separable
-    # convs each, cin=256 cout=128 — 6 convs total per iteration.
+    # convs each, 6 per iteration, over [h, motion] = 256 channels: what
+    # they make of the 128 context channels is _gru_context_flops, once.
     f += 6 * (2.0 * 5 * 256 * 128 * h8 * w8)
     # flow head
     f += _conv(3, 128, 256, h8, w8) + _conv(3, 256, 2, h8, w8)
     return f
+
+
+def _gru_context_flops(h8: int, w8: int, context_dim: int, hidden_dim: int) -> float:
+    """The six gate convolutions' share of the context features, once per
+    pair before the refinement loop (``nn/update.py``, PR 31)."""
+    return 6 * (2.0 * 5 * context_dim * hidden_dim * h8 * w8)
 
 
 def _ncup_flops(cfg: ModelConfig, H: int, W: int, batch_mult: int) -> float:
@@ -123,6 +130,7 @@ def forward_flops(
         # on-the-fly: per-iteration windowed dot products, L levels x K^2 taps
         K2 = (2 * cfg.resolved_corr_radius + 1) ** 2
         f += iters * cfg.corr_levels * K2 * 2.0 * h8 * w8 * cfg.fnet_dim
+    f += _gru_context_flops(h8, w8, cfg.context_dim, cfg.hidden_dim)
     f += iters * _update_block_flops(h8, w8, cfg.corr_planes)
     if cfg.variant == "raft_nc_dbl":
         f += iters * _ncup_flops(cfg, H, W, batch_mult=2)

@@ -203,6 +203,8 @@ def test_benchmark_models_fold_exactly_the_update_blocks_two_thin_sites(config):
     forms = layers.conv_forms()
     assert forms["folded_in"] == ["encoder/convf1"]
     assert forms["folded_out"] == ["flow_head/conv2"]
-    # Both encoders (one set of names), the GRU, the heads, the weights net.
-    assert len(forms["conv"]) >= 29 and "gru/convz1" in forms["conv"]
+    # Both encoders (one set of names), the GRU (each gate in two parts since
+    # PR 31, and no gate whole), the heads, the weights net.
+    assert len(forms["conv"]) >= 35 and "gru/convz1" not in forms["conv"]
+    assert {"gru/convz1/context", "gru/convz1/step"} <= set(forms["conv"])
     assert not set(forms["conv"]) & {"encoder/convf1", "flow_head/conv2"}

@@ -39,6 +39,12 @@ LOOKUP, UPDATE, LOOP, FNET = (
      "/rematted_computation/raft.corr_lookup/eq", LOOKUP + ".remat"),
     ("jit(step)/train.forward_backward/transpose(jvp(raft.refinement))/while/body/closed_call/add_any", LOOP + ".bwd"),
     ("jit(step)/train.forward_backward/transpose(train.forward_backward)/mul", "train.forward_backward.bwd"),
+    # the GRU's context terms, once before the loop (PR 31): a scope of their
+    # own in the forward, and in the step's backward after the loop's
+    ("jit(fn)/raft.gru_context/BasicUpdateBlock.context/gru.context/convz1.context/convz1._conv"
+     "/conv_general_dilated", "raft.gru_context"),
+    ("jit(step)/train.forward_backward/transpose(jvp(raft.gru_context))/BasicUpdateBlock.context"
+     "/gru.context/convz1.context/convz1._conv/conv_general_dilated", "raft.gru_context.bwd"),
     # StreamEngine's slot-table step (PR 30): stream.* around the forward's raft.*
     ("jit(step)/stream.warmstart_splat/argmin", "stream.warmstart_splat"),
     ("jit(step)/stream.slot_gather/gather", "stream.slot_gather"),

@@ -209,29 +209,72 @@ def _ncup_plane_convolutions(text: str, planes: int, h: int, w: int) -> list:
     ]
 
 
-def _thin_side_convolutions(text: str, h: int, w: int) -> list:
-    """Lines of the compiled text with a `convolution` (a `dot_general` is
-    one too, on the TPU) whose result or an operand is an (h, w) plane with
-    a 2-wide feature side: the update block's `convf1` (7x7, 2 -> 128) and
-    `flow_head.conv2` (3x3, 256 -> 2) as they were until PR 29, a 128-wide
-    MXU tile filled to a sixty-fourth per kernel tap. Folded
-    (`nn/layers.py::conv_form`), the same planes carry 98 and 18 features.
-    A line names its operands; their shapes are on the lines that define
-    them."""
+def _convolutions(text: str):
+    """(computation, line, [result shape, *operand shapes]) of every
+    `convolution` (a `dot_general` is one too, on the TPU) of a compiled
+    module's text. A line names its operands; their shapes are on the lines
+    that define them."""
     shape_of = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", text))
-
-    def thin(shape: str) -> bool:
-        dims = shape[shape.index("[") + 1 : -1].split(",")
-        return len(dims) == 4 and str(h) in dims and str(w) in dims and "2" in dims
-
-    found = []
+    comp = None
     for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
         if " convolution(" not in line:
             continue
         result = re.search(r" = (\w+\[[\d,]*\])", line).group(1)
         operands = re.findall(r"%[\w.\-]+", line.split(" convolution(")[1].split(")")[0])
-        if any(thin(s) for s in [result, *(shape_of[o] for o in operands)]):
-            found.append(line.strip()[:200])
+        yield comp, line, [result, *(shape_of[o] for o in operands)]
+
+
+def _dims(shape: str) -> list:
+    return [int(d) for d in shape[shape.index("[") + 1 : -1].split(",") if d]
+
+
+def _thin_side_convolutions(text: str, h: int, w: int) -> list:
+    """Lines of the compiled text with a `convolution` whose result or an
+    operand is an (h, w) plane with a 2-wide feature side: the update
+    block's `convf1` (7x7, 2 -> 128) and `flow_head.conv2` (3x3, 256 -> 2)
+    as they were until PR 29, a 128-wide MXU tile filled to a sixty-fourth
+    per kernel tap. Folded (`nn/layers.py::conv_form`), the same planes
+    carry 98 and 18 features."""
+
+    def thin(shape: str) -> bool:
+        dims = _dims(shape)
+        return len(dims) == 4 and h in dims and w in dims and 2 in dims
+
+    return [
+        line.strip()[:200] for _, line, shapes in _convolutions(text)
+        if any(thin(s) for s in shapes)
+    ]
+
+
+def _gru_gate_convolutions(text: str) -> dict:
+    """{(inside a `while` body?, contraction width): count} over the
+    `convolution`s of the compiled text that have one of the GRU gates'
+    5-tap kernels among their operands (forward, input cotangent) or as
+    their result (kernel cotangent): a shape that is, 1s aside, 5 taps by
+    128 gate outputs by `width` input rows. The full `[h, inp, motion]`
+    width is 384; since PR 31 the loop's gates contract `[h, motion]`, 256
+    wide, and what they make of the 128 context channels is convolved once,
+    outside every loop. A loop body is every computation reachable from a
+    `while`'s `body=` through `calls=` (fusions) and nested loops."""
+    called: dict = {}
+    for comp, body in re.findall(r"^(?:ENTRY )?(%[\w.\-]+) \(.*?\{$(.*?)^\}", text, re.M | re.S):
+        called[comp] = re.findall(r"(?:calls|body|condition)=(%[\w.\-]+)", body)
+    in_loop, todo = set(), re.findall(r"body=(%[\w.\-]+)", text)
+    while todo:
+        comp = todo.pop()
+        if comp not in in_loop:
+            in_loop.add(comp)
+            todo += called[comp]
+    found: dict = {}
+    for comp, _, shapes in _convolutions(text):
+        for shape in shapes:
+            dims = sorted(d for d in _dims(shape) if d != 1)
+            if len(dims) == 3 and dims[:2] == [5, 128]:
+                key = (comp in in_loop, dims[2])
+                found[key] = found.get(key, 0) + 1
     return found
 
 
@@ -258,6 +301,18 @@ def test_sintel_train_step_has_no_convolution_with_a_2_wide_side(
     rematerialised and both cotangents: no `convolution` of the step has a
     2-wide feature side on the 46x96 plane (368x768 / 8)."""
     assert _thin_side_convolutions(train_program.text, 46, 96) == []
+
+
+def test_sintel_train_step_loops_convolve_no_context_features(train_program):
+    """Since PR 31 the GRU's context terms are constants of the checkpointed
+    scan body: the forward, rematerialised and backward loops hold the
+    gates over `[h, motion]` alone (256 wide; no 384-wide kernel is left
+    anywhere), and the `inp` rows' products run ONCE a step outside the
+    loops, not 12 times in them: 6 forward, 6 input cotangents, 6 kernel
+    cotangents (the loop's cotangent of each term is summed first)."""
+    found = _gru_gate_convolutions(train_program.text)
+    assert {width for in_loop, width in found if in_loop} == {256}
+    assert {key: n for key, n in found.items() if not key[0]} == {(False, 128): 18}
 
 
 def test_sintel_train_step_temporaries_stay_under_8_gib(
@@ -294,6 +349,15 @@ def test_eval_cell_forward_has_no_convolution_with_a_2_wide_side(
     a batch of 8 cost (PR 28's ledger lines), are folded (PR 29): no
     `convolution` has a 2-wide feature side on the 55x128 plane."""
     assert _thin_side_convolutions(eval_program.text, 55, 128) == []
+
+
+def test_eval_cell_forward_loop_convolves_no_context_features(eval_program):
+    """Since PR 31: the six gates' share of the context features before the
+    loop, once; in the loop body six gate convolutions over `[h, motion]`;
+    no convolution of the program contracts the full 384."""
+    assert _gru_gate_convolutions(eval_program.text) == {
+        (False, 128): 6, (True, 256): 6,
+    }
 
 
 def test_eval_cell_forward_temporaries_stay_under_6_gib(
