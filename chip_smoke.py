@@ -38,6 +38,7 @@ MODEL = "raft_nc_dbl"
 EVAL_HW = (436, 1024)  # Sintel frame; InputPadder pads it to 440x1024
 EVAL_ITERS = 32
 SERVE_HW = (440, 1024)
+HD_HW = (1080, 1920)  # 1080p: a multiple of 8, InputPadder adds nothing
 TRAIN_HW = (368, 768)  # scripts/train_raft_nc_sintel.sh crop
 TRAIN_ITERS = 12
 EPE_BUDGET_PX = 0.5  # docs/PRECISION.md: the repo's own parity budget
@@ -512,40 +513,72 @@ def phase_trainer(
     }
 
 
-def phase_kernels(seed: int, variables, flow_volume, hw=EVAL_HW,
-                  iters=EVAL_ITERS, nconv_hw=TRAIN_HW) -> dict:
-    """Phase 5: both Pallas kernels compiled by Mosaic and checked
-    against their XLA twins on the device (the correlation kernel against
-    the eval phase's ``volume`` flow, same weights and frames). Dispatch
-    counts and the compiled text are read so that the phase cannot pass
-    on XLA."""
+def _pallas_against_volume(
+    seed: int, variables, hw, iters: int, flow_volume=None
+) -> tuple[dict, float]:
+    """``corr_impl="pallas"`` compiled by Mosaic and run at ``hw``, batch
+    1, against the ``volume`` flow of the same weights and frames (made
+    here when the caller has none). Dispatch counts and the compiled
+    text are read so that the check cannot pass on XLA. Returns
+    ``(dispatch tally, mean EPE in px)``."""
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
-    from raft_ncup_tpu.ops import corr_pallas, nconv
+    from raft_ncup_tpu.ops import corr_pallas
 
     padder, p1, p2 = _padded_pair(seed, hw)
-    model, _ = _flagship("pallas")
-    fn = jax.jit(
-        lambda v, a, b: model.apply(v, a, b, iters=iters, test_mode=True)[1]
-    )
+
+    def forward(impl):
+        model, _ = _flagship(impl)
+        return jax.jit(
+            lambda v, a, b: model.apply(v, a, b, iters=iters, test_mode=True)[1]
+        )
+
+    if flow_volume is None:
+        flow_volume = _checked_flow(
+            "volume", padder, forward("volume")(variables, p1, p2), hw
+        )
     corr_pallas.reset_dispatch_counts()
-    compiled = fn.lower(variables, p1, p2).compile()
+    compiled = forward("pallas").lower(variables, p1, p2).compile()
     tiers = corr_pallas.dispatch_counts()
     check(
         tiers["fallback"] == 0 and tiers["levels_total"] > 0,
-        f"corr_impl=pallas dispatch {tiers}: a level fell back to XLA",
+        f"corr_impl=pallas at {hw} dispatch {tiers}: a level fell back to XLA",
     )
     check(
         "tpu_custom_call" in compiled.as_text(),
-        "corr_impl=pallas: no tpu_custom_call in the compiled text",
+        f"corr_impl=pallas at {hw}: no tpu_custom_call in the compiled text",
     )
     flow = _checked_flow("pallas", padder, compiled(variables, p1, p2), hw)
     corr_epe = _mean_epe(flow_volume, flow)
     check(
         corr_epe < EPE_BUDGET_PX,
-        f"volume vs pallas mean EPE {corr_epe} px >= {EPE_BUDGET_PX} px",
+        f"volume vs pallas at {hw} mean EPE {corr_epe} px >= {EPE_BUDGET_PX} px",
+    )
+    return tiers, corr_epe
+
+
+def phase_kernels(seed: int, variables, flow_volume, hw=EVAL_HW,
+                  iters=EVAL_ITERS, nconv_hw=TRAIN_HW, hd_hw=HD_HW) -> dict:
+    """Phase 5: both Pallas kernels compiled by Mosaic and checked
+    against their XLA twins on the device: the correlation kernel against
+    the eval phase's ``volume`` flow (same weights and frames), then
+    again at ``hd_hw``, the shape of the benchmark's ``eval_1080p_nc``
+    cell, where levels 0-1 take the BANDED tier (at the Sintel frame only
+    level 0 does) and the volume it is compared with, 5.7 GB, fits for
+    one pair only: the smoke fails here before the benchmark does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from raft_ncup_tpu.ops import nconv
+
+    tiers, corr_epe = _pallas_against_volume(
+        seed, variables, hw, iters, flow_volume
+    )
+    hd_tiers, hd_epe = _pallas_against_volume(seed, variables, hd_hw, iters)
+    check(
+        hd_tiers["banded"] > 0,
+        f"corr_impl=pallas at {hd_hw} dispatch {hd_tiers}: no banded level",
     )
 
     # The fused NConv at the widest site of the NCUP stack at the Sintel
@@ -607,6 +640,9 @@ def phase_kernels(seed: int, variables, flow_volume, hw=EVAL_HW,
     return {
         "corr_tiers": tiers,
         "epe_volume_vs_pallas_px": corr_epe,
+        "hd_hw": list(hd_hw),
+        "hd_corr_tiers": hd_tiers,
+        "hd_epe_volume_vs_pallas_px": hd_epe,
         "nconv_shape": list(shape),
         "nconv_sites": sites,
         "nconv_max_abs_err_vs_f32_xla": err,

@@ -345,7 +345,10 @@ class RAFT:
                     )
 
         elif cfg.corr_impl == "pallas":
-            from raft_ncup_tpu.ops.corr_pallas import corr_lookup_pallas
+            from raft_ncup_tpu.ops.corr_pallas import (
+                corr_lookup_pallas,
+                prepare_lookup,
+            )
 
             # Dispatch is per pyramid level inside the op, THREE tiers:
             # levels whose padded slab fits the VMEM budget take the
@@ -361,11 +364,21 @@ class RAFT:
             from raft_ncup_tpu.utils.runtime import is_tpu_backend
 
             interpret = not is_tpu_backend()
+            # The pooled, zero-padded pyramid depends on the features
+            # alone: made here, once per pair, and closed over by every
+            # iteration's lookup (the compiler leaves the pads inside
+            # the loop otherwise). A cache of fmap2, not a second input:
+            # gradients take the op's own backward through the features.
+            prepared = jax.lax.stop_gradient(
+                prepare_lookup(
+                    fmap1, fmap2, radius, cfg.corr_levels, policy.corr_jnp
+                )
+            )
 
             def corr_fn(coords):
                 return corr_lookup_pallas(
                     fmap1, fmap2, coords, radius, cfg.corr_levels, interpret,
-                    policy.corr_jnp,
+                    policy.corr_jnp, prepared,
                 )
 
         else:

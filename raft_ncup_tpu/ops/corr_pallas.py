@@ -111,6 +111,13 @@ from raft_ncup_tpu.utils.runtime import VMEM_BYTES as _VMEM_BYTES
 _QUERY_BLOCK = 128
 _GROUP = 8  # queries per vectorized inner step (sublane tile)
 
+# Scopes of the XLA work around the kernels (docs/OBSERVABILITY.md): the
+# model's ``raft.corr_lookup`` holds the kernels and the output's
+# transposes; these two split off the once-per-pair padded pyramid and
+# the banded tier's per-iteration sort.
+_PAD_SCOPE = "raft.corr_lookup.pad_levels"
+_SORT_SCOPE = "raft.corr_lookup.band_sort"
+
 QUERY_BLOCK_ENV = "RAFT_NCUP_CORR_QUERY_BLOCK"
 BAND_ROWS_ENV = "RAFT_NCUP_CORR_BAND_ROWS"
 
@@ -438,9 +445,33 @@ def _lookup_kernel(
     jax.lax.fori_loop(0, out_ref.shape[0] // G, body, 0)
 
 
+def _pad_level(
+    f2l: jax.Array, radius: int, band_rows: int | None = None
+) -> jax.Array:
+    """The zero-padded (B, Hl, Wl, C) pooled level as a kernel reads it:
+    K + 2 zeros per side (:func:`_padded_hw`), zero columns on the right
+    up to :func:`_alloc_width`, and for the banded tier (``band_rows``
+    given) zero rows below so that every band's slab (``band_rows`` +
+    halo rows from its first origin row) is in-bounds — zeros, i.e. the
+    margin the clamped-origin semantics already rely on. The features
+    and ``radius`` fix it: once per pair, outside the refinement loop
+    (:func:`prepare_lookup`)."""
+    _, Hl, Wl, _ = f2l.shape
+    Hp, Wp, pad = _padded_hw(Hl, Wl, radius)
+    Wpa = _alloc_width(Wp, radius, jnp.dtype(f2l.dtype).itemsize)
+    extra = 0
+    if band_rows is not None:
+        _, n_bands = _band_geometry(Hp, radius, band_rows)
+        extra = n_bands * band_rows + _band_halo(radius) - Hp
+    return jnp.pad(
+        f2l, ((0, 0), (pad, pad + extra), (pad, pad + Wpa - Wp), (0, 0))
+    )
+
+
 def _lookup_one_level(
     f1: jax.Array,  # (B, N, C) pre-scaled query features, N = H*W
-    f2l: jax.Array,  # (B, Hl, Wl, C) pooled fmap2 level
+    f2p: jax.Array,  # (B, Hp, Wpa, C) level padded by _pad_level
+    level_hw: tuple[int, int],  # (Hl, Wl) of the pooled level
     coords: jax.Array,  # (B, N, 2)
     radius: int,
     level: int,
@@ -448,16 +479,13 @@ def _lookup_one_level(
     query_block: int = _QUERY_BLOCK,
 ) -> jax.Array:
     B, N, C = f1.shape
-    _, Hl, Wl, _ = f2l.shape
+    Hl, Wl = level_hw
     # Feature operands keep their (policy-chosen) dtype end to end: the
     # VMEM-resident slab and the f1 blocks are what the budget counts.
     fdt = f1.dtype
     K = 2 * radius + 1
     Hp, Wp, pad = _padded_hw(Hl, Wl, radius)
-    Wpa = _alloc_width(Wp, radius, jnp.dtype(fdt).itemsize)
-    f2p = jnp.pad(
-        f2l, ((0, 0), (pad, pad), (pad, pad + Wpa - Wp), (0, 0))
-    )
+    Wpa = f2p.shape[2]
 
     # Window origin + sub-pixel offset per query, computed on the XLA side
     # so the kernel's SMEM operand is plain int32 indices.
@@ -500,15 +528,26 @@ def _lookup_one_level(
         out_shape=jax.ShapeDtypeStruct((B, N + n_pad, K, K), jnp.float32),
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name="corr_lookup_resident",
+        name=f"corr_resident_l{level}",
     )(
         ibase,
         f1.astype(fdt),
         frac.astype(jnp.float32),
         f2p.astype(fdt),
     )
-    # (B, N, K_y, K_x) -> x-major taps (reference order).
-    return out[:, :N].transpose(0, 1, 3, 2).reshape(B, N, K * K)
+    return _x_major_taps(out)[:, :N]
+
+
+def _x_major_taps(out: jax.Array) -> jax.Array:
+    """A kernel's (B, Nq, K_y, K_x) windows as (B, Nq, K*K) rows of
+    x-major taps (the reference's order, core/corr.py:31-37). FIRST, before
+    any row is dropped or permuted: the kernel's (9, 9) windows sit in
+    (16, 128) tiles, 8 KB a query, 1.07 GB a level for a batch of four
+    1080p pairs, and XLA ran a slice or a gather over that form as a copy
+    of all of it (6.5 and 6.9 ms an iteration each on a v5e, PR 32); over
+    the 81-tap rows the same slice or gather moves a sixteenth."""
+    B, Nq, K, _ = out.shape
+    return out.transpose(0, 1, 3, 2).reshape(B, Nq, K * K)
 
 
 def _banded_lookup_kernel(
@@ -604,7 +643,8 @@ def _banded_lookup_kernel(
 
 def _banded_lookup_one_level(
     f1: jax.Array,  # (B, N, C) pre-scaled query features, N = H*W
-    f2l: jax.Array,  # (B, Hl, Wl, C) pooled fmap2 level
+    f2p: jax.Array,  # (B, Hb, Wpa, C) level padded by _pad_level(band_rows)
+    level_hw: tuple[int, int],  # (Hl, Wl) of the pooled level
     coords: jax.Array,  # (B, N, 2)
     radius: int,
     level: int,
@@ -618,93 +658,92 @@ def _banded_lookup_one_level(
     per-query math, only regrouped — the parity is pinned by
     tests/test_corr_pallas.py."""
     B, N, C = f1.shape
-    _, Hl, Wl, _ = f2l.shape
+    Hl, Wl = level_hw
     fdt = f1.dtype
     K = 2 * radius + 1
     K1 = K + 1
     halo = _band_halo(radius)
     Hp, Wp, pad = _padded_hw(Hl, Wl, radius)
     _, n_bands = _band_geometry(Hp, radius, band_rows)
-    # Zero-pad rows so every band slab (band_rows + halo rows from its
-    # first origin row) is in-bounds; the extra rows are zeros, i.e.
-    # exactly the margin the clamped-origin semantics already rely on.
-    extra = n_bands * band_rows + halo - Hp
-    Wpa = _alloc_width(Wp, radius, jnp.dtype(fdt).itemsize)
-    f2p = jnp.pad(
-        f2l, ((0, 0), (pad, pad + extra), (pad, pad + Wpa - Wp), (0, 0))
-    ).astype(fdt)
+    Wpa = f2p.shape[2]
+    f2p = f2p.astype(fdt)
 
-    cl = coords.astype(jnp.float32) / (2.0**level)
-    c0 = jnp.floor(cl)
-    frac = cl - c0  # (B, N, 2): (fx, fy)
-    lim = jnp.asarray([Wp - K1, Hp - K1], jnp.int32)
-    ib = jnp.clip(c0.astype(jnp.int32) - radius + pad, 0, lim)
-    band_id = ib[..., 1] // band_rows  # (B, N)
-    # Window origins as the kernel reads them: x in the padded level,
-    # y LOCAL to the query's own band slab.
-    ibase = jnp.stack(
-        [ib[..., 0], ib[..., 1] - band_id * band_rows], axis=-1
-    )
+    # Everything XLA does per iteration to feed the kernel — band
+    # assignment, the stable argsort, the row permutations and the chunk
+    # table — under one scope, so a capture tells it from the kernel.
+    with jax.named_scope(_SORT_SCOPE):
+        cl = coords.astype(jnp.float32) / (2.0**level)
+        c0 = jnp.floor(cl)
+        frac = cl - c0  # (B, N, 2): (fx, fy)
+        lim = jnp.asarray([Wp - K1, Hp - K1], jnp.int32)
+        ib = jnp.clip(c0.astype(jnp.int32) - radius + pad, 0, lim)
+        band_id = ib[..., 1] // band_rows  # (B, N)
+        # Window origins as the kernel reads them: x in the padded level,
+        # y LOCAL to the query's own band slab.
+        ibase = jnp.stack(
+            [ib[..., 0], ib[..., 1] - band_id * band_rows], axis=-1
+        )
 
-    # Stable argsort-by-band: queries of one band become contiguous (and
-    # keep raster order within it); the inverse permutation restores the
-    # caller's order after the kernel.
-    order = jnp.argsort(band_id, axis=1, stable=True)
+        # Stable argsort-by-band: queries of one band become contiguous (and
+        # keep raster order within it); the inverse permutation restores the
+        # caller's order after the kernel.
+        order = jnp.argsort(band_id, axis=1, stable=True)
 
-    def take(x):
-        return jnp.take_along_axis(x, order[..., None], axis=1)
+        def take(x):
+            return jnp.take_along_axis(x, order[..., None], axis=1)
 
-    f1_s, frac_s, ibase_s = take(f1), take(frac), take(ibase)
-    band_s = jnp.take_along_axis(band_id, order, axis=1)
+        f1_s, frac_s, ibase_s = take(f1), take(frac), take(ibase)
+        band_s = jnp.take_along_axis(band_id, order, axis=1)
 
-    qblk = query_block or effective_query_block()
-    qblk = min(qblk, max(_GROUP, (N + _GROUP - 1) // _GROUP * _GROUP))
-    qblk = max(qblk - qblk % _GROUP, _GROUP)
-    n_pad = (-N) % qblk
-    if n_pad:
-        f1_s = jnp.pad(f1_s, ((0, 0), (0, n_pad), (0, 0)))
-        frac_s = jnp.pad(frac_s, ((0, 0), (0, n_pad), (0, 0)))
-        ibase_s = jnp.pad(ibase_s, ((0, 0), (0, n_pad), (0, 0)))
-        # Padding queries ride the last band (edge mode) so they extend
-        # its final chunk instead of minting a fresh one; their ibase is
-        # (0, 0) — in-slab reads, results dropped by the [:N] slice.
-        band_s = jnp.pad(band_s, ((0, 0), (0, n_pad)), mode="edge")
-    Nq = N + n_pad
-    n_blocks = Nq // qblk
+        qblk = query_block or effective_query_block()
+        qblk = min(qblk, max(_GROUP, (N + _GROUP - 1) // _GROUP * _GROUP))
+        qblk = max(qblk - qblk % _GROUP, _GROUP)
+        n_pad = (-N) % qblk
+        if n_pad:
+            f1_s = jnp.pad(f1_s, ((0, 0), (0, n_pad), (0, 0)))
+            frac_s = jnp.pad(frac_s, ((0, 0), (0, n_pad), (0, 0)))
+            ibase_s = jnp.pad(ibase_s, ((0, 0), (0, n_pad), (0, 0)))
+            # Padding queries ride the last band (edge mode) so they extend
+            # its final chunk instead of minting a fresh one; their ibase is
+            # (0, 0) — in-slab reads, results dropped by the un-sort (the
+            # inverse permutation has N rows).
+            band_s = jnp.pad(band_s, ((0, 0), (0, n_pad)), mode="edge")
+        Nq = N + n_pad
+        n_blocks = Nq // qblk
 
-    # Chunk table: the sorted query array cut at every query-block start
-    # and band change — the (band x query_block) grid with empty cells
-    # compressed out. At most n_blocks + n_bands - 1 segments; unused
-    # slots become dummy chunks (lo == hi == Nq, clamped to the last
-    # block and band, fresh=0) that fetch nothing new and mask all work.
-    n_chunks = n_blocks + n_bands - 1
-    pos = jnp.arange(Nq, dtype=jnp.int32)
-    newband = jnp.concatenate(
-        [jnp.ones((B, 1), bool), band_s[:, 1:] != band_s[:, :-1]], axis=1
-    )
-    is_start = newband | ((pos % qblk) == 0)[None, :]
-    starts = jnp.sort(
-        jnp.where(is_start, pos[None], Nq).astype(jnp.int32), axis=1
-    )[:, :n_chunks]
-    ends = jnp.minimum(
-        jnp.concatenate(
-            [starts[:, 1:], jnp.full((B, 1), Nq, jnp.int32)], axis=1
-        ),
-        Nq,
-    )
-    blk = jnp.minimum(starts // qblk, n_blocks - 1)
-    bnd = jnp.take_along_axis(
-        band_s, jnp.minimum(starts, Nq - 1), axis=1
-    ).astype(jnp.int32)
-    fresh = jnp.concatenate(
-        [
-            jnp.ones((B, 1), jnp.int32),
-            (bnd[:, 1:] != bnd[:, :-1]).astype(jnp.int32),
-        ],
-        axis=1,
-    )
-    fresh = jnp.where(starts < Nq, fresh, 0)  # dummies never DMA
-    tbl = jnp.stack([bnd, blk, starts, ends, fresh], axis=-1)
+        # Chunk table: the sorted query array cut at every query-block start
+        # and band change — the (band x query_block) grid with empty cells
+        # compressed out. At most n_blocks + n_bands - 1 segments; unused
+        # slots become dummy chunks (lo == hi == Nq, clamped to the last
+        # block and band, fresh=0) that fetch nothing new and mask all work.
+        n_chunks = n_blocks + n_bands - 1
+        pos = jnp.arange(Nq, dtype=jnp.int32)
+        newband = jnp.concatenate(
+            [jnp.ones((B, 1), bool), band_s[:, 1:] != band_s[:, :-1]], axis=1
+        )
+        is_start = newband | ((pos % qblk) == 0)[None, :]
+        starts = jnp.sort(
+            jnp.where(is_start, pos[None], Nq).astype(jnp.int32), axis=1
+        )[:, :n_chunks]
+        ends = jnp.minimum(
+            jnp.concatenate(
+                [starts[:, 1:], jnp.full((B, 1), Nq, jnp.int32)], axis=1
+            ),
+            Nq,
+        )
+        blk = jnp.minimum(starts // qblk, n_blocks - 1)
+        bnd = jnp.take_along_axis(
+            band_s, jnp.minimum(starts, Nq - 1), axis=1
+        ).astype(jnp.int32)
+        fresh = jnp.concatenate(
+            [
+                jnp.ones((B, 1), jnp.int32),
+                (bnd[:, 1:] != bnd[:, :-1]).astype(jnp.int32),
+            ],
+            axis=1,
+        )
+        fresh = jnp.where(starts < Nq, fresh, 0)  # dummies never DMA
+        tbl = jnp.stack([bnd, blk, starts, ends, fresh], axis=-1)
 
     ibase_spec = pl.BlockSpec(
         (None, qblk, 2),
@@ -744,7 +783,7 @@ def _banded_lookup_one_level(
         out_shape=jax.ShapeDtypeStruct((B, Nq, K, K), jnp.float32),
         interpret=interpret,
         compiler_params=_compiler_params(),
-        name="corr_lookup_banded",
+        name=f"corr_banded_l{level}",
     )(
         tbl,
         ibase_s,
@@ -752,10 +791,67 @@ def _banded_lookup_one_level(
         frac_s.astype(jnp.float32),
         f2p,
     )
-    inv = jnp.argsort(order, axis=1)
-    out = jnp.take_along_axis(out, inv[..., None, None], axis=1)
-    # (B, N, K_y, K_x) -> x-major taps (reference order).
-    return out[:, :N].transpose(0, 1, 3, 2).reshape(B, N, K * K)
+    taps = _x_major_taps(out)
+    with jax.named_scope(_SORT_SCOPE):  # back to the caller's order
+        inv = jnp.argsort(order, axis=1)  # (B, N): the padding rows drop out
+        return jnp.take_along_axis(taps, inv[..., None], axis=1)
+
+
+def _level_tiers(
+    H: int, W: int, C: int, radius: int, num_levels: int, dtype, qblk: int
+) -> list[tuple[str, tuple[int, int], int | None]]:
+    """The static THREE-TIER dispatch of every pyramid level of an (H, W)
+    1/8-resolution map at ``dtype``'s element size, from shapes alone:
+    ``(tier, (Hl, Wl), band_rows)`` with tier ``kernel`` (the padded slab
+    fits VMEM: resident), ``banded`` (a fitting :func:`band_plan`) or
+    ``fallback`` (XLA onthefly). :func:`prepare_lookup` and the lookup
+    both call it, so the padded levels made once per pair are the ones
+    each iteration's kernels expect."""
+    tiers = []
+    for lvl in range(num_levels):
+        Hl, Wl = H >> lvl, W >> lvl  # avg_pool2 floors
+        if fits_vmem(Hl, Wl, C, radius, dtype=dtype):
+            tiers.append(("kernel", (Hl, Wl), None))
+        elif plan := band_plan(
+            Hl, Wl, C, radius, dtype=dtype, query_block=qblk
+        ):
+            tiers.append(("banded", (Hl, Wl), plan[0]))
+        else:
+            tiers.append(("fallback", (Hl, Wl), None))
+    return tiers
+
+
+def prepare_lookup(
+    fmap1: jax.Array,
+    fmap2: jax.Array,
+    radius: int,
+    num_levels: int = 4,
+    dtype=None,
+) -> tuple:
+    """What the lookup makes of the feature maps alone, ONCE per pair:
+    ``(f1, levels)``, the pre-scaled (B, H*W, C) query features and per
+    pyramid level the pooled fmap2 level zero-padded as its kernel tier
+    reads it (``None`` for a level that falls back to XLA). A caller
+    that looks up many times over the same features (the refinement
+    loop) makes this before the loop and hands it to
+    :func:`corr_lookup_pallas` as ``prepared``, so no iteration pools or
+    pads; without it every call prepares for itself."""
+    from raft_ncup_tpu.ops.corr import _pool_fmap_pyramid
+
+    B, H, W, C = fmap1.shape
+    dtype = jnp.dtype(dtype) if dtype is not None else jnp.float32
+    tiers = _level_tiers(
+        H, W, C, radius, num_levels, dtype, effective_query_block()
+    )
+    with jax.named_scope(_PAD_SCOPE):
+        f1 = (fmap1.reshape(B, H * W, C) * (1.0 / math.sqrt(C))).astype(dtype)
+        levels = tuple(
+            None if tier == "fallback" else _pad_level(f2l, radius, band_rows)
+            for f2l, (tier, _, band_rows) in zip(
+                _pool_fmap_pyramid(fmap2.astype(dtype), num_levels), tiers
+            )
+        )
+    return f1, levels
 
 
 def _forward(
@@ -766,6 +862,7 @@ def _forward(
     num_levels: int,
     interpret: bool = False,
     dtype=None,
+    prepared: tuple | None = None,
 ) -> jax.Array:
     """Volume-free fused lookup over all pyramid levels, with PER-LEVEL
     THREE-TIER dispatch at ``dtype``'s element size: levels whose
@@ -776,13 +873,13 @@ def _forward(
     level lands on a kernel tier — tests/test_pallas_lowering.py pins
     the exact counts, tests/test_precision.py the bf16 threshold
     ratios)."""
-    from raft_ncup_tpu.ops.corr import _pool_fmap_pyramid, corr_lookup_onthefly
+    from raft_ncup_tpu.ops.corr import corr_lookup_onthefly
 
     B, H, W, C = fmap1.shape
-    scale = 1.0 / math.sqrt(C)
     dtype = jnp.dtype(dtype) if dtype is not None else jnp.float32
-    f1 = (fmap1.reshape(B, H * W, C) * scale).astype(dtype)
-    f2_levels = _pool_fmap_pyramid(fmap2.astype(dtype), num_levels)
+    if prepared is None:
+        prepared = prepare_lookup(fmap1, fmap2, radius, num_levels, dtype)
+    f1, levels = prepared
     cflat = coords.astype(jnp.float32).reshape(B, H * W, 2)
 
     qblk = effective_query_block()
@@ -790,24 +887,20 @@ def _forward(
     outs: dict[int, jax.Array] = {}
     fallback = []
     _count("levels_total", num_levels)
-    for lvl, f2l in enumerate(f2_levels):
-        Hl, Wl = f2l.shape[1], f2l.shape[2]
-        if fits_vmem(Hl, Wl, C, radius, dtype=dtype):
-            _count("kernel")
+    tiers = _level_tiers(H, W, C, radius, num_levels, dtype, qblk)
+    for lvl, (tier, level_hw, band_rows) in enumerate(tiers):
+        _count(tier)
+        if tier == "kernel":
             outs[lvl] = _lookup_one_level(
-                f1, f2l, cflat, radius, lvl, interpret=interpret,
-                query_block=qblk,
-            )
-        elif plan := band_plan(
-            Hl, Wl, C, radius, dtype=dtype, query_block=qblk
-        ):
-            _count("banded")
-            outs[lvl] = _banded_lookup_one_level(
-                f1, f2l, cflat, radius, lvl, band_rows=plan[0],
+                f1, levels[lvl], level_hw, cflat, radius, lvl,
                 interpret=interpret, query_block=qblk,
             )
+        elif tier == "banded":
+            outs[lvl] = _banded_lookup_one_level(
+                f1, levels[lvl], level_hw, cflat, radius, lvl,
+                band_rows=band_rows, interpret=interpret, query_block=qblk,
+            )
         else:
-            _count("fallback")
             fallback.append(lvl)
     if fallback:
         if len(fallback) == num_levels:
@@ -848,6 +941,7 @@ def corr_lookup_pallas(
     num_levels: int = 4,
     interpret: bool = False,
     dtype=None,
+    prepared: tuple | None = None,
 ) -> jax.Array:
     """Fused correlation lookup: (B,H,W,C) x2 + (B,H,W,2) ->
     (B, H, W, L*(2r+1)^2) float32. Equivalent to the XLA paths in
@@ -855,25 +949,29 @@ def corr_lookup_pallas(
     materializes the correlation volume. ``dtype`` (static; default
     f32) is the feature/slab dtype the per-level THREE-TIER dispatch
     (resident kernel -> banded kernel -> XLA onthefly) budgets with —
-    the precision policy's ``corr_jnp``. The backward always
-    differentiates the f32 XLA path: gradients stay full precision
-    regardless of the forward's storage dtype (f32 master weights)."""
+    the precision policy's ``corr_jnp``. ``prepared``:
+    :func:`prepare_lookup`'s result for the same features, radius,
+    levels and dtype, made once by a caller that looks up in a loop; a
+    cache of the features, not an input of its own (its cotangent is
+    zero). The backward always differentiates the f32 XLA path:
+    gradients stay full precision regardless of the forward's storage
+    dtype (f32 master weights)."""
     return _forward(
-        fmap1, fmap2, coords, radius, num_levels, interpret, dtype
+        fmap1, fmap2, coords, radius, num_levels, interpret, dtype, prepared
     )
 
 
-def _fwd(fmap1, fmap2, coords, radius, num_levels, interpret, dtype):
+def _fwd(fmap1, fmap2, coords, radius, num_levels, interpret, dtype, prepared):
     out = _forward(
-        fmap1, fmap2, coords, radius, num_levels, interpret, dtype
+        fmap1, fmap2, coords, radius, num_levels, interpret, dtype, prepared
     )
-    return out, (fmap1, fmap2, coords)
+    return out, (fmap1, fmap2, coords, prepared)
 
 
 def _bwd(radius, num_levels, interpret, dtype, res, g):
     from raft_ncup_tpu.ops.corr import corr_lookup_onthefly
 
-    fmap1, fmap2, coords = res
+    fmap1, fmap2, coords, prepared = res
     # Backward through the mathematically equivalent XLA implementation —
     # autodiff of the gather path gives exact gradients for the same
     # function value.
@@ -883,7 +981,7 @@ def _bwd(radius, num_levels, interpret, dtype, res, g):
         fmap2,
         coords,
     )
-    return vjp(g)
+    return (*vjp(g), jax.tree.map(jnp.zeros_like, prepared))
 
 
 corr_lookup_pallas.defvjp(_fwd, _bwd)

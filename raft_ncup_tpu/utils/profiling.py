@@ -44,7 +44,7 @@ SPAN_MARK = "program_span"
 OP_SCOPES_FILE = "op_scopes.json"
 UNSCOPED = "unscoped"
 
-_SCOPE = re.compile(r"\b(?:raft|train|stream)\.[a-z_]+")
+_SCOPE = re.compile(r"\b(?:raft|train|stream)\.[a-z_]+(?:\.[a-z_]+)*")
 
 
 def _annotation(name: str, attrs: dict):

@@ -70,6 +70,7 @@ LONGEST_FILES_FIRST = (
     "tests/benchmark/test_train_cell.py",
     "tests/benchmark/test_benchmark.py",
     "tests/benchmark/test_stream_cell.py",
+    "tests/benchmark/test_1080p_cell.py",
     "tests/test_chip_smoke.py",
     "tests/test_train_loop.py",
     "tests/test_corr_pallas.py",

@@ -259,8 +259,8 @@ class TestBandedLookup:
 
         return self._run(
             lambda f1, f2l, cf, lvl, br, qb: cpk._banded_lookup_one_level(
-                f1, f2l, cf, RADIUS, lvl, band_rows=br, interpret=True,
-                query_block=qb,
+                f1, cpk._pad_level(f2l, RADIUS, br), f2l.shape[1:3], cf,
+                RADIUS, lvl, band_rows=br, interpret=True, query_block=qb,
             ),
             fmap1, fmap2, coords, levels, band_rows, qblk,
         )
@@ -270,7 +270,8 @@ class TestBandedLookup:
 
         return self._run(
             lambda f1, f2l, cf, lvl, br, qb: cpk._lookup_one_level(
-                f1, f2l, cf, RADIUS, lvl, interpret=True, query_block=qb,
+                f1, cpk._pad_level(f2l, RADIUS), f2l.shape[1:3], cf, RADIUS,
+                lvl, interpret=True, query_block=qb,
             ),
             fmap1, fmap2, coords, levels,
         )

@@ -50,6 +50,12 @@ LOOKUP, UPDATE, LOOP, FNET = (
     ("jit(step)/stream.slot_gather/gather", "stream.slot_gather"),
     ("jit(step)/stream.anomaly_scatter/scatter", "stream.anomaly_scatter"),
     ("jit(step)/upstream.thing/mul", None),
+    # the pallas lookup's own scopes (PR 32): a dotted name is ONE scope,
+    # the sort inside raft.corr_lookup, the padded pyramid before the loop
+    ("jit(fn)/raft.refinement/while/body/raft.corr_lookup/raft.corr_lookup.band_sort/sort",
+     LOOKUP + ".band_sort"),
+    ("jit(fn)/raft.corr_lookup.pad_levels/pad", LOOKUP + ".pad_levels"),
+    ("jit(fn)/raft.refinement/while/body/raft.corr_lookup/pallas_call", LOOKUP),
 ])
 def test_scope_of_takes_the_innermost_raft_scope(op_name, want):
     assert P.scope_of(op_name) == want

@@ -28,9 +28,11 @@ code generation), spread over whatever cores the host has free: 114 to
 190 s of wall alone on 8 cores, about 300 s as the first test of the
 suite, 607 s when it started minutes in and ended beside five busy
 workers (PR 28). The eval forward is 94 s alone and 240-275 s in the
-suite, the 14 kernel cases 40 s alone and 90 s there. The whole programs
-come first in the file, the kernel cases, seconds each, last: they are
-what is left when the worker asks for its next file.
+suite, the 14 kernel cases 40 s alone and 90 s there. The 1080p cell's
+forward (PR 32: batch 4, 1080x1920, the Pallas lookup; 32 iterations, the
+loop body is compiled once whatever their number) is 60-75 s alone. The
+whole programs come first in the file, the kernel cases, seconds each,
+last: they are what is left when the worker asks for its next file.
 """
 
 import re
@@ -196,6 +198,64 @@ def eval_program(sds) -> Program:
     return _program(compiled)
 
 
+class HdProgram(NamedTuple):
+    """The 1080p cell's compile: the program, what it asks of the device
+    with its arguments, and the lookup's trace-time dispatch tally."""
+
+    text: str
+    temp_and_arguments_gib: float
+    tiers: dict
+
+
+@pytest.fixture(scope="module")
+def hd_program(sds) -> HdProgram:
+    """The `eval_1080p_nc` cell's program (PR 32): raft_nc_dbl with
+    `corr_impl="pallas"`, 4x1080x1920 (1080 is a multiple of 8: the
+    padder adds nothing, and the 1/8 map is 135x240), 32 iterations, float32 with every product at `highest`. The model asks
+    the runtime whether it is on a TPU before it hands the kernels to
+    Mosaic (elsewhere they run interpreted); a compile for a described
+    chip answers for it here, in the test, as
+    tests/test_pallas_lowering.py does."""
+    from raft_ncup_tpu.config import flagship_config
+    from raft_ncup_tpu.models.raft import RAFT
+    from raft_ncup_tpu.utils import runtime
+
+    model = RAFT(flagship_config(dataset="sintel", corr_impl="pallas"))
+    variables = _abstract(sds, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), (1, 64, 96, 3))
+    ))
+    img = sds((4, 1080, 1920, 3))
+    patch = pytest.MonkeyPatch()
+    patch.setattr(runtime, "is_tpu_backend", lambda: True)
+    cpk.reset_dispatch_counts()
+    try:
+        with jax.default_matmul_precision("highest"):
+            compiled = jax.jit(
+                lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
+            ).lower(variables, img, img).compile()
+    finally:
+        patch.undo()
+    memory = compiled.memory_analysis()
+    asked = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    return HdProgram(compiled.as_text(), asked / 2**30, cpk.dispatch_counts())
+
+
+def _loop_computations(text: str) -> dict:
+    """{name: body text} of every computation reachable from a `while`'s
+    `body=` through `calls=` (fusions) and nested loops."""
+    bodies, called = {}, {}
+    for comp, body in re.findall(r"^(?:ENTRY )?(%[\w.\-]+) \(.*?\{$(.*?)^\}", text, re.M | re.S):
+        bodies[comp] = body
+        called[comp] = re.findall(r"(?:calls|body|condition)=(%[\w.\-]+)", body)
+    in_loop, todo = set(), re.findall(r"body=(%[\w.\-]+)", text)
+    while todo:
+        comp = todo.pop()
+        if comp not in in_loop:
+            in_loop.add(comp)
+            todo += called[comp]
+    return {comp: bodies[comp] for comp in in_loop}
+
+
 def _ncup_plane_convolutions(text: str, planes: int, h: int, w: int) -> list:
     """Lines of the compiled text with a `convolution` that has an operand
     of NCUP's plane shape: `planes` full-resolution frames with at most 4
@@ -259,15 +319,7 @@ def _gru_gate_convolutions(text: str) -> dict:
     wide, and what they make of the 128 context channels is convolved once,
     outside every loop. A loop body is every computation reachable from a
     `while`'s `body=` through `calls=` (fusions) and nested loops."""
-    called: dict = {}
-    for comp, body in re.findall(r"^(?:ENTRY )?(%[\w.\-]+) \(.*?\{$(.*?)^\}", text, re.M | re.S):
-        called[comp] = re.findall(r"(?:calls|body|condition)=(%[\w.\-]+)", body)
-    in_loop, todo = set(), re.findall(r"body=(%[\w.\-]+)", text)
-    while todo:
-        comp = todo.pop()
-        if comp not in in_loop:
-            in_loop.add(comp)
-            todo += called[comp]
+    in_loop = _loop_computations(text)
     found: dict = {}
     for comp, _, shapes in _convolutions(text):
         for shape in shapes:
@@ -366,6 +418,66 @@ def test_eval_cell_forward_temporaries_stay_under_6_gib(
     """The program's temporaries leave most of the chip free (PR 25: 4.54
     GiB; the gather form was 5.14 GiB; unchanged by PR 27)."""
     assert _record_temp(record_property, eval_program) < 6.0
+
+
+def test_hd_cell_forward_fits_the_chip_at_batch_4(hd_program, record_property):
+    """Without the volume (5.56 GB a pair at this shape: a batch of 4 could
+    not exist) the batch-4 program asks 6.18 GiB of temporaries + 0.21 of
+    arguments: under the 13 GiB over which ISSUE 32 would have cut the
+    cell's batch to 2, and over the quarter of the chip a cell must fill."""
+    record_property("temp_and_arguments_gib", round(hd_program.temp_and_arguments_gib, 3))
+    print(f"temp + arguments {hd_program.temp_and_arguments_gib:.3f} GiB")
+    assert 4.0 < hd_program.temp_and_arguments_gib < 13.0
+
+
+def test_hd_cell_forward_dispatches_two_banded_and_two_resident_levels(hd_program):
+    """Levels 0-1 (135x240, 67x120) exceed residency and take the banded
+    kernel, 2-3 stay resident; nothing falls back to XLA's gather form."""
+    assert hd_program.tiers == {
+        "kernel": 2, "banded": 2, "fallback": 0, "levels_total": 4,
+    }
+
+
+def test_hd_cell_forward_loop_holds_the_four_kernels_by_name(hd_program):
+    """The four Mosaic calls are in the refinement loop's body, each under
+    its own name, which is what a device trace and the cell's
+    `corr_kernel_ms_per_pair` find them by; none runs outside the loop."""
+    loop = "".join(_loop_computations(hd_program.text).values())
+    calls = re.findall(r"%(corr_\w+?_l\d)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", loop)
+    assert sorted(calls) == [
+        "corr_banded_l0", "corr_banded_l1", "corr_resident_l2", "corr_resident_l3",
+    ]
+    assert hd_program.text.count("tpu_custom_call") == 4
+
+
+def test_hd_cell_forward_pads_the_pyramid_once_not_in_the_loop(hd_program):
+    """`prepare_lookup` (PR 32): the pooled levels are zero-padded for their
+    kernels before the loop; the parent's program padded all four in every
+    iteration (the compiler does not hoist a pad that grows its operand).
+    The padded shapes: level 0 banded 179x280, level 1 banded 123x160,
+    level 2 resident 55x96, level 3 resident 38x72."""
+    padded = re.compile(r" = f32\[4,(?:179,280|123,160|55,96|38,72),256\]\S* pad\(")
+    loop = "".join(_loop_computations(hd_program.text).values())
+    assert len(padded.findall(hd_program.text)) == 4
+    assert padded.findall(loop) == []
+
+
+def test_hd_cell_forward_loop_gathers_rows_of_the_band_sort_only(hd_program):
+    """ISSUE 32 asked for no gather in the loop; the banded tier has twelve,
+    by design: it sorts a level's queries by band and gathers whole ROWS of
+    the query-major operands into that order (features 256 wide, origins,
+    fractions, band ids, the chunk table's band column) and the kernel's
+    output back. What the property holds: every gather of the loop is such a
+    row permutation over the 32,400 queries (32,512 with the last query
+    block filled) or the ~260-chunk table, i.e.
+    none reads a feature LEVEL, which is what a level fallen back to XLA's
+    `grid_sample` form would do (ROADMAP M4)."""
+    loop = "".join(_loop_computations(hd_program.text).values())
+    gathers = re.findall(r" = (\w+\[[\d,]*\])\S* gather\(", loop)
+    assert len(gathers) == 12
+    for shape in gathers:
+        dims = _dims(shape)
+        assert dims[0] == 4 and (dims[1] in (32400, 32512) or (len(dims) == 2 and dims[1] < 300)), shape
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
