@@ -13,7 +13,7 @@ throughput approaches S× without growing the batch — and segment
 boundaries are the natural early-exit points ROADMAP item 5 needs.
 
 **The tick.** Pipeline state is the models' segment carry
-(models/raft.py ``encode``: net, coords1, inp, fmap1, fmap2[, up_mask])
+(models/raft.py ``encode``: net, coords1, inp, fmap1, fmap2)
 stacked along a leading STAGE axis of size S, sharded ``P("pipe")`` so
 stage s's micro-batch lives on device group s. One tick of the
 schedule is ONE compiled SPMD program:

@@ -385,8 +385,11 @@ class RAFT:
             raise ValueError(f"unknown corr_impl: {cfg.corr_impl!r}")
         return corr_fn
 
-    def _upsample(self, run, flow_lr, net, up_mask, bn_train=False):
-        """Low-res flow -> full-res prediction, per variant."""
+    def _upsample(self, run, flow_lr, net, bn_train=False):
+        """Low-res flow -> full-res prediction, per variant. The convex
+        mask is a function of ``net`` alone and is computed here, so it
+        runs wherever a prediction is upsampled: once after the loop in
+        test mode, in every iteration of the training forward."""
         cfg = self.cfg
         policy = self.policy
         if cfg.variant == "raft_nc_dbl":
@@ -403,6 +406,8 @@ class RAFT:
                 "upsampler", self.upsampler, flow2, guidance, train=bn_train
             )
             return 8.0 * hr
+        with jax.named_scope("raft.mask_head"):
+            up_mask = run("update_block", self.update_block, net, method="mask")
         if up_mask is None:
             return upflow(flow_lr, 8, align_corners=cfg.align_corners)
         return convex_upsample(
@@ -418,18 +423,21 @@ class RAFT:
 
     def _make_step(
         self, run, corr_fn, coords0, gru_ctx, bstats, *, test_mode,
-        carry_mask, bn_train, early_exit_tol=None,
+        bn_train, early_exit_tol=None,
     ):
         """One refinement iteration on the ``(net, coords1, stats)``
         carry — the single step body every scan (monolithic or segment)
         runs, so segmented execution can never drift from ``apply``.
         ``gru_ctx``: :meth:`_gru_context`'s, constants of the loop.
+        In test mode the body is lookup, GRU and flow head and nothing
+        else: no prediction is upsampled inside the loop, so the convex
+        mask head does not run there and no mask rides the carry.
 
         ``early_exit_tol`` (test mode only; docs/PERF.md "Early exit"):
         per-sample convergence detection on the GRU's own flow delta.
         The carry's ``stats['converged']`` (B,) bool marks lanes whose
         mean |delta| fell below the tolerance on an EARLIER iteration;
-        those lanes' ``(net, coords1, up_mask)`` are frozen via
+        those lanes' ``(net, coords1)`` are frozen via
         ``jnp.where`` — a select, so a lane converged at iteration k is
         BITWISE the state it had after k (the same select contract as
         the streaming warm start). The mask is sticky and the freeze
@@ -456,7 +464,7 @@ class RAFT:
                 corr = corr_fn(coords1)
             flow = coords1 - coords0
             with jax.named_scope("raft.update_block"):
-                net, up_mask, delta = run(
+                net, delta = run(
                     "update_block",
                     self.update_block,
                     net,
@@ -477,8 +485,6 @@ class RAFT:
                 keep = frozen[:, None, None, None]
                 net = jnp.where(keep, net_in, net)
                 coords1 = jnp.where(keep, coords1_in, coords1)
-                if carry_mask:
-                    up_mask = jnp.where(keep, stats["up_mask"], up_mask)
                 # Detection norm: mean |delta| per sample, in the pinned
                 # coord dtype and in LOW-RES pixels (the 8x upsampling
                 # scales displacements, so tol=t low-res px bounds the
@@ -493,13 +499,11 @@ class RAFT:
             else:
                 with jax.named_scope("raft.upsample"):
                     out = self._upsample(
-                        run, coords1 - coords0, net, up_mask, bn_train
+                        run, coords1 - coords0, net, bn_train
                     )
             new_stats = dict(stats)
             if "upsampler" in stats:
                 new_stats["upsampler"] = bstats["upsampler"]
-            if carry_mask:
-                new_stats["up_mask"] = up_mask
             if converged is not None:
                 new_stats["converged"] = converged
                 if "exec_iters" in stats:
@@ -513,13 +517,6 @@ class RAFT:
             return (net, coords1, new_stats), out
 
         return step
-
-    @property
-    def _has_mask(self) -> bool:
-        # The raft (non-small) variant's convex upsampling needs the final
-        # iteration's mask; in test mode the mask rides the scan carry so
-        # upsampling runs once after the loop instead of every iteration.
-        return self.cfg.variant == "raft" and not self.cfg.small
 
     # ----------------------------------------------------------------- apply
 
@@ -550,7 +547,10 @@ class RAFT:
         Returns (train mode) the stacked per-iteration high-res flow
         predictions (iters, B, H, W, 2); (test_mode) the tuple
         ``(flow_lowres, flow_up)``. With ``mutable=True`` additionally
-        returns the updated batch_stats as a second element.
+        returns the updated batch_stats as a second element. In test mode
+        the prediction is upsampled once, after the loop, from the state
+        the loop leaves (convex mask head or NCUP): with ``iters=0`` that
+        is the initial ``net`` and the initial flow.
 
         ``metric_head`` (test mode only): a traceable callable applied to
         the final high-res flow INSIDE this program; the second result
@@ -617,20 +617,14 @@ class RAFT:
         B, H, W, _ = image1.shape
         coords0 = coords_grid(B, H // 8, W // 8)
 
-        carry_mask = self._has_mask and test_mode
         step = self._make_step(
             run, corr_fn, coords0, self._gru_context(run, inp), bstats,
-            test_mode=test_mode, carry_mask=carry_mask, bn_train=bn_train,
-            early_exit_tol=early_exit_tol,
+            test_mode=test_mode, bn_train=bn_train, early_exit_tol=early_exit_tol,
         )
 
         init_stats: dict = {}
         if bn_train and "upsampler" in bstats:
             init_stats["upsampler"] = bstats["upsampler"]
-        if carry_mask:
-            init_stats["up_mask"] = jnp.zeros(
-                (B, H // 8, W // 8, 9 * 64), net.dtype
-            )
         if early_exit_tol is not None:
             init_stats["converged"] = jnp.zeros((B,), jnp.bool_)
             init_stats["exec_iters"] = jnp.zeros((B,), jnp.int32)
@@ -671,8 +665,7 @@ class RAFT:
         if test_mode:
             with jax.named_scope("raft.upsample"):
                 flow_up = self._upsample(
-                    run, coords1 - coords0, net, final_stats.get("up_mask"),
-                    bn_train,
+                    run, coords1 - coords0, net, bn_train
                 ).astype(policy.output_jnp)  # serving/metrics contract: f32
             if metric_head is not None:
                 with jax.named_scope("raft.metric_head"):
@@ -711,8 +704,8 @@ class RAFT:
         refinement iteration, returned as a SEGMENT CARRY dict —
 
         - ``net`` / ``coords1``: the live recurrent state a refinement
-          iteration mutates (plus ``up_mask`` for the raft non-small
-          variant, whose final-iteration mask the upsampler needs);
+          iteration mutates (all of it: the convex mask is computed by
+          ``finalize`` from the last ``net``);
         - ``inp`` / ``fmap1`` / ``fmap2``: the micro-batch's immutable
           context, which must TRAVEL WITH the state between pipeline
           stages (stage s+1 refining this micro-batch needs its feature
@@ -740,11 +733,8 @@ class RAFT:
             "net": net, "coords1": coords1, "inp": inp,
             "fmap1": fmap1, "fmap2": fmap2,
         }
-        B = net.shape[0]
-        if self._has_mask:
-            _, h8, w8 = net.shape[:3]
-            carry["up_mask"] = jnp.zeros((B, h8, w8, 9 * 64), net.dtype)
         if early_exit:
+            B = net.shape[0]
             carry["converged"] = jnp.zeros((B,), jnp.bool_)
             carry["exec_iters"] = jnp.zeros((B,), jnp.int32)
         return carry
@@ -790,8 +780,7 @@ class RAFT:
         )
         B, h8, w8 = carry["net"].shape[:3]
         coords0 = coords_grid(B, h8, w8)
-        carry_mask = "up_mask" in carry
-        stats = {"up_mask": carry["up_mask"]} if carry_mask else {}
+        stats = {}
         if early_exit_tol is not None:
             if "converged" not in carry:
                 raise ValueError(
@@ -801,8 +790,7 @@ class RAFT:
             stats["converged"] = carry["converged"]
         step = self._make_step(
             run, corr_fn, coords0, self._gru_context(run, carry["inp"]), {},
-            test_mode=True, carry_mask=carry_mask, bn_train=False,
-            early_exit_tol=early_exit_tol,
+            test_mode=True, bn_train=False, early_exit_tol=early_exit_tol,
         )
         with jax.named_scope("raft.refinement"):
             (net, coords1, out_stats), _ = jax.lax.scan(
@@ -812,8 +800,6 @@ class RAFT:
         out = dict(carry)
         out["net"] = net
         out["coords1"] = coords1
-        if carry_mask:
-            out["up_mask"] = out_stats["up_mask"]
         if early_exit_tol is not None:
             out["converged"] = out_stats["converged"]
             # Segment-granularity billing (see docstring): lanes active
@@ -833,7 +819,9 @@ class RAFT:
     ):
         """Pipeline back half: upsample a finished segment carry to the
         test-mode result ``(flow_lr, flow_up)`` (plus ``net`` with
-        ``return_net`` — the streaming warm-start handoff)."""
+        ``return_net`` — the streaming warm-start handoff). The convex
+        mask head runs here, on the carry's ``net``, as after ``apply``'s
+        loop."""
         run = self._make_run(
             variables["params"], dict(variables.get("batch_stats", {})),
             False, rngs,
@@ -842,9 +830,9 @@ class RAFT:
         coords0 = coords_grid(B, h8, w8)
         flow_lr = carry["coords1"] - coords0
         with jax.named_scope("raft.upsample"):
-            flow_up = self._upsample(
-                run, flow_lr, carry["net"], carry.get("up_mask")
-            ).astype(self.policy.output_jnp)
+            flow_up = self._upsample(run, flow_lr, carry["net"]).astype(
+                self.policy.output_jnp
+            )
         if return_net:
             return flow_lr, flow_up, carry["net"]
         return flow_lr, flow_up

@@ -9,6 +9,13 @@ core/update.py:138-140).
 These run inside ``lax.scan`` over refinement iterations, so everything is
 shape-static. The GRU state is the scan carry.
 
+The mask head reads the hidden state alone and feeds nothing back into the
+recurrence, so it is a method of its own (``mask``) and not part of
+``step``: a caller that upsamples only the last iteration's flow (test
+mode) runs it once, after the loop, on the state the loop leaves; one that
+upsamples every iteration (the training loss) calls it after every
+``step`` (PERF.md section 6, PR 33).
+
 The GRU reads ``[h, inp, motion]``, and the context features ``inp`` are
 the same tensor in every iteration. What the gate convolutions make of them
 is therefore computed once per pair, before the loop (``context``), and the
@@ -140,19 +147,31 @@ class BasicMotionEncoder(nn.Module):
 
 class _UpdateBlock(nn.Module):
     """What the two update blocks share: ``context(inp)`` once per pair,
-    ``step(net, ctx, corr, flow)`` in every refinement iteration, and
-    ``__call__(net, inp, corr, flow)``, the two in a row (one iteration
-    on its own; ``init``)."""
+    ``step(net, ctx, corr, flow) -> (net, delta)`` in every refinement
+    iteration, ``mask(net)`` wherever a prediction is upsampled (``None``
+    without a mask head), and ``__call__(net, inp, corr, flow) -> (net,
+    mask, delta)``, the three in a row (one iteration on its own;
+    ``init``)."""
 
     def context(self, inp: jax.Array) -> dict:
         """The GRU gates' share of the context features (and the kernel
         rows of the rest): everything of the block that reads ``inp``."""
         return self.gru.context(inp)
 
+    def step(
+        self, net: jax.Array, ctx: dict, corr: jax.Array, flow: jax.Array
+    ) -> tuple[jax.Array, jax.Array]:
+        net = self.gru(net, ctx, self.encoder(flow, corr))
+        return net, self.flow_head(net)
+
+    def mask(self, net: jax.Array) -> Optional[jax.Array]:
+        return None
+
     def __call__(
         self, net: jax.Array, inp: jax.Array, corr: jax.Array, flow: jax.Array
     ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
-        return self.step(net, self.context(inp), corr, flow)
+        net, delta = self.step(net, self.context(inp), corr, flow)
+        return net, self.mask(net), delta
 
 
 class SmallUpdateBlock(_UpdateBlock):
@@ -170,12 +189,6 @@ class SmallUpdateBlock(_UpdateBlock):
             self.hidden_dim, 82 + self.context_dim, self.context_dim, dtype=self.dtype
         )
         self.flow_head = FlowHead(128, dtype=self.dtype)
-
-    def step(
-        self, net: jax.Array, ctx: dict, corr: jax.Array, flow: jax.Array
-    ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
-        net = self.gru(net, ctx, self.encoder(flow, corr))
-        return net, None, self.flow_head(net)
 
 
 class BasicUpdateBlock(_UpdateBlock):
@@ -202,14 +215,9 @@ class BasicUpdateBlock(_UpdateBlock):
             self.mask_conv1 = Conv2d(256, 3, dtype=self.dtype)
             self.mask_conv2 = Conv2d(64 * 9, 1, dtype=self.dtype)
 
-    def step(
-        self, net: jax.Array, ctx: dict, corr: jax.Array, flow: jax.Array
-    ) -> tuple[jax.Array, Optional[jax.Array], jax.Array]:
-        net = self.gru(net, ctx, self.encoder(flow, corr))
-        delta = self.flow_head(net)
-
-        mask = None
-        if self.use_mask_head:
-            # 0.25 scale to balance gradients (reference: core/update.py:140).
-            mask = 0.25 * self.mask_conv2(nn.relu(self.mask_conv1(net)))
-        return net, mask, delta
+    def mask(self, net: jax.Array) -> Optional[jax.Array]:
+        """The convex-upsampling weights of the hidden state ``net``."""
+        if not self.use_mask_head:
+            return None
+        # 0.25 scale to balance gradients (reference: core/update.py:140).
+        return 0.25 * self.mask_conv2(nn.relu(self.mask_conv1(net)))

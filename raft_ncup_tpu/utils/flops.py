@@ -86,6 +86,11 @@ def _gru_context_flops(h8: int, w8: int, context_dim: int, hidden_dim: int) -> f
     return 6 * (2.0 * 5 * context_dim * hidden_dim * h8 * w8)
 
 
+def _mask_head_flops(h8: int, w8: int, hidden_dim: int) -> float:
+    """``BasicUpdateBlock.mask``: 3x3 hidden -> 256, 1x1 256 -> 9 x 64."""
+    return _conv(3, hidden_dim, 256, h8, w8) + _conv(1, 256, 576, h8, w8)
+
+
 def _ncup_flops(cfg: ModelConfig, H: int, W: int, batch_mult: int) -> float:
     """One NCUP x4 upsampling pass: Simple weights-net at the x4 LR grid
     (H/4) + NConvUNet at full res with channels_to_batch (reference:
@@ -135,8 +140,9 @@ def forward_flops(
     if cfg.variant == "raft_nc_dbl":
         f += iters * _ncup_flops(cfg, H, W, batch_mult=2)
     else:
-        # convex-mask head (reference: core/update.py:123-126) + unfold blend
-        f += iters * (_conv(3, 128, 256, h8, w8) + _conv(1, 256, 576, h8, w8))
+        # convex-mask head (reference: core/update.py:123-126), once, on
+        # the state the loop leaves (models/raft.py::_upsample, PR 33)
+        f += _mask_head_flops(h8, w8, cfg.hidden_dim)
     return batch * f
 
 

@@ -45,6 +45,14 @@ LOOKUP, UPDATE, LOOP, FNET = (
      "/conv_general_dilated", "raft.gru_context"),
     ("jit(step)/train.forward_backward/transpose(jvp(raft.gru_context))/BasicUpdateBlock.context"
      "/gru.context/convz1.context/convz1._conv/conv_general_dilated", "raft.gru_context.bwd"),
+    # the convex-mask head (PR 33): its own scope inside raft.upsample, after
+    # the loop in test mode, in the loop body of the training forward
+    ("jit(fn)/raft.upsample/raft.mask_head/BasicUpdateBlock.mask/mask_conv2/conv_general_dilated",
+     "raft.mask_head"),
+    ("jit(step)/train.forward_backward/transpose(jvp(raft.refinement))/while/body/closed_call/checkpoint"
+     "/rematted_computation/raft.upsample/raft.mask_head/BasicUpdateBlock.mask/mask_conv1"
+     "/conv_general_dilated", "raft.mask_head.remat"),
+    ("jit(fn)/raft.upsample/reduce_sum", "raft.upsample"),
     # StreamEngine's slot-table step (PR 30): stream.* around the forward's raft.*
     ("jit(step)/stream.warmstart_splat/argmin", "stream.warmstart_splat"),
     ("jit(step)/stream.slot_gather/gather", "stream.slot_gather"),

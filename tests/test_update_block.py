@@ -318,13 +318,16 @@ def _traced_conv_flops(fn, *args):
     return count(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
-@pytest.mark.parametrize("part", ["context_once_per_pair", "step_per_iteration"])
+@pytest.mark.parametrize(
+    "part", ["context_once_per_pair", "step_per_iteration", "mask_once_after_the_loop"]
+)
 def test_flops_count_follows_the_traced_update_block(part):
     """``utils/flops.py`` counts what the program runs: the six context
-    convolutions once per pair (6 x 2 x 5 x 128 x 128 a pixel), and an
-    iteration whose gates contract ``hidden + motion`` = 256 channels."""
+    convolutions once per pair (6 x 2 x 5 x 128 x 128 a pixel), an
+    iteration whose gates contract ``hidden + motion`` = 256 channels and
+    holds no mask head, and the `raft` variant's mask head once a pair."""
     h8, w8 = 5, 6
-    block = BasicUpdateBlock(CP, 128, 128, use_mask_head=False)
+    block = BasicUpdateBlock(CP, 128, 128, use_mask_head=part == "mask_once_after_the_loop")
     net, inp = jnp.zeros((1, h8, w8, 128)), jnp.zeros((1, h8, w8, 128))
     corr, flow = jnp.zeros((1, h8, w8, CP)), jnp.zeros((1, h8, w8, 2))
     variables = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), net, inp, corr, flow))
@@ -332,6 +335,14 @@ def test_flops_count_follows_the_traced_update_block(part):
         traced = _traced_conv_flops(lambda v: block.apply(v, inp, method="context"), variables)
         assert traced == flops._gru_context_flops(h8, w8, 128, 128)
         assert traced == 6 * 2.0 * 5 * 128 * 128 * h8 * w8
+    elif part == "mask_once_after_the_loop":
+        traced = _traced_conv_flops(lambda v: block.apply(v, net, method="mask"), variables)
+        assert traced == flops._mask_head_flops(h8, w8, 128)
+        raft = ModelConfig(variant="raft", dataset="sintel")
+        assert (
+            flops.forward_flops(raft, 1, 64, 96, 32) - flops.forward_flops(raft, 1, 64, 96, 12)
+            == 20 * flops._update_block_flops(8, 12, raft.corr_planes)
+        )
     else:
         ctx = jax.eval_shape(lambda v: block.apply(v, inp, method="context"), variables)
         traced = _traced_conv_flops(
