@@ -66,44 +66,12 @@ def emit(record: dict) -> None:
     print(json.dumps(record), flush=True)
 
 
-class CompileMeter:
-    """XLA compiles, their wall seconds and persistent-cache hits/misses,
-    from jax.monitoring — the same event analysis/guards.py counts."""
-
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == self._COMPILE:
-            self.compiles += 1
-            self.compile_s += duration
-
-    def _on_event(self, event, **_):
-        if event == self._HIT:
-            self.hits += 1
-        elif event == self._MISS:
-            self.misses += 1
-
-    def snapshot(self) -> tuple:
-        return (self.compiles, self.compile_s, self.hits, self.misses)
-
-
 @contextlib.contextmanager
-def phase(name: str, meter: CompileMeter, device=None):
+def phase(name: str, meter, device=None):
     """Time one phase and print its JSON line when it ends without an
     exception. Yields the dict of facts the body fills; with ``device``
-    its peak memory so far is added."""
+    its peak memory so far is added. ``meter``: the program's compile
+    listener (``utils/profiling.compile_meter()``)."""
     facts: dict = {}
     t0 = time.monotonic()
     c0, s0, h0, m0 = meter.snapshot()
@@ -722,9 +690,10 @@ def main(argv=None) -> int:
 
     import jax
 
+    from raft_ncup_tpu.utils.profiling import compile_meter
     from raft_ncup_tpu.utils.runtime import enable_compilation_cache
 
-    meter = CompileMeter()
+    meter = compile_meter()
     devices = jax.devices()
     dev0 = devices[0]
     with phase("device", meter) as facts:

@@ -79,6 +79,12 @@ def main(argv=None) -> None:
 
     with trace(args.trace_dir):
         _evaluate(args, model, variables, data_cfg, mesh)
+    # evaluate.py has no warm-up of its own: each dataset's shapes build
+    # their executables as the first pass meets them, so the start-up
+    # line is printed once everything has been built.
+    from raft_ncup_tpu.observability import startup_line
+
+    print(startup_line(), file=sys.stderr)
 
 
 def _evaluate(args, model, variables, data_cfg, mesh) -> None:

@@ -36,7 +36,13 @@ transfer, waited for until the batch is resident) and ``input_wait``
 (consumer thread: how long ``next()`` waited for a staged batch — the
 only one of the three on the critical path).
 The wait that ends in exhaustion records nothing, so each count is the
-count of batches.
+count of batches. Once per prefetcher, ``input_start`` (with the caller's
+``span_attrs``): from construction to the first batch resident on the
+device and queued (worker spawn, the first ``input_stage`` and
+``input_h2d``); it starts on the constructing thread and ends on the
+worker, so its profiler annotation begins where the worker does. The
+process's first is banked as the start-up record's ``input_start_s``
+(``observability/startup.py``); an empty iterator records none.
 
 Transfer policy lives in :func:`raft_ncup_tpu.parallel.multihost.
 device_put_batch`: ``jax.device_put`` against the batch sharding on the
@@ -52,7 +58,11 @@ from typing import Any, Iterable, Iterator, Mapping, Optional
 
 import jax
 
-from raft_ncup_tpu.observability import get_telemetry
+from raft_ncup_tpu.observability import (
+    StartupPhase,
+    get_startup_record,
+    get_telemetry,
+)
 from raft_ncup_tpu.utils.profiling import annotate_spans
 
 # Queue sentinel: the wrapped iterator was exhausted (finite iterators —
@@ -108,6 +118,7 @@ class DevicePrefetcher:
         self._drop_keys = frozenset(drop_keys or ())
         self._stop = threading.Event()
         self._q: queue.Queue = queue.Queue(maxsize=depth)
+        self._start = StartupPhase(self._tel, "input_start", **self._span_attrs)
         self._thread = threading.Thread(
             target=self._worker, name="device-prefetch", daemon=True
         )
@@ -132,28 +143,34 @@ class DevicePrefetcher:
                 continue
         return False
 
+    def _stage(self, index: int):
+        """One batch from the wrapped iterator to the queue; False where
+        the iterator is exhausted or the prefetcher closed."""
+        attrs = {"batch": index, **self._span_attrs}
+        with self._tel.span("input_stage", **attrs) as span:
+            try:
+                batch = next(self._it)
+            except StopIteration:
+                span.discard()
+                self._put(_END)
+                return False
+        with self._tel.span("input_h2d", **attrs):
+            # device_put only enqueues the copy (2 ms for 115 MB on
+            # a v5e, PERF.md PR 24). Waiting for it here, off the
+            # critical path, makes the span the copy's own time and
+            # hands the consumer a batch that is really resident.
+            device_batch = jax.block_until_ready(self._transfer(batch))
+        return self._put(device_batch)
+
     def _worker(self) -> None:
         try:
-            index = 0
-            while not self._stop.is_set():
-                attrs = {"batch": index, **self._span_attrs}
-                with self._tel.span("input_stage", **attrs) as span:
-                    try:
-                        batch = next(self._it)
-                    except StopIteration:
-                        span.discard()
-                        self._put(_END)
-                        return
-                with self._tel.span("input_h2d", **attrs):
-                    # device_put only enqueues the copy (2 ms for 115 MB on
-                    # a v5e, PERF.md PR 24). Waiting for it here, off the
-                    # critical path, makes the span the copy's own time and
-                    # hands the consumer a batch that is really resident.
-                    device_batch = jax.block_until_ready(
-                        self._transfer(batch)
-                    )
-                if not self._put(device_batch):
+            with self._start as start:
+                if self._stop.is_set() or not self._stage(0):
+                    start.discard()
                     return
+            get_startup_record().phase("input_start_s", start.seconds)
+            index = 1
+            while not self._stop.is_set() and self._stage(index):
                 index += 1
         except BaseException as e:  # noqa: BLE001 — surfaced to consumer
             self._put(e)

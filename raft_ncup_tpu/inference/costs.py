@@ -16,7 +16,15 @@ applied to cost accounting): a process-wide :class:`CostLedger` that
 Per warmed executable the ledger holds:
 
 - ``flops`` / ``bytes_accessed`` from ``Compiled.cost_analysis()``,
-- ``compile_ms`` (wall time of ``lower().compile()``),
+- ``compile_ms`` (wall time of ``lower().compile()``): since the
+  start-up timeline (docs/OBSERVABILITY.md) the SUM of its two phases,
+  ``trace_lower_ms`` (Python trace + lowering, all host) and
+  ``backend_compile_ms`` (the backend's compile or the persistent
+  cache's load), which sit beside it with the cache's verdict ``cache``
+  (``hit`` / ``miss`` / ``off``), ``programs`` (compile events in the
+  second phase), ``probe_ms`` (what this ledger's own analysis of the
+  executable took) and, after the executable's first call,
+  ``first_run_ms`` (:func:`build_and_record`, :func:`first_run`),
 - ``memory_stats`` from ``Compiled.memory_analysis()``
   (argument/output/temp/generated-code bytes — the
   ``compiled_memory_stats`` surface),
@@ -62,8 +70,13 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from typing import Dict, Optional
 
+from raft_ncup_tpu.observability.startup import (
+    StartupPhase,
+    get_startup_record,
+)
 from raft_ncup_tpu.utils.flops import TPU_PEAK_FLOPS
 from raft_ncup_tpu.utils.knobs import knob_enabled, knob_raw
 
@@ -188,17 +201,31 @@ class CostLedger:
         self._lock = threading.Lock()
 
     def record_compiled(
-        self, key: str, compiled, *, compile_ms: Optional[float] = None,
-        backend: Optional[str] = None, **meta,
+        self, key: str, compiled, *, backend: Optional[str] = None,
+        phases: Optional[dict] = None, **meta,
     ) -> Optional[dict]:
+        """``phases`` (``utils/profiling.timed_build``): the build's two
+        start-up phases; ``compile_ms`` is their sum, unrounded, so that
+        the three agree to the last digit (``None`` for an executable
+        banked without its build)."""
         if not self.enabled:
             return None
+        t0 = time.perf_counter()
         entry = probe_compiled(compiled)
+        entry["probe_ms"] = (time.perf_counter() - t0) * 1e3
         entry["key"] = str(key)
         entry["backend"] = backend
-        entry["compile_ms"] = (
-            None if compile_ms is None else round(float(compile_ms), 1)
-        )
+        if phases is not None:
+            entry["trace_lower_ms"] = phases["trace_lower_s"] * 1e3
+            entry["backend_compile_ms"] = phases["compile_s"] * 1e3
+            entry["cache"] = phases["cache"]
+            entry["programs"] = phases["programs"]
+            entry["first_run_ms"] = None
+            entry["compile_ms"] = (
+                entry["trace_lower_ms"] + entry["backend_compile_ms"]
+            )
+        else:
+            entry["compile_ms"] = None
         entry["meta"] = {k: v for k, v in meta.items() if v is not None}
         # Pipelined executables (meta carries segments > 1, set by the
         # pipe_tick key parse in pipeline._ledger_meta): derive the
@@ -219,6 +246,13 @@ class CostLedger:
         with self._lock:
             self._entries[str(key)] = entry
         return entry
+
+    def record_first_run(self, key: str, ms: float) -> None:
+        """The first call of the executable banked under ``key``."""
+        with self._lock:
+            entry = self._entries.get(str(key))
+            if entry is not None:
+                entry["first_run_ms"] = float(ms)
 
     # ---------------------------------------------------------- consumers
 
@@ -287,6 +321,47 @@ class CostLedger:
     def reset(self) -> None:
         with self._lock:
             self._entries.clear()
+
+
+def build_and_record(
+    ledger: CostLedger, hub, jitfn, args: tuple, key: str, *,
+    backend: Optional[str], **meta,
+):
+    """The one way a chokepoint builds an executable
+    (``ShapeCachedForward._instrument``, ``training/loop._compile_step``):
+    ``lower`` and ``compile`` as their two start-up phases on ``hub``
+    (``utils/profiling.timed_build``), the executable's costs and phases
+    into ``ledger``, and the phases into the process's start-up record
+    with the compile listener's totals as they stand now
+    (``observability/startup.py``). Returns the executable."""
+    from raft_ncup_tpu.utils.profiling import compile_meter, timed_build
+
+    kind = str(meta.get("kind", "custom"))
+    compiled, phases = timed_build(hub, jitfn, args, key=key, kind=kind)
+    entry = ledger.record_compiled(
+        key, compiled, backend=backend, phases=phases, **meta
+    )
+    get_startup_record().program(
+        key, kind, trace_lower_s=phases["trace_lower_s"],
+        compile_s=phases["compile_s"], cache=phases["cache"],
+        probe_s=None if entry is None else entry["probe_ms"] / 1e3,
+        process=compile_meter().totals(),
+    )
+    return compiled
+
+
+def first_run(ledger: CostLedger, hub, compiled, args: tuple, key: str, kind: str):
+    """The first call of the executable :func:`build_and_record` banked
+    under ``key``, as the start-up phase ``startup_first_run`` on ``hub``,
+    banked beside the build's phases. It ends where the call returns (the
+    dispatch; nothing here waits for the device and no wait is added):
+    whoever called already waits for or hands on the result, which is
+    returned."""
+    with StartupPhase(hub, "startup_first_run", key=key, kind=kind) as phase:
+        out = compiled(*args)
+    ledger.record_first_run(key, phase.seconds * 1e3)
+    get_startup_record().first_run(key, phase.seconds)
+    return out
 
 
 _default_lock = threading.Lock()

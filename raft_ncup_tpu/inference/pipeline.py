@@ -43,7 +43,6 @@ from __future__ import annotations
 import queue
 import sys
 import threading
-import time
 from collections import OrderedDict, deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterable, Iterator, Optional
@@ -53,11 +52,15 @@ import jax.numpy as jnp
 
 from raft_ncup_tpu.data.device_prefetch import DevicePrefetcher
 from raft_ncup_tpu.inference import metrics as metrics_mod
-from raft_ncup_tpu.inference.costs import get_cost_ledger
+from raft_ncup_tpu.inference.costs import (
+    build_and_record,
+    first_run,
+    get_cost_ledger,
+)
 from raft_ncup_tpu.observability import NOOP_SPAN, get_telemetry
 from raft_ncup_tpu.observability.telemetry import LEGACY_KEY_ALIASES
 from raft_ncup_tpu.precision import resolve_policy
-from raft_ncup_tpu.utils.profiling import annotate_spans
+from raft_ncup_tpu.utils.profiling import annotate_spans, compile_meter
 
 _EXEC_CANON = LEGACY_KEY_ALIASES["inference"]
 
@@ -437,6 +440,10 @@ class ShapeCachedForward:
         # server or engine that handed the hub in) go on the profiler's
         # timeline too (utils/profiling.annotate_spans).
         annotate_spans(self._tel)
+        # The process's compile listener counts from here on (idempotent):
+        # the start-up record's process totals, and the cache verdict of
+        # every executable this cache builds (utils/profiling.timed_build).
+        compile_meter()
         # The executable cost ledger (inference/costs.py; docs/PERF.md):
         # every program this cache compiles is AOT-lowered so its XLA
         # cost analysis, compile wall time, and memory stats land in the
@@ -551,8 +558,11 @@ class ShapeCachedForward:
     def _instrument(self, full_key: tuple, raw_key: tuple, jitfn):
         """Wrap one freshly-built jitted program so its FIRST call
         AOT-compiles (``lower().compile()`` — still exactly one XLA
-        compile) and banks the executable's costs in the ledger; every
-        later call is one dict read then the compiled program. Plain
+        compile), banks the executable's costs in the ledger and runs as
+        the start-up phase ``startup_first_run`` (``costs.
+        build_and_record`` / ``first_run``; docs/OBSERVABILITY.md
+        "Start-up timeline");
+        every later call is one dict read then the compiled program. Plain
         callables (tests' stand-ins) and a disabled ledger pass through
         untouched."""
         if not self.costs.enabled or not hasattr(jitfn, "lower"):
@@ -571,6 +581,7 @@ class ShapeCachedForward:
             meta.update(corr_tuning_meta())
         box: dict = {}
         lock = threading.Lock()
+        tel = self._tel
 
         def warmed(*args):
             compiled = box.get("c")
@@ -579,13 +590,8 @@ class ShapeCachedForward:
                     compiled = box.get("c")
                     if compiled is None:
                         try:
-                            t0 = time.perf_counter()
-                            compiled = jitfn.lower(*args).compile()
-                            ledger.record_compiled(
-                                ledger_key, compiled,
-                                compile_ms=(
-                                    time.perf_counter() - t0
-                                ) * 1e3,
+                            compiled = build_and_record(
+                                ledger, tel, jitfn, args, ledger_key,
                                 backend=backend, **meta,
                             )
                         except Exception as e:  # pragma: no cover
@@ -596,8 +602,13 @@ class ShapeCachedForward:
                                 f"cost probe unavailable for "
                                 f"{ledger_key}: {e!r}", file=sys.stderr,
                             )
-                            compiled = jitfn
-                        box["c"] = compiled
+                            compiled = box["c"] = jitfn
+                        else:
+                            box["c"] = compiled
+                            return first_run(
+                                ledger, tel, compiled, args, ledger_key,
+                                str(meta.get("kind", "custom")),
+                            )
             return compiled(*args)
 
         # Inspection handle (inference/pipe_schedule.tick_text; bench's

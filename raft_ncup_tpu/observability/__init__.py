@@ -66,6 +66,14 @@ from raft_ncup_tpu.observability.spans import (
     new_span_id,
     new_trace_id,
 )
+from raft_ncup_tpu.observability.startup import (
+    StartupPhase,
+    StartupRecord,
+    get_startup_record,
+    set_startup_record,
+    startup_line,
+    startup_report,
+)
 from raft_ncup_tpu.observability.telemetry import (
     DEFAULT_BUCKETS_MS,
     LEGACY_KEY_ALIASES,
@@ -98,12 +106,15 @@ __all__ = [
     "SloSpec",
     "Span",
     "SpanTracer",
+    "StartupPhase",
+    "StartupRecord",
     "Telemetry",
     "TraceContext",
     "WARMING",
     "aggregate_registry",
     "collect_fleet_records",
     "fleet_traces",
+    "get_startup_record",
     "get_telemetry",
     "hop_attribution",
     "host_number",
@@ -116,7 +127,10 @@ __all__ = [
     "read_jsonl_tolerant",
     "render_trace",
     "serve_slos",
+    "set_startup_record",
     "set_telemetry",
+    "startup_line",
+    "startup_report",
     "stream_slos",
     "telemetry_report",
     "write_healthz",

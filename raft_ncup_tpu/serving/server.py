@@ -66,7 +66,12 @@ from raft_ncup_tpu.inference.pipeline import (
     ShapeCachedForward,
     env_earlyexit_tol,
 )
-from raft_ncup_tpu.observability import get_telemetry
+from raft_ncup_tpu.observability import (
+    StartupPhase,
+    get_startup_record,
+    get_telemetry,
+    startup_report,
+)
 from raft_ncup_tpu.ops.padding import InputPadder
 from raft_ncup_tpu.serving.admission import AdmissionQueue
 from raft_ncup_tpu.serving.budget import IterationBudgetController
@@ -580,20 +585,26 @@ class FlowServer:
         ph, pw = int(h) + t + b, int(w) + le + r
         before = self._fwd.stats["compiles"]
         warmed = []
-        for n in self.cfg.batch_sizes:
-            zeros = np.zeros((n, ph, pw, 3), np.float32)
-            for iters in self.cfg.iter_levels:
-                # Warm the exact program the dispatch path will run —
-                # with detection on, that is the early-exit executable
-                # (no request must ever pay its compile).
-                out = self._fwd.forward_device(
-                    zeros, zeros, iters,
-                    early_exit_tol=self._earlyexit_tol,
-                )
-                jax.block_until_ready(out)
-                warmed.append((ph, pw, n, iters))
+        # The whole warm-up as one start-up phase, parent of the
+        # per-executable phases it causes (docs/OBSERVABILITY.md
+        # "Start-up timeline").
+        with StartupPhase(self._tel, "startup_warmup") as phase:
+            for n in self.cfg.batch_sizes:
+                zeros = np.zeros((n, ph, pw, 3), np.float32)
+                for iters in self.cfg.iter_levels:
+                    # Warm the exact program the dispatch path will run —
+                    # with detection on, that is the early-exit executable
+                    # (no request must ever pay its compile).
+                    out = self._fwd.forward_device(
+                        zeros, zeros, iters,
+                        early_exit_tol=self._earlyexit_tol,
+                    )
+                    jax.block_until_ready(out)
+                    warmed.append((ph, pw, n, iters))
+            compiled = self._fwd.stats["compiles"] - before
+            phase.set(programs=compiled)
+        get_startup_record().phase("warmup_s", phase.seconds)
         self.warmed = warmed
-        compiled = self._fwd.stats["compiles"] - before
         self.health.ready(f"warmup compiled {compiled} programs")
         return compiled
 
@@ -670,6 +681,7 @@ class FlowServer:
             "mesh": self._fwd.mesh_fp,
             "stages": stages,
             "health": self.health.snapshot(),
+            "startup": startup_report(),
         }
 
     def __enter__(self) -> "FlowServer":

@@ -71,7 +71,12 @@ from raft_ncup_tpu.inference.pipeline import (
     DispatchThrottle,
     ShapeCachedForward,
 )
-from raft_ncup_tpu.observability import get_telemetry
+from raft_ncup_tpu.observability import (
+    StartupPhase,
+    get_startup_record,
+    get_telemetry,
+    startup_report,
+)
 from raft_ncup_tpu.observability.telemetry import LEGACY_KEY_ALIASES
 from raft_ncup_tpu.ops.padding import InputPadder
 from raft_ncup_tpu.serving.admission import AdmissionQueue
@@ -859,24 +864,32 @@ class StreamEngine:
             import jax.numpy as jnp
 
             scratch = self.cfg.capacity
-            for n in self.cfg.batch_sizes:
-                warmed.append((self._ph, self._pw, n, self.cfg.iters))
-                zeros = np.zeros(
-                    (n, self._ph, self._pw, 3), np.float32
-                )
-                step = self._step(n)
-                with self._table_lock:
-                    self._table, flow_up, bad = step(
-                        self._fwd.variables,
-                        self._table,
-                        jnp.asarray(zeros),
-                        jnp.asarray(zeros),
-                        jnp.asarray(
-                            np.full((n,), scratch, np.int32)
-                        ),
-                        jnp.asarray(np.ones((n,), np.float32)),
+            # The whole warm-up as one start-up phase, parent of the
+            # per-executable phases it causes (docs/OBSERVABILITY.md
+            # "Start-up timeline").
+            with StartupPhase(self._tel, "startup_warmup") as phase:
+                for n in self.cfg.batch_sizes:
+                    warmed.append((self._ph, self._pw, n, self.cfg.iters))
+                    zeros = np.zeros(
+                        (n, self._ph, self._pw, 3), np.float32
                     )
-                jax.block_until_ready((self._table, flow_up, bad))
+                    step = self._step(n)
+                    with self._table_lock:
+                        self._table, flow_up, bad = step(
+                            self._fwd.variables,
+                            self._table,
+                            jnp.asarray(zeros),
+                            jnp.asarray(zeros),
+                            jnp.asarray(
+                                np.full((n,), scratch, np.int32)
+                            ),
+                            jnp.asarray(np.ones((n,), np.float32)),
+                        )
+                    jax.block_until_ready((self._table, flow_up, bad))
+                phase.set(
+                    programs=self._fwd.stats["compiles"] - before
+                )
+            get_startup_record().phase("warmup_s", phase.seconds)
         finally:
             self._queue.set_paused(False)
         # The warmed (padded_h, padded_w, batch, iters) step set — the
@@ -974,6 +987,7 @@ class StreamEngine:
             "mesh": self._fwd.mesh_fp,
             "stages": stages,
             "health": self.health.snapshot(),
+            "startup": startup_report(),
         }
 
     def __enter__(self) -> "StreamEngine":

@@ -15,7 +15,11 @@ capture, on the profiler's host plane: ``train_dispatch`` (the jitted
 step's enqueue alone), ``train_throttle_wait`` (where the loop waits for
 the device), the prefetcher's ``input_wait`` / ``input_stage`` /
 ``input_h2d``, the logger's ``train_metrics_pull``; ``train_steps_total``
-and ``train_pairs_total`` grow where ``train_dispatch`` closes.
+and ``train_pairs_total`` grow where ``train_dispatch`` closes. Start-up
+phases ("Start-up timeline" there), each once per run: ``startup_weights``
+(:func:`open_train_run`), the step's ``startup_trace_lower`` and
+``startup_compile`` (:func:`_compile_step`), its ``startup_first_run``
+(:meth:`TrainRun.step`) and the prefetcher's ``input_start``.
 """
 
 from __future__ import annotations
@@ -50,9 +54,20 @@ class TrainRun:
         """One call of the compiled step: ``(state, metrics)``. ``state``
         is donated."""
         if self._compiled is None:
-            self._compiled = _compile_step(
+            self._compiled, fresh_key = _compile_step(
                 self.step_fn, self.train_cfg, state, batch, rng
             )
+            if fresh_key is not None:
+                # The first dispatch of a step this run compiled, as the
+                # start-up phase ``startup_first_run`` (the loop's
+                # ``train_throttle_wait`` is where the device is waited for).
+                from raft_ncup_tpu.inference.costs import first_run, get_cost_ledger
+                from raft_ncup_tpu.observability import get_telemetry
+
+                return first_run(
+                    get_cost_ledger(), get_telemetry(), self._compiled,
+                    (state, batch, rng), fresh_key, "train_step",
+                )
         return self._compiled(state, batch, rng)
 
     def close(self) -> None:
@@ -66,42 +81,44 @@ _COMPILED: dict = {}
 _COMPILED_MAX = 8
 
 
-def _compile_step(step_fn, cfg: TrainConfig, *args):
+def _compile_step(step_fn, cfg: TrainConfig, *args) -> tuple:
     """The first step AOT-compiles the jitted step (``lower().compile()``:
     still exactly one XLA compile) and banks the executable in the
     process's cost ledger, which gives a capture the step's operations by
     scope (``utils/profiling.trace`` writes the ledger's ``op_scopes``
-    beside it) and ``memory_analysis()`` to whoever asks. Where the probe
-    fails, or on a pod, the run goes through the jitted function itself."""
-    import time
-
-    from raft_ncup_tpu.inference.costs import get_cost_ledger
+    beside it), ``memory_analysis()`` to whoever asks, and the start-up
+    timeline the build's two phases (``costs.build_and_record``). Where
+    the probe fails, or on a pod, the run goes through the jitted function
+    itself. Returns ``(callable, ledger key)``; the key is ``None`` unless
+    the executable was built by this call."""
+    from raft_ncup_tpu.inference.costs import build_and_record, get_cost_ledger
+    from raft_ncup_tpu.observability import get_telemetry
 
     ledger = get_cost_ledger()
     if not ledger.enabled or jax.process_count() > 1:
-        return step_fn
+        return step_fn, None
     key = (step_fn, jax.tree.structure(args), tuple(
         (x.shape, str(x.dtype)) for x in jax.tree.leaves(args)
     ))
     if key in _COMPILED:
-        return _COMPILED[key]
+        return _COMPILED[key], None
+    ledger_key = (
+        f"{jax.default_backend()}|train_step|{cfg.stage}|{cfg.batch_size}"
+        f"x{cfg.image_size[0]}x{cfg.image_size[1]}|{cfg.iters}"
+    )
     try:
-        t0 = time.perf_counter()
-        compiled = step_fn.lower(*args).compile()
-        ledger.record_compiled(
-            f"{jax.default_backend()}|train_step|{cfg.stage}|{cfg.batch_size}"
-            f"x{cfg.image_size[0]}x{cfg.image_size[1]}|{cfg.iters}",
-            compiled, compile_ms=(time.perf_counter() - t0) * 1e3,
+        compiled = build_and_record(
+            ledger, get_telemetry(), step_fn, args, ledger_key,
             backend=jax.default_backend(), kind="train_step",
             shape=(cfg.batch_size, *cfg.image_size, 3), iters=cfg.iters,
         )
     except Exception as e:  # the probe must not be able to stop a run
         print(f"train step: cost probe unavailable ({e}); plain jit", flush=True)
-        return step_fn
+        return step_fn, None
     while len(_COMPILED) >= _COMPILED_MAX:
         _COMPILED.pop(next(iter(_COMPILED)))
     _COMPILED[key] = compiled
-    return compiled
+    return compiled, ledger_key
 
 
 def open_train_run(
@@ -127,18 +144,48 @@ def open_train_run(
     """
     from raft_ncup_tpu.data import DevicePrefetcher, FlowLoader, fetch_training_set
     from raft_ncup_tpu.inference.pipeline import DispatchThrottle
+    from raft_ncup_tpu.observability import (
+        StartupPhase,
+        get_startup_record,
+        get_telemetry,
+    )
     from raft_ncup_tpu.parallel.mesh import batch_sharding, replicated
     from raft_ncup_tpu.parallel.multihost import is_multihost
     from raft_ncup_tpu.parallel.step import make_train_step
     from raft_ncup_tpu.training.optim import build_schedule
     from raft_ncup_tpu.training.state import create_train_state
+    from raft_ncup_tpu.utils.profiling import annotate_spans, compile_meter
 
-    model, state = create_train_state(
-        jax.random.PRNGKey(train_cfg.seed), model_cfg, train_cfg,
-        variables=variables,
-    )
-    if restore is not None:
-        state = restore(state)
+    tel = get_telemetry()
+    annotate_spans(tel)
+    compile_meter()  # the process's compile listener counts from here on
+    # The train state from the caller's tree to the device, as one
+    # start-up phase: state build, optimizer init, restore, commit. It
+    # ends where the commit is enqueued and the restored step is read
+    # (the one leaf this function already waits for).
+    with StartupPhase(tel, "startup_weights") as weights:
+        model, state = create_train_state(
+            jax.random.PRNGKey(train_cfg.seed), model_cfg, train_cfg,
+            variables=variables,
+        )
+        if restore is not None:
+            state = restore(state)
+        if not is_multihost():
+            # Commit the state to where the step leaves its output. A fresh
+            # (uncommitted) state and the step's own committed output are two
+            # jit signatures, and the train program was compiled once for
+            # each: ~4 extra minutes at every start on the chip (first chip
+            # run, PR 21: 172 compiles, 2 x ~245 s in one trainer).
+            state = jax.device_put(
+                state,
+                replicated(mesh) if mesh is not None else jax.devices()[0],
+            )
+        step_i = int(state.step)
+        weights.set(bytes=sum(
+            getattr(x, "nbytes", 0) for x in jax.tree.leaves(state)
+        ))
+    get_startup_record().phase("weights_s", weights.seconds)
+    step_fn = make_train_step(model, train_cfg, mesh=mesh)
 
     if dataset is None:
         dataset = fetch_training_set(
@@ -161,18 +208,6 @@ def open_train_run(
         io_retries=data_cfg.io_retries,
         io_retry_backoff_s=data_cfg.io_retry_backoff_s,
     )
-
-    step_fn = make_train_step(model, train_cfg, mesh=mesh)
-    if not is_multihost():
-        # Commit the state to where the step leaves its output. A fresh
-        # (uncommitted) state and the step's own committed output are two
-        # jit signatures, and the train program was compiled once for
-        # each: ~4 extra minutes at every start on the chip (first chip
-        # run, PR 21: 172 compiles, 2 x ~245 s in one trainer).
-        state = jax.device_put(
-            state,
-            replicated(mesh) if mesh is not None else jax.devices()[0],
-        )
     # Batch shardings feed the device prefetcher on every mesh run (not
     # just multihost): single-process device_put straight into the step's
     # input layout means jit dispatch never re-lays-out the batch.
@@ -182,7 +217,6 @@ def open_train_run(
     # is deterministic per (seed, epoch, index), so the (epoch, batch)
     # position is derived from the restored step and the intra-epoch
     # batches already consumed are skipped without loading.
-    step_i = int(state.step)
     per_epoch = max(len(loader), 1)
     batches = loader.batches(
         start_epoch=step_i // per_epoch, start_batch=step_i % per_epoch

@@ -23,6 +23,12 @@ Here, one span system on the profiler's clock:
   tested without a trace, and :func:`read_device_trace` is the only
   part that touches ``jax.profiler.ProfileData``.
   ``scripts/device_trace_report.py`` prints it.
+- :class:`CompileMeter` listens to ``jax.monitoring``'s compile and
+  persistent-cache events; :func:`compile_meter` is the process's one
+  instance, installed by ``ShapeCachedForward`` and ``open_train_run``,
+  and :func:`timed_build` splits a program's build into its
+  ``startup_trace_lower`` and ``startup_compile`` phases with the cache's
+  verdict (docs/OBSERVABILITY.md "Start-up timeline").
 """
 
 from __future__ import annotations
@@ -34,9 +40,12 @@ import heapq
 import json
 import os
 import re
+import threading
 from typing import Iterable, Iterator, Optional
 
 import jax
+
+from raft_ncup_tpu.observability.startup import StartupPhase
 
 # The stat that marks a host event as one of the program's spans (and not
 # one of the runtime's own TraceMe events, which share the host plane).
@@ -59,6 +68,93 @@ def annotate_spans(telemetry) -> None:
     """Put ``telemetry``'s spans on the profiler's timeline (idempotent;
     a disabled hub hands out no span and so enters no annotation)."""
     telemetry.tracer.annotate = _annotation
+
+
+class CompileMeter:
+    """XLA compiles, their wall seconds and persistent-cache hits/misses,
+    from jax.monitoring — the same event analysis/guards.py counts. A
+    program loaded from the persistent cache fires the duration event too
+    (with the seconds the load took), so ``compiles`` counts programs
+    compiled OR loaded. An instance counts from its construction on;
+    listeners cannot be taken off again, so the program shares one
+    (:func:`compile_meter`)."""
+
+    _COMPILE = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+    _MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.hits = 0
+        self.misses = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, duration, **_):
+        if event == self._COMPILE:
+            self.compiles += 1
+            self.compile_s += duration
+
+    def _on_event(self, event, **_):
+        if event == self._HIT:
+            self.hits += 1
+        elif event == self._MISS:
+            self.misses += 1
+
+    def snapshot(self) -> tuple:
+        return (self.compiles, self.compile_s, self.hits, self.misses)
+
+    def totals(self) -> dict:
+        """The counts under the start-up record's names
+        (``observability/startup.py``)."""
+        return {
+            "programs_loaded": self.compiles, "compile_s": self.compile_s,
+            "cache_hits": self.hits, "cache_misses": self.misses,
+        }
+
+
+_meter_lock = threading.Lock()
+_meter: Optional[CompileMeter] = None
+
+
+def compile_meter() -> CompileMeter:
+    """The process's one listener (idempotent): every compile-or-load
+    event of the process from its first call on, the benchmark's own
+    reference programs included where a benchmark shares the process."""
+    global _meter
+    with _meter_lock:
+        if _meter is None:
+            _meter = CompileMeter()
+        return _meter
+
+
+def timed_build(hub, jitfn, args: tuple, *, key: str, kind: str) -> tuple:
+    """``jitfn.lower(*args).compile()`` as its two start-up phases on
+    ``hub``: ``startup_trace_lower`` (Python trace to jaxpr + lowering to
+    StableHLO: all host, paid warm and cold) and ``startup_compile`` (the
+    backend's compile or the persistent cache's load), the second with
+    the cache's verdict from the monitoring events fired between its
+    ends: ``miss`` (an entry was written), ``hit`` (one was read), ``off``
+    (neither: no cache, as on the CPU backend). Returns ``(compiled,
+    phases)``; ``phases`` holds ``trace_lower_s``, ``compile_s``,
+    ``cache`` and ``programs`` (compile events between the ends: 1 unless
+    the backend compiles helpers)."""
+    meter = compile_meter()
+    with StartupPhase(hub, "startup_trace_lower", key=key, kind=kind) as lower:
+        lowered = jitfn.lower(*args)
+    with StartupPhase(hub, "startup_compile", key=key, kind=kind) as build:
+        c0, _, h0, m0 = meter.snapshot()
+        compiled = lowered.compile()
+        c1, _, h1, m1 = meter.snapshot()
+        cache = "miss" if m1 > m0 else "hit" if h1 > h0 else "off"
+        build.set(cache=cache, programs=c1 - c0)
+    return compiled, {
+        "trace_lower_s": lower.seconds, "compile_s": build.seconds,
+        "cache": cache, "programs": c1 - c0,
+    }
 
 
 @contextlib.contextmanager

@@ -13,6 +13,12 @@ left under ``--out`` with its ``op_scopes.json``, and the report of
 benchmark; the numbers are of a traced window and are never end-to-end
 metrics.
 
+``--from_setup`` starts the capture before the driver's set-up, so the
+report's idle gaps during set-up carry the start-up phases' labels
+(``startup_trace_lower``, ``startup_compile``, ``startup_first_run``,
+``startup_weights``, ``startup_warmup``, ``input_start``) on the device's
+clock; ``startup_report()`` is printed in the JSON either way.
+
 Usage (on the chip):
     python scripts/capture_cell_trace.py --workload eval_sintel_nc --out chiprun_out/cap_eval
 """
@@ -20,6 +26,7 @@ Usage (on the chip):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -34,6 +41,10 @@ def main(argv=None) -> int:
     parser.add_argument("--out", required=True, help="directory for the capture")
     parser.add_argument("--seed", type=int, default=3000000017)
     parser.add_argument("--seconds", type=float, default=6.0)
+    parser.add_argument(
+        "--from_setup", action="store_true",
+        help="capture the driver's set-up too, not the window alone",
+    )
     args = parser.parse_args(argv)
 
     from benchmark import harness, trace_reduce
@@ -42,16 +53,21 @@ def main(argv=None) -> int:
     cell = harness.Cell(ROOT, bench, args.workload, args.seed)
     harness.setup_jax(cell, require_tpu=True)
 
-    from raft_ncup_tpu.observability import get_telemetry
+    from raft_ncup_tpu.observability import get_telemetry, startup_report
     from raft_ncup_tpu.utils.profiling import device_trace_report, find_xplane, trace
 
-    state = cell.driver.setup(cell)
-    try:
-        get_telemetry().reset()  # the process hub's spans of the window alone
-        with trace(args.out):
+    with contextlib.ExitStack() as capture:
+        if args.from_setup:
+            capture.enter_context(trace(args.out))
+        state = cell.driver.setup(cell)
+        try:
+            if not args.from_setup:
+                get_telemetry().reset()  # the process hub's spans of the window alone
+                capture.enter_context(trace(args.out))
             window = cell.driver.run(state, args.seconds)
-    finally:
-        cell.driver.close(state)
+            capture.close()
+        finally:
+            cell.driver.close(state)
 
     report = device_trace_report(args.out)
     ops, spans, _ = trace_reduce.read_xplane(find_xplane(args.out))
@@ -64,6 +80,7 @@ def main(argv=None) -> int:
         "driver_report_stages": window.get("report", {}).get("stages"),
         "process_hub_stages": get_telemetry().tracer.stage_summary(),
         "process_hub_counters": get_telemetry().registry.snapshot()["counters"],
+        "startup": startup_report(),
         **report,
     }))
     return 0

@@ -251,6 +251,9 @@ def run_stream(args, model, variables) -> int:
         f"iters={stream_cfg.iters})",
         file=sys.stderr,
     )
+    from raft_ncup_tpu.observability import startup_line
+
+    print(startup_line(), file=sys.stderr)
     traffic = StreamTraffic(
         size_hw,
         args.n_streams,
@@ -396,6 +399,9 @@ def run_replica(args, model, variables) -> int:
         f"{args.replica_socket}",
         file=sys.stderr,
     )
+    from raft_ncup_tpu.observability import startup_line
+
+    print(startup_line(), file=sys.stderr)
 
     # The address string decides the socket family (UDS path vs
     # host:port) — the same string the FleetConfig argv carried, so a
@@ -689,6 +695,9 @@ def main(argv=None) -> int:
         f"iter_levels={serve_cfg.iter_levels})",
         file=sys.stderr,
     )
+    from raft_ncup_tpu.observability import startup_line
+
+    print(startup_line(), file=sys.stderr)
 
     traffic = SyntheticTraffic(
         size_hw,

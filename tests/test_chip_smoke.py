@@ -20,6 +20,7 @@ import pytest
 
 import chip_smoke as cs
 from raft_ncup_tpu.utils import runtime
+from raft_ncup_tpu.utils.profiling import compile_meter
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -87,7 +88,7 @@ def test_script_exits_nonzero_and_prints_no_result_off_tpu(tmp_path):
 
 
 def test_phase_context_prints_one_json_line(capsys):
-    with cs.phase("toy", cs.CompileMeter()) as facts:
+    with cs.phase("toy", compile_meter()) as facts:
         jax.jit(lambda x: x * 3 + 1)(jax.numpy.ones((3, 5)))
         facts["answer"] = 42
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
@@ -98,7 +99,7 @@ def test_phase_context_prints_one_json_line(capsys):
 
 def test_phase_prints_nothing_when_its_body_fails(capsys):
     with pytest.raises(cs.SmokeFailure):
-        with cs.phase("toy", cs.CompileMeter()):
+        with cs.phase("toy", compile_meter()):
             cs.check(False, "boom")
     assert capsys.readouterr().out == ""
 

@@ -277,6 +277,14 @@ def main(argv=None) -> int:
         """Chaos, profile end, sentinel, checkpoint and validation at the
         step boundary; True halts the loop (sentinel)."""
         nonlocal profiling, halted
+        if step_i == start_step + 1:
+            # Warm-up ends where the first step has been dispatched: the
+            # one line that says where the start-up went.
+            from raft_ncup_tpu.observability import startup_line
+
+            line = startup_line()
+            print(line, flush=True)
+            logger.write_text(line)
         if chaos.sigterm_after == step_i:
             # Chaos harness: a REAL signal through the real handler,
             # pinned to a step boundary so tests replay exactly.
