@@ -11,24 +11,36 @@ the volume never exists anywhere — the §2a(a) design from SURVEY.md:
   (K+1) x (K+1) integer-aligned patch of correlations.
 - That patch is `sum_c f1[q, c] * f2[iy : iy+K+1, ix : ix+K+1, c]` — a
   dynamic-start slice of the VMEM-resident fmap2 level followed by a
-  lane reduction on the VPU. No gather, and HBM traffic is fmap2 once
-  per query block instead of a volume pass. (Mosaic takes a dynamic
-  start on the sublane-tiled column dimension only when it is provably
-  tile-aligned, so the slice is the aligned window holding the patch,
-  rotated by the residue — :func:`_load_patch`.)
+  lane reduction. No gather, and HBM traffic is fmap2 once per query
+  block instead of a volume pass. (Mosaic takes a dynamic start on the
+  sublane-tiled column dimension only when it is provably tile-aligned,
+  so the slice is the aligned window holding the patch; the shift by
+  the residue is applied to the summed correlations, never to the
+  C-channel data — :func:`_group_windows`.)
 
-Kernel shape (round-3 redesign; the round-2 version looped one query at a
-time with scalar work per step — VERDICT.md weak #3): queries are
-processed in GROUPS of 8 so every vector op runs on (8, 128)-tiled
-operands:
+Kernel shape: queries are processed in GROUPS of 8, one body for both
+tiers (:func:`_group_windows`), **reduce first, align afterwards**:
 
 - Integer window origins are precomputed on the XLA side and shipped as
   an int32 array in SMEM (the Mosaic-idiomatic home for indices that
   drive dynamic slices); fractional offsets ride along in VMEM.
-- Per group, 8 dynamic-start patch loads fill a VMEM scratch
-  (8, K+1, K+1, C); the correlation reduce, the 2x2 bilinear blend, and
-  the output store are then single vectorized ops over the whole group
-  (sublane dim = 8 queries, lane dim = C/taps).
+- Per query, the aligned (K+1, ``_patch_cols``, C) window is read
+  straight from the slab, once: its columns past the fold width
+  (``_fold_cols``, two float32 tiles) take the place of the columns
+  before the residue (a select; a K+1-column patch never needs both),
+  each row is multiplied by the query's feature row and summed over
+  the lanes. The C-channel data is never rotated, sliced, stored or
+  reloaded: a dynamic rotate of the 60-register window costs more than
+  everything else in the body (PERF.md section 6, PR 36).
+- The (fold,) sums of window row y land in lane ``g * 16 + y`` of a
+  (fold, 128) array, columns on sublanes, and the residue shift is a
+  sublane rotate of that one small array. The 8 queries' arrays have
+  disjoint lanes and add up to one PACKED array of two registers, on
+  which the 2x2 bilinear blend is four multiplies by per-lane weights;
+  one transpose hands out the (G, K, K) windows for the store.
+- What bounds the body now is the lane reduction itself, 20 a query
+  (K+1 rows x 2 tiles) on the chip's three cross-lane units (PERF.md
+  section 6, PR 36).
 
 Zero-padding semantics (out-of-bounds taps contribute zero, matching
 ``grid_sample``) come from pre-padding each level with K+2 zeros per
@@ -40,17 +52,18 @@ next to the pipeline's block buffers. The budget is Mosaic's scoped
 VMEM limit (16 MiB by default, stated to the compiler as
 ``vmem_limit_bytes``; override with RAFT_NCUP_VMEM_BYTES) and every
 buffer is counted as Mosaic allocates it — last two dims padded to the
-(8, 128) tile, pipelined blocks double-buffered (:func:`_block_bytes`).
-The chip's compiler refused the first, unpadded count (20.2 MB asked
-of 16 MiB); tests/test_tpu_aot_compile.py keeps gate and compiler in
-agreement at the flagship's widths.
+(8, 128) tile, pipelined blocks double-buffered (:func:`_block_bytes`):
+the slab and the f1 / frac / out blocks, nothing else (the group body
+works in registers). The chip's compiler refused the first, unpadded
+count (20.2 MB asked of 16 MiB); tests/test_tpu_aot_compile.py keeps
+gate and compiler in agreement at the flagship's widths.
 
 Banded tier (round-15 redesign — the correlation memory wall,
 ROADMAP item 4): levels whose padded slab exceeds the resident budget
 no longer fall straight back to XLA. The level is split into horizontal
 BANDS of ``band_rows`` origin rows; each program touches only its
 band's slab plus a ``K+2``-row halo, sized by :func:`band_plan` so
-``band_slab + query blocks + scratch`` fits the same ``fits_vmem``
+``band_slab + query blocks`` fits the same ``fits_vmem``
 budget at the policy itemsize. Mechanics:
 
 - The zero-padded level stays in HBM (``memory_space=ANY``); one band
@@ -110,6 +123,9 @@ from raft_ncup_tpu.utils.runtime import VMEM_BYTES as _VMEM_BYTES
 
 _QUERY_BLOCK = 128
 _GROUP = 8  # queries per vectorized inner step (sublane tile)
+# Lanes a query's K+1 window rows take in the group's packed array
+# (_group_windows): the widest window a kernel tier takes.
+_QUERY_LANES = 128 // _GROUP
 
 # Scopes of the XLA work around the kernels (docs/OBSERVABILITY.md): the
 # model's ``raft.corr_lookup`` holds the kernels and the output's
@@ -202,17 +218,18 @@ def _block_bytes(
     """Bytes of VMEM both kernel tiers need beside their slab, counted
     as Mosaic allocates them: the last two dims of every buffer padded
     to the (8, 128) tile, pipelined blocks double-buffered. The f1 block
-    is at ``itemsize``; the frac block (Q, 2), the out block (Q, K, K)
-    and the patch scratch (G, K+1, K+1, C) are float32 — the out block's
-    (9, 9) tail pads to (16, 128), which is most of the total and why
-    the default query block is small."""
-    K, K1 = 2 * radius + 1, 2 * radius + 2
+    is at ``itemsize``; the frac block (Q, 2) and the out block
+    (Q, K, K) are float32 — the out block's (9, 9) tail pads to
+    (16, 128), which is most of the total and why the default query
+    block is small. Nothing else: the group body
+    (:func:`_group_windows`) keeps a query's window and the group's
+    packed correlations in registers and has no scratch buffer."""
+    K = 2 * radius + 1
     t8 = lambda n: -(-n // 8) * 8  # noqa: E731
     f1 = 2 * query_block * channels * itemsize
     frac = 2 * query_block * 128 * 4
     out = 2 * query_block * t8(K) * 128 * 4
-    scratch = _GROUP * K1 * t8(K1) * channels * 4
-    return f1 + frac + out + scratch
+    return f1 + frac + out
 
 
 def _level_vmem_bytes(
@@ -224,8 +241,8 @@ def _level_vmem_bytes(
     itemsize: int = 4,
 ) -> int:
     """Bytes of VMEM the kernel needs for one (h, w) level: the resident
-    padded fmap2 slab + double-buffered query blocks + the group scratch,
-    all at ``itemsize`` bytes per element (the precision policy's
+    padded fmap2 slab + double-buffered query blocks, all at
+    ``itemsize`` bytes per element (the precision policy's
     compute dtype — 2 under the bf16 presets, which is exactly the
     dispatch-threshold doubling ROADMAP item 3 wanted; the frac/out
     blocks stay f32 but are a few percent of the slab, so budgeting them
@@ -289,8 +306,8 @@ def _banded_vmem_bytes(
     ``band_rows`` origin rows per band: the single-buffered band slab
     (``band_rows + K + 2`` padded rows — the level itself stays in HBM
     and the slab is DMA'd, so no pipeline double buffer) + the same
-    double-buffered query blocks and group scratch as the resident
-    kernel, all at ``itemsize`` (the policy's corr dtype — bf16 halves
+    double-buffered query blocks as the resident kernel, all at
+    ``itemsize`` (the policy's corr dtype — bf16 halves
     every term, exactly the threshold doubling the resident tier
     already has; tests/test_precision.py pins the ratio for this budget
     too)."""
@@ -337,7 +354,7 @@ def band_plan(
             h, w, channels, radius, 0, query_block, itemsize
         )
         if fixed > budget:
-            return None  # blocks+scratch+halo alone blow the budget
+            return None  # blocks+halo alone blow the budget
         per_row = itemsize * channels * _alloc_width(
             w + 2 * (2 * radius + 3), radius, itemsize
         )
@@ -378,28 +395,92 @@ def _alloc_width(wp: int, radius: int, itemsize: int) -> int:
     return -(-(wp + _patch_cols(radius, itemsize) - (2 * radius + 2)) // a) * a
 
 
-def _load_patch(ref, iy, ix, radius: int):
-    """The (K+1, K+1, C) float32 patch of ``ref`` (rows, cols, C) at the
-    dynamic origin (iy, ix). Rows are a leading, untiled dimension and
-    take any dynamic start. Columns ride the sublane tile, where Mosaic
-    accepts a dynamic start only if it is provably tile-aligned (the
-    chip's compiler refused the direct ``pl.ds(ix, K+1)`` load: "cannot
-    statically prove that index in dimension 2 is a multiple of 8"). So
-    load the aligned window that contains the patch and rotate it left
-    by the residue."""
-    K1 = 2 * radius + 2
-    itemsize = jnp.dtype(ref.dtype).itemsize
+def _fold_cols(radius: int, itemsize: int) -> int:
+    """Columns the aligned window folds to before the reduce: the fewest
+    whole sublane tiles that hold K+1 columns. The K+1 patch columns are
+    consecutive, so they keep distinct positions modulo this width."""
     a = _sublane_tile(itemsize)
-    cols = _patch_cols(radius, itemsize)
-    ix0 = pl.multiple_of((ix // a) * a, a)
-    win = ref[pl.ds(iy, K1), pl.ds(ix0, cols), :].astype(jnp.float32)
-    win = pltpu.roll(win, (cols - (ix - ix0)) % cols, axis=1)
-    return win[:, :K1, :]
+    return -(-(2 * radius + 2) // a) * a
 
 
-def _lookup_kernel(
-    ibase_ref, f1_ref, frac_ref, f2_ref, out_ref, scratch_ref, *, radius
-):
+def _group_windows(slab_ref, ibase_ref, f1_ref, frac_ref, base, radius: int):
+    """The (G, K, K) float32 windows, natural (y, x) order, of the G
+    queries at rows ``base .. base + G`` of the block's refs, read from
+    ``slab_ref`` (rows, cols, C) at the origins ``ibase_ref`` holds: the
+    one body of both kernel tiers, so they agree bit for bit.
+
+    Reduce over the channels first, align afterwards (module
+    docstring, "Kernel shape"). Rows are a leading, untiled dimension
+    and take any dynamic start. Columns ride the sublane tile, where
+    Mosaic accepts a dynamic start only if it is provably tile-aligned
+    (the chip's compiler refused the direct ``pl.ds(ix, K+1)`` load), so
+    each query loads the aligned window that holds its patch,
+    ``_patch_cols`` wide, and the shift by the residue ``ix % tile`` is
+    applied to the correlations, not to the C-channel data: it acts on
+    the column axis and commutes with the sum over channels. Columns of
+    the window outside the patch are in-bounds slab data
+    (:func:`_alloc_width`): computed, and dropped by the final slice."""
+    K = 2 * radius + 1
+    K1 = K + 1
+    G = _GROUP
+    L = _QUERY_LANES
+    assert K1 <= L, (radius, L)  # _level_tiers sends wider windows to XLA
+    itemsize = jnp.dtype(slab_ref.dtype).itemsize
+    a = _sublane_tile(itemsize)
+    F = _fold_cols(radius, itemsize)
+    # One tile of columns lies past the fold (K+1 is even, so it is
+    # never 1 modulo the tile and _patch_cols rounds up past it).
+    assert _patch_cols(radius, itemsize) == F + a
+    f32 = jnp.float32
+
+    f1g = f1_ref[pl.ds(base, G), :].astype(f32)  # (G, C)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (F, 128), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (a, 1), 0)
+    packed = jnp.zeros((F, 128), f32)
+    for g in range(G):
+        ix = ibase_ref[base + g, 0]
+        iy = ibase_ref[base + g, 1]
+        ix0 = pl.multiple_of((ix // a) * a, a)
+        res = ix - ix0
+        f1q = f1g[g : g + 1, :]  # (1, C)
+        # Two dynamic addresses for the window, the fold and the tile
+        # past it; the rows are static offsets from them.
+        rows = slab_ref[pl.ds(iy, K1), pl.ds(ix0, F), :]  # (K1, F, C)
+        past = slab_ref[pl.ds(iy, K1), pl.ds(pl.multiple_of(ix0 + F, a), a), :]
+        # This query's correlations, [column mod F, lane g * L + y].
+        mine = jnp.zeros((F, 128), f32)
+        for y in range(K1):
+            row = rows[y].astype(f32)  # (F, C)
+            # Fold: a column before the residue is not the patch's; its
+            # place takes the column F further right, which may be.
+            head = jnp.where(sub >= res, row[:a], past[y].astype(f32))
+            row = head if F == a else jnp.concatenate([head, row[a:]], axis=0)
+            corr = jnp.sum(row * f1q, axis=1, keepdims=True)  # (F, 1)
+            mine = jnp.where(lane == g * L + y, corr, mine)
+        # Align: the patch's first column to sublane 0. The queries'
+        # lanes are disjoint, so the sum only merges them.
+        packed = packed + pltpu.roll(mine, (F - res) % F, axis=0)
+
+    # Per-lane blend weights: lane l belongs to query l // L.
+    fr = frac_ref[pl.ds(base, G), :]  # (G, 2)
+    ql = jax.lax.broadcasted_iota(jnp.int32, (G, 128), 1)
+    qs = jax.lax.broadcasted_iota(jnp.int32, (G, 128), 0)
+    own = (ql >= qs * L) & (ql < (qs + 1) * L)
+    fx = jnp.sum(jnp.where(own, fr[:, 0:1], 0.0), axis=0, keepdims=True)
+    fy = jnp.sum(jnp.where(own, fr[:, 1:2], 0.0), axis=0, keepdims=True)
+    nx = pltpu.roll(packed, F - 1, axis=0)  # column x + 1
+    ny = pltpu.roll(packed, 127, axis=1)  # row y + 1
+    nxy = pltpu.roll(nx, 127, axis=1)
+    win = (
+        (1 - fy) * (1 - fx) * packed
+        + (1 - fy) * fx * nx
+        + fy * (1 - fx) * ny
+        + fy * fx * nxy
+    )  # (F, 128): [x, g * L + y]
+    return win.T.reshape(G, L, F)[:, :K, :K]
+
+
+def _lookup_kernel(ibase_ref, f1_ref, frac_ref, f2_ref, out_ref, *, radius):
     """One (batch, query-block) program, vectorized over groups of _GROUP.
 
     ibase_ref:   (Q, 2) int32, SMEM — clamped window origins (x, y) in the
@@ -410,36 +491,18 @@ def _lookup_kernel(
     f2_ref:      (Hp, Wp, C) compute dtype — zero-padded fmap2 level
                  (bf16 under the bf16 policies: the resident slab is the
                  VMEM term, so narrow STORAGE is the dispatch-threshold
-                 win; the reduce below upcasts, so ACCUMULATION is f32).
+                 win; the reduce upcasts, so ACCUMULATION is f32).
     out_ref:     (Q, K, K) float32 — window values in natural (y, x) order;
                  the caller transposes to the reference's x-major tap order
                  (core/corr.py:31-37).
-    scratch_ref: (G, K+1, K+1, C) compute-dtype VMEM scratch.
     """
-    K = 2 * radius + 1
     G = _GROUP
 
     def body(i, _):
-        base = i * G
-        # G dynamic-start patch loads (the only per-query work), stashed
-        # at static group offsets.
-        for g in range(G):
-            ix = ibase_ref[base + g, 0]
-            iy = ibase_ref[base + g, 1]
-            scratch_ref[g] = _load_patch(f2_ref, iy, ix, radius)
-        patch = scratch_ref[...]  # (G, K+1, K+1, C) float32
-        f1g = f1_ref[pl.ds(base, G), :].astype(jnp.float32)  # (G, C)
-        corr = jnp.sum(patch * f1g[:, None, None, :], axis=-1)  # (G,K+1,K+1)
-        fr = frac_ref[pl.ds(base, G), :]  # (G, 2)
-        fx = fr[:, 0][:, None, None]
-        fy = fr[:, 1][:, None, None]
-        win = (
-            (1 - fy) * (1 - fx) * corr[:, :K, :K]
-            + (1 - fy) * fx * corr[:, :K, 1:]
-            + fy * (1 - fx) * corr[:, 1:, :K]
-            + fy * fx * corr[:, 1:, 1:]
+        base = pl.multiple_of(i * G, G)
+        out_ref[pl.ds(base, G)] = _group_windows(
+            f2_ref, ibase_ref, f1_ref, frac_ref, base, radius
         )
-        out_ref[pl.ds(base, G)] = win
         return 0
 
     jax.lax.fori_loop(0, out_ref.shape[0] // G, body, 0)
@@ -512,12 +575,10 @@ def _lookup_one_level(
         lambda b, i: (b, i, 0),
         **({} if interpret else {"memory_space": pltpu.SMEM}),
     )
-    K1 = K + 1
 
     out = pl.pallas_call(
         functools.partial(_lookup_kernel, radius=radius),
         grid=(B, n_blocks),
-        scratch_shapes=[pltpu.VMEM((_GROUP, K1, K1, C), jnp.float32)],
         in_specs=[
             ibase_spec,
             pl.BlockSpec((None, qblk, C), lambda b, i: (b, i, 0)),
@@ -552,7 +613,7 @@ def _x_major_taps(out: jax.Array) -> jax.Array:
 
 def _banded_lookup_kernel(
     tbl_ref, ibase_ref, f1_ref, frac_ref, f2_ref, out_ref,
-    slab_ref, scratch_ref, sem, *, radius, qblk, band_rows,
+    slab_ref, sem, *, radius, qblk, band_rows,
 ):
     """One (batch, chunk) program of the banded tier.
 
@@ -571,11 +632,8 @@ def _banded_lookup_kernel(
     slab_ref:    (band_rows + K + 2, Wp, C) VMEM scratch — the band
                  slab, DMA'd from HBM on a fresh-band chunk. Single
                  buffered: this is what the banded budget counts.
-    scratch_ref: (G, K+1, K+1, C) VMEM scratch (as the resident kernel).
     sem:         DMA completion semaphore.
     """
-    K = 2 * radius + 1
-    K1 = K + 1
     G = _GROUP
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -605,32 +663,19 @@ def _banded_lookup_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     def body(i, _):
-        gbase = i * G
+        gbase = pl.multiple_of(i * G, G)
         q0 = base_q + gbase
 
         @pl.when((q0 + G > lo) & (q0 < hi))
         def _group():
-            # Masked group: same vectorized math as the resident kernel,
-            # reading the band slab with band-local row origins; lanes
-            # outside [lo, hi) (a boundary group's neighbours from the
-            # adjacent band) are computed against this band's slab —
-            # memory-safe via the band-local clamp — and masked out of
-            # the accumulate, so the neighbouring chunk supplies them.
-            for g in range(G):
-                ix = ibase_ref[gbase + g, 0]
-                iy = ibase_ref[gbase + g, 1]
-                scratch_ref[g] = _load_patch(slab_ref, iy, ix, radius)
-            patch = scratch_ref[...]
-            f1g = f1_ref[pl.ds(gbase, G), :].astype(jnp.float32)
-            corr = jnp.sum(patch * f1g[:, None, None, :], axis=-1)
-            fr = frac_ref[pl.ds(gbase, G), :]
-            fx = fr[:, 0][:, None, None]
-            fy = fr[:, 1][:, None, None]
-            win = (
-                (1 - fy) * (1 - fx) * corr[:, :K, :K]
-                + (1 - fy) * fx * corr[:, :K, 1:]
-                + fy * (1 - fx) * corr[:, 1:, :K]
-                + fy * fx * corr[:, 1:, 1:]
+            # Masked group: the resident kernel's windows, read from the
+            # band slab at band-local row origins; lanes outside
+            # [lo, hi) (a boundary group's neighbours from the adjacent
+            # band) are computed against this band's slab (memory-safe
+            # via the band-local clamp) and masked out of the
+            # accumulate, so the neighbouring chunk supplies them.
+            win = _group_windows(
+                slab_ref, ibase_ref, f1_ref, frac_ref, gbase, radius
             )
             qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (G, 1, 1), 0)
             mask = (qpos >= lo) & (qpos < hi)
@@ -768,7 +813,6 @@ def _banded_lookup_one_level(
         ),
         scratch_shapes=[
             pltpu.VMEM((band_rows + halo, Wpa, C), fdt),
-            pltpu.VMEM((_GROUP, K1, K1, C), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
     )
@@ -808,9 +852,14 @@ def _level_tiers(
     both call it, so the padded levels made once per pair are the ones
     each iteration's kernels expect."""
     tiers = []
+    # The group body packs a query's K+1 window rows into its lanes
+    # (:func:`_group_windows`): a wider window is XLA's.
+    packs = 2 * radius + 2 <= _QUERY_LANES
     for lvl in range(num_levels):
         Hl, Wl = H >> lvl, W >> lvl  # avg_pool2 floors
-        if fits_vmem(Hl, Wl, C, radius, dtype=dtype):
+        if not packs:
+            tiers.append(("fallback", (Hl, Wl), None))
+        elif fits_vmem(Hl, Wl, C, radius, dtype=dtype):
             tiers.append(("kernel", (Hl, Wl), None))
         elif plan := band_plan(
             Hl, Wl, C, radius, dtype=dtype, query_block=qblk

@@ -1,6 +1,9 @@
 """Equivalence tests for the Pallas corr-lookup kernel (interpret mode on
 CPU; the same kernel compiles for TPU)."""
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -478,6 +481,20 @@ class TestBandPlanAndKnobs:
             np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
         )
 
+    def test_window_wider_than_a_querys_lanes_falls_back(self):
+        """The group body packs a query's K+1 window rows into 16 lanes:
+        radius 7 (K+1 = 16) is the widest a kernel tier takes, radius 8
+        goes to the XLA path at every level, whatever fits VMEM."""
+        from raft_ncup_tpu.ops import corr_pallas as cpk
+
+        f32 = jnp.dtype(jnp.float32)
+        assert {t for t, _, _ in cpk._level_tiers(16, 24, 16, 7, 2, f32, 128)} == {
+            "kernel"
+        }
+        assert [t for t, _, _ in cpk._level_tiers(16, 24, 16, 8, 2, f32, 128)] == [
+            "fallback", "fallback",
+        ]
+
     def test_dispatch_counts_mutation_is_locked(self):
         """The satellite contract: concurrent traces must not lose
         tally increments (the lock exists; hammer it)."""
@@ -499,3 +516,82 @@ class TestBandPlanAndKnobs:
             t.join()
         assert cpk.dispatch_counts()["levels_total"] == n_threads * n_iter
         cpk.reset_dispatch_counts()
+
+
+RADIUS4 = 4  # the published radius: K+1 = 10 columns, two sublane tiles
+
+
+@functools.lru_cache(maxsize=None)
+def _one_level_lookup(tier):
+    """One level's kernel, jitted once per tier and dtype (jit's own
+    cache): the residue cases differ in their coordinates only."""
+    from raft_ncup_tpu.ops import corr_pallas as cpk
+
+    def run(f1, f2l, cflat):
+        hw = f2l.shape[1:3]
+        if tier == "banded":
+            return cpk._banded_lookup_one_level(
+                f1, cpk._pad_level(f2l, RADIUS4, 3), hw, cflat, RADIUS4, 0,
+                band_rows=3, interpret=True, query_block=16,
+            )
+        return cpk._lookup_one_level(
+            f1, cpk._pad_level(f2l, RADIUS4), hw, cflat, RADIUS4, 0,
+            interpret=True, query_block=16,
+        )
+
+    return jax.jit(run)
+
+
+def _residue_cases():
+    for name, dtype, tile in (("f32", jnp.float32, 8), ("bf16", jnp.bfloat16, 16)):
+        for tier in ("resident", "banded"):
+            for res in range(tile):
+                yield pytest.param(dtype, tile, tier, res, id=f"{name}-{tier}-r{res}")
+
+
+class TestColumnResidues:
+    """The group body aligns AFTER the channel sum: the window is loaded
+    at the tile-aligned column at or before its origin and the shift by
+    the residue (origin mod sublane tile: 8 rows of float32, 16 of
+    bf16) is applied to the summed correlations. Every residue is its
+    own alignment, residue 7 of float32 the only one whose patch reaches
+    the window's third column tile, and the right-most clamped origin
+    ``wp - (K+1)`` the one whose aligned window ends at the slab's
+    allocated width: each against the XLA on-the-fly path, on both
+    kernel tiers."""
+
+    H4, W4, C4 = 4, 24, 16  # 1/sqrt(16) is a power of two: exact in bf16
+
+    @pytest.mark.parametrize("dtype,tile,tier,res", list(_residue_cases()))
+    def test_every_origin_residue_matches_onthefly(self, dtype, tile, tier, res):
+        from raft_ncup_tpu.ops import corr_pallas as cpk
+        from raft_ncup_tpu.ops.corr import corr_lookup_onthefly
+
+        h, w, c, r = self.H4, self.W4, self.C4, RADIUS4
+        g = np.random.default_rng(100 + res)
+        fmap1 = jnp.asarray(g.normal(size=(1, h, w, c)), jnp.float32).astype(dtype)
+        fmap2 = jnp.asarray(g.normal(size=(1, h, w, c)), jnp.float32).astype(dtype)
+        _, wp, pad = cpk._padded_hw(h, w, r)
+        lim = wp - (2 * r + 2)  # the right-most clamped origin
+        # Origins res, res + tile, ... up to the clamp, cycled over a
+        # row's queries; a row's first query far left (clamped to 0, all
+        # taps out of bounds), its last far right (clamped to lim).
+        steps = (lim - res) // tile + 1
+        origin = res + tile * (np.arange(w) % steps)
+        cx = origin + r - pad + g.uniform(0.05, 0.95, (h, w))
+        cx[:, 0], cx[:, -1] = -3.0 * w, 3.0 * w
+        cy = np.arange(h)[:, None] + g.uniform(-1.5, 1.5, (h, w))
+        coords = jnp.asarray(np.stack([cx, cy], -1)[None], jnp.float32)
+        ib = np.clip(np.floor(cx).astype(int) - r + pad, 0, lim)
+        assert set((ib[:, 1:-1] % tile).ravel()) == {res}
+        assert (ib[:, -1] == lim).all() and (ib[:, 0] == 0).all()
+
+        f1 = fmap1.reshape(1, h * w, c) * (1.0 / math.sqrt(c))
+        out = _one_level_lookup(tier)(f1, fmap2, coords.reshape(1, h * w, 2))
+        ref = corr_lookup_onthefly(
+            fmap1.astype(jnp.float32), fmap2.astype(jnp.float32), coords, r, 1
+        ).reshape(1, h * w, -1)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4
+        )
+        assert not np.asarray(out)[0, ::w].any()  # far left: exact zeros

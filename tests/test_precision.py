@@ -163,9 +163,9 @@ class TestFitsVmemItemsize:
         from raft_ncup_tpu.ops.corr_pallas import _level_vmem_bytes
 
         # The slab and the f1 blocks halve at bf16; the frac/out blocks
-        # and the patch scratch are float32 whatever the policy, and the
-        # bf16 sublane tile is 16 rows, so the total lands between half
-        # and all of the f32 figure — counted as Mosaic allocates it.
+        # are float32 whatever the policy, and the bf16 sublane tile is
+        # 16 rows, so the total lands between half and all of the f32
+        # figure — counted as Mosaic allocates it.
         for h, w, c in ((46, 96, 256), (135, 240, 256), (17, 33, 128)):
             b16 = _level_vmem_bytes(h, w, c, 4, itemsize=2)
             f32 = _level_vmem_bytes(h, w, c, 4, itemsize=4)
@@ -199,7 +199,7 @@ class TestFitsVmemItemsize:
     def test_banded_budget_shrinks_with_itemsize(self):
         """The band-budget extension of the itemsize contract: the
         BANDED tier's VMEM bytes (_banded_vmem_bytes — single-buffered
-        band slab + query blocks + scratch) shrink at bf16 by the slab
+        band slab + query blocks) shrink at bf16 by the slab
         and f1 terms, for any band geometry."""
         from raft_ncup_tpu.ops.corr_pallas import _banded_vmem_bytes
 

@@ -454,9 +454,9 @@ def test_hd_cell_forward_pads_the_pyramid_once_not_in_the_loop(hd_program):
     """`prepare_lookup` (PR 32): the pooled levels are zero-padded for their
     kernels before the loop; the parent's program padded all four in every
     iteration (the compiler does not hoist a pad that grows its operand).
-    The padded shapes: level 0 banded 179x280, level 1 banded 123x160,
+    The padded shapes: level 0 banded 171x280, level 1 banded 139x160,
     level 2 resident 55x96, level 3 resident 38x72."""
-    padded = re.compile(r" = f32\[4,(?:179,280|123,160|55,96|38,72),256\]\S* pad\(")
+    padded = re.compile(r" = f32\[4,(?:171,280|139,160|55,96|38,72),256\]\S* pad\(")
     loop = "".join(_loop_computations(hd_program.text).values())
     assert len(padded.findall(hd_program.text)) == 4
     assert padded.findall(loop) == []
