@@ -25,6 +25,10 @@ Per warmed executable the ledger holds:
   second phase), ``probe_ms`` (what this ledger's own analysis of the
   executable took) and, after the executable's first call,
   ``first_run_ms`` (:func:`build_and_record`, :func:`first_run`),
+- ``product_sites``: which product site of the program took operands of
+  which dtype (``precision/sites.py``: the trace-time tally of the build's
+  lowering, ``{"<scope>/<site>": {"operands", "result"}}``); the start-up
+  report carries its summary beside the phases,
 - ``memory_stats`` from ``Compiled.memory_analysis()``
   (argument/output/temp/generated-code bytes — the
   ``compiled_memory_stats`` surface),
@@ -77,6 +81,7 @@ from raft_ncup_tpu.observability.startup import (
     StartupPhase,
     get_startup_record,
 )
+from raft_ncup_tpu.precision import sites
 from raft_ncup_tpu.utils.flops import TPU_PEAK_FLOPS
 from raft_ncup_tpu.utils.knobs import knob_enabled, knob_raw
 
@@ -337,15 +342,20 @@ def build_and_record(
     from raft_ncup_tpu.utils.profiling import compile_meter, timed_build
 
     kind = str(meta.get("kind", "custom"))
+    sites.reset_product_sites()  # the lowering below traces the program
     compiled, phases = timed_build(hub, jitfn, args, key=key, kind=kind)
+    traced = sites.product_sites()
     entry = ledger.record_compiled(
         key, compiled, backend=backend, phases=phases, **meta
     )
+    if entry is not None:
+        entry["product_sites"] = traced
     get_startup_record().program(
         key, kind, trace_lower_s=phases["trace_lower_s"],
         compile_s=phases["compile_s"], cache=phases["cache"],
         probe_s=None if entry is None else entry["probe_ms"] / 1e3,
         process=compile_meter().totals(),
+        precision=sites.summarize_sites(traced, str(meta.get("policy", "unknown"))),
     )
     return compiled
 

@@ -32,6 +32,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from raft_ncup_tpu.precision.sites import record_site, suspended
+
 # Policy-pinned dtypes (raft_ncup_tpu/precision/; docs/PRECISION.md).
 # PARAM_DTYPE: master-weight storage — every PrecisionPolicy preset pins
 # param_dtype to f32 (the policy constructor rejects anything else), so
@@ -182,9 +184,16 @@ def conv2d(
     accumulator is handed out in (None: the operands')."""
     form = conv_form(kernel.shape, stride, dilation, groups)
     _conv_forms[form].add(site)
+    record_site(site, x.dtype, out_dtype)
     if form == "folded_in":
         return _conv_folded_in(x, kernel, pad, out_dtype)
     if form == "folded_out":
+        if out_dtype is None and x.dtype != PARAM_DTYPE:
+            # Narrow operands: the taps' planes are added in the
+            # accumulator's dtype and the sum is rounded once, as the one
+            # product of the two other forms is (docs/PRECISION.md).
+            folded = functools.partial(_conv_folded_out, pad=pad)
+            return _wide_out(folded)(x, kernel).astype(x.dtype)
         return _conv_folded_out(x, kernel, pad, out_dtype)
     return jax.lax.conv_general_dilated(
         x,
@@ -276,14 +285,19 @@ def _wide_out(conv):
     ``preferred_element_type`` into one of the wide cotangent with the
     narrow operand, which ``conv_general_dilated`` refuses; the cotangent
     is rounded to the operands' dtype first, as it would be had the
-    forward rounded."""
+    forward rounded. Which of the three functions jax traces, and when
+    (the primal at the call, the rules often after the scopes around the
+    call have closed), depends on the transformation above: the product-site
+    tally is suspended in all three, and the caller records the site."""
 
     @jax.custom_vjp
     def wide(x, kernel):
-        return conv(x, kernel, out_dtype=PARAM_DTYPE)
+        with suspended():
+            return conv(x, kernel, out_dtype=PARAM_DTYPE)
 
     def bwd(operands, g):
-        return jax.vjp(conv, *operands)[1](g.astype(operands[0].dtype))
+        with suspended():
+            return jax.vjp(conv, *operands)[1](g.astype(operands[0].dtype))
 
     wide.defvjp(lambda x, kernel: (wide(x, kernel), (x, kernel)), bwd)
     return wide
@@ -328,12 +342,13 @@ class SplitConv2d(nn.Module):
         the two parts are added there, and rounded to a narrower compute
         dtype once, after."""
         kh, kw = kernel.shape[:2]
+        site = "/".join(self.path + (part,))
         conv = functools.partial(
-            conv2d, pad=((kh // 2, kh // 2), (kw // 2, kw // 2)),
-            site="/".join(self.path + (part,)),
+            conv2d, pad=((kh // 2, kh // 2), (kw // 2, kw // 2)), site=site
         )
         if x.dtype == PARAM_DTYPE:
             return conv(x, kernel)
+        record_site(site, x.dtype, PARAM_DTYPE)
         return _wide_out(conv)(x, kernel)
 
     def context(self, fixed_input: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -388,6 +403,7 @@ class ConvTranspose2d(nn.Module):
             PARAM_DTYPE,
         )
         cdt = self.dtype or x.dtype
+        record_site("/".join(self.path), cdt)
         y = jax.lax.conv_transpose(
             x.astype(cdt),
             kernel.astype(cdt),

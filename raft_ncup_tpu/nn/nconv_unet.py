@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from raft_ncup_tpu.nn.layers import PARAM_DTYPE
 from raft_ncup_tpu.ops.geometry import upsample_nearest
 from raft_ncup_tpu.ops.nconv import downsample_data_conf, nconv2d, positivity
+from raft_ncup_tpu.precision.sites import record_site
 
 
 class NConv2dLayer(nn.Module):
@@ -71,6 +72,7 @@ class NConv2dLayer(nn.Module):
 
             bias = self.param("bias", bias_init, (self.features,), PARAM_DTYPE)
 
+        record_site("/".join(self.path), data.dtype)
         return nconv2d(
             data, conf, weight, bias, groups=self.groups, propagate_conf=True
         )

@@ -91,13 +91,16 @@ class StartupRecord:
     def program(
         self, key: str, kind: str, *, trace_lower_s: float,
         compile_s: float, cache: str, probe_s: Optional[float] = None,
-        process: Optional[dict] = None,
+        process: Optional[dict] = None, precision: Optional[dict] = None,
     ) -> None:
         """One executable built: its two build phases, the persistent
-        cache's verdict, and the compile listener's process totals as
-        they stood when the build ended (``process``). A key built again
-        (an LRU eviction, a second run in one process) adds to its
-        entry's seconds and takes the newest verdict."""
+        cache's verdict, the compile listener's process totals as they
+        stood when the build ended (``process``), and under which
+        precision policy how many of its product sites took bfloat16 and
+        float32 operands (``precision``: ``{"policy", "sites_bf16",
+        "sites_f32"}``, from ``precision/sites.py``'s tally of the build's
+        trace). A key built again (an LRU eviction, a second run in one
+        process) adds to its entry's seconds and takes the newest verdict."""
         with self._lock:
             entry = self._programs.get(key)
             if entry is None and len(self._programs) >= MAX_PROGRAMS:
@@ -113,6 +116,8 @@ class StartupRecord:
                 entry["compile_s"] += float(compile_s)
                 entry["cache"] = cache
                 entry["builds"] += 1
+                if precision is not None:
+                    entry["precision"] = dict(precision)
                 if probe_s is not None:
                     entry["probe_s"] = (entry["probe_s"] or 0.0) + float(probe_s)
             if process is not None:
@@ -172,7 +177,8 @@ def set_startup_record(record: Optional[StartupRecord]) -> Optional[StartupRecor
 
 def startup_report() -> dict:
     """``{"programs": [{"key", "kind", "trace_lower_s", "compile_s",
-    "cache", "first_run_s", ...}], "phases": {"weights_s",
+    "cache", "first_run_s", "precision": {"policy", "sites_bf16",
+    "sites_f32"}, ...}], "phases": {"weights_s",
     "input_start_s", "warmup_s"}, "process": {"programs_loaded",
     "cache_hits", "cache_misses", "compile_s"}, "dropped"}`` — a phase
     the process has not run reads ``None``."""

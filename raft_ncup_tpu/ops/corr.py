@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from raft_ncup_tpu.ops.geometry import avg_pool2, grid_sample
+from raft_ncup_tpu.precision.sites import record_site
 from raft_ncup_tpu.utils.knobs import knob_positive_int
 
 ROW_CHUNK_ENV = "RAFT_NCUP_CORR_ROW_CHUNK"
@@ -127,6 +128,7 @@ def build_corr_pyramid(
     dtype = dtype or jnp.float32
     f1 = fmap1.reshape(B, H * W, C).astype(dtype)
     f2 = fmap2.reshape(B, H * W, C).astype(dtype)
+    record_site("corr_pyramid/volume", dtype, jnp.float32)
     corr = jnp.einsum(
         "bxc,byc->bxy", f1, f2, preferred_element_type=jnp.float32
     ) / math.sqrt(C)
@@ -221,6 +223,7 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Arr
         centre = coords.reshape(B, H * W, 2).astype(wdt) / (2**lvl)
         ax = _axis_weights(centre[..., 0], Wl, radius)  # (B, HW, K, Wl)
         ay = _axis_weights(centre[..., 1], Hl, radius)  # (B, HW, K, Hl)
+        record_site(f"level{lvl}", wdt)
         win = _window_contract(corr.astype(wdt), ax, ay)  # (B, HW, K_x, K_y)
         out.append(win.reshape(B, H, W, K * K))
     return jnp.concatenate(out, axis=-1)
@@ -298,6 +301,7 @@ def corr_lookup_onthefly(
             centroid = coords_chunk[:, :, :, None, None, :] / (2**lvl)
             taps = centroid + delta[None, None, None]  # (B, rc, W, K, K, 2)
             sampled = grid_sample(f2_levels[lvl], taps)  # (B, rc, W, K, K, C)
+            record_site(f"onthefly/level{lvl}", sampled.dtype, jnp.float32)
             corr = jnp.einsum(
                 "brwijc,brwc->brwij", sampled, f1_chunk,
                 preferred_element_type=jnp.float32,

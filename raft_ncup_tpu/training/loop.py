@@ -104,13 +104,14 @@ def _compile_step(step_fn, cfg: TrainConfig, *args) -> tuple:
         return _COMPILED[key], None
     ledger_key = (
         f"{jax.default_backend()}|train_step|{cfg.stage}|{cfg.batch_size}"
-        f"x{cfg.image_size[0]}x{cfg.image_size[1]}|{cfg.iters}"
+        f"x{cfg.image_size[0]}x{cfg.image_size[1]}|{cfg.iters}|{cfg.precision}"
     )
     try:
         compiled = build_and_record(
             ledger, get_telemetry(), step_fn, args, ledger_key,
             backend=jax.default_backend(), kind="train_step",
             shape=(cfg.batch_size, *cfg.image_size, 3), iters=cfg.iters,
+            policy=cfg.precision,
         )
     except Exception as e:  # the probe must not be able to stop a run
         print(f"train step: cost probe unavailable ({e}); plain jit", flush=True)
@@ -272,7 +273,7 @@ def train_steps(
             rng = jax.random.fold_in(
                 jax.random.PRNGKey(cfg.seed), run.step_i
             )
-            with tel.span("train_dispatch", step=run.step_i):
+            with tel.span("train_dispatch", step=run.step_i, precision=cfg.precision):
                 run.state, metrics = run.step(run.state, device_batch, rng)
             tel.inc("train_steps_total")
             tel.inc("train_pairs_total", cfg.batch_size)

@@ -68,6 +68,7 @@ def max_recompiles():
 LONGEST_FILES_FIRST = (
     "tests/test_tpu_aot_compile.py",
     "tests/benchmark/test_train_cell.py",
+    "tests/benchmark/test_train_mixed_cell.py",
     "tests/benchmark/test_benchmark.py",
     "tests/benchmark/test_stream_cell.py",
     "tests/benchmark/test_1080p_cell.py",
