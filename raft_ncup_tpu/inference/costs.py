@@ -82,6 +82,7 @@ from raft_ncup_tpu.observability.startup import (
     get_startup_record,
 )
 from raft_ncup_tpu.precision import sites
+from raft_ncup_tpu.utils import remat
 from raft_ncup_tpu.utils.flops import TPU_PEAK_FLOPS
 from raft_ncup_tpu.utils.knobs import knob_enabled, knob_raw
 
@@ -337,14 +338,17 @@ def build_and_record(
     ``lower`` and ``compile`` as their two start-up phases on ``hub``
     (``utils/profiling.timed_build``), the executable's costs and phases
     into ``ledger``, and the phases into the process's start-up record
-    with the compile listener's totals as they stand now
+    with the compile listener's totals as they stand now, the product
+    sites' summary and what the program's checkpoint policy saved by name
     (``observability/startup.py``). Returns the executable."""
     from raft_ncup_tpu.utils.profiling import compile_meter, timed_build
 
     kind = str(meta.get("kind", "custom"))
     sites.reset_product_sites()  # the lowering below traces the program
+    remat.reset_saved_residuals()
     compiled, phases = timed_build(hub, jitfn, args, key=key, kind=kind)
     traced = sites.product_sites()
+    saved = remat.saved_residuals()
     entry = ledger.record_compiled(
         key, compiled, backend=backend, phases=phases, **meta
     )
@@ -356,6 +360,9 @@ def build_and_record(
         probe_s=None if entry is None else entry["probe_ms"] / 1e3,
         process=compile_meter().totals(),
         precision=sites.summarize_sites(traced, str(meta.get("policy", "unknown"))),
+        # of a program that kept something across a checkpoint (the
+        # training step): how many values under each name
+        saved_residuals=saved if any(saved.values()) else None,
     )
     return compiled
 

@@ -6,8 +6,13 @@ wires them together. This keeps the recurrent refinement a plain
 ``jax.lax.scan`` — one compiled iteration body regardless of iteration
 count — with the GRU hidden state, query coordinates and (when BatchNorm
 lives inside the upsampler) mutable batch statistics as the scan carry.
-Gradient rematerialization wraps the body during training so the 12
-full-resolution NCUP passes don't hold live activations.
+Gradient rematerialization wraps the body during training: of every
+iteration the carry survives, and by name (``utils/remat.py``) the lookup's
+K*K*L planes at 1/8 resolution and the weights net's two hidden convolution
+outputs at 1/4 (0.9 GB a step at the Sintel fine-tune's batch 6, the
+dearest work of the second forward per byte held). NCUP's full-resolution
+planes are never kept (~2 GB an iteration for ~31 ms a step), so the 12
+NCUP passes don't hold live activations.
 
 Reference call structure: core/raft.py:87-143 (baseline) and
 core/raft_nc_dbl.py:115-173 (NCUP variant: mask head removed, per-iter
@@ -31,6 +36,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # jax.shard_map was promoted out of jax.experimental after 0.4.x; resolve
 # whichever this jax ships so the spatially-sharded corr lookup works on
@@ -55,6 +61,7 @@ from raft_ncup_tpu.ops.geometry import upsample_nearest
 # above keep their line count: a Pallas program's cache key carries the
 # line numbers of its call stack, PERF.md section 6, PR 33.)
 from raft_ncup_tpu.precision.sites import scope as _scope
+from raft_ncup_tpu.utils.remat import LOOKUP_OUT, save_named
 
 
 def _save_conv_outputs(prim, *_, **__) -> bool:
@@ -222,7 +229,11 @@ class RAFT:
         between them (bias, norm, relu, residual add). Kept whole, the
         encoders' activations at 1/2 and 1/4 resolution outlived the
         entire loop, forward and backward: 1.6 of the 1.9 GiB a sample
-        that the Sintel step held at its peak (PERF.md section 6, PR 26)."""
+        that the Sintel step held at its peak (PERF.md section 6, PR 26).
+        The loop's own checkpoint keeps more, by name (``apply``;
+        ``utils/remat.py``): what it keeps is stacked over the iterations
+        and read back inside the loop, under the peak the step already
+        has, where an encoder's outputs would be live across all of it."""
         cfg = self.cfg
         policy = self.policy
         if image1.shape[1] % 8 or image1.shape[2] % 8:
@@ -461,7 +472,7 @@ class RAFT:
             # lookup and the GRU update are the two halves an xprof
             # trace needs separated (correlation memory wall vs compute).
             with _scope("raft.corr_lookup"):
-                corr = corr_fn(coords1)
+                corr = checkpoint_name(corr_fn(coords1), LOOKUP_OUT)
             flow = coords1 - coords0
             with _scope("raft.update_block"):
                 net, delta = run(
@@ -631,7 +642,8 @@ class RAFT:
 
         body = step
         if train and remat:
-            body = jax.checkpoint(step)
+            # recomputed in the backward, but for what the policy keeps
+            body = jax.checkpoint(step, policy=save_named)
 
         with _scope("raft.refinement"):
             if early_exit_tol is not None:

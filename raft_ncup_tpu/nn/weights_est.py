@@ -8,8 +8,10 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from raft_ncup_tpu.nn.layers import Conv2d, ConvTranspose2d, Norm
+from raft_ncup_tpu.utils.remat import WEIGHTS_NET_CONV
 
 
 class SimpleWeightsNet(nn.Module):
@@ -38,6 +40,9 @@ class SimpleWeightsNet(nn.Module):
             x = Conv2d(
                 ch, k, dilation=d, padding=pad, dtype=self.dtype, name=f"conv{i}"
             )(x)
+            # What the training step keeps of this net across its loop
+            # (utils/remat.py); norm and ReLU are recomputed from it.
+            x = checkpoint_name(x, WEIGHTS_NET_CONV)
             if self.use_bn:
                 x = Norm("batch", name=f"bn{i}")(x, train=train)
             x = nn.relu(x)

@@ -92,6 +92,7 @@ class StartupRecord:
         self, key: str, kind: str, *, trace_lower_s: float,
         compile_s: float, cache: str, probe_s: Optional[float] = None,
         process: Optional[dict] = None, precision: Optional[dict] = None,
+        saved_residuals: Optional[dict] = None,
     ) -> None:
         """One executable built: its two build phases, the persistent
         cache's verdict, the compile listener's process totals as they
@@ -99,8 +100,11 @@ class StartupRecord:
         precision policy how many of its product sites took bfloat16 and
         float32 operands (``precision``: ``{"policy", "sites_bf16",
         "sites_f32"}``, from ``precision/sites.py``'s tally of the build's
-        trace). A key built again (an LRU eviction, a second run in one
-        process) adds to its entry's seconds and takes the newest verdict."""
+        trace), and how many values the program's checkpoint policy kept
+        under each of its names (``saved_residuals``: ``utils/remat.py``'s
+        tally of the same trace; the training step's). A key built again
+        (an LRU eviction, a second run in one process) adds to its entry's
+        seconds and takes the newest verdict."""
         with self._lock:
             entry = self._programs.get(key)
             if entry is None and len(self._programs) >= MAX_PROGRAMS:
@@ -118,6 +122,8 @@ class StartupRecord:
                 entry["builds"] += 1
                 if precision is not None:
                     entry["precision"] = dict(precision)
+                if saved_residuals is not None:
+                    entry["saved_residuals"] = dict(saved_residuals)
                 if probe_s is not None:
                     entry["probe_s"] = (entry["probe_s"] or 0.0) + float(probe_s)
             if process is not None:
@@ -178,8 +184,8 @@ def set_startup_record(record: Optional[StartupRecord]) -> Optional[StartupRecor
 def startup_report() -> dict:
     """``{"programs": [{"key", "kind", "trace_lower_s", "compile_s",
     "cache", "first_run_s", "precision": {"policy", "sites_bf16",
-    "sites_f32"}, ...}], "phases": {"weights_s",
-    "input_start_s", "warmup_s"}, "process": {"programs_loaded",
+    "sites_f32"}, "saved_residuals": {name: count}, ...}], "phases":
+    {"weights_s", "input_start_s", "warmup_s"}, "process": {"programs_loaded",
     "cache_hits", "cache_misses", "compile_s"}, "dropped"}`` — a phase
     the process has not run reads ``None``."""
     return get_startup_record().report()

@@ -186,6 +186,7 @@ def test_each_executable_key_leaves_one_record_and_later_calls_leave_nothing(rec
     assert entry["kind"] == "custom" and "'a'" in entry["key"] and entry["builds"] == 1
     assert entry["trace_lower_s"] > 0 and entry["compile_s"] > 0 and entry["first_run_s"] > 0
     assert entry["cache"] == "off"  # the CPU backend keeps no persistent cache
+    assert "saved_residuals" not in entry  # a program that builds no checkpoint
     for _ in range(5):  # the steady path: one dict read then the program
         program("a")(x)
     assert _span_counts(tel) == after_first and len(startup_report()["programs"]) == 1
@@ -421,6 +422,9 @@ def test_train_run_banks_weights_build_first_run_and_input_start(record, hub, tm
     (step,) = got["programs"]
     assert step["kind"] == "train_step" and "train_step|chairs|1x64x64|1" in step["key"]
     assert step["trace_lower_s"] > 0 and step["compile_s"] > 0 and step["first_run_s"] > 0
+    # what the loop's checkpoint kept, by name (utils/remat.py): the lookup's
+    # planes; 0 of the weights net's, which the baseline's head does not have
+    assert step["saved_residuals"] == {"raft.corr_lookup.out": 1, "ncup.weights_net.conv": 0}
     assert got["phases"]["weights_s"] > 0 and got["phases"]["input_start_s"] > 0
     entry = get_cost_ledger().entry(step["key"])
     assert entry["compile_ms"] == entry["trace_lower_ms"] + entry["backend_compile_ms"]

@@ -1,19 +1,24 @@
 """The training loop the package owns (``training/loop.py``), its spans and
 counters, and the rematerialisation of the training forward (PR 26)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from raft_ncup_tpu.config import DataConfig, TrainConfig, flagship_config
+from raft_ncup_tpu.config import DataConfig, ModelConfig, TrainConfig, flagship_config
 from raft_ncup_tpu.data import ArrayFlowDataset, SyntheticFlowDataset
 from raft_ncup_tpu.data.synthetic import make_pair
+from raft_ncup_tpu.models import raft as raft_module
 from raft_ncup_tpu.models.raft import RAFT
+from raft_ncup_tpu.nn import weights_est
 from raft_ncup_tpu.observability import Telemetry, set_telemetry, telemetry_report
 from raft_ncup_tpu.training.logger import Logger
 from raft_ncup_tpu.training.loop import open_train_run, train_steps
 from raft_ncup_tpu.training.loss import sequence_loss
+from raft_ncup_tpu.utils import remat
 
 HW = (64, 96)
 
@@ -103,7 +108,11 @@ def test_array_dataset_goes_through_the_file_datasets_sample():
 # --------------------------------------------------------- rematerialisation
 
 
-def _loss_and_grads(model, variables, batch, remat):
+def _model(variant, precision):
+    return RAFT(ModelConfig(variant=variant, dataset="sintel", precision=precision))
+
+
+def _loss_fn(model, variables, batch, remat):
     def loss_fn(params):
         preds = model.apply(
             {**variables, "params": params}, batch["image1"].astype(jnp.float32),
@@ -112,39 +121,106 @@ def _loss_and_grads(model, variables, batch, remat):
         )
         return sequence_loss(preds, batch["flow"], batch["valid"], 0.85)[0]
 
-    return jax.jit(jax.value_and_grad(loss_fn))(variables["params"])
+    return jax.jit(jax.value_and_grad(loss_fn))
 
 
-def test_rematerialised_step_is_the_old_arithmetic():
-    """Encoders and loop body rematerialised (what the step compiles)
-    against nothing rematerialised: same loss, same gradient on every
-    leaf, to float32 rounding."""
-    model = RAFT(flagship_config(dataset="sintel"))
+@pytest.mark.parametrize("precision", ["f32", "bf16_train"])
+@pytest.mark.parametrize("variant", ["raft_nc_dbl", "raft"])
+def test_rematerialised_step_is_the_old_arithmetic(variant, precision, monkeypatch):
+    """Encoders and loop body rematerialised, the loop's named values kept
+    (what the step compiles) against nothing rematerialised: same loss,
+    same gradient on every leaf, to float32 rounding. Under ``bf16_train``
+    a second forward does not round as the first did (XLA keeps float32
+    inside fused expressions), so ANY rematerialised step stands 2e-3 from
+    the plain one, with the policy and without (PERF.md section 6, PR 38):
+    there the policy step is held, leaf by leaf, to the checkpoint without
+    a policy: a kept value is the value the second forward would have
+    made."""
+    model = _model(variant, precision)
     variables = model.init(jax.random.PRNGKey(3), (1,) + HW + (3,))
     batch = _batch()
-    loss_r, grads_r = _loss_and_grads(model, variables, batch, remat=True)
-    loss_p, grads_p = _loss_and_grads(model, variables, batch, remat=False)
-    assert float(loss_r) == pytest.approx(float(loss_p), rel=1e-6)
-    flat_r = jax.tree_util.tree_leaves_with_path(grads_r)
-    flat_p = jax.tree.leaves(grads_p)
-    scale = max(float(jnp.max(jnp.abs(g))) for g in flat_p)
-    for (path, a), b in zip(flat_r, flat_p):
+    loss_r, grads_r = _loss_fn(model, variables, batch, True)(variables["params"])
+    if precision == "f32":
+        remat_too = False
+    else:
+        monkeypatch.setattr(raft_module, "save_named", None)  # jax.checkpoint(step)
+        remat_too = True
+    loss_o, grads_o = _loss_fn(model, variables, batch, remat_too)(variables["params"])
+    assert float(loss_r) == pytest.approx(float(loss_o), rel=1e-6)
+    flat_o = jax.tree.leaves(grads_o)
+    scale = max(float(jnp.max(jnp.abs(g))) for g in flat_o)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads_r), flat_o):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6 * scale,
             err_msg=jax.tree_util.keystr(path),
         )
 
 
-def test_only_the_training_forward_is_rematerialised():
+def _rematted_ops(lowered) -> list:
+    """``op_name`` paths below ``rematted_computation`` in a lowered
+    step: what the backward computes a second time."""
+    text = lowered.as_text(debug_info=True)
+    return [
+        m.split("rematted_computation/", 1)[1]
+        for m in re.findall(r'loc\("([^"]*rematted_computation/[^"]*)"', text)
+    ]
+
+
+def test_backward_holds_no_second_lookup_contraction_or_weights_net_product():
+    """The policy by name on the checkpointed scan body (utils/remat.py):
+    of the lookup the backward recomputes the axis weights alone (the
+    volume's cotangent needs nothing else: the coordinates are detached),
+    of the weights net the head's product alone; the update block's
+    products it still computes again."""
+    model = _model("raft_nc_dbl", "f32")
+    variables = model.init(jax.random.PRNGKey(3), (1,) + HW + (3,))
+    remat.reset_saved_residuals()
+    again = _rematted_ops(
+        _loss_fn(model, variables, _batch(), True).lower(variables["params"])
+    )
+    assert remat.saved_residuals() == {remat.LOOKUP_OUT: 1, remat.WEIGHTS_NET_CONV: 2}
+    lookup = [op for op in again if op.startswith("raft.corr_lookup/")]
+    assert lookup and not [
+        op for op in lookup if re.search(r"reduce_sum|dot_general|/mul$", op)
+    ]
+    net = [op for op in again if "weights_est_net" in op and "conv_general" in op]
+    assert net and all("/out/" in op for op in net)
+    assert [op for op in again if op.startswith("raft.update_block/") and "conv_general" in op]
+    # the baseline's head has no weights net: one name is never produced
+    model = _model("raft", "f32")
+    variables = model.init(jax.random.PRNGKey(3), (1,) + HW + (3,))
+    remat.reset_saved_residuals()
+    _loss_fn(model, variables, _batch(), True).lower(variables["params"])
+    assert remat.saved_residuals() == {remat.LOOKUP_OUT: 1, remat.WEIGHTS_NET_CONV: 0}
+
+
+def test_only_the_training_forward_is_rematerialised(monkeypatch):
     """The inference programs are what they were: no checkpoint in a
-    ``test_mode`` forward; the training forward has the loop body's and
-    the two encoders' (each nested: outer, and inner with the policy)."""
+    ``test_mode`` forward, and the names lower to nothing (the module is
+    the same text with them and without, to the number jax gives a private
+    function's symbol, which counts the equations before it); the training
+    forward has the loop body's and the two encoders' (each nested: outer,
+    and inner with the policy)."""
     model = RAFT(flagship_config(dataset="sintel"))
     variables = model.init(jax.random.PRNGKey(3), (1,) + HW + (3,))
     img = jnp.zeros((1,) + HW + (3,), jnp.float32)
-    infer = str(jax.make_jaxpr(
-        lambda v, a, b: model.apply(v, a, b, iters=2, test_mode=True))(variables, img, img))
-    assert "remat" not in infer and "checkpoint" not in infer
+
+    def infer():  # a function of its own each time: jax caches traces
+        return lambda v, a, b: model.apply(v, a, b, iters=2, test_mode=True)
+
+    def lowered():  # but for the counter in a private function's symbol
+        text = jax.jit(infer()).lower(variables, img, img).as_text()
+        return re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", text)
+
+    jaxpr = str(jax.make_jaxpr(infer())(variables, img, img))
+    assert "remat" not in jaxpr and "checkpoint" not in jaxpr and "name[" in jaxpr
+    remat.reset_saved_residuals()
+    named = lowered()
+    assert not any(remat.saved_residuals().values())
+    for module in (raft_module, weights_est):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    assert "name[" not in str(jax.make_jaxpr(infer())(variables, img, img))
+    assert lowered() == named
     train = str(jax.make_jaxpr(
         lambda v, a, b: model.apply(v, a, b, iters=2, train=True, freeze_bn=True))(variables, img, img))
     assert train.count("remat2[") == 5
