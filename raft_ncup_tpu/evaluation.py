@@ -189,7 +189,8 @@ def _run_metric_pass(
     (the bounded wait for an earlier batch), and once ``eval_pull`` (the
     pass's one pull, which waits for the device to finish). The counter
     ``eval_pairs_total`` grows by the batch's pairs where
-    ``eval_dispatch`` closes.
+    ``eval_dispatch`` closes. After the pull the pass publishes which
+    precision its executable ran at (:func:`_publish_precision`).
     """
     tel = telemetry if telemetry is not None else get_telemetry()
     pass_id = new_span_id()
@@ -263,7 +264,23 @@ def _run_metric_pass(
     # The window's single sanctioned pull: a few float32 sums, not fields.
     with tel.span("eval_pull", pass_id=pass_id):
         host_acc = jax.device_get(acc)
+    _publish_precision(tel, fwd)
     return np.asarray(host_acc, np.float64)
+
+
+def _publish_precision(tel, fwd) -> None:
+    """The pass's executable by its banked product-site tally
+    (``ShapeCachedForward.report()["precision"]``: preset, sites by operand
+    width), as the gauges ``infer_product_sites_f32`` /
+    ``infer_product_sites_bf16`` of the pass's hub: what ran pinned and what
+    ran at one MXU pass, where an operator reads everything else
+    (docs/OBSERVABILITY.md). Nothing for a stand-in without the report or
+    with the cost ledger off."""
+    report = getattr(fwd, "report", None)
+    precision = report()["precision"] if report is not None else None
+    if precision:
+        tel.gauge_set("infer_product_sites_f32", precision["sites_f32"])
+        tel.gauge_set("infer_product_sites_bf16", precision["sites_bf16"])
 
 
 # The device-side warm-start splat: jit caches one tiny executable per

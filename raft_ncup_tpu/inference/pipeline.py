@@ -60,6 +60,7 @@ from raft_ncup_tpu.inference.costs import (
 from raft_ncup_tpu.observability import NOOP_SPAN, get_telemetry
 from raft_ncup_tpu.observability.telemetry import LEGACY_KEY_ALIASES
 from raft_ncup_tpu.precision import resolve_policy
+from raft_ncup_tpu.precision.sites import summarize_sites
 from raft_ncup_tpu.utils.profiling import annotate_spans, compile_meter
 
 _EXEC_CANON = LEGACY_KEY_ALIASES["inference"]
@@ -605,6 +606,13 @@ class ShapeCachedForward:
                             compiled = box["c"] = jitfn
                         else:
                             box["c"] = compiled
+                            # shapes alone: what ``lowered_hlo`` lowers again
+                            box["avals"] = jax.tree.map(
+                                lambda a: jax.ShapeDtypeStruct(
+                                    jnp.shape(a), jnp.result_type(a)
+                                ),
+                                args,
+                            )
                             return first_run(
                                 ledger, tel, compiled, args, ledger_key,
                                 str(meta.get("kind", "custom")),
@@ -613,8 +621,11 @@ class ShapeCachedForward:
 
         # Inspection handle (inference/pipe_schedule.tick_text; bench's
         # sharding fingerprint): the warmed executable without a second
-        # lower().compile(). Empty until the first call.
+        # lower().compile(). Empty until the first call. The two others
+        # are what ``report`` and ``lowered_hlo`` find the executable by.
         warmed._compiled_box = box
+        warmed._jitfn = jitfn
+        warmed._ledger_key = ledger_key
         return warmed
 
     def _get(self, key, build):
@@ -646,6 +657,49 @@ class ShapeCachedForward:
                 file=sys.stderr,
             )
         return fn
+
+    # ---------------------------------------------- what a pass reports
+
+    def _newest(self):
+        """``(wrapper, ledger entry)`` of the whole-forward executable
+        ('metrics' or 'forward') this cache used last, or ``None``."""
+        for fn in reversed(list(self._fns.values())):
+            entry = self.costs.entry(getattr(fn, "_ledger_key", "")) or {}
+            if (entry.get("meta") or {}).get("kind") in ("metrics", "forward"):
+                return fn, entry
+        return None
+
+    def report(self) -> dict:
+        """What an evaluation pass reports of the executable it ran (the
+        whole-forward executable this cache used last): the cache's
+        compile / hit / eviction counts and ``precision``, from the
+        product-site tally banked when the executable was built
+        (``precision/sites.py``; docs/OBSERVABILITY.md): the preset in
+        the executable's key, how many sites took float32 and how many
+        bfloat16 operands, and the sites by path. ``precision`` is
+        ``None`` before the first call and where the cost ledger is
+        off."""
+        found = self._newest()
+        precision = None
+        if found is not None and found[1].get("product_sites"):
+            sites, meta = found[1]["product_sites"], found[1]["meta"]
+            counts = summarize_sites(sites, str(meta.get("policy")))
+            precision = {"preset": counts.pop("policy"), **counts, "sites": sites}
+        return {"executables": dict(self.stats), "precision": precision}
+
+    def lowered_hlo(self) -> Optional[str]:
+        """HLO text of the module the compiler was handed for that same
+        executable: its jitted function lowered once more on the shapes
+        of its first call, under the precision context of the caller
+        (the same text on every backend; the executable's own
+        ``as_text()`` is what the backend made of it). ``None`` before
+        the first call."""
+        found = self._newest()
+        if found is None or "avals" not in found[0]._compiled_box:
+            return None
+        fn = found[0]
+        lowered = fn._jitfn.lower(*fn._compiled_box["avals"])
+        return lowered.compiler_ir(dialect="hlo").as_hlo_module().to_string()
 
     # ------------------------------------------------------------- forwards
 

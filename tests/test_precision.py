@@ -389,6 +389,38 @@ class TestServingParity:
             assert srv.report()["precision"] == "bf16_infer"
 
 
+    def test_serve_config_and_model_precision_build_one_executable_key(
+        self, tiny_setup, monkeypatch
+    ):
+        """`ServeConfig.precision="bf16_infer"` over a float32 model and a
+        model built under `bf16_infer` with `ServeConfig.precision=None`
+        (inherit) resolve to ONE forward key, the policy's name in it: a
+        deployment that sets the preset either way warms the same
+        executable (PR 39)."""
+        import dataclasses
+
+        from raft_ncup_tpu.inference.pipeline import ShapeCachedForward
+        from raft_ncup_tpu.models.raft import get_model
+        from raft_ncup_tpu.serving import FlowServer
+
+        model, variables, ds = tiny_setup
+        img1, img2, _ = _stack(ds, [1])
+        keys = []
+        monkeypatch.setattr(
+            ShapeCachedForward, "_get",
+            lambda self, key, build: keys.append(tuple(key)) or (lambda *a: None),
+        )
+        narrow = get_model(dataclasses.replace(model.cfg, precision="bf16_infer"))
+        for m, precision in ((model, "bf16_infer"), (narrow, None), (model, None)):
+            cfg = ServeConfig(batch_sizes=(1,), iter_levels=(ITERS,), precision=precision)
+            want = "f32" if m is model and precision is None else "bf16_infer"
+            with FlowServer(m, variables, cfg) as srv:
+                assert srv.report()["precision"] == want
+                srv._fwd.forward_device(img1, img2, ITERS)
+        assert keys[0] == keys[1] and keys[0][-1] == "bf16_infer"
+        assert keys[2][-1] == "f32" and keys[2][:-1] == keys[0][:-1]
+
+
 # ------------------------------------------------ streaming warm-start
 
 

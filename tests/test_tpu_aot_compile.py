@@ -18,8 +18,9 @@ process.
 What the file costs, and why its place in the run matters. It is ten
 minutes of the suite and, being one file, one worker's work: the suite's
 wall cannot go under its length. tests/conftest.py therefore hands it
-out first (`LONGEST_FILES_FIRST`). The two whole programs (the Sintel
-train step at the published batch 6, the eval cell's batch-8 forward)
+out first (`LONGEST_FILES_FIRST`). The whole programs (the Sintel
+train step at the published batch 6, the eval cell's batch-8 forward,
+since PR 39 its `bf16_infer` twin at batch 16: 67 s alone)
 are compiled ONCE each, in module-scoped fixtures, and every property of
 a program is a test of its own name on that one compile. The train step
 is 4 s of tracing, 1 s of lowering and some 600 CPU-seconds in the TPU
@@ -35,6 +36,7 @@ whole programs come first in the file, the kernel cases, seconds each,
 last: they are what is left when the worker asks for its next file.
 """
 
+import math
 import re
 from typing import NamedTuple
 
@@ -240,6 +242,31 @@ def hd_program(sds) -> HdProgram:
     return HdProgram(compiled.as_text(), asked / 2**30, cpk.dispatch_counts())
 
 
+@pytest.fixture(scope="module")
+def eval_bf16_program(sds) -> Program:
+    """The `eval_sintel_nc_bf16` cell's program (PR 39): the same model
+    under `bf16_infer` at batch 16 (what the halved volume buys), 440x1024,
+    32 iterations, `highest` for the float32 pins (bfloat16 operands ignore
+    it). 67 s alone; one compile for its cases."""
+    import dataclasses
+
+    from raft_ncup_tpu.config import flagship_config
+    from raft_ncup_tpu.models.raft import RAFT
+
+    model = RAFT(dataclasses.replace(
+        flagship_config(dataset="sintel"), precision="bf16_infer"
+    ))
+    variables = _abstract(sds, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), (1, 64, 96, 3))
+    ))
+    img = sds((16, 440, 1024, 3))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(
+            lambda v, a, b: model.apply(v, a, b, iters=32, test_mode=True)
+        ).lower(variables, img, img).compile()
+    return _program(compiled)
+
+
 def _loop_computations(text: str) -> dict:
     """{name: body text} of every computation reachable from a `while`'s
     `body=` through `calls=` (fusions) and nested loops."""
@@ -443,6 +470,75 @@ def test_eval_cell_forward_temporaries_stay_under_6_gib(
     """The program's temporaries leave most of the chip free (PR 25: 4.54
     GiB; the gather form was 5.14 GiB; unchanged by PR 27)."""
     assert _record_temp(record_property, eval_program) < 6.0
+
+
+def test_eval_bf16_cell_forward_temporaries_stay_under_9_gib(
+    eval_bf16_program, record_property
+):
+    """Batch 16 under `bf16_infer`: 7.332 GiB of temporaries (my compile,
+    PR 39) where float32 at batch 8 asks 4.540: the volume is the same
+    2.11 GB, the float32 widenings and 32 frames' activations the rest."""
+    assert _record_temp(record_property, eval_bf16_program) < 9.0
+
+
+def test_eval_bf16_cell_forward_widens_level_0_inside_its_contraction(
+    eval_bf16_program, record_property
+):
+    """What decides the lookup's cost at one pass. The volume is stored in
+    bfloat16 and the lookup computes in float32 (P7), so every level is
+    widened every iteration. In this program level 0 (16 x 7040 x 55 x 128:
+    3.17 GB as float32) is NOT widened into a buffer of its own: no
+    instruction of the module has that float32 shape, and the loop's x
+    contraction is one `convolution` fusion that reads the bfloat16 level
+    and writes `f32[16,7040,55,9]`, half the bytes a pair that the float32
+    program reads. (The training step's 46x96 grid takes the multiply +
+    reduce form and materialises the widening as a copy, PERF.md section 7.)
+    Levels 1-3 are widened by `convert`s of their own inside the loop,
+    1.01 GB written and read again an iteration: recorded, not asserted
+    (fusing them is a gain)."""
+    text = eval_bf16_program.text
+    assert "f32[16,7040,55,128]" not in text
+    loops = _loop_computations(text)
+    contraction = [
+        body for body in loops.values()
+        if "bf16[16,7040,55,128]" in body
+        and re.search(r"ROOT \S+ = f32\[16,7040,55,9\]\S* convolution\(", body)
+    ]
+    assert len(contraction) == 1
+    widened = sorted({
+        shape for body in loops.values() for shape in
+        re.findall(r"= (f32\[16,7040,\d+,\d+\])\S* convert\(", body)
+    })
+    record_property("levels_widened_by_a_convert_in_the_loop", widened)
+    gib = sum(4 * math.prod(_dims(shape)) for shape in widened) / 2**30
+    record_property("their_float32_gib_an_iteration", round(gib, 3))
+    assert " gather(" not in text
+    assert _gru_gate_convolutions(text) == {(False, 128): 6, (True, 256): 6}
+
+
+def test_eval_bf16_cell_forward_pools_and_looks_up_what_a_level_stores(
+    eval_bf16_program,
+):
+    """P6 as the compiler kept it: "each pooled level the float32 mean of
+    the level below, rounded" holds only if nothing reads a level BEFORE its
+    rounding, and the compiler may drop a cast down and up again inside one
+    program (`xla_allow_excess_precision`: a small program that builds the
+    pyramid and looks it up in one piece read levels 1-3 unrounded on a v5e,
+    PERF.md section 6, PR 39). Here each pooling reads the bfloat16 level
+    (three fusions, `bf16[B*7040,h,w,1] -> f32[B*7040,2*(h//2),w,1]`), and
+    the four levels are operands of the refinement loop in bfloat16, so the
+    lookup inside it can only read what was stored."""
+    text = eval_bf16_program.text
+    pooled = re.findall(
+        r"\(param_\S+: bf16\[112640,(\d+),(\d+),1\]\) -> f32\[112640,(\d+),(\d+),1\]", text
+    )
+    assert sorted(tuple(map(int, p)) for p in pooled) == [
+        (13, 32, 12, 32), (27, 64, 26, 64), (55, 128, 54, 128)
+    ]
+    (loop,) = [line for line in text.splitlines() if re.search(r"= \(.*bf16\[16,7040,55,128\].*\) while\(", line)]
+    carried = loop.split(" while(", 1)[0]
+    for level in ("55,128", "27,64", "13,32", "6,16"):
+        assert f"bf16[16,7040,{level}]" in carried and f"f32[16,7040,{level}]" not in carried
 
 
 def test_hd_cell_forward_fits_the_chip_at_batch_4(hd_program, record_property):
