@@ -81,6 +81,7 @@ from raft_ncup_tpu.observability.startup import (
     StartupPhase,
     get_startup_record,
 )
+from raft_ncup_tpu.ops import corr
 from raft_ncup_tpu.precision import sites
 from raft_ncup_tpu.utils import remat
 from raft_ncup_tpu.utils.flops import TPU_PEAK_FLOPS
@@ -339,16 +340,19 @@ def build_and_record(
     (``utils/profiling.timed_build``), the executable's costs and phases
     into ``ledger``, and the phases into the process's start-up record
     with the compile listener's totals as they stand now, the product
-    sites' summary and what the program's checkpoint policy saved by name
+    sites' summary, what the program's checkpoint policy saved by name and
+    the form each level of its ``volume`` lookup took
     (``observability/startup.py``). Returns the executable."""
     from raft_ncup_tpu.utils.profiling import compile_meter, timed_build
 
     kind = str(meta.get("kind", "custom"))
     sites.reset_product_sites()  # the lowering below traces the program
     remat.reset_saved_residuals()
+    corr.reset_contract_forms()
     compiled, phases = timed_build(hub, jitfn, args, key=key, kind=kind)
     traced = sites.product_sites()
     saved = remat.saved_residuals()
+    forms = corr.contract_forms()
     entry = ledger.record_compiled(
         key, compiled, backend=backend, phases=phases, **meta
     )
@@ -363,6 +367,9 @@ def build_and_record(
         # of a program that kept something across a checkpoint (the
         # training step): how many values under each name
         saved_residuals=saved if any(saved.values()) else None,
+        # of a program that looks a materialised pyramid up: the form
+        # and stored dtype of each level (``ops/corr.py::contract_form``)
+        contract_forms=forms or None,
     )
     return compiled
 

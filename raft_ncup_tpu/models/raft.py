@@ -296,15 +296,20 @@ class RAFT:
             coords1 = coords1 + flow_init
         return fmap1, fmap2, net, inp, coords1
 
-    def _build_corr_fn(self, fmap1, fmap2, mesh=None, spatial_axis="spatial"):
+    def _build_corr_fn(
+        self, fmap1, fmap2, mesh=None, spatial_axis="spatial", *, train=False
+    ):
         """Correlation-lookup closure over a micro-batch's feature maps,
-        per ``cfg.corr_impl`` (volume / onthefly / pallas)."""
+        per ``cfg.corr_impl`` (volume / onthefly / pallas). ``train``: the
+        program differentiates the lookup, which the ``volume`` path's
+        choice of a contraction needs to know (``ops/corr.py::contract_form``)."""
         cfg = self.cfg
         policy = self.policy
         radius = cfg.resolved_corr_radius
         if cfg.corr_impl == "volume":
             pyramid = build_corr_pyramid(
-                fmap1, fmap2, cfg.corr_levels, dtype=policy.corr_jnp
+                fmap1, fmap2, cfg.corr_levels, dtype=policy.corr_jnp,
+                differentiated=train,
             )
 
             def corr_fn(coords):
@@ -623,7 +628,9 @@ class RAFT:
             flow_init=flow_init, net_init=net_init, net_warm=net_warm,
             remat=train and remat,
         )
-        corr_fn = self._build_corr_fn(fmap1, fmap2, mesh, spatial_axis)
+        corr_fn = self._build_corr_fn(
+            fmap1, fmap2, mesh, spatial_axis, train=train
+        )
 
         B, H, W, _ = image1.shape
         coords0 = coords_grid(B, H // 8, W // 8)

@@ -93,6 +93,7 @@ class StartupRecord:
         compile_s: float, cache: str, probe_s: Optional[float] = None,
         process: Optional[dict] = None, precision: Optional[dict] = None,
         saved_residuals: Optional[dict] = None,
+        contract_forms: Optional[dict] = None,
     ) -> None:
         """One executable built: its two build phases, the persistent
         cache's verdict, the compile listener's process totals as they
@@ -102,7 +103,10 @@ class StartupRecord:
         "sites_f32"}``, from ``precision/sites.py``'s tally of the build's
         trace), and how many values the program's checkpoint policy kept
         under each of its names (``saved_residuals``: ``utils/remat.py``'s
-        tally of the same trace; the training step's). A key built again
+        tally of the same trace; the training step's), and which form each
+        level of its ``volume`` lookup took over which stored dtype
+        (``contract_forms``: ``ops/corr.py``'s tally of the same trace,
+        ``{"level0": "dot/bfloat16", ...}``). A key built again
         (an LRU eviction, a second run in one process) adds to its entry's
         seconds and takes the newest verdict."""
         with self._lock:
@@ -124,6 +128,8 @@ class StartupRecord:
                     entry["precision"] = dict(precision)
                 if saved_residuals is not None:
                     entry["saved_residuals"] = dict(saved_residuals)
+                if contract_forms is not None:
+                    entry["contract_forms"] = dict(contract_forms)
                 if probe_s is not None:
                     entry["probe_s"] = (entry["probe_s"] or 0.0) + float(probe_s)
             if process is not None:
@@ -184,7 +190,8 @@ def set_startup_record(record: Optional[StartupRecord]) -> Optional[StartupRecor
 def startup_report() -> dict:
     """``{"programs": [{"key", "kind", "trace_lower_s", "compile_s",
     "cache", "first_run_s", "precision": {"policy", "sites_bf16",
-    "sites_f32"}, "saved_residuals": {name: count}, ...}], "phases":
+    "sites_f32"}, "saved_residuals": {name: count}, "contract_forms":
+    {level: "form/dtype"}, ...}], "phases":
     {"weights_s", "input_start_s", "warmup_s"}, "process": {"programs_loaded",
     "cache_hits", "cache_misses", "compile_s"}, "dropped"}`` — a phase
     the process has not run reads ``None``."""

@@ -19,6 +19,21 @@ Two interchangeable implementations behind one signature:
   5.3 against 9.9 ms; 27x64, 46x96, 68x120, 136x240: 1.1-2.6x slower), so
   ``_window_contract`` chooses by that.
 
+  A program that is not differentiated contracts a narrower level that is
+  STORED narrow (bfloat16 under ``bf16_infer``) in a third order of the
+  same float32 sums, ``_tap_sums``: the multiply + reduce form pays a pass
+  of its own to widen such a level (the compiler fuses no producer into a
+  reduction over a broadcast, which reuses every element of the level K
+  times; 27x64 bfloat16 at batch 16: 6.1 ms an iteration where float32 at
+  batch 8 pays 1.8), and one reduction of K operands, a product with each
+  tap's weights, reuses nothing, reads the stored level once and widens it
+  in the pass: 2.7 ms; 13x32 1.9 -> 1.2; 6x16 0.74 -> 0.72, hence
+  ``TAP_SUMS_MIN_SIZE`` (root PERF.md section 6, PR 41-42). The training
+  step asks for the two forms above alone
+  (``build_corr_pyramid(differentiated=True)``): it traces its lookup
+  three times over, and its start pays for every form
+  (``contract_form``).
+
 - ``corr_lookup_onthefly`` never materializes the volume. Because the
   lookup bilinearly samples the volume over its *second* pair of spatial
   dims for a fixed query pixel, and correlation is linear in fmap2,
@@ -93,6 +108,19 @@ class CorrPyramid(NamedTuple):
     query_hw: tuple[int, int]
 
 
+class DifferentiatedCorrPyramid(CorrPyramid):
+    """The pyramid of a program that takes gradients through its lookup
+    (the training step; ``build_corr_pyramid(differentiated=True)``):
+    :func:`corr_lookup` contracts it in the two forms of
+    :func:`_window_contract` alone (:func:`contract_form`). A type and not
+    a field or an argument of the lookup: it stays what it is through
+    ``jit``, ``scan`` and ``jax.checkpoint``, where a flag in the tuple
+    would become an array, and the lookup keeps its three arguments for
+    whoever calls, wraps or replaces it."""
+
+    __slots__ = ()
+
+
 def _delta_window(radius: int, dtype=jnp.float32) -> jax.Array:
     """(K, K, 2) window offsets, K = 2r+1.
 
@@ -109,7 +137,8 @@ def _delta_window(radius: int, dtype=jnp.float32) -> jax.Array:
 
 
 def build_corr_pyramid(
-    fmap1: jax.Array, fmap2: jax.Array, num_levels: int = 4, dtype=None
+    fmap1: jax.Array, fmap2: jax.Array, num_levels: int = 4, dtype=None,
+    differentiated: bool = False,
 ) -> CorrPyramid:
     """Compute the all-pairs correlation volume and its average pyramid.
 
@@ -123,6 +152,8 @@ def build_corr_pyramid(
         f32 regardless (``preferred_element_type``); only storage
         narrows. ``corr_lookup`` widens the level again before its
         arithmetic, so coordinates never demote.
+      differentiated: the program takes gradients through the lookups of
+        this pyramid (:class:`DifferentiatedCorrPyramid`).
     """
     B, H, W, C = fmap1.shape
     dtype = dtype or jnp.float32
@@ -139,33 +170,91 @@ def build_corr_pyramid(
         n, q, h, w = levels[-1].shape
         pooled = avg_pool2(levels[-1].reshape(n * q, h, w, 1))
         levels.append(pooled.reshape(n, q, pooled.shape[1], pooled.shape[2]))
-    return CorrPyramid(levels=tuple(levels), query_hw=(H, W))
+    kind = DifferentiatedCorrPyramid if differentiated else CorrPyramid
+    return kind(levels=tuple(levels), query_hw=(H, W))
 
 
-def _axis_weights(centre: jax.Array, size: int, radius: int) -> jax.Array:
-    """Bilinear weights of the K = 2r+1 window taps along one axis:
-    window centres ``(...)`` -> ``(..., K, size)``.
-
-    Tap ``k`` sits at ``t = centre + k - r``; its row holds ``1 - d`` at
-    position ``floor(t)`` and ``d = t - floor(t)`` at ``floor(t) + 1`` —
-    the very numbers ``grid_sample`` multiplies its corner taps by — and
-    zeros elsewhere. A corner outside ``[0, size)`` matches no position,
-    which is ``padding_mode='zeros'`` without a mask.
-    """
-    taps = jnp.arange(-radius, radius + 1, dtype=centre.dtype)
-    t = centre[..., None] + taps  # (..., K)
+def _tap_row(t: jax.Array, size: int) -> jax.Array:
+    """Bilinear weights along one axis of taps at ``t`` ``(...)``: rows
+    ``(..., size)`` holding ``1 - d`` at position ``floor(t)`` and
+    ``d = t - floor(t)`` at ``floor(t) + 1`` — the very numbers
+    ``grid_sample`` multiplies its corner taps by — and zeros elsewhere. A
+    corner outside ``[0, size)`` matches no position, which is
+    ``padding_mode='zeros'`` without a mask."""
     t0 = jnp.floor(t)
     d = (t - t0)[..., None]
     t0 = t0[..., None]
-    pos = jnp.arange(size, dtype=centre.dtype)
+    pos = jnp.arange(size, dtype=t.dtype)
     return jnp.where(pos == t0, 1.0 - d, 0.0) + jnp.where(
         pos == t0 + 1.0, d, 0.0
     )
 
 
+def _axis_weights(centre: jax.Array, size: int, radius: int) -> jax.Array:
+    """:func:`_tap_row` of the K = 2r+1 window taps along one axis: window
+    centres ``(...)`` -> ``(..., K, size)``; tap ``k`` sits at
+    ``centre + k - r``."""
+    taps = jnp.arange(-radius, radius + 1, dtype=centre.dtype)
+    return _tap_row(centre[..., None] + taps, size)
+
+
 # The TPU's lane width: a level whose row fills whole lanes contracts its
 # x axis on the MXU (``_window_contract``).
 _LANES = 128
+
+# The smallest level (elements a query) that takes the tap sums (13x32
+# gains, 6x16 does not: module docstring), and the rows of it that keep the
+# multiply + reduce form (``_tap_sums``).
+TAP_SUMS_MIN_SIZE = 256
+_HEAD_ROWS = 2
+
+# Which form each level of the last traced lookup took: a trace-time tally
+# in the manner of ``precision/sites.py`` (reset before a program is
+# lowered and read after it: ``inference/costs.build_and_record``).
+_contract_forms: dict[str, str] = {}
+
+
+def reset_contract_forms() -> None:
+    _contract_forms.clear()
+
+
+def contract_forms() -> dict:
+    """``{"level0": "<form>/<stored dtype>", ...}`` of the ``volume`` lookup
+    traced since the last reset (:func:`contract_form` names the forms);
+    empty where the program has none (the ``onthefly`` and ``pallas``
+    paths)."""
+    return dict(sorted(_contract_forms.items()))
+
+
+def contract_form(level_hw, stored, computed, differentiated: bool) -> str:
+    """How a level of this shape, stored as ``stored`` and contracted in
+    ``computed``, is contracted (timings: module docstring):
+
+    - ``"dot"``: a row that fills whole lanes takes its x axis to the MXU;
+      the compiler widens a narrow level inside that fusion.
+    - ``"tap_sums"``: in a program that is not differentiated, a narrower
+      level stored narrow, of :data:`TAP_SUMS_MIN_SIZE` elements or more,
+      is summed a tap at a time, all taps in one pass over the level as
+      stored (:func:`_tap_sums`).
+    - ``"multiply_reduce"``: everything else, y first then x.
+    """
+    hl, wl = level_hw
+    if wl % _LANES == 0:
+        return "dot"
+    narrow = jnp.dtype(stored).itemsize < jnp.dtype(computed).itemsize
+    if (
+        narrow and not differentiated
+        and hl > _HEAD_ROWS and hl * wl >= TAP_SUMS_MIN_SIZE
+    ):
+        return "tap_sums"
+    return "multiply_reduce"
+
+
+def _contract_y(cols: jax.Array, ay: jax.Array) -> jax.Array:
+    """``cols`` (..., Hl, K_x), a level's rows already contracted over x,
+    with ``ay`` (..., K_y, Hl) -> (..., K_x, K_y)."""
+    ay_t = jnp.swapaxes(ay, -1, -2)  # (..., Hl, K_y)
+    return jnp.sum(cols[..., :, :, None] * ay_t[..., :, None, :], axis=-3)
 
 
 def _window_contract(vol: jax.Array, ax: jax.Array, ay: jax.Array) -> jax.Array:
@@ -184,12 +273,77 @@ def _window_contract(vol: jax.Array, ax: jax.Array, ay: jax.Array) -> jax.Array:
             precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=vol.dtype,
         )  # (..., Hl, K_x)
-        ay_t = jnp.swapaxes(ay, -1, -2)  # (..., Hl, K_y)
-        return jnp.sum(cols[..., :, :, None] * ay_t[..., :, None, :], axis=-3)
+        return _contract_y(cols, ay)
     # y first: y is the major axis of the layout XLA gives the volume, so
     # this is an accumulation of whole rows; then x over the K rows left.
     rows = jnp.sum(ay[..., :, :, None] * vol[..., None, :, :], axis=-2)
     return jnp.sum(ax[..., :, None, :] * rows[..., None, :, :], axis=-1)
+
+
+def _sum_each(operands: Sequence[jax.Array], axis: int) -> tuple:
+    """``operand.sum(axis)`` of each operand, as ONE reduction of them all:
+    the compiler reads what the operands share once, and fuses a widening
+    of it into that pass, which it refuses a ``sum`` over a broadcast (a
+    product of the level with all K taps' weights reuses every element of
+    the level K times, and a producer is not fused into such a consumer)."""
+    axis %= operands[0].ndim
+    zeros = tuple(jnp.zeros((), x.dtype) for x in operands)
+    return tuple(jax.lax.reduce(
+        tuple(operands), zeros,
+        lambda a, b: tuple(x + y for x, y in zip(a, b)), (axis,),
+    ))
+
+
+def _tap_sums(vol: jax.Array, centre: jax.Array, radius: int, wdt) -> jax.Array:
+    """:func:`_window_contract`'s y-first sums over a level ``vol`` (..., Hl,
+    Wl) as stored, narrower than ``wdt``, around ``centre`` (..., 2) as (x,
+    y): one product of the widened level with each tap's row of weights and
+    the K row sums in one pass (:func:`_sum_each`), then x the same way
+    over the K rows left. Every product and every sum is ``wdt``
+    arithmetic; nothing is rounded that the other form does not round.
+
+    The first :data:`_HEAD_ROWS` rows are contracted apart, x first, as a
+    multiply + reduce. They are two rows' work, and they decide how the
+    whole level is stored: the compiler lays a level out for a multiply +
+    reduce that reads it (queries in the lanes, no padding), and for a
+    reduction of several operands alone it keeps the rows-in-lanes layout
+    the pooling wrote, which pads a 64-wide row to 128 lanes and was
+    slower than what this form replaces (root PERF.md section 6, PR 41).
+
+    jax does not transpose a reduction of several operands, and a program
+    that differentiates its lookup says so where it builds its pyramid
+    (:class:`DifferentiatedCorrPyramid`). One that differentiates this all
+    the same gets :func:`_window_contract`'s tangents, and its sums with
+    them."""
+    Hl, Wl = vol.shape[-2:]
+
+    def plain(vol, centre):
+        return _window_contract(
+            vol.astype(wdt), _axis_weights(centre[..., 0], Wl, radius),
+            _axis_weights(centre[..., 1], Hl, radius),
+        )
+
+    @jax.custom_jvp
+    def sums(vol, centre):
+        ax = _axis_weights(centre[..., 0], Wl, radius)  # (..., K_x, Wl)
+        body = vol[..., _HEAD_ROWS:, :].astype(wdt)
+        # Each tap's row of weights is made where it is used: a slice of
+        # the stacked rows is a copy of all of them first.
+        rows = _sum_each([
+            _tap_row(centre[..., 1] + tap, Hl)[..., _HEAD_ROWS:][..., None] * body
+            for tap in range(-radius, radius + 1)
+        ], -2)  # K_y x (..., Wl)
+        out = jnp.stack(
+            _sum_each([ax * row[..., None, :] for row in rows], -1), axis=-1
+        )  # (..., K_x, K_y)
+        head = vol[..., :_HEAD_ROWS, :].astype(wdt)
+        cols = jnp.sum(head[..., :, None, :] * ax[..., None, :, :], axis=-1)
+        return out + _contract_y(
+            cols, _axis_weights(centre[..., 1], _HEAD_ROWS, radius)
+        )
+
+    sums.defjvp(lambda primals, tangents: jax.jvp(plain, primals, tangents))
+    return sums(vol, centre)
 
 
 def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Array:
@@ -203,7 +357,11 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Arr
     the selection is arithmetic.
 
     Args:
-      pyramid: from :func:`build_corr_pyramid`.
+      pyramid: from :func:`build_corr_pyramid`. One built for a program
+        that differentiates its lookup (the training step) keeps to the two
+        forms of :func:`_window_contract`, whose trace a step pays three
+        times over at every start; any other may take :func:`_tap_sums` too
+        (:func:`contract_form`).
       coords: (B, H, W, 2) query positions in fmap2 pixel coordinates.
     Returns:
       (B, H, W, L * (2r+1)^2) at the promoted (volume, coords) dtype —
@@ -213,18 +371,25 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Arr
     """
     B, H, W, _ = coords.shape
     K = 2 * radius + 1
+    differentiated = isinstance(pyramid, DifferentiatedCorrPyramid)
 
     out = []
     for lvl, corr in enumerate(pyramid.levels):
         _, _, Hl, Wl = corr.shape
         # A narrow-storage volume (bf16 under the precision policy) is
-        # widened here; the coordinates are never narrowed.
+        # widened for its contraction; the coordinates are never narrowed.
         wdt = jnp.promote_types(corr.dtype, coords.dtype)
         centre = coords.reshape(B, H * W, 2).astype(wdt) / (2**lvl)
-        ax = _axis_weights(centre[..., 0], Wl, radius)  # (B, HW, K, Wl)
-        ay = _axis_weights(centre[..., 1], Hl, radius)  # (B, HW, K, Hl)
         record_site(f"level{lvl}", wdt)
-        win = _window_contract(corr.astype(wdt), ax, ay)  # (B, HW, K_x, K_y)
+        form = contract_form((Hl, Wl), corr.dtype, wdt, differentiated)
+        _contract_forms[f"level{lvl}"] = f"{form}/{corr.dtype}"
+        if form == "tap_sums":
+            win = _tap_sums(corr, centre, radius, wdt)
+        else:
+            ax = _axis_weights(centre[..., 0], Wl, radius)  # (B, HW, K, Wl)
+            ay = _axis_weights(centre[..., 1], Hl, radius)  # (B, HW, K, Hl)
+            win = _window_contract(corr.astype(wdt), ax, ay)
+        # win: (B, HW, K_x, K_y)
         out.append(win.reshape(B, H, W, K * K))
     return jnp.concatenate(out, axis=-1)
 

@@ -14,7 +14,7 @@ from raft_ncup_tpu.ops import (
     corr_lookup,
     corr_lookup_onthefly,
 )
-from raft_ncup_tpu.ops.corr import CorrPyramid
+from raft_ncup_tpu.ops.corr import CorrPyramid, DifferentiatedCorrPyramid
 from raft_ncup_tpu.ops.geometry import grid_sample
 
 
@@ -135,6 +135,12 @@ def gather_lookup_oracle(pyramid, coords, radius):
     return jnp.concatenate(out, axis=-1)
 
 
+# The oracle tests call both sides as one program each: op by op on the
+# CPU the lookup alone is ~3 s a case, compiled 0.3 s.
+_lookup = jax.jit(corr_lookup, static_argnums=2)
+_oracle = jax.jit(gather_lookup_oracle, static_argnums=2)
+
+
 def _random_pyramid(seed, B, H, W, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     f1 = jnp.asarray(rng.standard_normal((B, H, W, 8)).astype(np.float32))
@@ -152,7 +158,7 @@ def _jittered_coords(seed, B, H, W, spread):
 def _assert_matches_oracle(ours, pyr, coords, radius):
     np.testing.assert_allclose(
         np.asarray(ours),
-        np.asarray(gather_lookup_oracle(pyr, coords, radius)),
+        np.asarray(_oracle(pyr, coords, radius)),
         atol=2e-6, rtol=1e-6,
     )
 
@@ -175,7 +181,7 @@ def test_lookup_matches_gather_oracle(hw, radius):
     B, (H, W) = 2, hw
     pyr = _random_pyramid(2, B, H, W)
     coords = _jittered_coords(3, B, H, W, 6)
-    ours = corr_lookup(pyr, coords, radius)
+    ours = _lookup(pyr, coords, radius)
     assert ours.dtype == jnp.float32
     assert ours.shape == (B, H, W, 4 * (2 * radius + 1) ** 2)
     _assert_matches_oracle(ours, pyr, coords, radius)
@@ -208,7 +214,7 @@ def test_lookup_window_edges_match_gather_oracle(case):
     H, W, radius = 11, 16, 4
     pyr = _random_pyramid(4, 1, H, W)
     coords = _special_coords(case, H, W)
-    ours = np.asarray(corr_lookup(pyr, coords, radius))
+    ours = np.asarray(_lookup(pyr, coords, radius))
     _assert_matches_oracle(ours, pyr, coords, radius)
     if case in ("outside", "far_outside"):
         assert not ours.any()  # padding_mode='zeros': an all-zero window
@@ -224,7 +230,7 @@ def test_lookup_widens_a_bf16_volume_to_float32(hw):
     pyr = _random_pyramid(5, B, H, W, dtype=jnp.bfloat16)
     assert all(lvl.dtype == jnp.bfloat16 for lvl in pyr.levels)
     coords = _jittered_coords(6, B, H, W, 3)
-    ours = corr_lookup(pyr, coords, radius)
+    ours = _lookup(pyr, coords, radius)
     assert ours.dtype == jnp.float32
     # Against the oracle on the SAME bf16 values: were the weights or the
     # products rounded to bf16 the gap would be ~1e-2, not ~1e-6.
@@ -251,14 +257,21 @@ def test_lookup_grad_wrt_levels_matches_gather_oracle(hw, radius):
             lookup(CorrPyramid(levels, pyr.query_hw), coords, radius) * cot
         )
 
-    ours = jax.grad(loss(corr_lookup))(pyr.levels)
-    theirs = jax.grad(loss(gather_lookup_oracle))(pyr.levels)
+    ours = jax.jit(jax.grad(loss(corr_lookup)))(pyr.levels)
+    theirs = jax.jit(jax.grad(loss(gather_lookup_oracle)))(pyr.levels)
     for lvl, (g, t) in enumerate(zip(ours, theirs)):
         assert g.shape == pyr.levels[lvl].shape
         assert np.asarray(t).any()
         np.testing.assert_allclose(
             np.asarray(g), np.asarray(t), atol=2e-6, rtol=1e-6
         )
+
+
+def _walk(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
 
 
 def _lookup_eqns(ambient_precision, hw):
@@ -269,14 +282,7 @@ def _lookup_eqns(ambient_precision, hw):
     with jax.default_matmul_precision(ambient_precision):
         closed = jax.make_jaxpr(lambda lv, c: corr_lookup(
             CorrPyramid(lv, pyr.query_hw), c, 4))(pyr.levels, coords)
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from walk(sub)
-
-    return list(walk(closed.jaxpr))
+    return list(_walk(closed.jaxpr))
 
 
 @pytest.mark.parametrize("ambient", ["bfloat16", "float32"])
@@ -301,3 +307,105 @@ def test_lookup_has_no_gather_and_pins_its_own_precision(hw, ambient):
                 jax.lax.Precision.HIGHEST
             }
             assert e.params["preferred_element_type"] == jnp.float32
+
+
+def _parent_lookup(pyramid, coords, radius):
+    """``corr_lookup`` as it stood before the forward-only contraction (PR
+    42), statement for statement: what a program that differentiates its
+    lookup, and any program over float32 levels, must still trace."""
+    from raft_ncup_tpu.ops.corr import _axis_weights, _window_contract
+
+    B, H, W, _ = coords.shape
+    K = 2 * radius + 1
+    out = []
+    for lvl, corr in enumerate(pyramid.levels):
+        _, _, Hl, Wl = corr.shape
+        wdt = jnp.promote_types(corr.dtype, coords.dtype)
+        centre = coords.reshape(B, H * W, 2).astype(wdt) / (2**lvl)
+        ax = _axis_weights(centre[..., 0], Wl, radius)
+        ay = _axis_weights(centre[..., 1], Hl, radius)
+        win = _window_contract(corr.astype(wdt), ax, ay)
+        out.append(win.reshape(B, H, W, K * K))
+    return jnp.concatenate(out, axis=-1)
+
+
+# (level rows x columns, stored dtype) -> the form a forward-only program
+# contracts it in (``ops/corr.py::contract_form``): odd sizes, a width that
+# fills whole lanes and widths that do not, both sides of
+# ``TAP_SUMS_MIN_SIZE`` (7x40 = 280 elements, 9x24 = 216).
+FORWARD_ONLY_FORMS = {
+    ((27, 64), jnp.bfloat16): "tap_sums",
+    ((13, 32), jnp.bfloat16): "tap_sums",
+    ((7, 40), jnp.bfloat16): "tap_sums",
+    ((9, 24), jnp.bfloat16): "multiply_reduce",
+    ((5, 128), jnp.bfloat16): "dot",
+    ((27, 64), jnp.float32): "multiply_reduce",
+    ((5, 128), jnp.float32): "dot",
+}
+
+
+@pytest.mark.parametrize(
+    "level_hw,stored", list(FORWARD_ONLY_FORMS),
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else jnp.dtype(v).name,
+)
+def test_forward_only_contraction_is_the_parents_sums(level_hw, stored):
+    """A level stored narrower than the coordinates is contracted, in a
+    program that is not differentiated, a tap at a time in one pass over
+    the level as stored (``_tap_sums``): the same float32 products and
+    sums as ``_window_contract``'s, to the rounding of another order of
+    addition, centres outside the level included. A float32 level, a level
+    under the size, and every level of a pyramid built for a program that
+    differentiates its lookup trace the parent's jaxpr; differentiating the
+    forward-only form all the same gives the parent's gradient."""
+    from raft_ncup_tpu.ops import corr
+
+    (Hl, Wl), radius, (h, w) = level_hw, 4, (3, 5)
+    rng = np.random.default_rng(11)
+    levels = (
+        jnp.asarray(rng.standard_normal((2, h * w, Hl, Wl)).astype(np.float32)).astype(stored),
+    )
+    xy = np.stack([
+        rng.uniform(-radius - 3.0, Wl + radius + 3.0, (2, h, w)),
+        rng.uniform(-radius - 3.0, Hl + radius + 3.0, (2, h, w)),
+    ], axis=-1).astype(np.float32)
+    xy[0, 0, 0], xy[0, 0, 1] = (-80.0, -80.0), (1e6, -1e6)  # no tap inside
+    xy[0, 0, 2] = (Wl - 0.5, Hl - 0.5)  # the far corner
+    coords = jnp.asarray(xy)
+    form = FORWARD_ONLY_FORMS[level_hw, stored]
+
+    def plain(lv, c):
+        return corr.corr_lookup(CorrPyramid(lv, (h, w)), c, radius)
+
+    def training(lv, c):
+        return corr.corr_lookup(DifferentiatedCorrPyramid(lv, (h, w)), c, radius)
+
+    def parent(lv, c):
+        return _parent_lookup(CorrPyramid(lv, (h, w)), c, radius)
+
+    corr.reset_contract_forms()
+    traced = jax.make_jaxpr(plain)(levels, coords)
+    assert corr.contract_forms() == {"level0": f"{form}/{jnp.dtype(stored).name}"}
+    theirs = jax.make_jaxpr(parent)(levels, coords)
+    assert str(jax.make_jaxpr(training)(levels, coords)) == str(theirs)
+    recorded = corr.contract_forms()["level0"]
+    assert not recorded.startswith("tap_sums")
+    assert (str(traced) == str(theirs)) == (form != "tap_sums")
+    # float32 arithmetic, whatever the level is stored in
+    for eqn in _walk(traced.jaxpr):
+        if eqn.primitive.name in ("mul", "add", "reduce", "reduce_sum", "dot_general"):
+            assert {v.aval.dtype for v in eqn.invars} <= {jnp.dtype(jnp.float32)}, eqn
+
+    ours, want = jax.jit(plain)(levels, coords), jax.jit(parent)(levels, coords)
+    assert ours.dtype == jnp.float32 and np.asarray(want).any()
+    assert not np.asarray(ours)[0, 0, :2].any()  # padding_mode='zeros'
+    np.testing.assert_allclose(np.asarray(ours), np.asarray(want), atol=2e-6, rtol=0)
+    if form == "tap_sums":
+        cot = jnp.asarray(rng.standard_normal(want.shape).astype(np.float32))
+        g_ours, g_want = (
+            jax.jit(jax.grad(lambda lv: jnp.sum(f(lv, coords) * cot)))(levels)[0]
+            for f in (plain, parent)
+        )
+        assert g_ours.dtype == stored
+        np.testing.assert_array_equal(
+            np.asarray(g_ours.astype(jnp.float32)), np.asarray(g_want.astype(jnp.float32))
+        )

@@ -524,6 +524,64 @@ class TestTrainParity:
         # Sentinel arithmetic untouched by the preset.
         assert state16.sentinel["ema_grad_norm"].dtype == jnp.float32
 
+    @pytest.mark.parametrize("program,precision,forms", [
+        ("step", "bf16_train", {"multiply_reduce/bfloat16"}),
+        ("step", "f32", {"multiply_reduce/float32"}),
+        ("forward", "f32", {"multiply_reduce/float32"}),
+        # the control: at this shape a forward-only program over a narrow
+        # volume does take the other form, at level 0 (16x24; 8x12 is under
+        # the size)
+        ("forward", "bf16_infer", {"tap_sums/bfloat16", "multiply_reduce/bfloat16"}),
+    ])
+    def test_only_a_forward_only_narrow_volume_takes_the_tap_sums(
+        self, program, precision, forms
+    ):
+        """The ``volume`` lookup's forward-only contraction
+        (``ops/corr.py::_tap_sums``, PR 42) is traced by programs that are
+        not differentiated and store a level narrower than they contract it
+        in, and by no other: the training step traces its lookup three
+        times over and pays for every form at every start (PR 41: +7.6 s of
+        warm set-up), so under either preset it keeps the forms it had, as
+        every float32 program does. Lowered, not compiled."""
+        from raft_ncup_tpu.ops import corr
+        from raft_ncup_tpu.parallel.step import make_train_step
+        from raft_ncup_tpu.training.state import create_train_state
+
+        hw, batch = (128, 192), 1
+        model_cfg = small_model_config("raft", dataset="chairs", precision=precision)
+        images = jax.ShapeDtypeStruct((batch, *hw, 3), jnp.float32)
+        if program == "step":
+            train_cfg = TrainConfig(
+                stage="chairs", batch_size=batch, image_size=hw, iters=ITERS,
+                num_steps=5, precision=precision,
+            )
+            state = jax.eval_shape(lambda: create_train_state(
+                jax.random.PRNGKey(7), model_cfg, train_cfg, image_shape=(1, *HW, 3),
+            )[1])
+            model = RAFT(model_cfg)
+            data = {
+                "image1": images, "image2": images,
+                "flow": jax.ShapeDtypeStruct((batch, *hw, 2), jnp.float32),
+                "valid": jax.ShapeDtypeStruct((batch, *hw), jnp.float32),
+            }
+            program, args = make_train_step(model, train_cfg), (
+                state, data, jax.random.PRNGKey(0)
+            )
+        else:
+            model = RAFT(model_cfg)
+            variables = jax.eval_shape(
+                lambda: model.init(jax.random.PRNGKey(0), (1, *HW, 3))
+            )
+            program, args = jax.jit(
+                lambda v, a, b: model.apply(v, a, b, iters=ITERS, test_mode=True)
+            ), (variables, images, images)
+        corr.reset_contract_forms()  # what ``init`` traced is not the program's
+        program.lower(*args)
+        traced = corr.contract_forms()
+        assert sorted(traced) == [f"level{lvl}" for lvl in range(4)]
+        assert set(traced.values()) == forms
+        assert traced["level0"] == max(forms)
+
     def test_step_cache_keys_on_precision(self):
         """make_train_step memoization cannot hand a bf16 config the f32
         executable: the model config (which carries `precision`) is in

@@ -36,7 +36,6 @@ whole programs come first in the file, the kernel cases, seconds each,
 last: they are what is left when the worker asks for its next file.
 """
 
-import math
 import re
 from typing import NamedTuple
 
@@ -45,6 +44,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from raft_ncup_tpu.ops import corr
 from raft_ncup_tpu.ops import corr_pallas as cpk
 from raft_ncup_tpu.ops import nconv_pallas as npk
 
@@ -135,11 +135,14 @@ class Program(NamedTuple):
 
     text: str  # the optimised module
     temp_gib: float  # memory_analysis().temp_size_in_bytes
+    contract_forms: dict  # ops/corr.py's tally of the program's trace
 
 
 def _program(compiled) -> Program:
+    """Called where the program was traced last: every `volume` lookup
+    writes all four levels of the tally."""
     temp = compiled.memory_analysis().temp_size_in_bytes
-    return Program(compiled.as_text(), temp / 2**30)
+    return Program(compiled.as_text(), temp / 2**30, corr.contract_forms())
 
 
 @pytest.fixture(scope="module")
@@ -415,6 +418,16 @@ def test_sintel_train_step_backward_loop_runs_no_second_lookup_contraction(
         r"rematted_computation/\S*weights_est_net/conv\d+/conv_general_dilated", line)]
 
 
+def test_sintel_train_step_traces_no_forward_only_contraction(train_program):
+    """The step differentiates its lookup and says so
+    (`build_corr_pyramid(differentiated=True)`): every level keeps the form it had
+    before PR 42, whatever it is stored in, and the step's lowered module
+    is the parent's letter for letter (hashes: PERF.md section 6, PR 42)."""
+    assert train_program.contract_forms == {
+        f"level{lvl}": "multiply_reduce/float32" for lvl in range(4)
+    }
+
+
 def test_sintel_train_step_temporaries_stay_under_8_gib(
     train_program, record_property
 ):
@@ -486,16 +499,27 @@ def test_eval_bf16_cell_forward_widens_level_0_inside_its_contraction(
 ):
     """What decides the lookup's cost at one pass. The volume is stored in
     bfloat16 and the lookup computes in float32 (P7), so every level is
-    widened every iteration. In this program level 0 (16 x 7040 x 55 x 128:
-    3.17 GB as float32) is NOT widened into a buffer of its own: no
-    instruction of the module has that float32 shape, and the loop's x
-    contraction is one `convolution` fusion that reads the bfloat16 level
-    and writes `f32[16,7040,55,9]`, half the bytes a pair that the float32
-    program reads. (The training step's 46x96 grid takes the multiply +
-    reduce form and materialises the widening as a copy, PERF.md section 7.)
-    Levels 1-3 are widened by `convert`s of their own inside the loop,
-    1.01 GB written and read again an iteration: recorded, not asserted
-    (fusing them is a gain)."""
+    widened every iteration, and since PR 42 none of levels 0-2 into a
+    buffer of its own (until then levels 1-3 were: 1.01 GB written and read
+    again an iteration). Level 0 (16 x 7040 x 55 x 128: 3.17 GB as
+    float32): no instruction of the module has that float32 shape, and the
+    loop's x contraction is one `convolution` fusion that reads the
+    bfloat16 level and writes `f32[16,7040,55,9]`. Levels 1-2 take the tap
+    sums (`ops/corr.py::_tap_sums`): one reduction of nine operands reads
+    `bf16[16,7040,27,64]` / `[13,32]` as stored, queries in the lanes
+    (`{1,3,2,0}`: the layout the two head rows' multiply + reduce asks for;
+    rows in the lanes would pad 64 to 128), and no float32 tensor of a
+    level's size exists in the loop; the two head rows are widened by a
+    small fusion of their own (`f32[16,7040,2,64]`). Level 3 (96 elements a
+    query, under `TAP_SUMS_MIN_SIZE`) keeps the multiply + reduce form and
+    the only `convert` that is an instruction of the loop body itself:
+    0.043 GB an iteration. (The training step keeps the multiply + reduce
+    form at every level and materialises its widenings, PERF.md section
+    7.)"""
+    assert eval_bf16_program.contract_forms == {
+        "level0": "dot/bfloat16", "level1": "tap_sums/bfloat16",
+        "level2": "tap_sums/bfloat16", "level3": "multiply_reduce/bfloat16",
+    }
     text = eval_bf16_program.text
     assert "f32[16,7040,55,128]" not in text
     loops = _loop_computations(text)
@@ -505,13 +529,29 @@ def test_eval_bf16_cell_forward_widens_level_0_inside_its_contraction(
         and re.search(r"ROOT \S+ = f32\[16,7040,55,9\]\S* convolution\(", body)
     ]
     assert len(contraction) == 1
-    widened = sorted({
-        shape for body in loops.values() for shape in
-        re.findall(r"= (f32\[16,7040,\d+,\d+\])\S* convert\(", body)
-    })
-    record_property("levels_widened_by_a_convert_in_the_loop", widened)
-    gib = sum(4 * math.prod(_dims(shape)) for shape in widened) / 2**30
-    record_property("their_float32_gib_an_iteration", round(gib, 3))
+    in_loop = "\n".join(loops.values())
+    for level in ("27,64", "13,32"):
+        assert f"f32[16,7040,{level}]" not in in_loop
+        (tap_sums,) = [
+            b for b in loops.values()
+            if re.search(rf"param_\S+ = bf16\[16,7040,{level}\]\{{1,3,2,0", b)
+            and re.search(r"ROOT \S+ = \(f32\[16,7040,\d+\]\S*(, (/\*index=\d+\*/)?f32\[16,7040,\d+\]\S*){8}\) reduce\(", b)
+        ]
+    # a `convert` that is a fusion's own instruction is part of that fusion's
+    # pass; one that is an instruction of the loop body writes its result
+    (body,) = [
+        loops[name] for name in re.findall(r"body=(%[\w.\-]+)", text)
+        if "bf16[16,7040,27,64]" in loops[name]
+    ]
+    standalone = sorted(re.findall(r"= (f32\[16,7040,\d+,\d+\])\S* convert\(", body))
+    assert standalone == ["f32[16,7040,6,16]"]
+    # nor does any instruction of the body write a level, or a level less
+    # its head rows, as float32
+    assert not set(re.findall(r"= (f32\[16,7040,\d+,\d+\])", body)) & {
+        f"f32[16,7040,{rows},{cols}]"
+        for rows, cols in ((27, 64), (25, 64), (13, 32), (11, 32))
+    }
+    record_property("levels_widened_by_a_convert_of_their_own", standalone)
     assert " gather(" not in text
     assert _gru_gate_convolutions(text) == {(False, 128): 6, (True, 256): 6}
 
