@@ -152,6 +152,51 @@ def _pad_host(pad_spec, *arrays: np.ndarray) -> list[np.ndarray]:
     return [np.pad(x, spec, mode="edge") for x in arrays]
 
 
+def _stack(arrays: list, dtype) -> np.ndarray:
+    """``np.stack`` straight into ``dtype``: one write of the batch, where
+    ``np.stack(...).astype(...)`` writes it twice."""
+    return np.stack(arrays, dtype=dtype, casting="unsafe")
+
+
+def _stage_batch(
+    group: list, *, pad_mode: Optional[str] = None, divisor: int = 8,
+    bucket: int = 0, with_valid: bool = False, band_fn=None,
+) -> tuple[dict, Optional[tuple]]:
+    """Validation's one staging convention: ``(arrays, pad_spec)`` of a
+    group of samples, at the width the dataset hands them.
+
+    Frames that arrive uint8 (the datasets' contract, data/datasets.py;
+    the benchmark's pool) are stacked, padded and later copied AS uint8:
+    edge replication of uint8 is the same pixels, and the widening to
+    float32 is the first thing ``ShapeCachedForward.metrics``'s program
+    does, exact, so the encoders read the values they always read at a
+    quarter of the host's writes and of the copy. A batch with a frame of
+    any other dtype is staged float32, as it always was: the form follows
+    what the samples hold, nothing selects it. ``flow`` / ``valid`` /
+    ``band`` are float32 at native shape, written once. ``pad_mode`` None
+    skips padding (``pad_spec`` None)."""
+    frames = [s[k] for s in group for k in ("image1", "image2")]
+    narrow = all(np.asarray(f).dtype == np.uint8 for f in frames)
+    width = np.uint8 if narrow else np.float32
+    img1 = _stack([s["image1"] for s in group], width)
+    img2 = _stack([s["image2"] for s in group], width)
+    arrays = {"flow": _stack([s["flow"] for s in group], np.float32)}
+    if with_valid:
+        arrays["valid"] = _stack([s["valid"] for s in group], np.float32)
+    if band_fn is not None:
+        arrays["band"] = _stack(
+            [band_fn(s["flow"]) for s in group], np.float32
+        )
+    pad = None
+    if pad_mode is not None:
+        pad = InputPadder(
+            img1.shape, mode=pad_mode, divisor=divisor, bucket=bucket
+        ).pad_spec
+        img1, img2 = _pad_host(pad, img1, img2)
+    arrays["image1"], arrays["image2"] = img1, img2
+    return arrays, pad
+
+
 def _run_metric_pass(
     fwd: ShapeCachedForward,
     dataset,
@@ -177,7 +222,9 @@ def _run_metric_pass(
     ``pad_mode`` None skips padding (chairs/synthetic shapes are already
     stride-aligned); otherwise images pad host-side on the staging
     thread and the static pad spec rides the batch meta so the jitted
-    program crops predictions in-graph (metrics.unpad_in_graph).
+    program crops predictions in-graph (metrics.unpad_in_graph). Frames
+    are staged and copied at the dataset's own width (uint8 stays uint8,
+    :func:`_stage_batch`); the metrics program widens them.
     ``band_fn`` (epe_band only) computes the host-side boundary mask
     during staging. Returns the host accumulator (float32 sums, ready
     for ``allreduce_sum_across_hosts`` + ``metrics.finalize``).
@@ -189,7 +236,10 @@ def _run_metric_pass(
     (the bounded wait for an earlier batch), and once ``eval_pull`` (the
     pass's one pull, which waits for the device to finish). The counter
     ``eval_pairs_total`` grows by the batch's pairs where
-    ``eval_dispatch`` closes. After the pull the pass publishes which
+    ``eval_dispatch`` closes, ``eval_input_bytes_total`` by the staged
+    batch's host bytes (frames, ground truth, masks: what ``input_h2d``
+    then copies) where it is staged; bytes a pair says which width the
+    frames went at. After the pull the pass publishes which
     precision its executable ran at (:func:`_publish_precision`).
     """
     tel = telemetry if telemetry is not None else get_telemetry()
@@ -197,27 +247,13 @@ def _run_metric_pass(
     divisor = _pad_divisor(mesh)
 
     def stage(group: list) -> tuple:
-        img1 = np.stack([s["image1"] for s in group]).astype(np.float32)
-        img2 = np.stack([s["image2"] for s in group]).astype(np.float32)
-        arrays = {
-            "flow": np.stack([s["flow"] for s in group]).astype(np.float32)
-        }
-        if with_valid:
-            arrays["valid"] = np.stack(
-                [s["valid"] for s in group]
-            ).astype(np.float32)
-        if band_fn is not None:
-            arrays["band"] = np.stack(
-                [band_fn(s["flow"]) for s in group]
-            ).astype(np.float32)
-        pad = None
-        if pad_mode is not None:
-            padder = InputPadder(
-                img1.shape, mode=pad_mode, divisor=divisor, bucket=bucket
-            )
-            pad = padder.pad_spec
-            img1, img2 = _pad_host(pad, img1, img2)
-        arrays["image1"], arrays["image2"] = img1, img2
+        arrays, pad = _stage_batch(
+            group, pad_mode=pad_mode, divisor=divisor, bucket=bucket,
+            with_valid=with_valid, band_fn=band_fn,
+        )
+        tel.inc(
+            "eval_input_bytes_total", sum(a.nbytes for a in arrays.values())
+        )
         return arrays, {"pad": pad}
 
     shardings = None
@@ -331,19 +367,13 @@ def _run_warmstart_metric_pass(
             sequence = sequence_of(s)
             if sequence != seq_prev:
                 flow_prev = None
-            img1 = np.asarray(s["image1"], np.float32)[None]
-            img2 = np.asarray(s["image2"], np.float32)[None]
-            gt = np.asarray(s["flow"], np.float32)[None]
-            padder = InputPadder(img1.shape, mode=pad_mode)
-            pad = padder.pad_spec
-            img1, img2 = _pad_host(pad, img1, img2)
+            batch, pad = _stage_batch([s], pad_mode=pad_mode)
             if flow_prev is None:
                 # Cold frames reuse the warm executable with a zero
                 # init (coords + 0 is bitwise the cold start), so the
                 # whole pass is ONE program per shape.
-                h8, w8 = img1.shape[1] // 8, img1.shape[2] // 8
-                flow_prev = jnp.zeros((1, h8, w8, 2), jnp.float32)
-            batch = {"image1": img1, "image2": img2, "flow": gt}
+                _, h, w, _ = batch["image1"].shape
+                flow_prev = jnp.zeros((1, h // 8, w // 8, 2), jnp.float32)
             acc, flow_lr = fwd.metrics(
                 batch, iters=iters, acc=acc, kind=kind, pad=pad,
                 flow_init=flow_prev,

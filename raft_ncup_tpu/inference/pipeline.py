@@ -65,6 +65,14 @@ from raft_ncup_tpu.utils.profiling import annotate_spans, compile_meter
 
 _EXEC_CANON = LEGACY_KEY_ALIASES["inference"]
 
+# FRAME_DTYPE: what ``RAFT.apply`` takes its frames as, float32 in
+# [0, 255], under every PrecisionPolicy preset: the model normalises them
+# first and the policy's compute cast comes after (models/raft.py), so no
+# preset names this dtype and none narrows it. A metrics program widens
+# the frames a pass staged narrower (uint8 rows) to it; for float32 frames
+# the cast traces to nothing.
+FRAME_DTYPE = jnp.float32
+
 
 def env_earlyexit_tol() -> Optional[float]:
     """Resolve the early-exit knobs (utils/knobs.py; docs/PERF.md "Early
@@ -519,12 +527,13 @@ class ShapeCachedForward:
             return meta
         if key and key[0] == "metrics":
             # ("metrics", img_shape, flow_shape, extras, iters, kind,
-            #  pad, warm, policy_fp) — policy distinguishes the f32 and
-            # bf16 twins of one shape (they are different executables
-            # with different XLA flops; a meta lookup must not conflate
-            # them).
+            #  pad, warm, frame_dtypes, policy_fp) — policy distinguishes
+            # the f32 and bf16 twins of one shape (they are different
+            # executables with different XLA flops; a meta lookup must
+            # not conflate them), ``frames`` the uint8-fed and the
+            # float32-fed one.
             return {"kind": "metrics", "shape": key[1], "iters": key[4],
-                    "policy": key[8]}
+                    "frames": key[8], "policy": key[9]}
         if key and key[0] == "custom":
             # Pipeline programs (inference/pipe_schedule.py) get full
             # structured identity: the tick's segment count rides into
@@ -796,6 +805,15 @@ class ShapeCachedForward:
         static ``InputPadder.pad_spec``. Returns the updated accumulator
         (device-resident). No flow field ever reaches the host.
 
+        The frames come at the width the pass staged them
+        (evaluation._stage_batch: uint8 from every dataset) and the
+        program's first act is their widening to float32, exact, so the
+        model reads its float32 [0, 255] contract either way. The frames'
+        dtypes close the executable's key (the first call AOT-compiles
+        for its avals: a uint8 batch must not meet the float32
+        executable of its shape); for float32 frames the cast traces to
+        nothing and the program is the one it always was.
+
         ``flow_init`` (warm-start validation): a device-resident
         (B, H/8, W/8, 2) initial low-res flow; when given the program
         additionally returns the final low-res flow so the caller can
@@ -824,11 +842,18 @@ class ShapeCachedForward:
             kind,
             pad,
             warm,
+            # Behind the fields the cost ledger's meta and the benchmark's
+            # roofline reader find by place, before the policy's name,
+            # which stays the key's last word.
+            tuple(str(batch[k].dtype) for k in ("image1", "image2")),
             pol.fingerprint(),
         )
 
         def build():
             mesh = self.mesh
+
+            def widen(i1, i2):
+                return i1.astype(FRAME_DTYPE), i2.astype(FRAME_DTYPE)
 
             if warm:
 
@@ -845,7 +870,7 @@ class ShapeCachedForward:
                         )
 
                     flow_lr, acc_out = model.apply(
-                        v, i1, i2, iters=iters, flow_init=finit,
+                        v, *widen(i1, i2), iters=iters, flow_init=finit,
                         test_mode=True, mesh=mesh, metric_head=head,
                     )
                     return acc_out, flow_lr
@@ -865,8 +890,8 @@ class ShapeCachedForward:
                     )
 
                 _, acc_out = model.apply(
-                    v, i1, i2, iters=iters, test_mode=True, mesh=mesh,
-                    metric_head=head,
+                    v, *widen(i1, i2), iters=iters, test_mode=True,
+                    mesh=mesh, metric_head=head,
                 )
                 return acc_out
 
