@@ -197,12 +197,10 @@ def _axes_in_tree(tree, aliases) -> set:
 
 def _axis_literals(node) -> set:
     """Literal axis-name strings reachable from one ``Mesh`` axis-names
-    expression. Descends conditional expressions — the production
-    declarer (parallel/mesh.py) declares its pipeline axis as
-    ``("data", "spatial", "pipe") if pipe > 1 else ("data", "spatial")``
-    and BOTH branches are real declarations (whichever the runtime
-    picks, a PartitionSpec naming 'pipe' is judged against a mesh that
-    can legally carry it)."""
+    expression. Descends conditional expressions: a declarer that picks
+    between two axis tuples in one call declares both (whichever the
+    runtime picks, a PartitionSpec naming an axis of either branch is
+    judged against a mesh that can legally carry it)."""
     out: set = set()
     if isinstance(node, ast.IfExp):
         out |= _axis_literals(node.body)

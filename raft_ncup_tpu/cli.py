@@ -54,8 +54,6 @@ _UPSAMPLER_CLASSES = {
     # reference class names (core/upsampler.py) -> our registry kinds
     "NConvUpsampler": "nconv",
     "Bilinear": "bilinear",
-    "PacJointUpsampleFull": "pac",
-    "DjifOriginal": "djif",
 }
 
 
@@ -172,25 +170,24 @@ def str2ints(v: str) -> tuple[int, ...]:
 
 
 def str2mesh(v: str) -> tuple[int, ...]:
-    """Parse the ``--mesh DATA,SPATIAL[,PIPE]`` device-mesh spec."""
+    """Parse the ``--mesh DATA,SPATIAL`` device-mesh spec."""
     out = str2ints(v)
-    if len(out) not in (2, 3) or any(x < 1 for x in out):
+    if len(out) != 2 or any(x < 1 for x in out):
         raise argparse.ArgumentTypeError(
-            f"mesh spec must be DATA,SPATIAL[,PIPE] positive sizes: {v!r}"
+            f"mesh spec must be DATA,SPATIAL, two positive sizes: {v!r}"
         )
     return out
 
 
 def add_mesh_arg(parser: argparse.ArgumentParser) -> None:
-    """The (data x spatial[, pipe]) SPMD mesh flag shared by evaluate.py
-    and serve.py (docs/SHARDING.md)."""
+    """The (data x spatial) SPMD mesh flag shared by evaluate.py and
+    serve.py (docs/SHARDING.md)."""
     parser.add_argument(
-        "--mesh", type=str2mesh, default=None, metavar="DATA,SPATIAL[,PIPE]",
+        "--mesh", type=str2mesh, default=None, metavar="DATA,SPATIAL",
         help="run the inference/serving stack sharded on a "
-        "(data x spatial[, pipe]) device mesh, e.g. '1,2' or '1,1,2' "
-        "(docs/SHARDING.md). Batches shard over data, image height over "
-        "spatial; pads round up to 8*spatial. A third element adds the "
-        "iteration-pipeline axis (\"Pipeline axis\"). Default: unsharded.",
+        "(data x spatial) device mesh, e.g. '1,2' (docs/SHARDING.md). "
+        "Batches shard over data, image height over spatial; pads round "
+        "up to 8*spatial. Default: unsharded.",
     )
 
 
@@ -201,8 +198,8 @@ def mesh_from_args(args: argparse.Namespace):
         return None
     from raft_ncup_tpu.parallel.mesh import make_mesh
 
-    pipe = spec[2] if len(spec) == 3 else 1
-    return make_mesh(data=spec[0], spatial=spec[1], pipe=pipe)
+    data, spatial = spec
+    return make_mesh(data=data, spatial=spatial)
 
 
 def add_serve_args(parser: argparse.ArgumentParser) -> None:

@@ -33,7 +33,7 @@ class UpsamplerConfig:
     constructor flags (reference: train_raft_nc_things.sh:31-50).
     """
 
-    # 'nconv' (NCUP), 'bilinear', 'pac', 'djif'. The RAFT baseline's convex
+    # 'nconv' (NCUP) or 'bilinear'. The RAFT baseline's convex
     # upsampler is part of the model itself, not this registry — as in the
     # reference (core/raft.py:73-84).
     kind: str = "nconv"
@@ -259,25 +259,22 @@ class DataConfig:
 
 
 def _check_mesh_field(mesh, batch_sizes: tuple, pad_bucket: int = 0) -> None:
-    """Shared (data, spatial[, pipe]) mesh-field validation for the
+    """Shared (data, spatial) mesh-field validation for the
     serving and streaming configs: jit's in_shardings require every
     allowed batch size to divide the `data` axis, and under a mesh
     every pad rounds to 8*spatial, so an explicit ``pad_bucket`` must
     be a multiple of that divisor (InputPadder rejects the combination
     per call — a violation must be a clear error at config time, not
     an exception escaping FlowServer.submit() past the terminal-status
-    contract). An optional third element is the ``pipe`` axis
-    (parallel/mesh.py; docs/SHARDING.md "Pipeline axis") — it shards
-    neither the batch nor the image dims, so it adds nothing to either
-    rule here."""
+    contract)."""
     if mesh is None:
         return
     m = tuple(int(x) for x in mesh)
-    if len(m) not in (2, 3) or any(x < 1 for x in m):
+    if len(m) != 2 or any(x < 1 for x in m):
         raise ValueError(
-            f"mesh must be (data, spatial[, pipe]) positive sizes: {mesh!r}"
+            f"mesh must be two positive sizes, (data, spatial): {mesh!r}"
         )
-    data, spatial = m[0], m[1]
+    data, spatial = m
     bad = [b for b in batch_sizes if b % data]
     if bad:
         raise ValueError(
@@ -355,14 +352,13 @@ class ServeConfig:
     # None (default) inherits the model's own policy — a server wrapped
     # around a bf16-configured model serves bf16 unless told otherwise.
     precision: str | None = None
-    # (data, spatial[, pipe]) device-mesh sizes (docs/SHARDING.md): the
+    # (data, spatial) device-mesh sizes (docs/SHARDING.md): the
     # server's whole executable set compiles as SPMD programs over this
     # mesh — request batches shard over `data`, image height over
     # `spatial` (pads round up to 8*spatial so the 1/8-res feature
-    # height divides the spatial axis); an optional third size is the
-    # iteration-pipeline axis (docs/SHARDING.md "Pipeline axis"), which
-    # shards neither. The mesh fingerprint rides every compiled-program
-    # key. None (default) = unsharded single-device serving.
+    # height divides the spatial axis). The mesh fingerprint rides every
+    # compiled-program key. None (default) = unsharded single-device
+    # serving.
     mesh: tuple[int, ...] | None = None
 
     def __post_init__(self) -> None:
@@ -459,7 +455,7 @@ class StreamConfig:
     # policy's pinned f32 coord dtype in-graph. None (default) inherits
     # the model's own policy.
     precision: str | None = None
-    # (data, spatial[, pipe]) device-mesh sizes (docs/SHARDING.md): the
+    # (data, spatial) device-mesh sizes (docs/SHARDING.md): the
     # step programs compile as SPMD over this mesh — frame batches shard
     # over `data`, frame height over `spatial`, and the slot table
     # shards over `data` when (capacity + 1) divides it (else it

@@ -234,22 +234,6 @@ class CostLedger:
         else:
             entry["compile_ms"] = None
         entry["meta"] = {k: v for k, v in meta.items() if v is not None}
-        # Pipelined executables (meta carries segments > 1, set by the
-        # pipe_tick key parse in pipeline._ledger_meta): derive the
-        # per-segment split of the whole-tick costs. One tick runs all
-        # S segments concurrently (one per device group), so per-stage
-        # work is total/S — the figure flip_recommendations compares
-        # against the monolithic scan's cost to judge pipeline balance.
-        segs = entry["meta"].get("segments")
-        if isinstance(segs, int) and segs > 1:
-            entry["flops_per_segment"] = (
-                None if entry["flops"] is None
-                else entry["flops"] / segs
-            )
-            entry["bytes_per_segment"] = (
-                None if entry["bytes_accessed"] is None
-                else entry["bytes_accessed"] / segs
-            )
         with self._lock:
             self._entries[str(key)] = entry
         return entry

@@ -535,24 +535,10 @@ class ShapeCachedForward:
             return {"kind": "metrics", "shape": key[1], "iters": key[4],
                     "frames": key[8], "policy": key[9]}
         if key and key[0] == "custom":
-            # Pipeline programs (inference/pipe_schedule.py) get full
-            # structured identity: the tick's segment count rides into
-            # the ledger meta so costs.record_compiled can derive
-            # per-segment flops/bytes and flip_recommendations can
-            # judge the pipeline against the monolithic scan.
-            meta = None
-            if len(key) >= 6 and key[1] == "pipe_tick":
-                meta = {"kind": "pipe_tick", "shape": key[2],
-                        "iters": key[3], "segments": key[4],
-                        "policy": key[5]}
-            elif len(key) >= 4 and key[1] == "pipe_encode":
-                meta = {"kind": "pipe_encode", "shape": key[2],
-                        "policy": key[3]}
-            elif len(key) >= 4 and key[1] == "stream":
+            if len(key) >= 4 and key[1] == "stream":
                 # StreamEngine's slot-table step (streaming/engine.py).
                 meta = {"kind": "stream_step", "rows": key[2],
                         "policy": key[3]}
-            if meta is not None:
                 # Optional trailing ("earlyexit", tol) marker — same
                 # contract as the forward key above.
                 for part in key[4:]:
@@ -628,8 +614,7 @@ class ShapeCachedForward:
                             )
             return compiled(*args)
 
-        # Inspection handle (inference/pipe_schedule.tick_text; bench's
-        # sharding fingerprint): the warmed executable without a second
+        # Inspection handle: the warmed executable without a second
         # lower().compile(). Empty until the first call. The two others
         # are what ``report`` and ``lowered_hlo`` find the executable by.
         warmed._compiled_box = box

@@ -18,15 +18,15 @@ Reference call structure: core/raft.py:87-143 (baseline) and
 core/raft_nc_dbl.py:115-173 (NCUP variant: mask head removed, per-iter
 nearest x2 -> NCUP x4 -> values x8).
 
-The refinement is COMPOSABLE (inference/pipe_schedule.py; docs/SHARDING.md
-"Pipeline axis"): ``encode`` produces a segment carry (GRU state, query
-coordinates, context features and the correlation feature maps for one
-micro-batch), ``refine_segment`` advances it by any contiguous block of
-iterations, and ``finalize`` upsamples the final carry — so N iterations
-can run as one monolithic scan (``apply``, unchanged semantics) or as S
-scan segments on S pipeline stages with the carry handed between device
-groups. All three share the same step body and upsampling head as
-``apply``, so segmented and monolithic execution agree by construction.
+The refinement is also stated as three stages: ``encode`` produces a
+segment carry (GRU state, query coordinates, context features and the
+correlation feature maps of one batch), ``refine_segment`` advances it by
+any contiguous block of iterations, and ``finalize`` upsamples the final
+carry — so N iterations can run as one monolithic scan (``apply``) or as S
+scan segments across S jit boundaries. All three share the step body and
+the upsampling head of ``apply``; tests/test_model_stages.py holds the two
+statements of the forward equal. Only ``finalize`` has a caller outside the
+tests (the benchmark's mixed-precision evaluation driver).
 """
 
 from __future__ import annotations
@@ -525,8 +525,8 @@ class RAFT:
                 if "exec_iters" in stats:
                     # Per-lane executed-iteration count: a lane active at
                     # step entry pays this iteration; a frozen lane does
-                    # not. (Segment-granularity counting — the pipelined
-                    # path — happens in refine_segment instead.)
+                    # not. (refine_segment counts at segment granularity
+                    # instead.)
                     new_stats["exec_iters"] = stats["exec_iters"] + (
                         ~frozen
                     ).astype(jnp.int32)
@@ -719,22 +719,21 @@ class RAFT:
         rngs: Optional[dict] = None,
         early_exit: bool = False,
     ) -> dict:
-        """Pipeline front half (inference): everything before the first
+        """Front half (inference): everything before the first
         refinement iteration, returned as a SEGMENT CARRY dict —
 
         - ``net`` / ``coords1``: the live recurrent state a refinement
           iteration mutates (all of it: the convex mask is computed by
           ``finalize`` from the last ``net``);
-        - ``inp`` / ``fmap1`` / ``fmap2``: the micro-batch's immutable
-          context, which must TRAVEL WITH the state between pipeline
-          stages (stage s+1 refining this micro-batch needs its feature
-          maps, not its neighbor's).
+        - ``inp`` / ``fmap1`` / ``fmap2``: the batch's immutable context,
+          which travels with the state from segment to segment (the next
+          segment rebuilds its correlation closure from these).
 
         ``early_exit=True`` seeds the convergence-detection keys the
         early-exit segments read and update: ``converged`` (B,) bool
         (all False — every lane starts active) and ``exec_iters`` (B,)
-        int32 (zeros). They ride the carry between stages like the rest
-        of the state; ``finalize`` ignores them.
+        int32 (zeros). They ride the carry between segments like the
+        rest of the state; ``finalize`` ignores them.
 
         ``encode -> refine_segment x S -> finalize`` reproduces
         ``apply(test_mode=True)`` exactly: same submodule code, same
@@ -772,8 +771,8 @@ class RAFT:
         iterations (one ``lax.scan`` — one compiled iteration body, as
         in ``apply``) and return the updated carry. The correlation
         closure is rebuilt from the carry's own feature maps, so a
-        carry handed in from another device group (or another jit
-        boundary) refines identically to one that never moved; for the
+        carry handed in across a jit boundary refines identically to
+        one that never left its program; for the
         'volume' impl this re-derives the pyramid per segment — one
         matmul + avg-pools, cheap against a segment of GRU iterations,
         and bitwise the same pyramid every time.
@@ -783,12 +782,10 @@ class RAFT:
         detection and freeze run INSIDE the segment — flow is identical
         to the monolithic early-exit path — but the executed-iters
         count quantizes to SEGMENT boundaries: a lane active at segment
-        entry is billed the whole segment, because under the pipe axis
-        the tick executable runs on schedule regardless and a segment
-        seam is the first point a lane's exit is observable. So
-        ``exec_iters(pipelined) == ceil(exec_iters(monolithic) /
-        seg_len) * seg_len`` — the quantization contract
-        tests/test_earlyexit.py pins for S in {1, 2, 4}.
+        entry is billed the whole segment, because a segment seam is
+        the first point a lane's exit is observable from outside the
+        scan. So ``exec_iters(segmented) == ceil(exec_iters(monolithic)
+        / seg_len) * seg_len``.
         """
         run = self._make_run(
             variables["params"], dict(variables.get("batch_stats", {})),
@@ -836,7 +833,7 @@ class RAFT:
         rngs: Optional[dict] = None,
         return_net: bool = False,
     ):
-        """Pipeline back half: upsample a finished segment carry to the
+        """Back half: upsample a finished segment carry to the
         test-mode result ``(flow_lr, flow_up)`` (plus ``net`` with
         ``return_net`` — the streaming warm-start handoff). The convex
         mask head runs here, on the carry's ``net``, as after ``apply``'s

@@ -3,8 +3,7 @@
 The NCUP path is the paper's contribution: zero-stuff the low-res flow
 onto the high-res grid, estimate per-pixel confidences from guidance
 (+ data), and interpolate with the normalized-conv U-Net. The bilinear
-upsampler baseline is also provided; PAC/DJIF ablation heads live in
-``raft_ncup_tpu.nn.pac``.
+upsampler baseline is also provided.
 """
 
 from __future__ import annotations
@@ -156,12 +155,7 @@ def build_upsampler(
         )
     if cfg.kind == "bilinear":
         return BilinearUpsampler(cfg, name=name)
-    if cfg.kind in ("pac", "djif"):
-        try:
-            from raft_ncup_tpu.nn.pac import build_pac_upsampler
-        except ImportError as e:
-            raise NotImplementedError(
-                f"upsampler kind {cfg.kind!r} requires raft_ncup_tpu.nn.pac"
-            ) from e
-        return build_pac_upsampler(cfg, dtype=dtype, name=name)
-    raise ValueError(f"unknown upsampler kind: {cfg.kind!r}")
+    raise ValueError(
+        f"unknown upsampler kind {cfg.kind!r}: 'nconv' and 'bilinear' "
+        "are built"
+    )

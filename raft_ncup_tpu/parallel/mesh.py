@@ -11,14 +11,6 @@ single-process ``nn.DataParallel`` over 2 GPUs, reference: train.py:169-175):
   for spatially-sharded convolutions automatically. This is what lets
   1080p 32-iteration inference (whose correlation volume would otherwise
   be several GB) scale across chips.
-- axis ``pipe``: iteration pipelining (docs/SHARDING.md "Pipeline
-  axis"; inference/pipe_schedule.py). RAFT's N identical GRU refinement
-  iterations split into S contiguous segments placed on S device
-  groups; micro-batches stream through the stages, carries handed
-  between groups by ``collective_permute``. ``pipe=1`` (the default)
-  produces the exact 2-axis ``(data, spatial)`` mesh every existing
-  fingerprint/cache key was minted against — the third axis only exists
-  when a pipeline asked for it.
 
 Multi-host: ``jax.distributed.initialize`` + the same mesh spanning all
 processes; each host feeds its local shard of the batch
@@ -39,78 +31,52 @@ def make_mesh(
     data: Optional[int] = None,
     spatial: int = 1,
     devices: Optional[Sequence[jax.Device]] = None,
-    pipe: int = 1,
 ) -> Mesh:
-    """Build a (data, spatial[, pipe]) mesh. ``data=None`` uses all
-    remaining devices after spatial (and pipe) partitioning.
+    """Build a (data, spatial) mesh. ``data=None`` uses all remaining
+    devices after spatial partitioning.
 
-    ``pipe`` (default 1) is the iteration-pipelining axis
-    (inference/pipe_schedule.py): S pipeline stages on S device groups.
-    ``pipe=1`` deliberately yields the identical 2-axis
-    ``("data", "spatial")`` mesh this function always built — same axis
-    names, same fingerprint, so no existing cache key or bench
-    provenance string changes under the default.
-
-    An explicit ``data`` x ``spatial`` x ``pipe`` smaller than the
-    device set warns loudly: the stripped devices sit idle for the
-    whole program, which is a legitimate ops choice (e.g.
-    ``--spatial_parallel 2`` on an 8-chip host while debugging) but
-    must never happen silently — a mis-sized mesh that quietly drops 6
-    of 8 chips looks exactly like a 4x perf regression.
+    An explicit ``data`` x ``spatial`` smaller than the device set warns
+    loudly: the stripped devices sit idle for the whole program, which
+    is a legitimate ops choice (e.g. ``--spatial_parallel 2`` on an
+    8-chip host while debugging) but must never happen silently — a
+    mis-sized mesh that quietly drops 6 of 8 chips looks exactly like a
+    4x perf regression.
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
-    pipe = int(pipe)
-    if pipe < 1:
-        raise ValueError(f"pipe must be >= 1, got {pipe}")
     if data is None:
-        if n % (spatial * pipe):
+        if n % spatial:
             raise ValueError(
                 f"{n} devices not divisible by spatial={spatial}"
-                + (f" * pipe={pipe}" if pipe > 1 else "")
             )
-        data = n // (spatial * pipe)
-    use = data * spatial * pipe
-    shape_str = f"{data}x{spatial}" + (f"x{pipe}" if pipe > 1 else "")
+        data = n // spatial
+    use = data * spatial
     if use > n:
         raise ValueError(
-            f"mesh {shape_str} needs {use} devices, have {n}"
+            f"mesh {data}x{spatial} needs {use} devices, have {n}"
         )
     if use < n:
         warnings.warn(
-            f"mesh {shape_str} uses only {use} of {n} visible "
+            f"mesh {data}x{spatial} uses only {use} of {n} visible "
             f"devices; {n - use} device(s) will sit idle. Pass data=None "
             "to span all devices, or restrict `devices=` explicitly if "
             "the subset is intentional.",
             stacklevel=2,
         )
-    # One Mesh(...) call declares both shapes: the axis-name tuple is a
-    # conditional literal so lint JGL006's declared-axes discovery (which
-    # parses this file) sees 'pipe' exactly when the code can build it.
-    arr = np.asarray(devices[:use]).reshape(
-        (data, spatial, pipe) if pipe > 1 else (data, spatial)
-    )
-    return Mesh(
-        arr,
-        ("data", "spatial", "pipe") if pipe > 1 else ("data", "spatial"),
-    )
+    arr = np.asarray(devices[:use]).reshape(data, spatial)
+    return Mesh(arr, ("data", "spatial"))
 
 
 def resolve_config_mesh(mesh, cfg_mesh) -> tuple:
     """The serving/streaming mesh-resolution rule, in one place: an
-    explicit ``mesh`` wins, else a config's ``(data, spatial)`` or
-    ``(data, spatial, pipe)`` sizes build one, else unsharded. Returns
-    ``(mesh_or_None, pad_divisor)`` where the divisor is 8*spatial —
-    every image padded for this mesh must round to it so the 1/8-res
-    feature height divides the spatial axis (evaluation._pad_divisor's
-    rule; the pipe axis never shards image dims, so it adds nothing to
-    the divisor)."""
+    explicit ``mesh`` wins, else a config's ``(data, spatial)`` sizes
+    build one, else unsharded. Returns ``(mesh_or_None, pad_divisor)``
+    where the divisor is 8*spatial — every image padded for this mesh
+    must round to it so the 1/8-res feature height divides the spatial
+    axis (evaluation._pad_divisor's rule)."""
     if mesh is None and cfg_mesh is not None:
-        mesh = make_mesh(
-            data=int(cfg_mesh[0]),
-            spatial=int(cfg_mesh[1]),
-            pipe=int(cfg_mesh[2]) if len(cfg_mesh) > 2 else 1,
-        )
+        data, spatial = cfg_mesh
+        mesh = make_mesh(data=int(data), spatial=int(spatial))
     spatial = int(mesh.shape.get("spatial", 1)) if mesh is not None else 1
     return mesh, 8 * spatial
 
@@ -150,10 +116,9 @@ def collective_stats(hlo_text: str) -> dict:
     (``compiled.as_text()``), plus the same pair broken out per op kind
     under ``by_op`` — ``{"all-gather": {"count": n, "bytes": b}, ...}``
     with every kind in ``_COLLECTIVE_OPS`` present (zeros included, so
-    consumers index without guards). The breakout is what lets pipeline
-    carry-handoff traffic (``collective-permute`` over the ``pipe``
-    axis) be attributed separately from spatial halo exchanges and
-    fmap2 all-gathers in one mixed-mesh program.
+    consumers index without guards). The breakout is what lets halo
+    exchanges (``collective-permute``) be told from the fmap2
+    all-gathers and the gradient all-reduces of one program.
 
     An unsharded program has zero of everything; a spatially-sharded
     forward shows the halo exchanges and the replicated-fmap2

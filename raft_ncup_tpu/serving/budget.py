@@ -45,7 +45,6 @@ class IterationBudgetController:
         high_water: float = 0.75,
         low_water: float = 0.25,
         recover_patience: int = 4,
-        segments: int = 1,
     ):
         levels = tuple(int(x) for x in levels)
         if not levels or any(x <= 0 for x in levels):
@@ -54,20 +53,6 @@ class IterationBudgetController:
             raise ValueError(
                 f"iteration levels must be strictly descending: {levels!r}"
             )
-        # Pipelined deployments (inference/pipe_schedule.py) add a third
-        # constraint: every level must land on a scan-segment boundary,
-        # or a degraded budget would need its own tick executable —
-        # exactly the recompile storm constraint 1 exists to prevent.
-        # Validated at CONSTRUCTION (the level set and mesh are both
-        # deploy-time choices; a mid-burst decide() must never be the
-        # first place the mismatch surfaces). segments=1 (default, no
-        # pipeline) imposes nothing.
-        from raft_ncup_tpu.inference.pipe_schedule import (
-            validate_segment_levels,
-        )
-
-        validate_segment_levels(levels, segments)
-        self.segments = int(segments)
         if not 0.0 <= low_water < high_water <= 1.0:
             raise ValueError(
                 f"want 0 <= low_water < high_water <= 1, got "

@@ -151,13 +151,6 @@ class FlowServer:
             high_water=self.cfg.high_water,
             low_water=self.cfg.low_water,
             recover_patience=self.cfg.recover_patience,
-            # Under a pipelined mesh every budget level must land on a
-            # scan-segment boundary (inference/pipe_schedule.py) —
-            # surface a level-set/mesh mismatch HERE, at server
-            # construction, not mid-burst in decide().
-            segments=(
-                int(mesh.shape.get("pipe", 1)) if mesh is not None else 1
-            ),
         )
         # Early exit (docs/PERF.md "Early exit"): resolved from the env
         # knobs ONCE at construction — executable identity must not flip

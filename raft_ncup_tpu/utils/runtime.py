@@ -45,9 +45,23 @@ def force_platform(platform: str) -> None:
     the tests' virtual CPU devices). Must run before the first device
     use. The env var is written for child processes, which inherit it;
     the config is written because ``jax`` read the env var when it was
-    imported, which may have been before this call."""
-    import jax
+    imported, which may have been before this call.
 
+    Once a backend is initialised the config is read no more: a re-point
+    to another platform then cannot apply, and says so instead of
+    leaving the process on the backend it has (ADVICE round 5, found in
+    ``__graft_entry__.py``, whose re-point moved here)."""
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        have = jax.default_backend()
+        if have != platform.split(",")[0]:
+            raise RuntimeError(
+                f"force_platform({platform!r}) cannot apply: this process "
+                f"already initialised the {have!r} backend. Pin the "
+                "platform before the first device use."
+            )
     os.environ["JAX_PLATFORMS"] = platform
     jax.config.update("jax_platforms", platform)
 

@@ -56,33 +56,39 @@ def max_recompiles():
 # nowhere else. The driver runs `-n 6 --dist loadfile`: a file is one unit
 # of work, a worker takes the next unit of the queue when it has at most
 # two tests left, and xdist's default queue is "most tests first", which
-# hands the cheap many-test files out first and the file that compiles
-# whole programs for the chip minutes into the run, to end alone (1378 s
-# of wall for a file of 1088 s, PR 28). So: the files that cost over 100 s
-# in the driver's junit file of that run go first, longest first, and the
-# rest follow in collection order (but see CPU_BUDGETED_FILES_LAST below).
-# tests/test_tpu_aot_compile.py must stay
-# first: it is the critical path whatever follows it, and with more than
-# two tests it gets no second unit queued behind it at the start.
+# hands the cheap many-test files out first and the files that compile
+# whole programs minutes into the run, to end alone. So: the files that
+# cost 60 s and more in the driver's junit file of PR 43's tree (7,107
+# worker-seconds, 1238 s of wall) go first, longest first, and the rest
+# follow in collection order (but see CPU_BUDGETED_FILES_LAST below).
+# tests/benchmark/test_train_mixed_cell.py (966 s) and
+# tests/test_tpu_aot_compile.py (965 s) are the floor under the wall
+# whatever follows them: each has a worker to itself from the first second,
+# and with more than two tests neither gets a second unit queued behind it
+# at the start. Then 884, 518, 508, 444, 424, 408, 275, 210, 174, 144 and
+# 95 s; the last seven are 51-83 s each.
 # tests/test_collection_order.py fails when a listed file is gone.
 LONGEST_FILES_FIRST = (
+    "tests/benchmark/test_train_mixed_cell.py",
     "tests/test_tpu_aot_compile.py",
     "tests/benchmark/test_train_cell.py",
-    "tests/benchmark/test_train_mixed_cell.py",
-    "tests/benchmark/test_benchmark.py",
-    "tests/benchmark/test_stream_cell.py",
-    "tests/benchmark/test_1080p_cell.py",
-    "tests/test_chip_smoke.py",
-    "tests/test_train_loop.py",
     "tests/test_corr_pallas.py",
+    "tests/benchmark/test_benchmark.py",
+    "tests/test_chip_smoke.py",
+    "tests/benchmark/test_1080p_cell.py",
+    "tests/test_train_loop.py",
+    "tests/benchmark/test_stream_cell.py",
     "tests/test_drivers.py",
     "tests/test_nconv.py",
+    "tests/benchmark/test_eval_mixed_cell.py",
+    "tests/test_eval_staging.py",
     "tests/test_corr.py",
     "tests/test_chaos_train.py",
-    "tests/test_pac.py",
     "tests/test_multihost.py",
     "tests/test_checkpoint.py",
     "tests/test_earlyexit.py",
+    "tests/test_mask_head.py",
+    "tests/test_mesh_sharding.py",
 )
 
 

@@ -92,6 +92,22 @@ class TestCli:
         assert model_cfg.upsampler.kind == "bilinear"
 
 
+    @pytest.mark.parametrize("name", ["PacJointUpsampleFull", "DjifOriginal"])
+    def test_a_head_that_left_the_tree_is_a_usage_error(self, name, capsys):
+        """The reference's other two upsampler class names stop at parse
+        time, and the message lists the two that are built."""
+        with pytest.raises(SystemExit) as e:
+            parse_train(
+                ["--stage", "chairs", "--model", "raft_nc_dbl",
+                 f"--final_upsampling={name}"]
+            )
+        assert e.value.code == 2
+        err = capsys.readouterr().err
+        assert "--final_upsampling" in err and name in err
+        assert "choose from" in err
+        assert "Bilinear" in err and "NConvUpsampler" in err
+
+
 # ------------------------------------------------------------------ fixtures
 
 

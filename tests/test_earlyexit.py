@@ -14,10 +14,6 @@ The contracts pinned here:
 - **Guard cleanliness.** Detection lives in-graph: a warm early-exit
   window performs ZERO implicit host transfers and ZERO recompiles —
   no host code ever inspects the convergence mask.
-- **Segment quantization.** Under the pipe axis the tick schedule is
-  fixed, so exits bill whole segments:
-  ``exec_pipe == ceil(exec_mono / seg_len) * seg_len`` (S in {1,2,4}),
-  with the flow unchanged.
 - **Expected-iteration budgeting.** ``IterationBudgetController``
   scales occupancy by the executed-iters EWMA — admitted depth before
   degrade RISES as the EWMA falls — while the unfed controller and the
@@ -31,8 +27,6 @@ changes).
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,7 +34,6 @@ import pytest
 
 from raft_ncup_tpu.config import ServeConfig, small_model_config
 from raft_ncup_tpu.inference.costs import CostLedger
-from raft_ncup_tpu.inference.pipe_schedule import PipelinedForward
 from raft_ncup_tpu.inference.pipeline import (
     ShapeCachedForward,
     env_earlyexit_tol,
@@ -52,7 +45,7 @@ from raft_ncup_tpu.serving.budget import IterationBudgetController
 
 HW = (32, 32)
 B = 3
-ITERS = 4  # divisible by S in {1, 2, 4}
+ITERS = 4
 
 
 @pytest.fixture(scope="module")
@@ -200,46 +193,6 @@ class TestGuards:
             jax.device_get(outs[-1][1][0, 0, 0, 0])
         assert wd.count == 0
         assert stats.host_transfers == 0
-
-
-# --------------------------------------------------- segment quantization
-
-
-class TestPipeQuantization:
-    @pytest.mark.parametrize("segments", [1, 2, 4])
-    def test_exec_quantizes_to_segment_boundaries(
-        self, raft, fwd, images, segments
-    ):
-        """``exec_pipe == ceil(exec_mono / seg_len) * seg_len``: the
-        tick schedule is fixed, so a converged lane rides frozen to the
-        next seam and bills the whole segment — and the flow itself is
-        unchanged (the freeze inside a segment is still per-iteration
-        and bitwise)."""
-        model, variables = raft
-        i1, i2 = images
-        tol = _splitting_tol(_dnorm1(fwd, i1, i2))
-        lr_m, up_m, ex_m = fwd.forward_device(
-            i1, i2, ITERS, early_exit_tol=tol
-        )
-        ex_m = _pull(ex_m)
-        pf = PipelinedForward(model, variables, segments=segments)
-        outs = pf.forward_many([(i1, i2)], ITERS, early_exit_tol=tol)
-        assert len(outs) == 1 and len(outs[0]) == 3
-        lr_p, up_p, ex_p = outs[0]
-        if segments == 1:
-            # Delegation path: no tick schedule, so no quantization —
-            # the true per-sample counts pass through.
-            want = [int(k) for k in ex_m]
-        else:
-            seg_len = ITERS // segments
-            want = [math.ceil(int(k) / seg_len) * seg_len for k in ex_m]
-        assert list(_pull(ex_p)) == want
-        np.testing.assert_allclose(
-            _pull(up_p), _pull(up_m), rtol=1e-5, atol=1e-5
-        )
-        np.testing.assert_allclose(
-            _pull(lr_p), _pull(lr_m), rtol=1e-5, atol=1e-5
-        )
 
 
 # ------------------------------------------------------------- API edges

@@ -546,6 +546,24 @@ class TestCostLedger:
         )
         return fwd, ledger
 
+    def test_custom_key_meta_parse(self):
+        """``custom()`` programs: StreamEngine's step carries a structured
+        identity (its report's ``executable_memory`` filters on it) with
+        the optional early-exit marker; any other custom key keeps the
+        opaque kind."""
+        meta = ShapeCachedForward._ledger_meta
+        assert meta(("custom", "stream", 8, "f32")) == {
+            "kind": "stream_step", "rows": 8, "policy": "f32",
+        }
+        assert meta(("custom", "stream", 8, "f32", ("earlyexit", 0.1))) == {
+            "kind": "stream_step", "rows": 8, "policy": "f32",
+            "earlyexit_tol": 0.1,
+        }
+        assert meta(("custom", "other", 2)) == {"kind": "custom"}
+        assert meta(("custom", "pipe_tick", (1, 32, 32, 3), 8, 4, "f32")) == {
+            "kind": "custom"
+        }
+
     def test_records_costs_at_compile_time_only(self):
         fwd, ledger = self._fwd_with_ledger()
         img = np.zeros((1, 8, 10, 3), np.float32)

@@ -208,3 +208,25 @@ def test_benchmark_models_fold_exactly_the_update_blocks_two_thin_sites(config):
     assert len(forms["conv"]) >= 35 and "gru/convz1" not in forms["conv"]
     assert {"gru/convz1/context", "gru/convz1/step"} <= set(forms["conv"])
     assert not set(forms["conv"]) & {"encoder/convf1", "flow_head/conv2"}
+
+
+@pytest.mark.parametrize("kind", ["pac", "djif"])
+def test_registry_refuses_a_kind_it_cannot_build(kind):
+    """The upsampler registry builds `nconv` and `bilinear`; the kinds of
+    the heads that left the tree are a ValueError that names those two,
+    not an import of a module that is not there."""
+    from raft_ncup_tpu.config import UpsamplerConfig
+    from raft_ncup_tpu.nn.upsampler import (
+        BilinearUpsampler,
+        NConvUpsampler,
+        build_upsampler,
+    )
+
+    with pytest.raises(ValueError, match="'nconv' and 'bilinear'") as e:
+        build_upsampler(UpsamplerConfig(kind=kind), dataset="things")
+    assert repr(kind) in str(e.value)
+    built = {
+        k: type(build_upsampler(UpsamplerConfig(kind=k), dataset="things"))
+        for k in ("nconv", "bilinear")
+    }
+    assert built == {"nconv": NConvUpsampler, "bilinear": BilinearUpsampler}

@@ -448,10 +448,10 @@ def test_jgl006_standalone_subsystem_negative_declared_axes(tmp_path):
 
 def test_jgl006_discovers_conditional_axis_tuple(tmp_path):
     """Declared-axes discovery descends conditional-expression axis
-    tuples — ``Mesh(arr, (..., "pipe") if pipe > 1 else (...))`` is how
-    make_mesh declares the pipeline axis in ONE call (both branches
-    count as declarations), so 'pipe' must be usable in PartitionSpecs
-    without a JGL006 false positive, while a typo'd axis still fires."""
+    tuples — ``Mesh(arr, (..., "pipe") if pipe > 1 else (...))``
+    declares a third axis in ONE call (both branches count as
+    declarations), so 'pipe' must be usable in PartitionSpecs without a
+    JGL006 false positive, while a typo'd axis still fires."""
     from raft_ncup_tpu.analysis.lint import run_lint
 
     d = tmp_path / "pipe_ok"
@@ -503,14 +503,13 @@ def test_jgl006_discovers_conditional_axis_tuple(tmp_path):
     assert "pip" in result.findings[0].message
 
 
-def test_jgl006_production_axes_include_pipe():
-    """The real make_mesh's conditional axis tuple feeds discovery: the
-    production fallback set must see all three axes, or every
-    P('pipe') in inference/pipe_schedule.py would be a false positive
-    in standalone subsystem lint runs."""
+def test_jgl006_production_axes_are_data_and_spatial():
+    """The real make_mesh feeds the production fallback set: exactly the
+    two axes it can build, so a PartitionSpec naming any other axis in
+    a standalone subsystem lint run is a finding."""
     from raft_ncup_tpu.analysis.lint import production_declared_axes
 
-    assert production_declared_axes() >= {"data", "spatial", "pipe"}
+    assert production_declared_axes() == frozenset({"data", "spatial"})
 
 
 # --------------------------------------------------------------- JGL007
@@ -1736,6 +1735,52 @@ def test_catalog_markdown_covers_registry():
     table = knobs.catalog_markdown()
     for knob in knobs.KNOBS:
         assert f"`{knob.name}`" in table
+
+
+def test_every_knob_name_in_the_documents_is_registered():
+    """The other direction: a full `RAFT_NCUP_*` name in README.md or
+    docs/*.md is a declared knob (the bare prefix `RAFT_NCUP_` is not a
+    name), so a document cannot advertise a switch the code never reads."""
+    import glob
+    import re
+
+    from raft_ncup_tpu.utils import knobs
+
+    declared = {k.name for k in knobs.KNOBS}
+    paths = [os.path.join(REPO, "README.md")] + sorted(
+        glob.glob(os.path.join(REPO, "docs", "*.md"))
+    )
+    unknown = {}
+    for path in paths:
+        with open(path, encoding="utf-8") as fh:
+            names = set(re.findall(r"RAFT_NCUP_[A-Z0-9_]*[A-Z0-9]", fh.read()))
+        if names - declared:
+            unknown[os.path.relpath(path, REPO)] = sorted(names - declared)
+    assert not unknown, f"undeclared knob names in the documents: {unknown}"
+    assert len(declared) == 13
+
+
+def test_every_path_of_the_readme_module_tree_exists():
+    """README.md's "Layout" block names modules; each is in the tree. An
+    entry is the head of a line before its description: under
+    `raft_ncup_tpu/` when indented, at the root of the repo otherwise."""
+    import re
+
+    with open(os.path.join(REPO, "README.md"), encoding="utf-8") as fh:
+        text = fh.read()
+    block = re.search(r"```\n(raft_ncup_tpu/\n.*?)```", text, re.S).group(1)
+    missing, seen = [], 0
+    for line in block.splitlines()[1:]:
+        indent = len(line) - len(line.lstrip(" "))
+        if indent not in (0, 2):
+            continue  # a description's continuation line
+        head = re.split(r"\s{2,}", line.strip(), maxsplit=1)[0]
+        base = os.path.join(REPO, "raft_ncup_tpu") if indent else REPO
+        for entry in re.split(r"[,\s]+", head):
+            seen += 1
+            if not os.path.exists(os.path.join(base, entry)):
+                missing.append(entry)
+    assert seen >= 25 and not missing, missing
 
 
 # ------------------------------------------------------------ self-check

@@ -83,45 +83,34 @@ class TestMakeMesh:
         assert fp == "mesh(data=1,spatial=2:cpu)"
         assert fp != mesh_fingerprint(_mesh(2, 1))
 
-    def test_pipe_axis_mesh_and_fingerprint(self):
-        """The third axis (docs/SHARDING.md "Pipeline axis"): explicit
-        pipe>1 grows the mesh and the fingerprint; every compiled-
-        program key downstream inherits the distinction for free."""
-        mesh = make_mesh(
-            data=1, spatial=1, pipe=4, devices=jax.devices()[:4]
-        )
-        assert dict(mesh.shape) == {"data": 1, "spatial": 1, "pipe": 4}
-        assert mesh_fingerprint(mesh) == "mesh(data=1,spatial=1,pipe=4:cpu)"
-        # data=None spans all devices after spatial*pipe partitioning.
-        auto = make_mesh(spatial=1, pipe=4)
-        assert dict(auto.shape) == {"data": 2, "spatial": 1, "pipe": 4}
-        with pytest.raises(ValueError, match="not divisible by spatial"):
-            make_mesh(spatial=1, pipe=3)
+    def test_make_mesh_takes_no_third_axis(self):
+        """There is no `pipe` axis: the keyword is gone, not ignored (a
+        mesh that accepted it used to idle the devices it named)."""
+        with pytest.raises(TypeError, match="pipe"):
+            make_mesh(data=1, spatial=1, pipe=4, devices=jax.devices()[:4])
 
-    def test_pipe_default_is_the_identical_two_axis_mesh(self):
-        """pipe=1 must yield the exact 2-axis mesh this function always
-        built — same axis names, same fingerprint — so no existing
-        cache key or bench provenance string changes under the
-        default."""
-        a = _mesh(1, 2)
-        b = make_mesh(
-            data=1, spatial=2, pipe=1, devices=jax.devices()[:2]
-        )
-        assert tuple(b.axis_names) == ("data", "spatial")
-        assert dict(a.shape) == dict(b.shape)
-        assert mesh_fingerprint(a) == mesh_fingerprint(b)
+    def test_two_axis_mesh_is_what_it_was(self):
+        """Axis names, shape and fingerprint of the one mesh there is:
+        every compiled-program key and provenance string minted against
+        it stays valid. data=None spans what spatial leaves."""
+        mesh = _mesh(1, 2)
+        assert tuple(mesh.axis_names) == ("data", "spatial")
+        assert mesh.devices.shape == (1, 2)
+        assert mesh_fingerprint(mesh) == "mesh(data=1,spatial=2:cpu)"
+        auto = make_mesh(spatial=2)
+        assert dict(auto.shape) == {"data": 4, "spatial": 2}
+        with pytest.raises(ValueError, match="not divisible by spatial=3"):
+            make_mesh(spatial=3)
 
-    def test_resolve_config_mesh_accepts_pipe_triple(self):
+    def test_resolve_config_mesh_refuses_a_triple(self):
         from raft_ncup_tpu.parallel.mesh import resolve_config_mesh
 
-        mesh, div = resolve_config_mesh(None, (1, 1, 4))
-        assert dict(mesh.shape) == {"data": 1, "spatial": 1, "pipe": 4}
-        # The pipe axis never shards image dims: pad divisor is still
-        # 8 * spatial.
-        assert div == 8
+        with pytest.raises(ValueError):
+            resolve_config_mesh(None, (1, 1, 4))
         mesh2, div2 = resolve_config_mesh(None, (1, 2))
         assert dict(mesh2.shape) == {"data": 1, "spatial": 2}
         assert div2 == 16
+        assert resolve_config_mesh(None, None) == (None, 8)
 
 
 # ------------------------------------------------------ collective_stats
@@ -217,34 +206,41 @@ class TestMeshKeyedCache:
         # A bucket the divisor divides is fine.
         assert ServeConfig(mesh=(1, 2), pad_bucket=32).pad_bucket == 32
 
+    def test_stream_config_refuses_a_mesh_triple(self):
+        """The engine's config holds the same rule as the server's
+        (tests/test_model_stages.py): a mesh is (data, spatial)."""
+        with pytest.raises(ValueError, match="two positive sizes"):
+            StreamConfig(mesh=(1, 1, 2))
+        assert StreamConfig(mesh=(1, 2)).mesh == (1, 2)
+
     def test_cli_mesh_spec(self):
         import argparse
 
         from raft_ncup_tpu.cli import str2mesh
 
         assert str2mesh("1,2") == (1, 2)
-        assert str2mesh("1,1,2") == (1, 1, 2)
-        with pytest.raises(argparse.ArgumentTypeError):
-            str2mesh("2")
-        with pytest.raises(argparse.ArgumentTypeError):
-            str2mesh("0,2")
-        with pytest.raises(argparse.ArgumentTypeError):
-            str2mesh("1,1,0")
-        with pytest.raises(argparse.ArgumentTypeError):
-            str2mesh("1,1,2,2")
+        for bad in ("2", "0,2", "1,1,2", "1,1,2,2"):
+            with pytest.raises(argparse.ArgumentTypeError):
+                str2mesh(bad)
 
-    def test_cli_mesh_triple_builds_pipe_mesh(self):
+    def test_cli_mesh_triple_is_a_usage_error(self, capsys):
+        """`--mesh 1,1,2` stops at parse time with a message that says a
+        mesh is two sizes; the pair still builds the two-axis mesh."""
         import argparse
 
-        from raft_ncup_tpu.cli import mesh_from_args
+        from raft_ncup_tpu.cli import add_mesh_arg, mesh_from_args
 
-        mesh = mesh_from_args(argparse.Namespace(mesh=(1, 1, 2)))
-        assert dict(mesh.shape) == {"data": 1, "spatial": 1, "pipe": 2}
-        # The 2-tuple path still yields the identical 2-axis mesh.
-        assert mesh_from_args(argparse.Namespace(mesh=(1, 2))).axis_names == (
-            "data",
-            "spatial",
-        )
+        parser = argparse.ArgumentParser()
+        add_mesh_arg(parser)
+        with pytest.raises(SystemExit) as e:
+            parser.parse_args(["--mesh", "1,1,2"])
+        assert e.value.code == 2
+        assert "DATA,SPATIAL, two positive sizes" in capsys.readouterr().err
+        args = parser.parse_args(["--mesh", "1,2"])
+        with pytest.warns(UserWarning, match="only 2 of 8"):
+            mesh = mesh_from_args(args)
+        assert mesh.axis_names == ("data", "spatial")
+        assert mesh_from_args(parser.parse_args([])) is None
 
 
 # ------------------------------------------------------ forward parity

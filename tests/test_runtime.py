@@ -38,6 +38,23 @@ def test_force_platform_writes_env_and_config(monkeypatch):
     assert jax.config.jax_platforms == "cpu"
 
 
+def test_force_platform_after_backend_init_says_it_cannot_apply(monkeypatch):
+    """ADVICE round 5 (then `__graft_entry__.py`'s re-point, now this
+    function): once a backend is initialised `jax_platforms` is read no
+    more, so a re-point to ANOTHER platform must fail loudly and leave
+    env and config alone; the platform the process already has is fine."""
+    import jax
+    import pytest
+
+    jax.devices()  # the conftest's cpu backend, initialised
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    with pytest.raises(RuntimeError, match="cannot apply.*'cpu' backend"):
+        runtime.force_platform("tpu")
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert jax.config.jax_platforms == "cpu"
+    runtime.force_platform("cpu")  # no re-point: nothing to refuse
+
+
 def test_tpu_backend_check_is_an_equality_not_a_denylist(monkeypatch):
     import jax
 

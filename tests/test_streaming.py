@@ -443,6 +443,207 @@ class TestEngineLifecycle:
 # ------------------------------------------------- carry_net (GRU state)
 
 
+# ------------------------------------------- the no-silent-loss contract
+#
+# The engine's half of what tests/test_serving.py holds the server to
+# (TestServerErrorPath, TestDrainWorkerFailure, TestBatchStageSpans): a
+# fault of the engine's own answers every admitted frame `error` and
+# releases what the frame held, and the batch's stage spans tile the
+# externally timed ``stream_drain``.
+
+_BATCH_STAGES = (
+    "stream_dispatch", "stream_throttle_wait", "stream_device_wait",
+    "stream_pull", "stream_deliver",
+)
+
+
+class _TickClock:
+    """Every read is one second after the last, from any thread; ``log``
+    keeps which thread read which second."""
+
+    def __init__(self):
+        self._t, self._lock, self.log = 0.0, threading.Lock(), []
+
+    def __call__(self) -> float:
+        with self._lock:
+            self._t += 1.0
+            self.log.append((self._t, threading.get_ident()))
+            return self._t
+
+
+def _stage_records(tel, batch_id):
+    out = {}
+    for name in _BATCH_STAGES + ("stream_drain",):
+        (rec,) = [
+            r for r in tel.tracer.records(name)
+            if r["attrs"]["batch_id"] == batch_id
+        ]
+        out[name] = (rec["t_s"], rec["t_s"] + rec["duration_ms"] / 1e3)
+    return out
+
+
+class TestEngineErrorPath:
+    def test_forward_failure_answers_the_batch_and_engine_survives(self):
+        """A forward that raises is the engine's fault, not the
+        client's: every frame of the batch answers `error`, the frames'
+        pending counts are released (a stream closed meanwhile gets its
+        slot back), and the dispatcher serves the next batch."""
+
+        class FlakyVideoModel(_DummyVideoModel):
+            fail = True
+
+            def apply(self, *a, **kw):
+                if self.fail:
+                    raise ValueError("boom")
+                return super().apply(*a, **kw)
+
+        model = FlakyVideoModel()
+        eng = StreamEngine(model, {}, _scfg())
+        try:
+            eng.pause()
+            ha = eng.submit("a", _img(1), _img(2))
+            hb = eng.submit("b", _img(3), _img(4))
+            eng.close_stream("b")  # deferred: its frame is pending
+            eng.resume()
+            ra, rb = ha.result(30), hb.result(30)
+            assert ra.status == "error" and "boom" in ra.detail
+            assert rb.status == "error" and "boom" in rb.detail
+            with eng._reg_lock:
+                assert eng.registry.get("a").pending == 0
+                assert eng.registry.get("b") is None  # slot released
+            model.fail = False
+            assert eng.submit("a", _img(5), _img(6)).result(30).ok
+        finally:
+            stats = eng.drain()
+        assert stats.errors == 2 and stats.completed == 1
+        assert stats.batches == 2 and stats.streams_closed == 1
+        assert eng._handles == {} and eng._inflight == {}
+
+
+class TestEngineDrainWorkerFailure:
+    def test_stranded_batches_flushed_and_drain_returns_the_stats(self):
+        """AsyncDrain surfaces a worker error from a LATER submit or from
+        close(): the in-flight registry must answer the batches the
+        worker stranded (with a drain-failure detail) on either path, and
+        drain() still returns the stats with nothing admitted lost."""
+
+        class AsyncDeadDrainer:
+            calls = 0
+
+            def submit(self, tree, cb, span=None):
+                self.calls += 1
+                if self.calls == 2:
+                    raise RuntimeError("pull failed")
+                # calls 1 and 3: accepted; the worker dies before
+                # delivering.
+
+            def close(self):
+                raise RuntimeError("worker died")
+
+        eng = _engine(batch_sizes=(1,))
+        eng._drainer = AsyncDeadDrainer()
+        ha = eng.submit("a", _img(1), _img(2))  # batch 1: stranded
+        hb = eng.submit("b", _img(3), _img(4))  # batch 2: submit raises
+        ra, rb = ha.result(30), hb.result(30)
+        assert ra.status == "error" and "result drain failed" in ra.detail
+        assert rb.status == "error" and "pull failed" in rb.detail
+        hc = eng.submit("a", _img(5), _img(6))  # batch 3: stranded
+        _wait(lambda: eng._inflight, msg="batch 3 in flight")
+        stats = eng.drain(timeout=30)  # close() raises: batch 3 flushed
+        rc = hc.result(1)
+        assert rc.status == "error" and "worker died" in rc.detail
+        assert stats is eng.stats
+        assert stats.submitted == 3 == stats.errors
+        assert stats.completed == 0
+        assert eng._handles == {} and eng._inflight == {}
+        with eng._reg_lock:
+            assert eng.registry.get("a").pending == 0
+            assert eng.registry.get("b").pending == 0
+
+
+class TestEngineBatchStageSpans:
+    """``stream_dispatch`` is the copy and the jit dispatch alone; the
+    throttle's wait and the drain worker's device wait, pull and deliver
+    are spans of their own under the batch's id. With the wait in the
+    drainer's queue they tile the externally timed ``stream_drain``."""
+
+    def test_stages_tile_stream_drain_under_an_injected_clock(self):
+        from raft_ncup_tpu.observability import Telemetry
+
+        clock = _TickClock()
+        tel = Telemetry(clock=clock)
+        eng = StreamEngine(
+            _DummyVideoModel(), {}, _scfg(batch_sizes=(2,)), clock=clock,
+            telemetry=tel,
+        )
+        try:
+            eng.warmup()  # the compile's own phases read the clock too
+            eng.pause()
+            h1 = eng.submit("a", _img(1), _img(2))
+            h2 = eng.submit("b", _img(3), _img(4))
+            eng.resume()
+            assert h1.result(60).ok and h2.result(60).ok
+        finally:
+            eng.drain()
+        st = _stage_records(tel, batch_id=0)
+        order = [st[name] for name in _BATCH_STAGES]
+        # disjoint, in this order, each one tick long
+        for (s0, e0), (s1, e1) in zip(order, order[1:]):
+            assert s0 < e0 < s1 < e1
+        assert [e - s for s, e in order[:4]] == [1.0] * 4
+        # stream_drain runs from the read before stream_dispatch to the
+        # read at the top of deliver; observe_ms reads the clock once
+        # more to place it, hence the tick taken off its end.
+        drain_start = st["stream_drain"][0] - 1.0
+        drain_end = st["stream_drain"][1] - 1.0
+        assert drain_start == st["stream_dispatch"][0] - 1.0
+        assert st["stream_pull"][1] < drain_end
+        assert st["stream_deliver"][0] < drain_end < st["stream_deliver"][1]
+        covered = sum(e - s for s, e in order[:4])
+        # What the four spans leave of stream_drain is clock reads alone:
+        # 2 per span boundary pair, plus deliver's start and `done`, plus
+        # whatever the dispatcher read for its NEXT assembly once it had
+        # handed this batch to the drain worker.
+        handed_over = st["stream_throttle_wait"][1]
+        dispatcher = eng._thread.ident
+        next_assembly = [
+            t for t, thread in clock.log
+            if thread == dispatcher and handed_over < t < drain_end
+        ]
+        assert (drain_end - drain_start) - covered - len(
+            next_assembly
+        ) == pytest.approx(6.0)
+
+    def test_every_batch_has_each_stage_once_inside_its_drain(self):
+        from raft_ncup_tpu.observability import Telemetry
+
+        tel = Telemetry()
+        eng = StreamEngine(_DummyVideoModel(), {}, _scfg(), telemetry=tel)
+        try:
+            # One stream: its frames never share a batch.
+            handles = [
+                eng.submit("s", _img(i), _img(i + 1)) for i in range(5)
+            ]
+            assert all(h.result(60).ok for h in handles)
+        finally:
+            stats = eng.drain()
+        batches = {
+            r["attrs"]["batch_id"] for r in tel.tracer.records("stream_drain")
+        }
+        assert len(batches) == stats.batches == 5
+        for b in batches:
+            st = _stage_records(tel, b)  # exactly one record a stage
+            order = [st[name] for name in _BATCH_STAGES]
+            slack = 2e-3  # records round to microseconds and 1e-3 ms
+            for (s0, e0), (s1, e1) in zip(order, order[1:]):
+                assert s0 <= e0 <= s1 + slack and s1 <= e1
+            d0, d1 = st["stream_drain"]
+            assert d0 - slack <= order[0][0] and order[3][1] <= d1 + slack
+        report = eng.report()["stages"]
+        assert set(_BATCH_STAGES) <= set(report)
+        assert report["stream_pull"]["count"] == stats.batches
+
+
 class TestCarryNet:
     def test_net_carried_only_when_enabled_and_warm(self):
         img1, img2 = _img(200), _img(201)
