@@ -152,8 +152,10 @@ def add_data_args(parser: argparse.ArgumentParser) -> None:
                         "are counted and logged")
     parser.add_argument("--eval_pad_bucket", type=int, default=d.eval_pad_bucket,
                         help="round padded eval shapes up to multiples of "
-                        "this bucket (0=off) so KITTI's shape diversity "
-                        "compiles a small fixed executable set")
+                        "this bucket (0=off: every frame padded to its own "
+                        "multiple of 8; a validation pass already runs one "
+                        "executable a native size) where the sizes "
+                        "outnumber --eval_cache_size")
     parser.add_argument("--synthetic_ok", action="store_true",
                         help="fall back to procedural data if roots missing")
     parser.add_argument("--synthetic_style", default=d.synthetic_style,

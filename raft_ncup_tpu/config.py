@@ -240,14 +240,21 @@ class DataConfig:
     io_retry_backoff_s: float = 0.05
     # --- eval/inference pipeline (inference/pipeline.py) ----------------
     # Bound on the shape-cached compiled eval executables (LRU). Each
-    # distinct (padded shape, iters, metric kind) compiles once; KITTI's
-    # native-shape diversity is what the bound protects against —
-    # evictions are counted and logged loudly.
+    # distinct (shape with the batch, native shape, iters, metric kind)
+    # compiles once. A validation pass needs one a native size
+    # (pipeline.uniform_batches groups by size across the stream and a
+    # masked pass fills the remainders): KITTI-2015's four sizes fit. The
+    # bound still protects the paths that dispatch frame by frame over
+    # footage of many sizes (the submission writers, warm-start
+    # validation) and a data set with more sizes than this: evictions are
+    # counted, logged loudly and published per pass (eval_pass_programs).
     eval_cache_size: int = 8
-    # Round padded eval shapes up to multiples of this bucket (0 = off).
-    # Collapses KITTI's couple-dozen native resolutions onto a small
-    # fixed shape set so the executable count is known up front. Must be
-    # a multiple of 8 when set; applied to the KITTI validator/submission.
+    # Round padded eval shapes up to multiples of this bucket (0 = off:
+    # every frame is padded to its own multiple of 8, as upstream pads).
+    # Collapses many native sizes onto a small fixed set of padded shapes
+    # where they outnumber eval_cache_size (KITTI-2015 has four and does
+    # not need it). Must be a multiple of 8 when set; applied to the
+    # KITTI validator/submission.
     eval_pad_bucket: int = 0
     # When no dataset is present on disk, the loader can serve procedurally
     # generated pairs so training/benchmarking still exercises the full path.

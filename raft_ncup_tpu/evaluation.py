@@ -19,11 +19,16 @@ docs/PERF.md "Eval pipeline"):
 - Submissions still need full-field pulls; they go through
   :class:`AsyncDrain`, which performs the sanctioned ``jax.device_get``
   on a worker thread behind dispatch.
-- Compiled test-mode executables are cached per padded shape in a
-  bounded LRU (:class:`ShapeCachedForward`, knob
-  ``DataConfig.eval_cache_size``); KITTI's native-shape diversity can
-  additionally be collapsed with pad bucketing
-  (``DataConfig.eval_pad_bucket``).
+- Compiled test-mode executables are cached per shape in a bounded LRU
+  (:class:`ShapeCachedForward`, knob ``DataConfig.eval_cache_size``). A
+  validation pass groups its samples by native size across the stream
+  (``inference/pipeline.uniform_batches``), and a pass with a ``valid``
+  mask (KITTI) fills each size's remainder to a full batch with rows the
+  metric head counts as no frame: the executables of a KITTI pass are one
+  a native size (four for KITTI-2015), whatever the order of the frames,
+  and nothing is built after the first pass. Pad bucketing
+  (``DataConfig.eval_pad_bucket``) is separate and off by default: every
+  frame is padded to its own multiple of 8, as upstream pads it.
 """
 
 from __future__ import annotations
@@ -232,15 +237,20 @@ def _run_metric_pass(
     Spans, on ``telemetry`` (None: the process hub), all with this pass's
     ``pass_id`` and the batch's index: the pipeline's ``input_wait`` /
     ``input_stage`` / ``input_h2d`` (data/device_prefetch.py), then per
-    batch ``eval_dispatch`` (the jit dispatch) and ``eval_throttle_wait``
+    batch ``eval_dispatch`` (the jit dispatch; it carries the batch's
+    native ``size``) and ``eval_throttle_wait``
     (the bounded wait for an earlier batch), and once ``eval_pull`` (the
-    pass's one pull, which waits for the device to finish). The counter
-    ``eval_pairs_total`` grows by the batch's pairs where
-    ``eval_dispatch`` closes, ``eval_input_bytes_total`` by the staged
+    pass's one pull, which waits for the device to finish). Where
+    ``eval_dispatch`` closes three counters grow: ``eval_rows_total`` by
+    the batch's rows, ``eval_fill_rows_total`` by those of them that are
+    fill rows (``with_valid`` passes fill each size's remainder to a full
+    batch, ``uniform_batches``) and ``eval_pairs_total`` by the real pairs
+    alone; ``eval_input_bytes_total`` grows by the staged
     batch's host bytes (frames, ground truth, masks: what ``input_h2d``
     then copies) where it is staged; bytes a pair says which width the
     frames went at. After the pull the pass publishes which
-    precision its executable ran at (:func:`_publish_precision`).
+    precision its executable ran at (:func:`_publish_precision`) and what
+    it built (:func:`_publish_programs`).
     """
     tel = telemetry if telemetry is not None else get_telemetry()
     pass_id = new_span_id()
@@ -254,7 +264,11 @@ def _run_metric_pass(
         tel.inc(
             "eval_input_bytes_total", sum(a.nbytes for a in arrays.values())
         )
-        return arrays, {"pad": pad}
+        h, w = group[0]["image1"].shape[:2]
+        return arrays, {
+            "pad": pad, "rows": len(group), "size": f"{h}x{w}",
+            "fill": sum(1 for s in group if s.get("fill")),
+        }
 
     shardings = None
     if mesh is not None and not is_multihost():
@@ -277,10 +291,12 @@ def _run_metric_pass(
 
     acc = metrics_mod.init_acc(kind)
     throttle = DispatchThrottle()  # backend-tuned in-flight bound
+    built_before = dict(getattr(fwd, "stats", {}))
     with EvalPipeline(
         dataset,
         stage,
         batch_size=batch_size,
+        fill_valid=with_valid,
         depth=depth,
         num_workers=num_workers,
         mesh=mesh,
@@ -290,18 +306,41 @@ def _run_metric_pass(
     ) as pipe:
         for index, (batch, meta) in enumerate(pipe):
             attrs = {"pass_id": pass_id, "batch": index}
-            with tel.span("eval_dispatch", **attrs):
+            with tel.span("eval_dispatch", size=meta["size"], **attrs):
                 acc = fwd.metrics(
                     batch, iters=iters, acc=acc, kind=kind, pad=meta["pad"]
                 )
-            tel.inc("eval_pairs_total", batch["image1"].shape[0])
+            tel.inc("eval_pairs_total", meta["rows"] - meta["fill"])
+            tel.inc("eval_rows_total", meta["rows"])
+            tel.inc("eval_fill_rows_total", meta["fill"])
             with tel.span("eval_throttle_wait", **attrs):
                 throttle.push(acc)
     # The window's single sanctioned pull: a few float32 sums, not fields.
     with tel.span("eval_pull", pass_id=pass_id):
         host_acc = jax.device_get(acc)
     _publish_precision(tel, fwd)
+    _publish_programs(tel, fwd, built_before, pass_id)
     return np.asarray(host_acc, np.float64)
+
+
+def _publish_programs(tel, fwd, before: dict, pass_id) -> None:
+    """What the pass cost the executable cache: the gauge
+    ``eval_programs_resident`` (executables the cache holds now: compiles
+    less evictions) and the event ``eval_pass_programs`` with the pass's
+    own ``compiles`` and ``evictions`` (``ShapeCachedForward.stats`` now
+    less ``before``, read when the pass began). A pass after the first
+    reads 0 and 0; anything else is a shape the earlier passes did not
+    bring, or a cache smaller than the pass's set of sizes. Nothing for a
+    stand-in without ``stats``."""
+    stats = getattr(fwd, "stats", None)
+    if not stats:
+        return
+    tel.gauge_set("eval_programs_resident", stats["compiles"] - stats["evictions"])
+    tel.event(
+        "eval_pass_programs", pass_id=pass_id,
+        compiles=stats["compiles"] - before.get("compiles", 0),
+        evictions=stats["evictions"] - before.get("evictions", 0),
+    )
 
 
 def _publish_precision(tel, fwd) -> None:
@@ -505,11 +544,16 @@ def validate_kitti(
     """KITTI-2015 train-split EPE + F1 (reference: evaluate.py:146-182).
     F1 = % of valid pixels with epe > 3 and epe/mag > 0.05.
 
-    Frames group per native shape (``uniform_batches``; KITTI has a
-    handful of resolutions — ``DataConfig.eval_pad_bucket`` collapses
-    the *padded* shape set so the executable count stays small). The
-    reference streams singletons; per-frame metric semantics are
-    unchanged: EPE averages per frame, F1 pools valid pixels."""
+    Frames group by native size across the whole stream
+    (``uniform_batches``): KITTI-2015's 200 pairs come in four sizes in
+    no useful order, every group is a full batch (a size's remainder is
+    filled with rows whose ``valid`` is all zero, which the metric head
+    counts as no frame), and the pass runs one executable a native size,
+    built in the first pass and never again. Each frame is padded to its
+    own multiple of 8 (``DataConfig.eval_pad_bucket`` stays 0 unless
+    asked for). The reference streams singletons; per-frame metric
+    semantics are unchanged and the sums commute: EPE averages per
+    frame, F1 pools valid pixels."""
     cfg = data_cfg or DataConfig()
     dataset = ds_mod.KITTI(None, split="training", root=cfg.root_kitti)
     dataset, n, do_reduce = _shard_for_validation(dataset, mesh)

@@ -17,9 +17,12 @@ The eval loop's steady state mirrors the train loop's (docs/PERF.md):
   batch moves to device ``depth`` batches ahead of compute — the
   consumer's ``next()`` returns device-resident arrays.
 - **compute** (:class:`ShapeCachedForward`): one compiled executable per
-  (padded shape, iters, metric kind), bounded by an LRU (KITTI's shape
-  diversity is further collapsed by pad bucketing —
-  ``ops/padding.InputPadder(bucket=...)``). The metric variant folds
+  (padded shape with the batch, native shape, iters, metric kind),
+  bounded by an LRU. A validation pass groups its samples by native size
+  across the stream (:func:`uniform_batches`), so a pass over KITTI's
+  mixed sizes runs one executable a size; pad bucketing
+  (``ops/padding.InputPadder(bucket=...)``) is the separate, optional
+  collapse of the PADDED shapes. The metric variant folds
   ``inference/metrics.py`` into the SAME jitted program as the forward
   (``RAFT.apply(metric_head=...)``), so validation never materializes a
   full flow field on host.
@@ -49,6 +52,7 @@ from typing import Callable, Iterable, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from raft_ncup_tpu.data.device_prefetch import DevicePrefetcher
 from raft_ncup_tpu.inference import metrics as metrics_mod
@@ -155,29 +159,45 @@ class SamplePrefetcher:
 
 
 def uniform_batches(
-    samples: Iterable[dict], batch_size: int
+    samples: Iterable[dict], batch_size: int, fill_valid: bool = False
 ) -> Iterator[list]:
-    """Group an ordered sample stream into fixed-size same-shape batches.
+    """Group a sample stream into same-size batches, BY NATIVE SIZE ACROSS
+    THE STREAM: one pending list a size, a group emitted when a list holds
+    ``batch_size``, the remainders at the stream's end in the order of
+    their shapes (not of arrival). Pending samples never exceed sizes x
+    (``batch_size`` - 1). A stream of one size (Sintel, chairs) comes out
+    as it always did: full groups in arrival order and one short last
+    group. Batching amortizes dispatch and fills the MXU; the reference
+    evaluates strictly frame-by-frame (evaluate.py:98-104), and every
+    metric here is a sum that commutes, so the order of dispatch changes
+    no answer.
 
-    Emits a short group on shape change (KITTI's mixed native
-    resolutions — pad bucketing upstream keeps those rare) and at stream
-    end. Batching amortizes dispatch and fills the MXU; the reference
-    evaluates strictly frame-by-frame (evaluate.py:98-104).
+    ``fill_valid`` (the kinds whose batch carries a ``valid`` mask:
+    KITTI) also emits each remainder as a FULL group: the missing rows
+    are fill rows, the group's last real sample again with its ``valid``
+    all zero and the key ``"fill"`` set. The metric head counts a row
+    without a valid pixel as no frame (inference/metrics.py), so the sums
+    are those of the real samples alone, and every group of a size has
+    one shape: the executables a pass needs are one a native size,
+    whatever the order of arrival and whatever the remainders (groups cut
+    at every change of size would need one a (size, length), and
+    KITTI-2015's four sizes in no order then overflow the executable
+    cache). Without it a remainder stays short: a kind without a mask has
+    no operand that could mark a fill row.
     """
-    pending: list = []
-    shape = None
+    pending: dict = {}
     for s in samples:
-        if shape is not None and s["image1"].shape != shape:
-            if pending:
-                yield pending
-            pending = []
         shape = s["image1"].shape
-        pending.append(s)
-        if len(pending) == batch_size:
-            yield pending
-            pending = []
-    if pending:
-        yield pending
+        pending.setdefault(shape, []).append(s)
+        if len(pending[shape]) == batch_size:
+            yield pending.pop(shape)
+    for shape in sorted(pending):
+        group = pending[shape]
+        if fill_valid:
+            last = group[-1]
+            fill = {**last, "valid": np.zeros_like(last["valid"]), "fill": True}
+            group = group + [fill] * (batch_size - len(group))
+        yield group
 
 
 class EvalPipeline:
@@ -186,7 +206,9 @@ class EvalPipeline:
 
     ``stage_fn(group) -> (arrays, meta)`` turns a list of samples into a
     dict of host numpy arrays (stack + pad) plus a small host-side meta
-    dict (pad spec, group size). Staging runs inside the
+    dict (pad spec, group size). The groups are :func:`uniform_batches`'s:
+    by native size across the stream, and with ``fill_valid`` every group
+    a full one (fill rows carry the key ``"fill"``). Staging runs inside the
     DevicePrefetcher's worker thread, and the staged arrays are moved to
     device ``depth`` batches ahead — iterating yields
     ``(device_batch, meta)`` pairs whose alignment is guaranteed by the
@@ -215,6 +237,7 @@ class EvalPipeline:
         stage_fn: Callable[[list], tuple],
         *,
         batch_size: int = 1,
+        fill_valid: bool = False,
         depth: int = 2,
         num_workers: int = 4,
         lookahead: Optional[int] = None,
@@ -233,7 +256,7 @@ class EvalPipeline:
 
         def staged():
             try:
-                for group in uniform_batches(sp, batch_size):
+                for group in uniform_batches(sp, batch_size, fill_valid):
                     arrays, meta = stage_fn(group)
                     meta_q.append(meta)
                     yield arrays
@@ -390,14 +413,16 @@ class ShapeCachedForward:
     fingerprint, padded shape, iters, warm-start presence, metric
     kind/pad, precision-policy fingerprint).
 
-    Frames stream with dataset-dependent sizes, so each unique padded
-    shape compiles once; the LRU bound (default 8, knob:
-    ``DataConfig.eval_cache_size``) keeps KITTI-style shape diversity
+    Frames stream with dataset-dependent sizes, so each unique shape
+    compiles once; the LRU bound (default 8, knob:
+    ``DataConfig.eval_cache_size``) keeps footage of many sizes (a
+    folder of clips, a submission pass, which dispatches pair by pair)
     from growing the cache without limit, and ``stats`` counts
     compiles/hits/evictions so an eviction storm is visible instead of
-    silent recompile churn (pair with pad bucketing,
-    ``InputPadder(bucket=...)``, to make the executable set small and
-    known up front).
+    silent recompile churn. A validation pass holds the set at one
+    executable a native size (:func:`uniform_batches`: KITTI-2015's four
+    fit the bound whatever their order); ``InputPadder(bucket=...)``
+    makes it smaller still where the sizes outnumber the bound.
 
     ``policy`` (a :mod:`raft_ncup_tpu.precision` preset name or
     ``PrecisionPolicy``; default = the model's own) selects the dtype

@@ -128,18 +128,21 @@ def make_chairs_fixture(root, n=3, hw=(48, 64)):
     return split_file
 
 
-def make_kitti_fixture(root, split, n=2, hw=(48, 64)):
+def make_kitti_fixture(root, split, n=2, hw=(48, 64), sizes=None):
+    """``sizes``: one (h, w) a frame (KITTI's native sizes differ from
+    drive to drive); default ``n`` frames of ``hw``."""
+    sizes = sizes or [hw] * n
     d = root / split
     (d / "image_2").mkdir(parents=True)
     g = np.random.default_rng(1)
-    for i in range(n):
+    for i, hw in enumerate(sizes):
         for suffix in ("10", "11"):
             Image.fromarray(
                 g.integers(0, 255, (*hw, 3), dtype=np.uint8)
             ).save(d / "image_2" / f"{i:06d}_{suffix}.png")
     if split == "training":
         (d / "flow_occ").mkdir(parents=True)
-        for i in range(n):
+        for i, hw in enumerate(sizes):
             write_flow_kitti(
                 d / "flow_occ" / f"{i:06d}_10.png",
                 g.normal(size=(*hw, 2)).astype(np.float32),
@@ -197,6 +200,24 @@ class TestEvaluation:
         out = validate_kitti(model, variables, cfg, iters=2)
         assert np.isfinite(out["kitti-epe"])
         assert 0.0 <= out["kitti-f1"] <= 100.0
+
+    def test_validate_kitti_over_mixed_sizes_is_the_pair_at_a_time_pass(
+        self, tmp_path, tiny_raft
+    ):
+        """Five frames of two native sizes, alternating, at batch 2: the
+        pass groups them by size across the stream and fills each
+        remainder, and the numbers are upstream's one-pair-at-a-time
+        numbers to float32 summation order."""
+        from raft_ncup_tpu.config import DataConfig
+
+        a, b = (45, 61), (43, 64)  # both pad to 48x64
+        make_kitti_fixture(tmp_path / "KITTI", "training", sizes=[a, b, a, b, a])
+        model, variables = tiny_raft
+        cfg = DataConfig(root_kitti=str(tmp_path / "KITTI"))
+        one = validate_kitti(model, variables, cfg, iters=2, batch_size=1)
+        two = validate_kitti(model, variables, cfg, iters=2, batch_size=2)
+        assert two["kitti-epe"] == pytest.approx(one["kitti-epe"], rel=1e-5)
+        assert two["kitti-f1"] == pytest.approx(one["kitti-f1"], rel=1e-6)
 
     def test_validate_sintel_and_submission(self, tmp_path, tiny_raft):
         from raft_ncup_tpu.config import DataConfig

@@ -125,12 +125,168 @@ class TestSamplePrefetcher:
 
 
 class TestUniformBatches:
-    def test_groups_and_shape_breaks(self):
+    def test_groups_by_size_across_the_stream(self):
         a = {"image1": np.zeros((4, 6, 3), np.float32)}
         b = {"image1": np.zeros((6, 4, 3), np.float32)}
         groups = list(uniform_batches(iter([a, a, a, b, b, a]), 2))
-        sizes = [len(g) for g in groups]
-        assert sizes == [2, 1, 2, 1]  # short group at each shape change
+        # no short group at a change of shape: a's four make two full groups
+        assert [(len(g), g[0]["image1"].shape[0]) for g in groups] == [
+            (2, 4), (2, 6), (2, 4)]
+
+    @pytest.mark.parametrize("n,batch_size,sizes", [
+        (5, 2, [2, 2, 1]), (4, 4, [4]), (3, 1, [1, 1, 1]), (7, 3, [3, 3, 1]),
+    ])
+    def test_one_size_comes_out_as_it_always_did(self, n, batch_size, sizes):
+        """A stream of one size (Sintel, chairs): full groups in arrival
+        order and one short last group, with or without ``fill_valid`` off."""
+        samples = _mk_samples(n)
+        groups = list(uniform_batches(iter(samples), batch_size))
+        assert [len(g) for g in groups] == sizes
+        assert [s for g in groups for s in g] == samples  # order kept
+
+
+# ---------------------------------- a pass over mixed native sizes (KITTI)
+
+MIXED_COUNTS = {(13, 21): 5, (11, 18): 3, (16, 24): 6}  # all pad to 16x24
+MIXED_ORDERS = ["runs", 0, 1, 2, 3]
+
+
+def _mixed_samples(order, counts=MIXED_COUNTS):
+    """Samples of three native sizes with sparse masks, in runs by size or
+    in a seeded permutation; the same samples whatever the order."""
+    g = np.random.default_rng(7)
+    samples = []
+    for hw, n in counts.items():
+        for _ in range(n):
+            valid = (g.random(hw) < 0.3).astype(np.float32)
+            valid[: hw[0] // 3] = 0.0
+            samples.append({
+                "image1": g.random((*hw, 3), np.float32) * 4,
+                "image2": g.random((*hw, 3), np.float32),
+                "flow": g.random((*hw, 2), np.float32) * 4,
+                "valid": valid,
+            })
+    if order != "runs":
+        samples = [samples[i] for i in np.random.default_rng(order).permutation(len(samples))]
+    return samples
+
+
+class TestMixedSizePass:
+    """``uniform_batches(fill_valid=True)`` and the pass built on it: one
+    executable a native size whatever the order of arrival, remainders
+    dispatched as whole batches whose fill rows count as no frame, and the
+    sums of the one-pair-at-a-time pass."""
+
+    BATCH = 4
+
+    def _pass(self, fwd, samples, batch_size, tel=None):
+        from raft_ncup_tpu.evaluation import _run_metric_pass
+
+        return _run_metric_pass(
+            fwd, _ListDataset(samples), kind="kitti", iters=1,
+            batch_size=batch_size, pad_mode="kitti", with_valid=True,
+            num_workers=2, telemetry=tel,
+        )
+
+    @pytest.mark.parametrize("order", MIXED_ORDERS)
+    def test_same_groups_for_every_order_and_pending_is_bounded(self, order):
+        samples = _mixed_samples(order)
+        consumed = [0]
+
+        def stream():
+            for s in samples:
+                consumed[0] += 1
+                yield s
+
+        groups, real_out = [], 0
+        for g in uniform_batches(stream(), self.BATCH, fill_valid=True):
+            if consumed[0] < len(samples):  # mid-stream: what is still pending
+                real_out += len(g)
+                pending = consumed[0] - real_out
+                assert pending <= len(MIXED_COUNTS) * (self.BATCH - 1)
+            groups.append(g)
+        assert all(len(g) == self.BATCH for g in groups)
+        assert all(len({s["image1"].shape for s in g}) == 1 for g in groups)
+        shape_of = sorted(
+            (g[0]["image1"].shape[:2], sum(1 for s in g if s.get("fill"))) for g in groups)
+        assert shape_of == [((11, 18), 1), ((13, 21), 0), ((13, 21), 3),
+                            ((16, 24), 0), ((16, 24), 2)]
+        real = [s for g in groups for s in g if not s.get("fill")]
+        assert sorted(id(s) for s in real) == sorted(id(s) for s in samples)
+        for g in groups:
+            for s in g:
+                if s.get("fill"):  # a real sample of the group again, masked out
+                    assert not s["valid"].any()
+                    assert any(s["image1"] is r["image1"] for r in g if not r.get("fill"))
+
+    @pytest.mark.parametrize("order", MIXED_ORDERS)
+    def test_one_program_a_size_and_the_sums_of_one_pair_at_a_time(self, order):
+        from raft_ncup_tpu.observability import Telemetry
+
+        samples = _mixed_samples(order)
+        tel = Telemetry()
+        fwd = ShapeCachedForward(_DummyModel(), {}, telemetry=tel)
+        accs = [self._pass(fwd, samples, self.BATCH, tel) for _ in range(3)]
+        assert fwd.stats["compiles"] == 3 and fwd.stats["evictions"] == 0
+        events = tel.tracer.records("eval_pass_programs")
+        assert [(e["attrs"]["compiles"], e["attrs"]["evictions"]) for e in events] == [
+            (3, 0), (0, 0), (0, 0)]
+        assert tel.registry.get("eval_programs_resident").value == 3
+        # 14 real pairs in 5 batches of 4: 6 fill rows, counted as rows, not as pairs
+        assert tel.counter_value("eval_pairs_total") == 3 * 14
+        assert tel.counter_value("eval_rows_total") == 3 * 20
+        assert tel.counter_value("eval_fill_rows_total") == 3 * 6
+        sizes = {r["attrs"]["size"] for r in tel.tracer.records("eval_dispatch")}
+        assert sizes == {"13x21", "11x18", "16x24"}
+        # upstream's way: one pair at a time, no fill rows
+        one = self._pass(ShapeCachedForward(_DummyModel(), {}, telemetry=Telemetry()), samples, 1)
+        for acc in accs:
+            assert acc[1] == one[1] == 14  # frames: a fill row is no frame
+            np.testing.assert_array_equal(acc[2:], one[2:])  # outliers, valid pixels
+            np.testing.assert_allclose(acc[0], one[0], rtol=1e-5)  # float32 summation order
+
+    def test_a_real_frame_without_a_valid_pixel_still_counts_as_no_frame(self):
+        samples = _mixed_samples(1)
+        samples[4] = {**samples[4], "valid": np.zeros_like(samples[4]["valid"])}
+        from raft_ncup_tpu.observability import Telemetry
+
+        tel = Telemetry()
+        acc = self._pass(ShapeCachedForward(_DummyModel(), {}, telemetry=tel), samples, self.BATCH, tel)
+        assert acc[1] == 13 and tel.counter_value("eval_pairs_total") == 14
+        assert acc[3] == sum(s["valid"].sum() for s in samples)
+
+    def test_a_cache_smaller_than_the_sizes_is_reported_pass_by_pass(self, capsys):
+        from raft_ncup_tpu.observability import Telemetry
+
+        tel = Telemetry()
+        fwd = ShapeCachedForward(_DummyModel(), {}, cache_size=2, telemetry=tel)
+        for _ in range(2):
+            self._pass(fwd, _mixed_samples(2), self.BATCH, tel)
+        events = [e["attrs"] for e in tel.tracer.records("eval_pass_programs")]
+        assert events[1]["compiles"] > 0 and events[1]["evictions"] > 0
+        assert tel.registry.get("eval_programs_resident").value == 2
+        assert "EVICTING" in capsys.readouterr().err
+
+    @pytest.mark.parametrize("kind,extra", [("epe", {}), ("px", {"pad_mode": "sintel"})])
+    def test_kinds_without_a_mask_keep_their_short_last_batch(self, kind, extra):
+        """No fill rows where the batch has no ``valid``: the remainder of
+        each size is dispatched short, as a Sintel pass's always was, so those
+        kinds keep the executables (and keys) they had."""
+        from raft_ncup_tpu.evaluation import _run_metric_pass
+        from raft_ncup_tpu.observability import Telemetry
+
+        tel = Telemetry()
+        fwd = ShapeCachedForward(_DummyModel(), {}, telemetry=tel)
+        samples = _mk_samples(5, hw=(16, 24))
+        acc = _run_metric_pass(
+            fwd, _ListDataset(samples), kind=kind, iters=1, batch_size=2,
+            num_workers=2, telemetry=tel, **extra,
+        )
+        assert acc[1] == 5 * 16 * 24
+        assert tel.counter_value("eval_fill_rows_total") == 0
+        assert tel.counter_value("eval_rows_total") == tel.counter_value("eval_pairs_total") == 5
+        batches = sorted(key[2][0] for key in fwd._fns)  # the keys' image shapes
+        assert batches == [1, 2] and all("valid" not in key[4] for key in fwd._fns)
 
 
 # --------------------------------------------------------- EvalPipeline
