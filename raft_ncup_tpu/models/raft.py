@@ -51,7 +51,7 @@ from raft_ncup_tpu.nn.extractor import Encoder
 from raft_ncup_tpu.nn.update import BasicUpdateBlock, SmallUpdateBlock
 from raft_ncup_tpu.nn.upsampler import build_upsampler
 from raft_ncup_tpu.ops.corr import (
-    build_corr_pyramid,
+    build_loop_pyramid,
     corr_lookup,
     corr_lookup_onthefly,
 )
@@ -301,13 +301,13 @@ class RAFT:
     ):
         """Correlation-lookup closure over a micro-batch's feature maps,
         per ``cfg.corr_impl`` (volume / onthefly / pallas). ``train``: the
-        program differentiates the lookup, which the ``volume`` path's
-        choice of a contraction needs to know (``ops/corr.py::contract_form``)."""
+        program differentiates the lookup, which the ``volume`` path's choice
+        of a pyramid and a contraction needs to know (``ops/corr.py``)."""
         cfg = self.cfg
         policy = self.policy
         radius = cfg.resolved_corr_radius
         if cfg.corr_impl == "volume":
-            pyramid = build_corr_pyramid(
+            pyramid = build_loop_pyramid(
                 fmap1, fmap2, cfg.corr_levels, dtype=policy.corr_jnp,
                 differentiated=train,
             )

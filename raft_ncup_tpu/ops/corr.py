@@ -19,6 +19,27 @@ Two interchangeable implementations behind one signature:
   5.3 against 9.9 ms; 27x64, 46x96, 68x120, 136x240: 1.1-2.6x slower), so
   ``_window_contract`` chooses by that.
 
+  The multiply + reduce form is bound by the bytes of the level, and how
+  often it reads them the compiler decides from the level's WIDTH. It lays
+  a level out queries in lanes; where the width is whole sublane tiles
+  (27x64, 47x160) x goes to the sublanes and the first stage takes all K
+  taps in one walk of the level; at a width that is not (KITTI's 47x156, 8
+  x 7,332 queries) the batch goes there, the taps are cut into three
+  blocks of three and the 1.72 GB level is walked once a block
+  (``output_window_bounds`` [3,47,6,1,1] against [1,9,47,2,1]; its own
+  ``estimated_cycles`` do not tell the two apart): 7.97 ms an iteration at
+  level 0 and 2.16 at level 1 where 47x160 and 23x80 take 3.34 and 0.95,
+  the four levels 16.9 against 10.2 (root PERF.md section 6, PR 47, which
+  lands what PR 46 measured). So a
+  refinement loop that is not differentiated STORES a level whose width is
+  no multiple of 8 at the next one (``build_loop_pyramid``,
+  ``stored_width``: zero columns, +2.6% of level 0), once a pair where it
+  is built. Level 0 stored 256 wide and sent to the MXU like Sintel's read
+  6.11 ms for 3.34 + 1.81 and asks 3 GiB more of the device.
+  The training step's pyramid keeps every level's own width: its backward
+  lays the cotangent sums out anew with every change to a level (PERF.md
+  section 5).
+
   A program that is not differentiated contracts a narrower level that is
   STORED narrow (bfloat16 under ``bf16_infer``) in a third order of the
   same float32 sums, ``_tap_sums``: the multiply + reduce form pays a pass
@@ -101,7 +122,9 @@ class CorrPyramid(NamedTuple):
 
     ``levels[l]`` has shape (B, H1*W1, H2/2^l, W2/2^l): all-pairs
     correlation between every query pixel of fmap1 and the (pooled) pixels
-    of fmap2, pre-divided by sqrt(dim) (reference: core/corr.py:47-55).
+    of fmap2, pre-divided by sqrt(dim) (reference: core/corr.py:47-55);
+    from :func:`build_loop_pyramid`, zero columns after those up to
+    :func:`stored_width`.
     """
 
     levels: tuple[jax.Array, ...]
@@ -136,11 +159,52 @@ def _delta_window(radius: int, dtype=jnp.float32) -> jax.Array:
     return jnp.stack([di, dj], axis=-1)  # [..., 0] -> x offset, [..., 1] -> y
 
 
+def _zero_columns(x: jax.Array, axis: int, width: int) -> jax.Array:
+    """``x`` with zeros after its ``axis`` up to ``width``; ``x`` itself,
+    and no ``pad`` in the trace, where it is that wide already."""
+    if x.shape[axis] == width:
+        return x
+    pads = [(0, 0)] * x.ndim
+    pads[axis] = (0, width - x.shape[axis])
+    return jnp.pad(x, pads)
+
+
+def _build_levels(fmap1, fmap2, num_levels: int, dtype, stored: Sequence[int]) -> tuple:
+    """The volume of (B, H, W, C) feature maps and its average pyramid,
+    level ``l`` (``W >> l`` columns of its own) with zero columns on its
+    right up to ``stored[l]``. Level 0's are zero FEATURE columns of
+    ``fmap2``, so the product writes the level at its stored width and no
+    second copy of it exists; a pooled level is pooled from the columns
+    that are the level's own (an odd width pools VALID: 153 -> 76, no
+    half-weight column) and padded after."""
+    B, H, W, C = fmap1.shape
+    f1 = fmap1.reshape(B, H * W, C).astype(dtype)
+    f2 = _zero_columns(fmap2.astype(dtype), 2, stored[0])
+    f2 = f2.reshape(B, H * stored[0], C)
+    record_site("corr_pyramid/volume", dtype, jnp.float32)
+    corr = jnp.einsum(
+        "bxc,byc->bxy", f1, f2, preferred_element_type=jnp.float32
+    ) / math.sqrt(C)
+    corr = corr.astype(dtype).reshape(B, H * W, H, stored[0])
+
+    levels = [corr]
+    for lvl in range(1, num_levels):
+        own = levels[-1]
+        if own.shape[3] != W >> (lvl - 1):
+            own = own[..., : W >> (lvl - 1)]
+        n, q, h, w = own.shape
+        pooled = avg_pool2(own.reshape(n * q, h, w, 1))
+        pooled = pooled.reshape(n, q, pooled.shape[1], pooled.shape[2])
+        levels.append(_zero_columns(pooled, 3, stored[lvl]))
+    return tuple(levels)
+
+
 def build_corr_pyramid(
     fmap1: jax.Array, fmap2: jax.Array, num_levels: int = 4, dtype=None,
     differentiated: bool = False,
 ) -> CorrPyramid:
-    """Compute the all-pairs correlation volume and its average pyramid.
+    """Compute the all-pairs correlation volume and its average pyramid,
+    every level at its own width.
 
     Args:
       fmap1, fmap2: (B, H, W, C) feature maps (cast to ``dtype``, default
@@ -155,23 +219,49 @@ def build_corr_pyramid(
       differentiated: the program takes gradients through the lookups of
         this pyramid (:class:`DifferentiatedCorrPyramid`).
     """
-    B, H, W, C = fmap1.shape
-    dtype = dtype or jnp.float32
-    f1 = fmap1.reshape(B, H * W, C).astype(dtype)
-    f2 = fmap2.reshape(B, H * W, C).astype(dtype)
-    record_site("corr_pyramid/volume", dtype, jnp.float32)
-    corr = jnp.einsum(
-        "bxc,byc->bxy", f1, f2, preferred_element_type=jnp.float32
-    ) / math.sqrt(C)
-    corr = corr.astype(dtype).reshape(B, H * W, H, W)
-
-    levels = [corr]
-    for _ in range(num_levels - 1):
-        n, q, h, w = levels[-1].shape
-        pooled = avg_pool2(levels[-1].reshape(n * q, h, w, 1))
-        levels.append(pooled.reshape(n, q, pooled.shape[1], pooled.shape[2]))
+    W = fmap1.shape[2]
+    levels = _build_levels(
+        fmap1, fmap2, num_levels, dtype or jnp.float32,
+        [W >> lvl for lvl in range(num_levels)],
+    )
     kind = DifferentiatedCorrPyramid if differentiated else CorrPyramid
-    return kind(levels=tuple(levels), query_hw=(H, W))
+    return kind(levels=levels, query_hw=fmap1.shape[1:3])
+
+
+def stored_width(wl: int) -> int:
+    """The width a refinement loop that is not differentiated stores a
+    level ``wl`` columns wide at: the next multiple of the TPU's 8
+    sublanes (timings and the compiler's schedules: module docstring). The
+    rule reads the shape alone: a multiple of 8 (of 128 among them, the
+    ``dot`` form's) is stored as it is."""
+    return -(-wl // _SUBLANES) * _SUBLANES
+
+
+def build_loop_pyramid(
+    fmap1: jax.Array, fmap2: jax.Array, num_levels: int = 4, dtype=None,
+    differentiated: bool = False,
+) -> CorrPyramid:
+    """:func:`build_corr_pyramid` for a refinement loop, which looks its
+    pyramid up every iteration. One that takes gradients through its
+    lookups (``differentiated``) gets that very pyramid: its backward lays
+    the cotangent sums out anew with every change to a level. Any other
+    gets each level :func:`stored_width` wide, zero columns on its right,
+    which the lookup's weights meet as they meet ``padding_mode='zeros'``
+    (a tap that lands there is a weight on a zero: the same sums plus exact
+    zeros); where every width is a multiple of 8 already (Sintel's 128 / 64
+    / 32 / 16) that is :func:`build_corr_pyramid`'s trace, letter for
+    letter. A builder of its own and not the other's default: what reads
+    ``levels[l]`` as the level itself (the benchmark's site checks, the
+    tests' oracles) builds the other."""
+    if differentiated:
+        return build_corr_pyramid(fmap1, fmap2, num_levels, dtype, True)
+    W = fmap1.shape[2]
+    stored = [stored_width(W >> lvl) for lvl in range(num_levels)]
+    levels = _build_levels(fmap1, fmap2, num_levels, dtype or jnp.float32, stored)
+    _stored_widths.update({
+        f"level{lvl}": s for lvl, s in enumerate(stored) if s != W >> lvl
+    })
+    return CorrPyramid(levels=levels, query_hw=fmap1.shape[1:3])
 
 
 def _tap_row(t: jax.Array, size: int) -> jax.Array:
@@ -201,6 +291,9 @@ def _axis_weights(centre: jax.Array, size: int, radius: int) -> jax.Array:
 # The TPU's lane width: a level whose row fills whole lanes contracts its
 # x axis on the MXU (``_window_contract``).
 _LANES = 128
+# Its sublane count: a refinement loop that is not differentiated stores a
+# level of any other width at the next multiple of it (``stored_width``).
+_SUBLANES = 8
 
 # The smallest level (elements a query) that takes the tap sums (13x32
 # gains, 6x16 does not: module docstring), and the rows of it that keep the
@@ -211,24 +304,36 @@ _HEAD_ROWS = 2
 # Which form each level of the last traced lookup took: a trace-time tally
 # in the manner of ``precision/sites.py`` (reset before a program is
 # lowered and read after it: ``inference/costs.build_and_record``).
-_contract_forms: dict[str, str] = {}
+_contract_forms: dict[str, tuple[str, str]] = {}
+# The stored width of each level that a ``build_loop_pyramid`` traced since
+# the last reset stored wider than the level's own.
+_stored_widths: dict[str, int] = {}
 
 
 def reset_contract_forms() -> None:
     _contract_forms.clear()
+    _stored_widths.clear()
 
 
 def contract_forms() -> dict:
     """``{"level0": "<form>/<stored dtype>", ...}`` of the ``volume`` lookup
-    traced since the last reset (:func:`contract_form` names the forms);
+    traced since the last reset (:func:`contract_form` names the forms),
+    ``"<form>@<stored width>/<stored dtype>"`` for a level the pyramid
+    traced since then stores wider than its own (:func:`stored_width`);
     empty where the program has none (the ``onthefly`` and ``pallas``
     paths)."""
-    return dict(sorted(_contract_forms.items()))
+    return {
+        level: f"{form}@{_stored_widths[level]}/{stored}"
+        if level in _stored_widths else f"{form}/{stored}"
+        for level, (form, stored) in sorted(_contract_forms.items())
+    }
 
 
 def contract_form(level_hw, stored, computed, differentiated: bool) -> str:
-    """How a level of this shape, stored as ``stored`` and contracted in
-    ``computed``, is contracted (timings: module docstring):
+    """How a level of this shape (as it is STORED: :func:`build_loop_pyramid`
+    has made a width that misfits the multiply + reduce form a multiple of
+    8 by now, except in a differentiated pyramid), stored as ``stored`` and
+    contracted in ``computed``, is contracted (timings: module docstring):
 
     - ``"dot"``: a row that fills whole lanes takes its x axis to the MXU;
       the compiler widens a narrow level inside that fusion.
@@ -357,7 +462,9 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Arr
     the selection is arithmetic.
 
     Args:
-      pyramid: from :func:`build_corr_pyramid`. One built for a program
+      pyramid: from :func:`build_corr_pyramid` or, a level's zero columns
+        reading as the outside of the level, :func:`build_loop_pyramid`. One
+        built for a program
         that differentiates its lookup (the training step) keeps to the two
         forms of :func:`_window_contract`, whose trace a step pays three
         times over at every start; any other may take :func:`_tap_sums` too
@@ -382,7 +489,7 @@ def corr_lookup(pyramid: CorrPyramid, coords: jax.Array, radius: int) -> jax.Arr
         centre = coords.reshape(B, H * W, 2).astype(wdt) / (2**lvl)
         record_site(f"level{lvl}", wdt)
         form = contract_form((Hl, Wl), corr.dtype, wdt, differentiated)
-        _contract_forms[f"level{lvl}"] = f"{form}/{corr.dtype}"
+        _contract_forms[f"level{lvl}"] = (form, str(corr.dtype))
         if form == "tap_sums":
             win = _tap_sums(corr, centre, radius, wdt)
         else:

@@ -14,7 +14,11 @@ from raft_ncup_tpu.ops import (
     corr_lookup,
     corr_lookup_onthefly,
 )
-from raft_ncup_tpu.ops.corr import CorrPyramid, DifferentiatedCorrPyramid
+from raft_ncup_tpu.ops.corr import (
+    CorrPyramid,
+    DifferentiatedCorrPyramid,
+    build_loop_pyramid,
+)
 from raft_ncup_tpu.ops.geometry import grid_sample
 
 
@@ -99,18 +103,119 @@ def test_onthefly_matches_volume():
     np.testing.assert_allclose(vol, otf, atol=2e-4)
 
 
-def test_corr_pyramid_shapes():
+LOOP_PYRAMIDS = {  # what a refinement loop builds -> the widths of 24 / 12 / 6 / 3
+    "forward_only": (lambda f: build_loop_pyramid(f, f, 4), CorrPyramid, (24, 16, 8, 8)),
+    "differentiated": (
+        lambda f: build_loop_pyramid(f, f, 4, differentiated=True),
+        DifferentiatedCorrPyramid, (24, 12, 6, 3),
+    ),
+    "plain": (lambda f: build_corr_pyramid(f, f, num_levels=4), CorrPyramid, (24, 12, 6, 3)),
+}
+
+
+@pytest.mark.parametrize("which", list(LOOP_PYRAMIDS))
+def test_corr_pyramid_shapes(which):
+    """``build_corr_pyramid`` gives every level at its own width, whoever
+    asks; a refinement loop that is not differentiated stores a level whose
+    width is no multiple of 8 at the next one (``ops/corr.py::stored_width``),
+    one that is gets the plain builder's pyramid under its own type."""
+    build, kind, widths = LOOP_PYRAMIDS[which]
     B, H, W, C = 2, 16, 24, 4
-    f = jnp.zeros((B, H, W, C))
-    pyr = build_corr_pyramid(f, f, num_levels=4)
+    pyr = build(jnp.zeros((B, H, W, C)))
+    assert type(pyr) is kind and pyr.query_hw == (H, W)
     assert [lvl.shape for lvl in pyr.levels] == [
-        (B, H * W, 16, 24),
-        (B, H * W, 8, 12),
-        (B, H * W, 4, 6),
-        (B, H * W, 2, 3),
+        (B, H * W, H >> lvl, width) for lvl, width in enumerate(widths)
     ]
     out = corr_lookup(pyr, coords_grid(B, H, W), radius=4)
     assert out.shape == (B, H, W, 4 * 81)
+
+
+# Widths that misfit as `eval_kitti_nc`'s do: 20 -> 10 -> 5 -> 2 is even at
+# level 0 (156 -> 78 -> 39 -> 19), 23 -> 11 -> 5 -> 2 drops a column at every
+# pooling (153 -> 76), 13 -> 6 -> 3 -> 1 ends one column wide.
+MISFIT_WIDTHS = [(10, 20), (9, 23), (9, 13)]
+
+
+def _hw_id(hw) -> str:
+    return "x".join(map(str, hw))
+
+
+@pytest.mark.parametrize("stored", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("hw", MISFIT_WIDTHS, ids=_hw_id)
+def test_stored_levels_are_the_levels_own_columns_and_zeros(hw, stored):
+    """Every level of the pyramid of a loop that is not differentiated holds
+    the columns of the level at its own width (what ``build_corr_pyramid``
+    builds, for a loop that is differentiated too: an odd width pools VALID, no
+    half-weight column; to the rounding of a product blocked for another
+    width, on this backend) and exact zeros after them up to a multiple of
+    8."""
+    H, W = hw
+    rng = np.random.default_rng(12)
+    f1, f2 = (
+        jnp.asarray(rng.standard_normal((2, H, W, 8)).astype(np.float32))
+        for _ in range(2)
+    )
+    ours = jax.jit(build_loop_pyramid, static_argnums=(2, 3))(f1, f2, 4, stored)
+    own = jax.jit(build_corr_pyramid, static_argnums=(2, 3))(f1, f2, 4, stored)
+    assert type(ours) is type(own) is CorrPyramid and ours.query_hw == own.query_hw == hw
+    for lvl, (got, want) in enumerate(zip(ours.levels, own.levels)):
+        wl = W >> lvl
+        assert want.shape == (2, H * W, H >> lvl, wl) and want.dtype == stored
+        assert got.shape == (2, H * W, H >> lvl, -(-wl // 8) * 8) and got.dtype == stored
+        got, want = (np.asarray(x.astype(jnp.float32)) for x in (got, want))
+        assert want.any() and got.shape != want.shape
+        np.testing.assert_allclose(got[..., :wl], want, atol=2e-6, rtol=0)
+        assert not got[..., wl:].any()
+
+
+@pytest.mark.parametrize(
+    "width,differentiated,tally",
+    [
+        (128, False, ["dot", "multiply_reduce", "multiply_reduce", "multiply_reduce"]),
+        (96, True, ["multiply_reduce"] * 4),
+        (96, False, ["multiply_reduce"] * 3 + ["multiply_reduce@16"]),
+        (156, True, ["multiply_reduce"] * 4),
+        (156, False, ["multiply_reduce@160", "multiply_reduce@80",
+                      "multiply_reduce@40", "multiply_reduce@24"]),
+        (132, False, ["multiply_reduce@136", "multiply_reduce@72",
+                      "multiply_reduce@40", "multiply_reduce"]),
+    ],
+    ids=["128", "96-differentiated", "96", "156-differentiated", "156", "132"],
+)
+def test_only_a_misfit_width_of_a_forward_only_pyramid_traces_a_pad(
+    width, differentiated, tally
+):
+    """The Sintel grid (128 / 64 / 32 / 16 columns) and any pyramid built
+    for a program that differentiates its lookup (a training crop's 96 / 48
+    / 24 / 12, where a forward-only program stores level 3 at 16) trace no
+    ``pad``, the tally they always had and the plain builder's jaxpr, letter
+    for letter; a width of `eval_kitti_nc`'s traces one ``pad`` a level that
+    misfits (156 -> 78 -> 39 -> 19: all four; 132 -> 66 -> 33 -> 16: three)
+    and names the stored width in its tally."""
+    from raft_ncup_tpu.ops import corr
+
+    H, C = 8, 4
+    f = jax.ShapeDtypeStruct((1, H, width, C), jnp.float32)
+    coords = jax.ShapeDtypeStruct((1, H, width, 2), jnp.float32)
+
+    def program(f1, f2, c):
+        pyr = build_loop_pyramid(f1, f2, 4, differentiated=differentiated)
+        return corr_lookup(pyr, c, 4)
+
+    def plain(f1, f2, c):  # every level at its own width
+        pyr = build_corr_pyramid(f1, f2, 4, differentiated=differentiated)
+        return corr_lookup(pyr, c, 4)
+
+    theirs = jax.make_jaxpr(plain)(f, f, coords)
+    corr.reset_contract_forms()
+    traced = jax.make_jaxpr(program)(f, f, coords)
+    assert corr.contract_forms() == {
+        f"level{lvl}": f"{form}/float32" for lvl, form in enumerate(tally)
+    }
+    stored_wider = sum("@" in form for form in tally)
+    names = [e.primitive.name for e in _walk(traced.jaxpr)]
+    assert names.count("pad") == stored_wider
+    assert (str(traced) == str(theirs)) == (stored_wider == 0)
 
 
 # ---------------------------------------------------------------------------
@@ -141,11 +246,15 @@ _lookup = jax.jit(corr_lookup, static_argnums=2)
 _oracle = jax.jit(gather_lookup_oracle, static_argnums=2)
 
 
-def _random_pyramid(seed, B, H, W, dtype=jnp.float32):
+def _random_pyramid(
+    seed, B, H, W, dtype=jnp.float32, differentiated=False, build=build_loop_pyramid
+):
+    """What a refinement loop builds (``build_loop_pyramid``), or the same
+    features through ``build=build_corr_pyramid``."""
     rng = np.random.default_rng(seed)
     f1 = jnp.asarray(rng.standard_normal((B, H, W, 8)).astype(np.float32))
     f2 = jnp.asarray(rng.standard_normal((B, H, W, 8)).astype(np.float32))
-    return build_corr_pyramid(f1, f2, num_levels=4, dtype=dtype)
+    return build(f1, f2, num_levels=4, dtype=dtype, differentiated=differentiated)
 
 
 def _jittered_coords(seed, B, H, W, spread):
@@ -156,9 +265,16 @@ def _jittered_coords(seed, B, H, W, spread):
 
 
 def _assert_matches_oracle(ours, pyr, coords, radius):
+    """Against the gathers over each level's OWN columns: what a level is
+    stored with beyond them (``ops/corr.py::stored_width``) the oracle
+    never sees, so a tap there must read as one outside the level."""
+    W = pyr.query_hw[1]
+    own = CorrPyramid(
+        tuple(lvl[..., : W >> i] for i, lvl in enumerate(pyr.levels)), pyr.query_hw
+    )
     np.testing.assert_allclose(
         np.asarray(ours),
-        np.asarray(_oracle(pyr, coords, radius)),
+        np.asarray(_oracle(own, coords, radius)),
         atol=2e-6, rtol=1e-6,
     )
 
@@ -167,12 +283,14 @@ def _assert_matches_oracle(ours, pyr, coords, radius):
 # at levels 0 -> 1 (11 -> 5) and 1 -> 2 (5 -> 2), as 55 -> 27 -> 13 does.
 # A 128-wide level 0 (whole lanes) takes the lookup's matmul form for x;
 # every narrower level takes multiply + reduce.
+# The last three are widths no level of which is a multiple of 8 as it
+# stands (``MISFIT_WIDTHS``): every level is stored wider than its own.
 SHAPES = pytest.mark.parametrize(
-    "hw", [(11, 16), (16, 24), (9, 128)], ids=["11x16", "16x24", "9x128"]
+    "hw", [(11, 16), (16, 24), (9, 128), *MISFIT_WIDTHS], ids=_hw_id
 )
-BOTH_FORMS = pytest.mark.parametrize(
-    "hw", [(11, 16), (9, 128)], ids=["11x16", "9x128"]
-)
+_BOTH_FORMS = [(11, 16), (9, 128)]
+BOTH_FORMS = pytest.mark.parametrize("hw", _BOTH_FORMS, ids=_hw_id)
+STORED_NARROW = pytest.mark.parametrize("hw", [*_BOTH_FORMS, (17, 23)], ids=_hw_id)
 
 
 @pytest.mark.parametrize("radius", [3, 4])
@@ -205,14 +323,24 @@ def _special_coords(case, H, W):
     )
 
 
+BUILDERS = {"loop": build_loop_pyramid, "plain": build_corr_pyramid}
+
+
+@pytest.mark.parametrize("builder", list(BUILDERS))
+@pytest.mark.parametrize("hw", [(11, 16), (11, 13)], ids=["11x16", "11x13"])
 @pytest.mark.parametrize(
     "case",
     ["integers", "left", "right", "top", "bottom", "corner", "outside",
      "far_outside"],
 )
-def test_lookup_window_edges_match_gather_oracle(case):
-    H, W, radius = 11, 16, 4
-    pyr = _random_pyramid(4, 1, H, W)
+def test_lookup_window_edges_match_gather_oracle(case, hw, builder):
+    """At 11x13 the loop's builder stores every level wider than its own
+    (13 -> 16, 6 -> 8, 3 -> 8, 1 -> 8; at 11x16 levels 2-3): the windows of
+    ``right`` and ``corner`` hang over the level's last column into the zeros
+    it is stored with, where the plain builder's hang over the level's end."""
+    (H, W), radius = hw, 4
+    pyr = _random_pyramid(4, 1, H, W, build=BUILDERS[builder])
+    assert (pyr.levels[3].shape[3] == W >> 3) == (builder == "plain")
     coords = _special_coords(case, H, W)
     ours = np.asarray(_lookup(pyr, coords, radius))
     _assert_matches_oracle(ours, pyr, coords, radius)
@@ -222,10 +350,36 @@ def test_lookup_window_edges_match_gather_oracle(case):
         assert ours.any()
 
 
-@BOTH_FORMS
+@pytest.mark.parametrize("stored", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("hw", MISFIT_WIDTHS, ids=_hw_id)
+def test_lookup_over_the_loop_pyramid_is_the_lookup_over_the_plain_one(hw, stored):
+    """The zero columns are weights on exact zeros: the lookup over a pyramid
+    stored wider answers what it answers over ``build_corr_pyramid``'s (to the
+    rounding of a product blocked for another width, on this backend), with
+    queries jittered far enough that windows reach past both ends of a row."""
+    B, (H, W), radius = 2, hw, 4
+    ours, plain = (
+        _random_pyramid(11, B, H, W, dtype=stored, build=build)
+        for build in BUILDERS.values()
+    )
+    assert [lvl.shape[3] for lvl in plain.levels] == [W >> lvl for lvl in range(4)]
+    assert all(a.shape[3] > b.shape[3] for a, b in zip(ours.levels, plain.levels))
+    coords = _jittered_coords(12, B, H, W, 6)
+    got, want = (np.asarray(_lookup(pyr, coords, radius)) for pyr in (ours, plain))
+    assert want.any()
+    # float32: read 0 to 7.2e-7 here. bfloat16: read 0; the room is one ulp of
+    # a stored value under 8, should a product fall across a rounding tie
+    np.testing.assert_allclose(
+        got, want, atol=4e-6 if stored == jnp.float32 else 2.0**-6, rtol=0
+    )
+
+
+@STORED_NARROW
 def test_lookup_widens_a_bf16_volume_to_float32(hw):
     """``PrecisionPolicy.corr_jnp`` stores the volume in bf16; the
-    interpolation still runs, and answers, in float32."""
+    interpolation still runs, and answers, in float32. At 17x23 level 0
+    (17 rows of 23 columns, stored 24 wide: 408 elements) takes the tap
+    sums over its stored width."""
     B, (H, W), radius = 1, hw, 4
     pyr = _random_pyramid(5, B, H, W, dtype=jnp.bfloat16)
     assert all(lvl.dtype == jnp.bfloat16 for lvl in pyr.levels)
@@ -244,7 +398,7 @@ def test_lookup_grad_wrt_levels_matches_gather_oracle(hw, radius):
     (coords are stop_gradient-ed per iteration): the gather's scatter-add
     and the contraction's transposes must deposit the same cotangent."""
     B, (H, W) = 1, hw
-    pyr = _random_pyramid(7, B, H, W)
+    pyr = _random_pyramid(7, B, H, W, differentiated=True)
     coords = _jittered_coords(8, B, H, W, 5)
     cot = jnp.asarray(
         np.random.default_rng(10)

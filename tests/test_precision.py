@@ -529,8 +529,8 @@ class TestTrainParity:
         ("step", "f32", {"multiply_reduce/float32"}),
         ("forward", "f32", {"multiply_reduce/float32"}),
         # the control: at this shape a forward-only program over a narrow
-        # volume does take the other form, at level 0 (16x24; 8x12 is under
-        # the size)
+        # volume does take the other form, at levels 0-1 (16x64, 8x32; 4x16 is
+        # under the size)
         ("forward", "bf16_infer", {"tap_sums/bfloat16", "multiply_reduce/bfloat16"}),
     ])
     def test_only_a_forward_only_narrow_volume_takes_the_tap_sums(
@@ -547,7 +547,9 @@ class TestTrainParity:
         from raft_ncup_tpu.parallel.step import make_train_step
         from raft_ncup_tpu.training.state import create_train_state
 
-        hw, batch = (128, 192), 1
+        # a grid of 64 / 32 / 16 / 8 columns: every level is stored at its own
+        # width, so the tally names forms alone (``ops/corr.py::stored_width``)
+        hw, batch = (128, 512), 1
         model_cfg = small_model_config("raft", dataset="chairs", precision=precision)
         images = jax.ShapeDtypeStruct((batch, *hw, 3), jnp.float32)
         if program == "step":

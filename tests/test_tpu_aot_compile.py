@@ -270,6 +270,33 @@ def eval_bf16_program(sds) -> Program:
     return _program(compiled)
 
 
+@pytest.fixture(scope="module")
+def kitti_lookup_program(sds) -> Program:
+    """The `volume` lookup ALONE at `eval_kitti_nc`'s largest grid (PR 47):
+    two `[8,47,156,256]` feature maps through `build_loop_pyramid` and a
+    `scan` of 24 lookups, as the refinement loop runs them, float32 at
+    `highest`. ~10 s where the whole KITTI forward is 85-100 (a scratch
+    compile of it gave the same loop fusions and 4.984 GiB of temporaries
+    for the parent's 4.964: PERF.md section 6, PR 47). 156 -> 78 -> 39 -> 19
+    columns: no level's width is a multiple of 8, the case
+    `ops/corr.py::stored_width` is for."""
+
+    def lookups(fmap1, fmap2, coords):
+        pyramid = corr.build_loop_pyramid(fmap1, fmap2, LEVELS)
+
+        def body(c, _):
+            out = corr.corr_lookup(pyramid, c, RADIUS)  # every channel is used
+            return c + 0.01 * out.reshape(*out.shape[:3], -1, 2).sum(-2), None
+
+        return jax.lax.scan(body, coords, None, length=24)[0]
+
+    corr.reset_contract_forms()
+    feat = sds((8, 47, 156, C))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(lookups).lower(feat, feat, sds((8, 47, 156, 2))).compile()
+    return _program(compiled)
+
+
 def _loop_computations(text: str) -> dict:
     """{name: body text} of every computation reachable from a `while`'s
     `body=` through `calls=` (fusions) and nested loops."""
@@ -579,6 +606,70 @@ def test_eval_bf16_cell_forward_pools_and_looks_up_what_a_level_stores(
     carried = loop.split(" while(", 1)[0]
     for level in ("55,128", "27,64", "13,32", "6,16"):
         assert f"bf16[16,7040,{level}]" in carried and f"f32[16,7040,{level}]" not in carried
+
+
+def _first_stage_windows(text: str, queries: int) -> dict:
+    """{stored width: `output_window_bounds`} of the loop's first-stage (y)
+    lookup fusions: a multiply + reduce from a level `[8, queries, Hl, Wl]`
+    to `[8, queries, 9, Wl]`, whose window the compiler writes on the
+    fusion's line."""
+    windows = {}
+    for body in _loop_computations(text).values():
+        for line in body.splitlines():
+            m = re.search(
+                rf"%multiply_reduce_fusion\S* = f32\[8,{queries},9,(\d+)\]\S* fusion\(.*"
+                r'"output_window_bounds":\[([^\]]*)\]', line)
+            if m and int(m.group(1)) > 9:
+                windows[int(m.group(1))] = [int(b) for b in re.findall(r"\d+", m.group(2))]
+    return windows
+
+
+def test_kitti_lookup_walks_levels_0_to_2_once_an_iteration(
+    kitti_lookup_program, record_property
+):
+    """What PR 47 is for (PR 46's change, asked again). At its own width
+    (156, 78) the compiler cuts the nine taps of a level's first stage into
+    three blocks of three and walks
+    the level once a block (`output_window_bounds` `[3,47,6,1,1]`,
+    `[3,23,13,1,1]`; batch in sublanes, `{1,0,3,2}`): 5.16 GB an iteration
+    for a 1.72 GB level, the first bottleneck of `eval_kitti_nc` at PR 45.
+    Stored at a multiple of 8 the level takes x in sublanes (`{1,3,2,0}`)
+    and all nine taps in one block. So: every level of the loop is read at
+    its stored width and in that layout, nothing in the loop (nor anything
+    the loop carries) has a level's own width, and the first-stage fusions
+    of levels 0-2 each hold the 9 in one window (level 3, 5 x 24, is now
+    cut in three where 5 x 19 held the nine: 0.03 GB a walk)."""
+    assert kitti_lookup_program.contract_forms == {
+        "level0": "multiply_reduce@160/float32", "level1": "multiply_reduce@80/float32",
+        "level2": "multiply_reduce@40/float32", "level3": "multiply_reduce@24/float32",
+    }
+    text = kitti_lookup_program.text
+    in_loop = "\n".join(_loop_computations(text).values())
+    for rows, own, stored in ((47, 156, 160), (23, 78, 80), (11, 39, 40), (5, 19, 24)):
+        assert f"f32[8,7332,{rows},{own}]" not in in_loop
+        assert f"f32[8,7332,{rows},{stored}]{{1,3,2,0:" in in_loop
+        assert f"f32[8,7332,{rows},{stored}]{{1,0,3,2:" not in in_loop
+    windows = _first_stage_windows(text, 7332)
+    record_property("first_stage_output_window_bounds", windows)
+    assert sorted(windows) == [24, 40, 80, 160]
+    for stored, rows in ((160, 47), (80, 23), (40, 11)):
+        # the level's own pass: all nine taps and every row in one window
+        assert 9 in windows[stored] and rows in windows[stored], windows[stored]
+
+
+def test_kitti_lookup_temporaries_hold_one_level_0_not_two(
+    kitti_lookup_program, record_property
+):
+    """Level 0 is 8 x 7,332 x 47 x 160 x 4 = 1.643 GiB. Its zero columns are
+    zero FEATURE columns of `fmap2`, so the product writes it at its stored
+    width: 4.932 GiB of temporaries for the parent's 4.910 (the product, its
+    relayout and the pooling's relayout of the own columns are one level-0
+    buffer each, no two of them live with a third; PR 46's compiles, made
+    again by PR 47). A `pad` of the built level is a buffer beside it: 6.093
+    GiB."""
+    assert _record_temp(record_property, kitti_lookup_program) < 5.5
+    assert "f32[8,7332,47,160]" in kitti_lookup_program.text
+    assert not re.search(r"= f32\[8,7332,47,160\]\S* pad\(", kitti_lookup_program.text)
 
 
 def test_hd_cell_forward_fits_the_chip_at_batch_4(hd_program, record_property):
