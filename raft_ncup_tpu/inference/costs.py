@@ -77,6 +77,7 @@ import threading
 import time
 from typing import Dict, Optional
 
+from raft_ncup_tpu.nn import layers
 from raft_ncup_tpu.observability.startup import (
     StartupPhase,
     get_startup_record,
@@ -324,8 +325,9 @@ def build_and_record(
     (``utils/profiling.timed_build``), the executable's costs and phases
     into ``ledger``, and the phases into the process's start-up record
     with the compile listener's totals as they stand now, the product
-    sites' summary, what the program's checkpoint policy saved by name and
-    the form each level of its ``volume`` lookup took
+    sites' summary, what the program's checkpoint policy saved by name,
+    the form each level of its ``volume`` lookup took and which ``Conv2d``
+    sites took another form than ``conv_general_dilated``
     (``observability/startup.py``). Returns the executable."""
     from raft_ncup_tpu.utils.profiling import compile_meter, timed_build
 
@@ -333,10 +335,12 @@ def build_and_record(
     sites.reset_product_sites()  # the lowering below traces the program
     remat.reset_saved_residuals()
     corr.reset_contract_forms()
+    layers.reset_conv_forms()
     compiled, phases = timed_build(hub, jitfn, args, key=key, kind=kind)
     traced = sites.product_sites()
     saved = remat.saved_residuals()
     forms = corr.contract_forms()
+    reformed = {f: s for f, s in layers.conv_forms().items() if f != "conv"}
     entry = ledger.record_compiled(
         key, compiled, backend=backend, phases=phases, **meta
     )
@@ -354,6 +358,9 @@ def build_and_record(
         # of a program that looks a materialised pyramid up: the form
         # and stored dtype of each level (``ops/corr.py::contract_form``)
         contract_forms=forms or None,
+        # of a program with thin ``Conv2d`` sites: the module paths that
+        # were folded or phased (``nn/layers.py::conv_form``)
+        conv_forms=reformed if any(reformed.values()) else None,
     )
     return compiled
 

@@ -15,11 +15,14 @@ convolution computes in bfloat16 (the TPU analogue of the reference's CUDA
 autocast regions), while norms always compute in float32.
 
 How ``Conv2d`` is computed is chosen from the kernel's shape, stride,
-dilation and groups (:func:`conv_form`). A convolution with a thin side (the
-motion encoder's 7x7 over the 2 flow channels, the flow head's 3x3 onto
-them) fills a sixty-fourth of an MXU tile per kernel tap as
-``conv_general_dilated``; with its taps folded into the thin dimension it
-is ONE matrix product (PERF.md section 6, PR 29).
+dilation and groups and the operands' width (:func:`conv_form`). A
+convolution with a thin side (the motion encoder's 7x7 over the 2 flow
+channels, the flow head's 3x3 onto them) fills a sixty-fourth of an MXU tile
+per kernel tap as ``conv_general_dilated``; with its taps folded into the
+thin dimension it is ONE matrix product (PERF.md section 6, PR 29). The
+encoders' 7x7 stride-2 stems over the 3 image channels are a stride-1
+convolution over the plane's 2x2 phases instead (PR 48): folded into one
+product they were faster alone and slower in the program.
 """
 
 from __future__ import annotations
@@ -58,7 +61,9 @@ def _pair(v) -> tuple[int, int]:
 # sites were folded and that every other one is the convolution it was.
 # Sites are module paths below the module that was applied (fnet's and
 # cnet's ``conv1`` are one name); sets, so a retrace changes nothing.
-_conv_forms: dict[str, set] = {"folded_in": set(), "folded_out": set(), "conv": set()}
+_conv_forms: dict[str, set] = {
+    "folded_in": set(), "folded_out": set(), "phased_in": set(), "conv": set(),
+}
 
 # Widest thin side that is folded (:func:`conv_form`). Measured on a v5e,
 # float32 `highest`, each site alone: device time of a call in ms, forward
@@ -91,6 +96,49 @@ _conv_forms: dict[str, set] = {"folded_in": set(), "folded_out": set(), "conv": 
 # rule stops at 8, the widest side measured at both kernel sizes in both
 # forms, 3x or more ahead everywhere; the models' thin sites are 2 wide,
 # and the weights net's 64 -> 32 stays the convolution it was.
+#
+# At stride (2, 2) (PR 48; the encoders' stem, 7x7 3 -> 64) a number of the
+# site ALONE misleads. Device ms of a call, forward / forward + kernel
+# cotangent (chiprun_out/pr48/stem_bench.jsonl): ``conv_general_dilated``
+# against the strided patches folded into ONE ``dot_general``, cut as 7 + 7
+# strided slices (K = 147) or from the plane's 2x2 phases by 4 + 4
+# unit-stride slices with the kernel zero-extended to 8x8 (K = 192):
+#
+#                         float32 `highest`                             bfloat16
+#   frames           conv          7+7 slices    phases          conv         7+7 slices    phases
+#   16 x 440 x 1024  23.36 / 52.02  39.41 / 41.90  16.89 / 20.01   4.41 / 6.92  33.63 / 34.65  8.89 / 10.11
+#    8 x 440 x 1024  23.62 / 53.02  20.07 / 21.31  11.32 / 12.86   3.55 / 5.04  15.94 / 17.10  5.61 /  7.21
+#   12 x 368 x 768   14.45 / 32.32  18.48 / 19.58   8.57 /  9.97   2.66 / 4.21  15.17 / 16.16  3.99 /  5.41
+#
+# The phases' product is 1.4-2.1x ahead at float32 alone and it LOST in the
+# program: `serve_sintel_raft` 49.562 -> 52.254 ms a pair, `stream_sintel_nc`
+# 91.654 -> 94.338, `eval_sintel_nc_bf16` 43.718 -> 47.119
+# (chiprun_out/pr48/A/results.jsonl). A ``dot_general`` over ``[B, 220, 512,
+# K]`` is a convolution whose batch is W; the compiler lays its output out
+# W-minor, the residual stage after it follows, and each of that stage's
+# eight 3x3 convolutions then pays a relayout in and one out (3.5 ms each by
+# the compiler's own estimate). So the forms are compared IN PLACE: both
+# encoders with the input normalisation in one program (``model.encode``),
+# device ms a call (chiprun_out/pr48/enc_bench.jsonl, enc_bench_final.jsonl):
+#
+#   form of the two stems                      float32, 8 pairs  bf16_infer, 16 pairs  float32 forward +
+#                                              of 440 x 1024     of 440 x 1024         backward, 6 of 368 x 768
+#   conv_general_dilated 7x7 stride 2          141.51            48.69                 334.45
+#   phases, one dot_general (K = 192)          163.04
+#   phases, convolution [4,4,12,64]            134.27            57.74                 341.49
+#   ... cut by reshape + transpose             138.02
+#   phases + 4 column shifts, [4,1,48,64]      126.74            72.03                 318.68
+#   ... the 48 channels behind a barrier       117.27            61.52                 312.55   (kept)
+#
+# The convolution emitter runs 4 taps of 48 channels in 6.9 ms where 16 taps
+# of 12 take 17.0 and 49 taps of 3 take 19.8 (fnet, 16 frames), and keeps the
+# layouts of the stage after it. What is left is the relayout of the thin
+# stacks into the convolution's channel-minor layout, lanes filled to a
+# tenth: without the barrier the compiler fuses the concatenation into the
+# convolution and relayouts each 12-channel shift on its own (4 copies, 9.5
+# ms for fnet by its estimate; 1 copy of the 48 behind it). At one pass
+# (bfloat16) the convolution is already 2.9 + 2.6 ms and every form loses:
+# :func:`conv_form` reads the operands' itemsize.
 FOLD_MAX_THIN = 8
 
 
@@ -100,23 +148,34 @@ def reset_conv_forms() -> None:
 
 
 def conv_forms() -> dict:
-    """{'folded_in' | 'folded_out' | 'conv': sorted module paths} of every
-    ``Conv2d`` call site traced since the last reset."""
+    """{'folded_in' | 'folded_out' | 'phased_in' | 'conv': sorted module
+    paths} of every ``Conv2d`` call site traced since the last reset."""
     return {form: sorted(sites) for form, sites in _conv_forms.items()}
 
 
-def conv_form(kernel_shape, stride=(1, 1), dilation=(1, 1), groups: int = 1) -> str:
+def conv_form(
+    kernel_shape, stride=(1, 1), dilation=(1, 1), groups: int = 1,
+    itemsize: Optional[int] = None,
+) -> str:
     """How a convolution with this HWIO kernel is computed, decided from
-    what the call can see: 'folded_in' (thin input: the taps join the
-    contraction), 'folded_out' (thin output: the taps join the outputs) or
-    'conv' (``conv_general_dilated``: everything wide, strided, dilated,
-    grouped, even or 1x1)."""
+    what the call can see: 'folded_in' (thin input at stride 1: the taps
+    join the contraction), 'folded_out' (thin output at stride 1: the taps
+    join the outputs), 'phased_in' (thin input at stride (2, 2) of operands
+    ``itemsize`` = 4 bytes wide or more, the encoders' float32 stems: the
+    plane's 2x2 phases and their column shifts join the channels,
+    :func:`_conv_phased_in`) or 'conv' (``conv_general_dilated``: everything
+    wide, any other stride, dilated, grouped, even or 1x1, and a strided
+    thin input of narrower operands, which one MXU pass already runs in a
+    sixth of the time, or of a width the caller does not state)."""
     kh, kw, cin, cout = kernel_shape
+    stride = tuple(stride)
     if (
-        tuple(stride) != (1, 1) or tuple(dilation) != (1, 1) or groups != 1
+        stride not in ((1, 1), (2, 2)) or tuple(dilation) != (1, 1) or groups != 1
         or kh % 2 == 0 or kw % 2 == 0 or kh * kw == 1
     ):
         return "conv"
+    if stride == (2, 2):
+        return "phased_in" if cin <= FOLD_MAX_THIN and (itemsize or 0) >= 4 else "conv"
     if cin <= FOLD_MAX_THIN:
         return "folded_in"
     if cout <= FOLD_MAX_THIN:
@@ -124,11 +183,11 @@ def conv_form(kernel_shape, stride=(1, 1), dilation=(1, 1), groups: int = 1) -> 
     return "conv"
 
 
-def _window_hw(x: jax.Array, kernel: jax.Array, pad) -> tuple[int, int]:
+def _window_hw(x: jax.Array, kernel: jax.Array, pad, stride=(1, 1)) -> tuple[int, int]:
     (ph, _), (pw, _) = pad
     return (
-        x.shape[1] + 2 * ph - kernel.shape[0] + 1,
-        x.shape[2] + 2 * pw - kernel.shape[1] + 1,
+        (x.shape[1] + 2 * ph - kernel.shape[0]) // stride[0] + 1,
+        (x.shape[2] + 2 * pw - kernel.shape[1]) // stride[1] + 1,
     )
 
 
@@ -150,6 +209,48 @@ def _conv_folded_in(x: jax.Array, kernel: jax.Array, pad, out_dtype=None) -> jax
     return jax.lax.dot_general(
         patches, kernel.reshape(-1, cout), (((3,), (0,)), ((), ())),
         preferred_element_type=out_dtype,
+    )
+
+
+def _conv_phased_in(x: jax.Array, kernel: jax.Array, pad, out_dtype=None) -> jax.Array:
+    """Stride-(2, 2) convolution of a thin input as a stride-1 convolution
+    over its 2x2 phases: ``xpad[2i + ky, 2j + kx]`` with ``ky = 2a + py``,
+    ``kx = 2b + px`` is ``phases[i + a, j + b, (py, px)]``, so the padded
+    plane is cut into its four phases once (``[B, Ho + ah - 1, Wo + aw - 1,
+    4 Cin]``, ah = ceil(kh / 2)), the aw column shifts of that stack join
+    the channels too (``4 aw Cin``: 48 for the encoders' 7x7 over 3), and
+    ``conv_general_dilated`` walks the ah row taps with the kernel
+    zero-extended to ``[2 ah, 2 aw]`` and regrouped ``[ah, 1, 4 aw Cin,
+    Cout]``. The extension's rows are exact zeros against finite data: the
+    same sums in another order.
+
+    Why a convolution and not PR 29's one product: a ``dot_general`` over the
+    whole patch stack hands the residual stages after it another layout, and
+    every convolution there then pays two relayouts (table above
+    ``FOLD_MAX_THIN``). Why the barrier: without it the compiler fuses the
+    concatenation into the convolution and relayouts each of the aw shifted
+    12-channel stacks on its own, lanes filled to a tenth, four times."""
+    kh, kw, cin, cout = kernel.shape
+    ah, aw = (kh + 1) // 2, (kw + 1) // 2
+    ho, wo = _window_hw(x, kernel, pad, (2, 2))
+    hp, wp = 2 * (ho + ah - 1), 2 * (wo + aw - 1)
+    (pt, _), (pl, _) = pad
+    xpad = jnp.pad(x, (
+        (0, 0), (pt, hp - x.shape[1] - pt), (pl, wp - x.shape[2] - pl), (0, 0)
+    ))
+    # ``lax.slice``: a stepped ``jnp`` index lowers to a gather.
+    phases = jnp.concatenate([
+        jax.lax.slice(xpad, (0, py, px, 0), xpad.shape, (1, 2, 2, 1))
+        for py in (0, 1) for px in (0, 1)
+    ], axis=-1)
+    cols = jax.lax.optimization_barrier(
+        jnp.concatenate([phases[:, :, b : b + wo] for b in range(aw)], axis=-1)
+    )
+    taps = jnp.pad(kernel, ((0, 2 * ah - kh), (0, 2 * aw - kw), (0, 0), (0, 0)))
+    taps = taps.reshape(ah, 2, aw, 2, cin, cout).transpose(0, 2, 1, 3, 4, 5)
+    return jax.lax.conv_general_dilated(
+        cols, taps.reshape(ah, 1, 4 * aw * cin, cout), (1, 1), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), preferred_element_type=out_dtype,
     )
 
 
@@ -182,11 +283,13 @@ def conv2d(
     """NHWC x HWIO convolution in the form :func:`conv_form` gives its
     kernel, tallied under ``site``. ``out_dtype``: the dtype the products'
     accumulator is handed out in (None: the operands')."""
-    form = conv_form(kernel.shape, stride, dilation, groups)
+    form = conv_form(kernel.shape, stride, dilation, groups, x.dtype.itemsize)
     _conv_forms[form].add(site)
     record_site(site, x.dtype, out_dtype)
     if form == "folded_in":
         return _conv_folded_in(x, kernel, pad, out_dtype)
+    if form == "phased_in":
+        return _conv_phased_in(x, kernel, pad, out_dtype)
     if form == "folded_out":
         if out_dtype is None and x.dtype != PARAM_DTYPE:
             # Narrow operands: the taps' planes are added in the

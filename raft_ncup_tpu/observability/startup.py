@@ -94,6 +94,7 @@ class StartupRecord:
         process: Optional[dict] = None, precision: Optional[dict] = None,
         saved_residuals: Optional[dict] = None,
         contract_forms: Optional[dict] = None,
+        conv_forms: Optional[dict] = None,
     ) -> None:
         """One executable built: its two build phases, the persistent
         cache's verdict, the compile listener's process totals as they
@@ -106,9 +107,13 @@ class StartupRecord:
         tally of the same trace; the training step's), and which form each
         level of its ``volume`` lookup took over which stored dtype
         (``contract_forms``: ``ops/corr.py``'s tally of the same trace,
-        ``{"level0": "dot/bfloat16", ...}``). A key built again
-        (an LRU eviction, a second run in one process) adds to its entry's
-        seconds and takes the newest verdict."""
+        ``{"level0": "dot/bfloat16", ...}``), and which of its ``Conv2d``
+        sites were not computed as the ``conv_general_dilated`` they are
+        written as (``conv_forms``: ``nn/layers.py``'s tally of the same
+        trace without its ``conv`` list, ``{"folded_in": ["encoder/convf1"],
+        "folded_out": ["flow_head/conv2"], "phased_in": ["conv1"]}``). A key
+        built again (an LRU eviction, a second run in one process) adds to
+        its entry's seconds and takes the newest verdict."""
         with self._lock:
             entry = self._programs.get(key)
             if entry is None and len(self._programs) >= MAX_PROGRAMS:
@@ -130,6 +135,8 @@ class StartupRecord:
                     entry["saved_residuals"] = dict(saved_residuals)
                 if contract_forms is not None:
                     entry["contract_forms"] = dict(contract_forms)
+                if conv_forms is not None:
+                    entry["conv_forms"] = {f: list(s) for f, s in conv_forms.items()}
                 if probe_s is not None:
                     entry["probe_s"] = (entry["probe_s"] or 0.0) + float(probe_s)
             if process is not None:
@@ -191,7 +198,8 @@ def startup_report() -> dict:
     """``{"programs": [{"key", "kind", "trace_lower_s", "compile_s",
     "cache", "first_run_s", "precision": {"policy", "sites_bf16",
     "sites_f32"}, "saved_residuals": {name: count}, "contract_forms":
-    {level: "form/dtype"}, ...}], "phases":
+    {level: "form/dtype"}, "conv_forms": {"folded_in" | "folded_out" |
+    "phased_in": [site]}, ...}], "phases":
     {"weights_s", "input_start_s", "warmup_s"}, "process": {"programs_loaded",
     "cache_hits", "cache_misses", "compile_s"}, "dropped"}`` — a phase
     the process has not run reads ``None``."""

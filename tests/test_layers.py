@@ -105,6 +105,36 @@ FOLDED_SITES = {
 }
 
 
+def _assert_thin_conv_is_the_convolution(mod, params, x, g, stride, use_bias):
+    """``mod`` (a ``Conv2d`` that ``conv_form`` does not leave 'conv') equals
+    ``conv_general_dilated`` at `highest` with torch's ``padding=k//2``:
+    output, input, kernel and bias cotangents, to float32 rounding of sums
+    taken in another order. Returns the jaxpr of its gradient."""
+    kh, kw = params["kernel"].shape[:2]
+
+    def reference(params, x):
+        y = jax.lax.conv_general_dilated(
+            x, params["kernel"], (stride, stride), ((kh // 2,) * 2, (kw // 2,) * 2),
+            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision="highest")
+        return y + params["bias"] if use_bias else y
+
+    def thin(params, x):
+        return mod.apply({"params": params}, x)
+
+    out, vjp = jax.vjp(thin, params, x)
+    ref, ref_vjp = jax.vjp(reference, params, x)
+    assert out.shape == ref.shape == g.shape
+    got = jax.tree.leaves((out, vjp(g)))
+    want = jax.tree.leaves((ref, ref_vjp(g)))
+    assert len(got) == (4 if use_bias else 3)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.float32 and a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=4e-6 * float(jnp.abs(b).max()))
+    return jax.make_jaxpr(jax.grad(
+        lambda p, x: (thin(p, x) * g).sum(), argnums=(0, 1)))(params, x)
+
+
 @pytest.mark.parametrize("use_bias", [True, False], ids=["bias", "no_bias"])
 @pytest.mark.parametrize("site", FOLDED_SITES)
 def test_folded_conv_is_the_convolution_forward_and_every_cotangent(site, use_bias):
@@ -121,65 +151,124 @@ def test_folded_conv_is_the_convolution_forward_and_every_cotangent(site, use_bi
     params = mod.init(keys[2], x)["params"]
     if use_bias:  # torch's bias bound is tiny at fan_in 2304: make it count
         params = {**params, "bias": jnp.linspace(-1.0, 1.0, cout)}
-
-    def reference(params, x):
-        y = jax.lax.conv_general_dilated(
-            x, params["kernel"], (1, 1), ((kh // 2,) * 2, (kw // 2,) * 2),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision="highest")
-        return y + params["bias"] if use_bias else y
-
-    def folded(params, x):
-        return mod.apply({"params": params}, x)
-
-    jaxpr = str(jax.make_jaxpr(jax.grad(
-        lambda p, x: (folded(p, x) * g).sum(), argnums=(0, 1)))(params, x))
+    jaxpr = str(_assert_thin_conv_is_the_convolution(mod, params, x, g, 1, use_bias))
     assert "conv_general_dilated" not in jaxpr and "dot_general" in jaxpr
 
-    out, vjp = jax.vjp(folded, params, x)
-    ref, ref_vjp = jax.vjp(reference, params, x)
-    assert out.shape == ref.shape == (2, 9, 13, cout)
-    got = jax.tree.leaves((out, vjp(g)))
-    want = jax.tree.leaves((ref, ref_vjp(g)))
-    assert len(got) == (4 if use_bias else 3)
-    for a, b in zip(got, want):
-        assert a.dtype == jnp.float32 and a.shape == b.shape
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
-                                   atol=4e-6 * float(jnp.abs(b).max()))
+
+# The encoders' stems (PR 48): 7x7 at stride 2 over the 3 image channels,
+# torch's ``padding=3``; `raft --small`'s is 32 wide.
+STEM_SITES = {
+    "stem_7x7_stride_2_3_to_64": (7, 7, 3, 64),
+    "stem_small_7x7_stride_2_3_to_32": (7, 7, 3, 32),
+}
+
+
+def _conv_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "conv_general_dilated":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _conv_eqns(sub)
+
+
+@pytest.mark.parametrize("use_bias", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize(
+    "hw", [(16, 24), (15, 24), (16, 23), (15, 23), (7, 8)], ids=lambda hw: f"{hw[0]}x{hw[1]}"
+)
+@pytest.mark.parametrize("site", STEM_SITES)
+def test_phased_stem_is_the_strided_convolution_forward_and_every_cotangent(site, hw, use_bias):
+    """A thin input at stride (2, 2) is a stride-1 convolution over the
+    plane's 2x2 phases with their four column shifts on the channel axis
+    (48 channels, 4 row taps): ``floor((H + 6 - 7) / 2) + 1`` rows and as
+    many columns by the same rule, whether the padded plane's last row and
+    column are read (H, W odd) or not (even), equal to the strided
+    convolution at `highest` forward and in every cotangent; no convolution
+    of its forward or backward runs at a stride or reads the 3-channel
+    plane, and no stepped index became a gather."""
+    kh, kw, cin, cout = STEM_SITES[site]
+    h, w = hw
+    ho, wo = (h + 6 - 7) // 2 + 1, (w + 6 - 7) // 2 + 1
+    mod = Conv2d(cout, (kh, kw), stride=2, use_bias=use_bias)
+    keys = jax.random.split(jax.random.PRNGKey(h * 100 + w + cout), 3)
+    x = jax.random.normal(keys[0], (2, h, w, cin))
+    g = jax.random.normal(keys[1], (2, ho, wo, cout))
+    params = mod.init(keys[2], x)["params"]
+    if use_bias:
+        params = {**params, "bias": jnp.linspace(-1.0, 1.0, cout)}
+    jaxpr = _assert_thin_conv_is_the_convolution(mod, params, x, g, 2, use_bias)
+    convs = list(_conv_eqns(jaxpr.jaxpr))
+    assert convs and "gather" not in str(jaxpr)
+    for eqn in convs:
+        assert eqn.params["window_strides"] == (1, 1)
+        shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+        assert (2, h, w, cin) not in shapes and (kh, kw, cin, cout) not in shapes
+        assert any(48 in shape for shape in shapes)  # 4 phases x 4 column shifts x 3
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16], ids=["bfloat16", "float16"])
+def test_a_strided_thin_input_of_narrow_operands_stays_the_convolution(dtype):
+    """At one MXU pass the stems run in a sixth of the time and the phases'
+    copies cost more than they save (`eval_sintel_nc_bf16`: table above
+    ``FOLD_MAX_THIN``): the rule reads the operands' itemsize and leaves
+    them ``conv_general_dilated``; a stride-1 thin input folds whatever its
+    dtype, as before."""
+    assert layers.conv_form((7, 7, 3, 64), (2, 2), itemsize=jnp.dtype(dtype).itemsize) == "conv"
+    assert layers.conv_form((7, 7, 3, 64), (2, 2)) == "conv"  # a width nobody stated
+    assert layers.conv_form((7, 7, 2, 128), (1, 1), itemsize=jnp.dtype(dtype).itemsize) == "folded_in"
+    mod = Conv2d(64, 7, stride=2, dtype=dtype)
+    x = jnp.ones((1, 8, 10, 3))
+    variables = jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0), x))
+    layers.reset_conv_forms()
+    jaxpr = str(jax.make_jaxpr(lambda v: mod.apply(v, x))(variables))
+    assert "conv_general_dilated" in jaxpr and "optimization_barrier" not in jaxpr
+    assert layers.conv_forms()["conv"] == [""] and layers.conv_forms()["phased_in"] == []
 
 
 # kernel shape, stride, dilation, groups -> the form the rule gives it.
 CONV_FORM_TABLE = {
     **{site: (shape, 1, 1, 1, form) for site, (shape, form) in FOLDED_SITES.items()},
+    **{site: (shape, 2, 1, 1, "phased_in") for site, shape in STEM_SITES.items()},
     "sep_gru_1x5_thin_in": ((1, 5, 2, 128), 1, 1, 1, "folded_in"),
-    "stem_7x7_stride_2_3_to_64": ((7, 7, 3, 64), 2, 1, 1, "conv"),
+    "strided_3x3_64_to_96": ((3, 3, 64, 96), 2, 1, 1, "conv"),
+    "strided_3x3_onto_a_thin_output": ((3, 3, 64, 2), 2, 1, 1, "conv"),
+    "stride_2_by_1_thin_in": ((7, 7, 3, 64), (2, 1), 1, 1, "conv"),
+    "stride_3_thin_in": ((7, 7, 3, 64), 3, 1, 1, "conv"),
     "weights_out_1x1_32_to_2": ((1, 1, 32, 2), 1, 1, 1, "conv"),
+    "strided_1x1_3_to_64": ((1, 1, 3, 64), 2, 1, 1, "conv"),
     "dilated_3x3_2_to_64": ((3, 3, 2, 64), 1, 2, 1, "conv"),
+    "strided_dilated_3x3_2_to_64": ((3, 3, 2, 64), 2, 2, 1, "conv"),
     "grouped_3x3_2_to_64": ((3, 3, 2, 64), 1, 1, 2, "conv"),
     "even_4x4_2_to_64": ((4, 4, 2, 64), 1, 1, 1, "conv"),
     "weights_net_3x3_130_to_64": ((3, 3, 130, 64), 1, 1, 1, "conv"),
     "gru_1x5_384_to_128": ((1, 5, 384, 128), 1, 1, 1, "conv"),
     "just_over_the_width": ((3, 3, layers.FOLD_MAX_THIN + 1, 128), 1, 1, 1, "conv"),
+    "just_over_the_width_strided": ((3, 3, layers.FOLD_MAX_THIN + 1, 128), 2, 1, 1, "conv"),
 }
 
 
 @pytest.mark.parametrize("case", CONV_FORM_TABLE)
 def test_conv_form_is_read_from_the_kernel_stride_dilation_and_groups(case):
-    """The rule's table: only stride-1, undilated, ungrouped odd kernels
-    with more than one tap and a side of at most ``FOLD_MAX_THIN`` channels
-    fold; every other site lowers to ``conv_general_dilated`` as before,
-    and the tally says which form the site took."""
+    """The rule's table: only undilated, ungrouped odd kernels with more
+    than one tap and a side of at most ``FOLD_MAX_THIN`` channels leave
+    ``conv_general_dilated`` as it was: folded at stride 1, either side; a
+    thin input at stride (2, 2) (PR 48) a stride-1 convolution over its
+    phases. Every other site lowers as before, and the tally says which
+    form the site took."""
     shape, stride, dilation, groups, form = CONV_FORM_TABLE[case]
     kh, kw, cin_g, cout = shape
-    assert layers.conv_form(shape, (stride,) * 2, (dilation,) * 2, groups) == form
+    stride = stride if isinstance(stride, tuple) else (stride,) * 2
+    assert layers.conv_form(shape, stride, (dilation,) * 2, groups, itemsize=4) == form
     mod = Conv2d(cout, (kh, kw), stride=stride, dilation=dilation, groups=groups,
                  padding=(dilation * (kh // 2), dilation * (kw // 2)))
     x = jnp.ones((1, 8, 10, cin_g * groups))
     variables = jax.eval_shape(lambda: mod.init(jax.random.PRNGKey(0), x))
     layers.reset_conv_forms()
-    jaxpr = str(jax.make_jaxpr(lambda v: mod.apply(v, x))(variables))
-    assert ("conv_general_dilated" in jaxpr) == (form == "conv")
+    jaxpr = jax.make_jaxpr(lambda v: mod.apply(v, x))(variables)
+    assert ("conv_general_dilated" in str(jaxpr)) == (form in ("conv", "phased_in"))
+    if form == "phased_in":  # not the strided convolution it was
+        assert [e.params["window_strides"] for e in _conv_eqns(jaxpr.jaxpr)] == [(1, 1)]
     assert layers.conv_forms() == {
-        name: [""] if name == form else [] for name in ("folded_in", "folded_out", "conv")
+        name: [""] if name == form else [] for name in ("folded_in", "folded_out", "phased_in", "conv")
     }
 
 
@@ -187,7 +276,9 @@ def test_conv_form_is_read_from_the_kernel_stride_dilation_and_groups(case):
 def test_benchmark_models_fold_exactly_the_update_blocks_two_thin_sites(config):
     """Initialising and applying each benchmark configuration's model (a
     toy frame): ``encoder.convf1`` is 'folded_in', ``flow_head.conv2`` is
-    'folded_out', every other ``Conv2d`` of the model is 'conv'."""
+    'folded_out', the encoders' stem ``conv1`` is 'phased_in', every other
+    ``Conv2d`` of the model, the strided ones of the residual stages among
+    them, is 'conv'."""
     import json
     import os
 
@@ -203,11 +294,16 @@ def test_benchmark_models_fold_exactly_the_update_blocks_two_thin_sites(config):
     forms = layers.conv_forms()
     assert forms["folded_in"] == ["encoder/convf1"]
     assert forms["folded_out"] == ["flow_head/conv2"]
+    # ``conv1``: both encoders' stem, one name (PR 48); every other
+    # ``conv1`` is a residual block's or the flow head's, below a module.
+    assert forms["phased_in"] == ["conv1"]
     # Both encoders (one set of names), the GRU (each gate in two parts since
-    # PR 31, and no gate whole), the heads, the weights net.
-    assert len(forms["conv"]) >= 35 and "gru/convz1" not in forms["conv"]
+    # PR 31, and no gate whole), the heads, the weights net: 35 names and
+    # more before the stems' left them.
+    assert len(forms["conv"]) >= 34 and "gru/convz1" not in forms["conv"]
     assert {"gru/convz1/context", "gru/convz1/step"} <= set(forms["conv"])
-    assert not set(forms["conv"]) & {"encoder/convf1", "flow_head/conv2"}
+    assert {"layer2_0/conv1", "layer3_0/conv1", "layer2_0/downsample_conv"} <= set(forms["conv"])
+    assert not set(forms["conv"]) & {"conv1", "encoder/convf1", "flow_head/conv2"}
 
 
 @pytest.mark.parametrize("kind", ["pac", "djif"])

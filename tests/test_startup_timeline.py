@@ -112,6 +112,24 @@ def test_record_banks_programs_phases_and_process_totals():
         rec.phase("imports_s", 1.0)
 
 
+def test_record_carries_the_folded_conv_sites_of_a_program_that_has_them():
+    """``conv_forms`` (PR 48): the tally of ``nn/layers.py`` as
+    ``build_and_record`` hands it over (the sites that did not stay
+    ``conv_general_dilated``), lists of its own on the entry; a program
+    built without the key (no thin ``Conv2d`` site) carries none."""
+    rec = StartupRecord()
+    folded = {
+        "folded_in": ["encoder/convf1"], "folded_out": ["flow_head/conv2"], "phased_in": ["conv1"],
+    }
+    rec.program("k1", "forward", trace_lower_s=1.0, compile_s=2.0, cache="miss",
+                conv_forms=folded)
+    rec.program("k2", "custom", trace_lower_s=1.0, compile_s=2.0, cache="miss")
+    first, second = rec.report()["programs"]
+    assert first["conv_forms"] == folded and "conv_forms" not in second
+    folded["phased_in"].append("changed after the call")
+    assert rec.report()["programs"][0]["conv_forms"]["phased_in"] == ["conv1"]
+
+
 def test_record_keeps_the_first_64_programs_and_counts_the_rest():
     rec = StartupRecord()
     for i in range(MAX_PROGRAMS + 1):
@@ -188,6 +206,7 @@ def test_each_executable_key_leaves_one_record_and_later_calls_leave_nothing(rec
     assert entry["cache"] == "off"  # the CPU backend keeps no persistent cache
     assert "saved_residuals" not in entry  # a program that builds no checkpoint
     assert "contract_forms" not in entry  # nor looks a materialised pyramid up
+    assert "conv_forms" not in entry  # nor has a ``Conv2d`` site to fold
     for _ in range(5):  # the steady path: one dict read then the program
         program("a")(x)
     assert _span_counts(tel) == after_first and len(startup_report()["programs"]) == 1
@@ -436,6 +455,15 @@ def test_train_run_banks_weights_build_first_run_and_input_start(record, hub, tm
     # the form and stored dtype of each level of its lookup (ops/corr.py)
     assert step["contract_forms"] == {
         f"level{lvl}": "multiply_reduce/float32" for lvl in range(4)
+    }
+    # the ``Conv2d`` sites that did not stay the convolution they were
+    # (nn/layers.py): the motion encoder's 7x7 over the flow and the small
+    # encoder's 8-channel bottlenecks folded, the flow head folded, and both
+    # encoders' stride-2 stem ``conv1`` over its phases (PR 48)
+    assert step["conv_forms"] == {
+        "folded_in": ["encoder/convf1", "layer1_0/conv2", "layer1_1/conv2"],
+        "folded_out": ["flow_head/conv2"],
+        "phased_in": ["conv1"],
     }
     assert got["phases"]["weights_s"] > 0 and got["phases"]["input_start_s"] > 0
     entry = get_cost_ledger().entry(step["key"])

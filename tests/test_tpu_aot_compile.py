@@ -366,6 +366,27 @@ def _thin_side_convolutions(text: str, h: int, w: int) -> list:
     ]
 
 
+def _stem_convolutions(text: str, h: int, w: int) -> list:
+    """Lines of the compiled text with a `convolution` that has the
+    encoders' stem among its shapes as it was until PR 48: the (h, w) frame
+    with its 3 channels as the input feature dimension, or the `[7,7,3,64]`
+    kernel (an operand forward, the result of its cotangent). Phased
+    (`nn/layers.py::conv_form` at stride 2), the convolution reads the
+    frame's 2x2 phases with their four column shifts, 48 features on an
+    (h / 2 + 3, w / 2) plane, and a `[4,1,48,64]` kernel."""
+
+    def stem(shape: str) -> bool:
+        dims = _dims(shape)
+        return len(dims) == 4 and 3 in dims and (
+            (h in dims and w in dims) or sorted(dims) == [3, 7, 7, 64]
+        )
+
+    return [
+        line.strip()[:200] for _, line, shapes in _convolutions(text)
+        if any(stem(s) for s in shapes)
+    ]
+
+
 def _gru_gate_convolutions(text: str) -> dict:
     """{(inside a `while` body?, contraction width): count} over the
     `convolution`s of the compiled text that have one of the GRU gates'
@@ -410,6 +431,18 @@ def test_sintel_train_step_has_no_convolution_with_a_2_wide_side(
     rematerialised and both cotangents: no `convolution` of the step has a
     2-wide feature side on the 46x96 plane (368x768 / 8)."""
     assert _thin_side_convolutions(train_program.text, 46, 96) == []
+
+
+def test_sintel_train_step_has_no_convolution_with_a_3_wide_input(
+    train_program,
+):
+    """Since PR 48 the encoders' 7x7 stride-2 stems are stride-1
+    convolutions over the crop's phases, forward, rematerialised and kernel
+    cotangent: no `convolution` of the step reads a 368x768 frame's 3
+    channels or the `[7,7,3,64]` kernel; fnet's reads 12 frames' 187x384
+    plane of 48 features."""
+    assert _stem_convolutions(train_program.text, 368, 768) == []
+    assert "f32[12,187,384,48]" in train_program.text
 
 
 def test_sintel_train_step_loops_convolve_no_context_features(train_program):
@@ -493,6 +526,23 @@ def test_eval_cell_forward_has_no_convolution_with_a_2_wide_side(
     a batch of 8 cost (PR 28's ledger lines), are folded (PR 29): no
     `convolution` has a 2-wide feature side on the 55x128 plane."""
     assert _thin_side_convolutions(eval_program.text, 55, 128) == []
+
+
+def test_eval_cell_forward_has_no_convolution_with_a_3_wide_input(
+    eval_program,
+):
+    """`raft.fnet` and `raft.cnet`'s `conv1`, 40.9 ms of a batch of 8 as a
+    strided `conv_general_dilated` over 3 channels (PR 47's ledger lines:
+    2-5% of their six-pass peak), are phased (PR 48): no `convolution`
+    reads a 440x1024 frame's 3 channels or the `[7,7,3,64]` kernel; each
+    reads ONE 223x512 stack of 48 features, 16 frames for fnet and 8 for
+    cnet (the barrier in `_conv_phased_in`: four 12-feature stacks would be
+    four relayouts)."""
+    assert _stem_convolutions(eval_program.text, 440, 1024) == []
+    assert "f32[16,223,512,48]" in eval_program.text
+    assert "f32[8,223,512,48]" in eval_program.text
+    relayouts = re.findall(r"= f32\[(?:8|16),223,512,12\]\S* copy\(", eval_program.text)
+    assert relayouts == []
 
 
 def test_eval_cell_forward_loop_convolves_no_context_features(eval_program):
