@@ -35,7 +35,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raft_ncup_tpu.precision.sites import record_site, suspended
+from raft_ncup_tpu.precision.sites import innermost_scope, record_site, suspended
 
 # Policy-pinned dtypes (raft_ncup_tpu/precision/; docs/PRECISION.md).
 # PARAM_DTYPE: master-weight storage — every PrecisionPolicy preset pins
@@ -526,13 +526,57 @@ class ConvTranspose2d(nn.Module):
         return y
 
 
+class BatchNormTrain(nn.Module):
+    """BatchNorm in training mode as ``torch.nn.BatchNorm2d`` computes it,
+    under flax ``nn.BatchNorm``'s variable names (``scale`` / ``bias``,
+    ``batch_stats``: ``mean`` / ``var``), so a tree serves both. The
+    statistics are taken in float32 over the whole batch and every position,
+    mean first and the variance about it; the input is normalised by the
+    BIASED variance, the gradient flows through both, and the running pair
+    moves by ``1 - momentum`` toward the batch mean and the UNBIASED variance
+    (torch's convention; flax's ``BatchNorm`` keeps the biased one). The
+    statistics and the running update lie under the scope ``<innermost
+    raft.* scope>.bn_stats`` (``raft.cnet.bn_stats``), which a capture by
+    scope splits from the convolutions around them (docs/OBSERVABILITY.md).
+    """
+
+    momentum: float = 0.9
+    epsilon: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        c = x.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (c,), PARAM_DTYPE)
+        bias = self.param("bias", nn.initializers.zeros, (c,), PARAM_DTYPE)
+        ra_mean = self.variable(
+            "batch_stats", "mean", lambda: jnp.zeros((c,), NORM_DTYPE)
+        )
+        ra_var = self.variable(
+            "batch_stats", "var", lambda: jnp.ones((c,), NORM_DTYPE)
+        )
+        outer = innermost_scope()
+        with jax.named_scope(f"{outer}.bn_stats" if outer else "bn_stats"):
+            axes = tuple(range(x.ndim - 1))
+            n = math.prod(x.shape[:-1])
+            mean = jnp.mean(x, axes)
+            var = jnp.mean(jnp.square(x - mean), axes)
+            if not self.is_initializing():
+                keep = self.momentum
+                ra_mean.value = keep * ra_mean.value + (1.0 - keep) * mean
+                ra_var.value = keep * ra_var.value + (1.0 - keep) * var * (
+                    n / max(n - 1, 1)
+                )
+        return (x - mean) * jax.lax.rsqrt(var + self.epsilon) * scale + bias
+
+
 class Norm(nn.Module):
     """Normalization factory matching the reference's norm_fn choices
     (reference: core/extractor.py:16-38,123-133).
 
     - 'group': GroupNorm(affine), eps 1e-5.
     - 'batch': BatchNorm, momentum 0.1 (torch) == flax momentum 0.9,
-       eps 1e-5. Eval/frozen mode uses running stats.
+       eps 1e-5. Eval/frozen mode uses running stats (flax's module);
+       training mode is :class:`BatchNormTrain`.
     - 'instance': per-channel, per-sample normalization without affine
        (torch InstanceNorm2d default affine=False).
     - 'none': identity.
@@ -555,9 +599,11 @@ class Norm(nn.Module):
             y = nn.GroupNorm(
                 num_groups=x.shape[-1], epsilon=1e-5, use_bias=False, use_scale=False
             )(x32)
+        elif self.kind == "batch" and train:
+            y = BatchNormTrain(momentum=0.9, epsilon=1e-5, name="BatchNorm_0")(x32)
         elif self.kind == "batch":
             y = nn.BatchNorm(
-                use_running_average=not train, momentum=0.9, epsilon=1e-5
+                use_running_average=True, momentum=0.9, epsilon=1e-5
             )(x32)
         else:
             raise ValueError(f"unknown norm kind: {self.kind!r}")

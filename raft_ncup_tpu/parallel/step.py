@@ -63,12 +63,17 @@ def make_train_step(
     ``batch``: dict with image1/image2 (B, H, W, 3) uint8 or float32 in
     [0, 255] (the loader ships uint8; the cast happens on device), flow
     (B, H, W, 2), valid (B, H, W).
+
+    ``step.report["bn_layers_training"]``, once the step has been traced
+    (its first call or ``lower``): the BatchNorm layers whose running
+    statistics a call replaces; 0 where ``freeze_bn`` holds.
     """
     cache_key = _step_cache_key(model.cfg, cfg, mesh)
     cached = _STEP_CACHE.get(cache_key)
     if cached is not None:
         return cached
     freeze_bn = cfg.stage != "chairs"  # reference: train.py:185-186
+    report: dict = {}  # filled while the step is traced; ``jitted.report``
 
     def loss_fn(params, batch_stats, batch, rng):
         img1 = batch["image1"].astype(jnp.float32)
@@ -96,6 +101,13 @@ def make_train_step(
             rngs={"dropout": rng} if model.cfg.dropout > 0 else None,
             mutable=True,
             mesh=mesh,
+        )
+        # What this trace of the step does with the running statistics, for
+        # the loop's counter and gauge (docs/OBSERVABILITY.md): the layers
+        # of every submodule whose statistics the forward replaced.
+        report["bn_layers_training"] = sum(
+            bn_layer_count(new_stats[name]) for name in new_stats
+            if new_stats[name] is not batch_stats.get(name)
         )
         loss, metrics = sequence_loss(
             preds, batch["flow"], batch["valid"], cfg.gamma, cfg.max_flow
@@ -139,10 +151,19 @@ def make_train_step(
             out_shardings=(repl, repl),
             donate_argnums=0,
         )
+    jitted.report = report
     while len(_STEP_CACHE) >= _STEP_CACHE_MAX:
         _STEP_CACHE.pop(next(iter(_STEP_CACHE)))
     _STEP_CACHE[cache_key] = jitted
     return jitted
+
+
+def bn_layer_count(batch_stats) -> int:
+    """BatchNorm layers in a ``batch_stats`` tree: one running mean each."""
+    return sum(
+        1 for path, _ in jax.tree_util.tree_leaves_with_path(batch_stats)
+        if getattr(path[-1], "key", None) == "mean"
+    )
 
 
 def make_synthetic_batch(rng: jax.Array, batch: int, height: int, width: int):

@@ -55,6 +55,12 @@ def scope(name: str):
         stack.pop()
 
 
+def innermost_scope() -> str | None:
+    """The name of the innermost :func:`scope` open in this thread."""
+    stack = _stack()
+    return stack[-1] if stack else None
+
+
 @contextlib.contextmanager
 def suspended():
     """Around code that jax traces LATE, after the scopes around its call

@@ -15,7 +15,11 @@ capture, on the profiler's host plane: ``train_dispatch`` (the jitted
 step's enqueue alone), ``train_throttle_wait`` (where the loop waits for
 the device), the prefetcher's ``input_wait`` / ``input_stage`` /
 ``input_h2d``, the logger's ``train_metrics_pull``; ``train_steps_total``
-and ``train_pairs_total`` grow where ``train_dispatch`` closes. Start-up
+and ``train_pairs_total`` grow where ``train_dispatch`` closes, and with
+them ``train_bn_stat_updates_total`` (BatchNorm layers whose running
+statistics the dispatched step replaced: what the traced step says of
+itself, ``parallel/step.py``) beside the gauge ``train_bn_layers_training``
+(0 on every step that freezes BatchNorm). Start-up
 phases ("Start-up timeline" there), each once per run: ``startup_weights``
 (:func:`open_train_run`), the step's ``startup_trace_lower`` and
 ``startup_compile`` (:func:`_compile_step`), its ``startup_first_run``
@@ -277,6 +281,9 @@ def train_steps(
                 run.state, metrics = run.step(run.state, device_batch, rng)
             tel.inc("train_steps_total")
             tel.inc("train_pairs_total", cfg.batch_size)
+            bn_layers = run.step_fn.report["bn_layers_training"]  # traced by now
+            tel.inc("train_bn_stat_updates_total", bn_layers)
+            tel.gauge_set("train_bn_layers_training", bn_layers)
             run.step_i += 1
             with tel.span("train_throttle_wait", step=run.step_i - 1):
                 run.throttle.push(metrics["loss"])

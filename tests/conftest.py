@@ -79,6 +79,7 @@ LONGEST_FILES_FIRST = (
     "tests/test_train_loop.py",
     "tests/benchmark/test_stream_cell.py",
     "tests/test_drivers.py",
+    "tests/benchmark/test_chairs_cell.py",  # PR 49: 170 s alone
     "tests/test_nconv.py",
     "tests/benchmark/test_eval_mixed_cell.py",
     "tests/test_eval_staging.py",

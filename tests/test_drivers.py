@@ -77,6 +77,35 @@ class TestCli:
         assert train_cfg.validation == ("sintel",)
         assert data_cfg.compressed_ft
 
+    def test_the_chairs_script_is_the_benchmarks_configuration(self):
+        """``scripts/train_raft_chairs.sh`` (upstream RAFT's
+        ``train_standard.sh``, first command) resolves to the ``train`` block
+        of ``benchmark/configs/raft-chairs.json``, the deployment the cell
+        ``train_chairs_raft`` measures, and to its model."""
+        import json
+        import shlex
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        text = open(os.path.join(root, "scripts", "train_raft_chairs.sh")).read()
+        command = text[text.index("python -u train.py") + len("python -u train.py"):]
+        flags = [
+            t.replace("$EXP", "raft-chairs")
+            for t in shlex.split(command.replace("\\\n", " ")) if t != "$@"
+        ]
+        assert "--gpus" not in flags and "--mixed_precision" not in flags
+        _, model_cfg, train_cfg, _ = parse_train(flags)
+        config = json.load(open(os.path.join(root, "benchmark", "configs", "raft-chairs.json")))
+        want = config["train"]
+        for key in ("stage", "lr", "num_steps", "batch_size", "iters", "wdecay", "epsilon",
+                    "clip", "gamma", "max_flow", "optimizer", "scheduler", "add_noise", "sum_freq"):
+            assert getattr(train_cfg, key) == want[key], key
+        assert list(train_cfg.image_size) == want["image_size"]
+        assert train_cfg.validation == ("chairs",) and train_cfg.precision == config["model"]["precision"]
+        assert (train_cfg.stage != "chairs") is want["freeze_bn"]  # parallel/step.py's rule
+        assert model_cfg.freeze_raft is want["freeze_raft"]
+        for key in ("variant", "small", "corr_impl", "corr_levels", "corr_radius", "precision"):
+            assert getattr(model_cfg, key) == config["model"][key], key
+
     def test_eval_parser(self):
         args, model_cfg, data_cfg = parse_eval(
             ["--model", "raft_nc_dbl", "--dataset", "sintel",

@@ -83,6 +83,57 @@ def test_batch_norm_train_and_frozen():
     assert np.abs(new_mean).max() > 0  # stats moved toward batch mean
 
 
+def test_batch_norm_train_keeps_torchs_running_variance():
+    """Training mode (PR 49): statistics over the whole batch in float32, the
+    input normalised by the BIASED variance, the running variance moved toward
+    the UNBIASED one (torch; flax's own module keeps the biased one), under
+    flax's variable names."""
+    rng = np.random.default_rng(1)
+    x = (rng.standard_normal((2, 3, 4, 5)) * 3 - 1).astype(np.float32)
+    mod = Norm("batch")
+    v = mod.init(jax.random.key(0), jnp.asarray(x))
+    assert set(v["batch_stats"]["BatchNorm_0"]) == {"mean", "var"}
+    y, mut = mod.apply(v, jnp.asarray(x), train=True, mutable=["batch_stats"])
+    n = 2 * 3 * 4
+    mu, var = x.mean((0, 1, 2)), x.var((0, 1, 2))
+    np.testing.assert_allclose(np.asarray(y), (x - mu) / np.sqrt(var + 1e-5), atol=2e-6)
+    stats = mut["batch_stats"]["BatchNorm_0"]
+    np.testing.assert_allclose(np.asarray(stats["mean"]), 0.1 * mu, rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(stats["var"]), 0.9 + 0.1 * var * n / (n - 1), rtol=1e-6)
+    assert stats["var"].dtype == jnp.float32
+    # a bfloat16 activation: float32 statistics, the activation's dtype out
+    y16, mut16 = mod.apply(v, jnp.asarray(x, jnp.bfloat16), train=True, mutable=["batch_stats"])
+    assert y16.dtype == jnp.bfloat16 and mut16["batch_stats"]["BatchNorm_0"]["var"].dtype == jnp.float32
+
+
+def test_frozen_batch_norm_lowers_to_the_module_it_did():
+    """The frozen path is flax's own ``BatchNorm`` on its running statistics,
+    untouched by PR 49: the lowered module of ``Norm("batch")`` with
+    ``train=False`` is, to the letter, that of the branch as it stood."""
+    import flax.linen as nn
+
+    class Before(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            y = nn.BatchNorm(use_running_average=True, momentum=0.9, epsilon=1e-5)(
+                x.astype(jnp.float32)
+            )
+            return y.astype(x.dtype)
+
+    x = jnp.ones((2, 6, 5, 3), jnp.bfloat16)
+    v = Norm("batch").init(jax.random.key(0), x)
+
+    def lowered(apply):
+        def fn(v, x):
+            return apply(v, x)
+
+        return jax.jit(fn).lower(v, x).as_text()
+
+    now = lowered(lambda v, x: Norm("batch").apply(v, x, train=False))
+    assert now == lowered(lambda v, x: Before().apply(v, x))
+    assert "reduce" not in now  # no statistic is taken
+
+
 def test_conv2d_torch_default_init_range():
     """torch kaiming_uniform(a=sqrt(5)) => bound sqrt(1/fan_in)."""
     mod = Conv2d(8, 3)

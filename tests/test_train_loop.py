@@ -69,11 +69,38 @@ def test_loop_counts_steps_spans_and_stops_where_told(tmp_path, hub):
         logger.close()
     assert hub.counter_value("train_steps_total") == 5
     assert hub.counter_value("train_pairs_total") == 10
+    # every stage but chairs freezes BatchNorm: no layer's statistics are replaced
+    assert hub.counter_value("train_bn_stat_updates_total") == 0
+    assert hub.registry.get("train_bn_layers_training").value == 0
     stages = telemetry_report(hub)["stages"]
     for name, count in (("train_dispatch", 5), ("train_throttle_wait", 5),
                         ("train_metrics_pull", 1), ("input_wait", 5)):
         assert stages[name]["count"] == count, name
     assert not run.throttle._pending  # every dispatched step has finished
+
+
+def test_the_chairs_stage_publishes_the_layers_its_step_trains(tmp_path, hub):
+    """The chairs stage trains BatchNorm (PR 49): the loop's counter grows by
+    the layers whose running statistics the dispatched step replaced (the
+    flagship's: cnet's 15 and the weights net's 2, carried through the scan),
+    the gauge holds them, and the state's statistics move every step."""
+    train_cfg = TrainConfig(stage="chairs", batch_size=2, image_size=HW, iters=2,
+                            num_steps=10, checkpoint_dir=str(tmp_path))
+    run = open_train_run(flagship_config(dataset="sintel"), train_cfg, DataConfig(num_workers=1),
+                         dataset=SyntheticFlowDataset(HW, length=8))
+    stats = [jax.tree.map(np.asarray, run.state.batch_stats)]
+    try:
+        for n in (1, 2):
+            train_steps(run, lambda i: i >= n)
+            stats.append(jax.tree.map(np.asarray, run.state.batch_stats))
+    finally:
+        run.close()
+    assert run.step_fn.report == {"bn_layers_training": 17}
+    assert hub.counter_value("train_bn_stat_updates_total") == 2 * 17
+    assert hub.registry.get("train_bn_layers_training").value == 17
+    for before, after in zip(stats, stats[1:]):
+        moved = jax.tree.map(lambda a, b: bool(np.any(a != b)), before, after)
+        assert set(moved) == {"cnet", "upsampler"} and all(jax.tree.leaves(moved))
 
 
 def test_run_starts_from_given_weights_and_keeps_the_callers_tree(tmp_path, hub):
